@@ -1,4 +1,4 @@
-"""Config 3 (BASELINE.md): BERT-base SST-2-shaped fine-tune, DP all-reduce.
+"""Config 3 (BASELINE.json): BERT-base SST-2-shaped fine-tune, DP all-reduce.
 
 Metric: trainer samples/sec/chip at SST-2 fine-tune shapes (seq 128, classification
 head), bf16 compute / f32 params, through the framework's device-resident step path.
@@ -98,7 +98,7 @@ def main() -> None:
         f"final loss {result.history[-1]['loss']:.3f}"
     )
     # MFU: fwd+bwd ~ 6 * matmul-params * tokens FLOPs. Embedding gathers are not
-    # FLOPs (BASELINE.md convention, same as bench_llama_lora), so the ~24M
+    # FLOPs (the MFU convention here, same as bench_llama_lora), so the ~24M
     # tok/pos/type embedding params are excluded from the accounting.
     embed_params = sum(
         int(np.prod(p.shape))

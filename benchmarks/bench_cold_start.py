@@ -9,7 +9,7 @@ populated by a previous process, warmup *deserializes* the same executables
 (serving/aot.py) and cold-start-to-first-token becomes load-bound.
 
 Headline: **cold/warm ratio** of ready-to-first-token wall time (higher is
-better — ``run_all.py``'s keep-best accretion applies). The acceptance bar is
+better). The acceptance bar is
 >= 3x on this workload. Each leg runs in its OWN interpreter (via this same
 script's ``--child`` mode) so jit caches cannot leak between legs, and the
 persistent XLA compilation cache is pinned OFF in the children so the cold
@@ -33,7 +33,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from benchmarks.common import emit, log, pin_platform  # noqa: E402
+from benchmarks.common import emit, log  # noqa: E402
 
 BUCKETS = (32, 64, 128)   # three prefill shapes: each is its own compile
 NEW_TOKENS = 8
@@ -46,7 +46,6 @@ PROMPT_LEN = 24
 def _child(store_dir: str) -> None:
     """One fresh-process leg: build the production-shaped engine, warm it,
     serve one request, and report ready/first-token wall times as JSON."""
-    pin_platform()
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -94,8 +93,8 @@ def _child(store_dir: str) -> None:
 
 def _run_leg(store_dir: str) -> dict:
     env = os.environ.copy()
-    # the persistent XLA cache would quietly warm the "cold" leg (run_all
-    # exports it suite-wide); the AOT store must be the only warm path here
+    # the persistent XLA cache would quietly warm the "cold" leg; the AOT
+    # store must be the only warm path here
     env["UNIONML_TPU_COMPILE_CACHE"] = "0"
     env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(

@@ -13,13 +13,12 @@ concurrent generation requests queue serially. There is no reference number;
 the baseline is our own single-stream rate.
 
 ``BENCH_STALL_ONLY=1`` runs the **stall-free admission** lane instead (the
-``continuous_stall`` CPU entry in ``run_all.py``): a prefill-heavy mixed
+``continuous_stall`` CPU lane): a prefill-heavy mixed
 workload — short resident streams decoding while a long prompt admits —
 measured twice, monolithic admission vs chunked (``admit_chunk``), reporting
 the residents' TBT p99/max (the stall a streaming client feels), the long
 prompt's TTFT, and aggregate tok/s. The headline value is the
-monolithic/chunked stall-reduction ratio — higher is better, so run_all's
-keep-best accretion retains the best capture (the acceptance bar is >= 3x on
+monolithic/chunked stall-reduction ratio — higher is better (the acceptance bar is >= 3x on
 this synthetic workload, with aggregate tok/s within ~5%); the chunked TBT
 p99 ms rides along as ``chunked_tbt_p99_ms``.
 
@@ -36,7 +35,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import numpy as np
 
-from benchmarks.common import Timer, emit, log, pin_platform
+from benchmarks.common import Timer, emit, log
 
 import os
 
@@ -118,7 +117,6 @@ def stall_main() -> None:
     the same prefill-heavy workload; the stall shows up as the residents' TBT
     p99 covering the long prompt's whole prefill, and chunking bounds it at
     ~one chunk's dispatch."""
-    pin_platform()
     import jax
     import jax.numpy as jnp
 
@@ -191,10 +189,7 @@ def stall_main() -> None:
 
     _, mono, chunked, stall_reduction, throughput_ratio = best
     emit(
-        # headline value is the reduction RATIO (higher = better), not the raw
-        # TBT ms: run_all's keep-best accretion retains the LARGEST value on a
-        # rerun, so a lower-is-better headline would let a noisy regression
-        # clobber the best capture
+        # headline value is the reduction RATIO (higher = better), not the raw TBT ms
         "continuous_stall_reduction",
         round(stall_reduction, 3),
         "x",
@@ -214,7 +209,6 @@ def stall_main() -> None:
 
 
 def main() -> None:
-    pin_platform()
     import jax
     import jax.numpy as jnp
 

@@ -1,4 +1,4 @@
-"""Config 1 (BASELINE.md): sklearn LogisticRegression digits — the reference README
+"""Config 1 (BASELINE.json): sklearn LogisticRegression digits — the reference README
 quickstart app (reference README.md:56-101), run through the full spec layer.
 
 Metric: trainer samples/sec through ``model.train`` (reader -> split -> parse ->

@@ -20,10 +20,9 @@ The headline is the chaos/no-fault tok/s PARITY ratio, **gated** on the
 replay's availability verdict: every well-behaved tenant's success ratio
 >= 0.99, every fault recovered (first routed token after each onset), and
 every failure clean (a real error record, never a hang). An attempt that
-fails a gate scores zero — run_all's keep-best accretion retains the last
-valid capture.
+fails a gate scores zero.
 
-CPU-substrate by design (run_all pins it CPU_ONLY): it measures the fleet's
+CPU-substrate by design: it measures the fleet's
 degradation posture, not chip speed. Every printed line goes to stderr except
 the final JSON metric line. Usage: ``python benchmarks/bench_fleet_chaos.py``.
 """

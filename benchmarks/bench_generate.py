@@ -29,7 +29,7 @@ import numpy as np
 
 from benchmarks.common import Timer, emit, log
 
-V5E_HBM_BYTES_PER_S = 819e9
+V5E_HBM_BYTES_PER_S = 819e9  # TODO(S2): key by device_kind, with its source
 
 PROXY_LAYERS = 8
 BATCH = 8

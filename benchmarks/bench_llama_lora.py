@@ -1,4 +1,4 @@
-"""Config 4 (BASELINE.md): Llama LoRA fine-tune, FSDP-style sharded params.
+"""Config 4 (BASELINE.json): Llama LoRA fine-tune, FSDP-style sharded params.
 
 Metric: trainer tokens/sec/chip for a LoRA fine-tune (rank-16 adapters on q/k/v/o +
 mlp, base weights frozen via optax.multi_transform) of a Llama-3-family decoder.

@@ -17,7 +17,7 @@ not throughput), with the cross-host prefill→decode handoff transfer_ms
 captured from a second, role-split pass (prefill host → KV pages over the
 wire → decode host).
 
-CPU-substrate by design (run_all pins it CPU_ONLY): it compares two fleet
+CPU-substrate by design: it compares two fleet
 TOPOLOGIES on the same substrate — the process boundary's cost, not chip
 speed. Every printed line goes to stderr except the final JSON metric line.
 Usage: ``python benchmarks/bench_multihost.py``.

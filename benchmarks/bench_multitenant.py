@@ -27,9 +27,9 @@ starting at submit, so admission queueing lands in the first gap — exactly
 the stall a streaming user sees. The headline is the well-behaved-tenant
 TBT-p99 ratio (QoS-off / QoS-on, higher = better, bar >= 3x), scored jointly
 with the aggregate tok/s ratio (bar >= 0.95x) so the isolation is never
-bought with throughput; run_all's keep-best accretion applies.
+bought with throughput.
 
-CPU-substrate by design (run_all pins it CPU_ONLY). Every printed line goes
+CPU-substrate by design. Every printed line goes
 to stderr except the final JSON metric line (stdout).
 Usage: ``python benchmarks/bench_multitenant.py``.
 """
@@ -215,8 +215,7 @@ def main() -> None:
 
     _, off, on, ratio, throughput_ratio = best
     emit(
-        # headline is the isolation RATIO (higher = better) so run_all's
-        # keep-best accretion retains the best capture; bar >= 3x at
+        # headline is the isolation RATIO (higher = better); bar >= 3x at
         # throughput_ratio >= 0.95
         "multitenant_tbt_isolation",
         round(ratio, 3),

@@ -22,7 +22,7 @@ from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
-from benchmarks.common import emit, fence, log
+from benchmarks.common import emit, log
 
 S, H, HKV, D = 8, 8, 2, 128
 BLOCK = 16
@@ -35,11 +35,11 @@ def _time(fn, *args) -> float:
 
     compiled = jax.jit(fn)
     for _ in range(WARMUP):
-        fence(compiled(*args))
+        jax.block_until_ready(compiled(*args))
     start = time.perf_counter()
     for _ in range(ITERS):
         out = compiled(*args)
-    fence(out)
+    jax.block_until_ready(out)
     return (time.perf_counter() - start) / ITERS
 
 

@@ -8,8 +8,7 @@ gathers them from the pool and prefills ONLY its suffix — TTFT drops from
 ~(prefix+suffix) prefill dispatches to ~one chunk.
 
 Headline: **prefill tokens avoided ratio** over the warm phase (avoided
-prefill tokens / total prompt tokens submitted, 0..1, higher is better — so
-``run_all.py``'s keep-best accretion applies). The cold/warm TTFT reduction
+prefill tokens / total prompt tokens submitted, 0..1, higher is better). The cold/warm TTFT reduction
 rides along (the acceptance signal: >= 2x on this workload).
 
 CPU-substrate by design (a ratio of two same-substrate runs through one warm
@@ -28,7 +27,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import numpy as np  # noqa: E402
 
-from benchmarks.common import emit, log, pin_platform  # noqa: E402
+from benchmarks.common import emit, log  # noqa: E402
 
 SYSTEM_LEN = 224   # the shared system prompt every request extends
 SUFFIX_LEN = 8     # the per-request unique tail
@@ -96,7 +95,6 @@ def _attempt(module, params, cfg, make_prompts):
 
 
 def main() -> None:
-    pin_platform()
     import jax
     import jax.numpy as jnp
 
@@ -145,7 +143,7 @@ def main() -> None:
 
     emit(
         # headline is the avoided RATIO (higher = better, deterministic for
-        # the workload) so keep-best accretion retains the best capture; the
+        # the workload); the
         # TTFT reduction — the latency the avoidance buys — rides along
         "prefix_cache_tokens_avoided_ratio",
         round(best["avoided_ratio"], 3),

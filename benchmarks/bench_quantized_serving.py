@@ -12,9 +12,9 @@ Method: two continuous engines over the same model share one POOL BYTE BUDGET
 — the bf16 arm gets ``budget // bf16_block_bytes`` blocks, the int8 arm
 (``--quantize int8 --kv-cache-dtype int8``: int8 weights AND int8 KV)
 ``budget // int8_block_bytes``. The same burst of concurrent unique prompts
-runs through each; a watcher samples ``stats()["resident"]`` for the realized
+runs through each; a sampler thread reads ``stats()["resident"]`` for the realized
 peak residency. Headline: **max-resident-streams ratio** (int8 / bf16, higher
-is better so ``run_all.py``'s keep-best accretion applies; acceptance bar
+is better; acceptance bar
 >= 1.8x). Aggregate tok/s for both arms rides along.
 
 Win-or-cut quality gate (token-identity-RELAXED — int8 is lossy by design, so
@@ -40,7 +40,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
 import numpy as np  # noqa: E402
 
-from benchmarks.common import emit, log, pin_platform  # noqa: E402
+from benchmarks.common import emit, log  # noqa: E402
 
 PROMPT_LEN = 64
 NEW_TOKENS = 32
@@ -72,7 +72,7 @@ def _pool_block_bytes(config, kv_dtype) -> int:
 
 def _run_arm(module, params, cfg, quantize, pool_blocks, prompts):
     """One engine at its block budget under the shared burst: returns the
-    watcher-sampled peak residency, wall time, and aggregate tok/s."""
+    sampled peak residency, wall time, and aggregate tok/s."""
     from unionml_tpu.models import Generator
     from unionml_tpu.serving import ContinuousBatcher
 
@@ -94,8 +94,8 @@ def _run_arm(module, params, cfg, quantize, pool_blocks, prompts):
                 peak[0] = max(peak[0], batcher.stats()["resident"])
                 time.sleep(0.002)
 
-        watcher = threading.Thread(target=watch, daemon=True)
-        watcher.start()
+        sampler = threading.Thread(target=watch, daemon=True)
+        sampler.start()
         results = [0] * len(prompts)
 
         def drain(i):
@@ -110,7 +110,7 @@ def _run_arm(module, params, cfg, quantize, pool_blocks, prompts):
             t.join(timeout=600)
         wall = time.perf_counter() - start
         stop.set()
-        watcher.join(timeout=5)
+        sampler.join(timeout=5)
         tokens = sum(results)
         return {
             "peak_resident": peak[0],
@@ -151,7 +151,6 @@ def _quality_agreement(module, config, params, cfg, prompts) -> float:
 
 
 def main() -> None:
-    pin_platform()
     import jax
     import jax.numpy as jnp
 
@@ -213,7 +212,7 @@ def main() -> None:
 
     emit(
         # headline: resident streams per byte of KV pool, int8 over bf16
-        # (higher is better, so keep-best accretion retains the best capture)
+        # (higher is better)
         "quantized_serving_residency_ratio",
         round(best["ratio"], 3),
         "ratio",

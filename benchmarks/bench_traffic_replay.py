@@ -15,10 +15,9 @@ The headline is the suite's aggregate tok/s, **gated** on the replay being a
 valid judgment: wall-clock schedule adherence >= 0.95 (a harness that fell
 behind its own trace measured itself, not the server), every well-behaved
 tenant's SLO verdict passing, and the hostile burst tenant actually shedding
-against its bucket. An attempt that fails a gate scores zero — run_all's
-keep-best accretion then retains the last valid capture.
+against its bucket. An attempt that fails a gate scores zero.
 
-CPU-substrate by design (run_all pins it CPU_ONLY): the lane pins scheduling
+CPU-substrate by design: the lane pins scheduling
 and front-door behavior under realistic arrivals, not chip throughput. Every
 printed line goes to stderr except the final JSON metric line (stdout).
 Usage: ``python benchmarks/bench_traffic_replay.py``.
@@ -213,7 +212,7 @@ def main() -> None:
     emit(
         # headline: the four-scenario suite's aggregate tok/s through the real
         # HTTP stack with all gates green (adherence >= 0.95, well-behaved
-        # verdicts pass, hostile tenant sheds); keep-best accretion applies
+        # verdicts pass, hostile tenant sheds)
         "traffic_replay_tokens_per_s",
         round(score, 1),
         "tok/s",

@@ -1,0 +1,105 @@
+"""The chip's compiler, without the chip: every shipped Pallas kernel is compiled for
+a *described* TPU v5e at the shapes ``chip_smoke.py`` runs, so a kernel Mosaic
+refuses fails here, at no chip time (interpret mode accepts layouts the chip does
+not). Nothing executes; a compile that passes is not a chip run. Also: the smoke
+itself must fail, and claim nothing, where there is no TPU."""
+
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from unionml_tpu.ops.flash_attention import flash_attention
+from unionml_tpu.ops.int8_matmul import int8_matmul
+from unionml_tpu.ops.paged_attention import paged_decode_attention
+
+REPO = Path(__file__).resolve().parents[2]
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """One device of a described v5e 2x2; the persistent compilation cache is off
+    around these tests (a chip-less process cannot read such entries back)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs under /tmp
+    try:
+        topology = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except (RuntimeError, NotImplementedError) as exc:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {exc}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topology.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _flash(direction):
+    def forward(q, k, v):
+        return flash_attention(q, k, v, causal=True)
+
+    def backward(q, k, v):
+        return jax.grad(lambda *a: forward(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(q, k, v)
+
+    q, kv = ((4, 1024, 32, 128), jnp.bfloat16), ((4, 1024, 8, 128), jnp.bfloat16)  # GQA 32/8, D=128
+    return (forward if direction == "fwd" else backward), [q, kv, kv]
+
+
+def _int8_matmul(m):
+    return int8_matmul, [((m, 4096), jnp.bfloat16), ((4096, 14336), jnp.int8), ((1, 14336), jnp.float32)]
+
+
+def _paged(int8):
+    pages = ((8, 512, 64, 128), jnp.int8 if int8 else jnp.bfloat16)  # 8 KV heads x 512 pages of 64
+    args = [((8, 32, 128), jnp.bfloat16), pages, pages, ((8,), jnp.int32), ((8, 64), jnp.int32)]
+    if not int8:
+        return paged_decode_attention, args
+    scales = ((8, 512, 64, 1), jnp.float32)
+
+    def quantized(q, k, v, lengths, table, k_scales, v_scales):
+        return paged_decode_attention(q, k, v, lengths, table, k_scales=k_scales, v_scales=v_scales)
+
+    return quantized, args + [scales, scales]
+
+
+CASES = {
+    "flash_fwd": _flash("fwd"),
+    "flash_bwd": _flash("bwd"),
+    "int8_matmul_m8": _int8_matmul(8),
+    "int8_matmul_m1": _int8_matmul(1),
+    "paged_decode_bf16": _paged(int8=False),
+    "paged_decode_int8": _paged(int8=True),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(chip, case):
+    fn, shapes = CASES[case]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would raise
+    assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the compiled program"
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_fails_and_claims_nothing_without_a_tpu(tmp_path, where):
+    """No accelerator, or nothing of the repo beside the script: a non-zero exit and
+    no success line — never a CPU run reported as a chip run."""
+    script = REPO / "chip_smoke.py"
+    cwd = REPO
+    if where == "alone":
+        cwd = tmp_path
+        script = Path(shutil.copy(script, tmp_path))
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    env["JAX_PLATFORMS"] = "cpu"
+    done = subprocess.run([sys.executable, str(script)], cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+    assert done.returncode != 0, done.stdout[-2000:]
+    assert '"ok": true' not in done.stdout

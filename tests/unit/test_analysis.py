@@ -373,14 +373,14 @@ def test_tpu004_flags_blocking_in_loops_and_async(tmp_path):
 
 
 def test_tpu004_near_miss_plain_method(tmp_path):
-    # a throttle in a plain watcher method (not a handler, not a *_loop, not
+    # a throttle in a plain poller method (not a handler, not a *_loop, not
     # async) is ordinary host code
     result = lint_source(
         tmp_path,
         """
         import time
 
-        class Watcher:
+        class Poller:
             def poll(self):
                 time.sleep(0.5)
 
@@ -1953,7 +1953,7 @@ def test_tpu015_flags_unbounded_retry_loops(tmp_path):
 def test_tpu015_bounded_and_paced_loops_stay_clean(tmp_path):
     # the three brakes: a bounded for-range envelope (the
     # RemoteHost._call_retry shape), a Compare-bounded while (attempt counter
-    # or deadline), and an Event.wait-paced watcher loop — plus the walk of a
+    # or deadline), and an Event.wait-paced polling loop — plus the walk of a
     # finite host list, which is one attempt per host, not a retry
     result = lint_source(
         tmp_path,

@@ -76,7 +76,8 @@ def test_quantized_generation_runs_and_is_deterministic():
 
 def test_int8_matmul_kernel_matches_dequant_reference():
     """Pallas kernel (interpret mode on CPU) vs dequant + dot, several shapes
-    incl. M needing padding and the fallback path for untileable shapes."""
+    incl. M needing padding; an untileable shape is an error under
+    ``impl="pallas"``, never a quiet dequant path."""
     from unionml_tpu.ops.int8_matmul import int8_matmul, quantized_matmul
 
     rng = np.random.default_rng(1)
@@ -88,10 +89,12 @@ def test_int8_matmul_kernel_matches_dequant_reference():
         scale_ref = np.abs(ref).max() + 1e-9
         assert np.abs(out - ref).max() / scale_ref < 0.01  # bf16 x-cast rounding
 
-    # untileable weight shape: quantized_matmul silently takes the dequant path
+    # untileable weight shape: the asked-for kernel cannot run, and says so
     qt = quantize_array(rng.normal(size=(96, 100)).astype(np.float32))
     x = jnp.asarray(rng.normal(size=(4, 96)), jnp.float32)
-    out = quantized_matmul(x, qt, out_dtype=jnp.float32, impl="pallas")
+    with pytest.raises(ValueError, match="no block tiling"):
+        quantized_matmul(x, qt, out_dtype=jnp.float32, impl="pallas")
+    out = quantized_matmul(x, qt, out_dtype=jnp.float32)
     ref = np.asarray(x) @ (np.asarray(qt.q, np.float32) * np.asarray(qt.scale))
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5, atol=1e-5)
 

@@ -10,7 +10,7 @@ stall, not a per-request cost.
 
 Scope: functions defined with ``async def`` (anywhere), plus sync methods
 whose names mark them as serving loops (``*_loop``) or handlers
-(``handle*``/``on_*``). A deliberate throttle in a watcher loop belongs in a
+(``handle*``/``on_*``). A deliberate throttle in a polling loop belongs in a
 plain helper thread — or carries a justified suppression.
 """
 

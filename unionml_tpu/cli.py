@@ -1154,7 +1154,7 @@ def _serve_with_reload(app_ref: str, poll_interval: float = 0.5) -> None:
         argv.append(arg)
     current: "list[Any]" = [None]
 
-    def forward_term(signum, frame):  # terminating the watcher must stop the server
+    def forward_term(signum, frame):  # terminating the reload parent must stop the server
         if current[0] is not None and current[0].poll() is None:
             current[0].terminate()
         raise SystemExit(0)

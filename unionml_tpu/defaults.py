@@ -466,8 +466,8 @@ _FALSY_FLAGS = ("", "0", "false", "no", "off")
 def _env_path_flag(name: str, default_dir: str) -> "str | None":
     """Parse a path-or-flag env var: off-flags (and unset) mean None, truthy
     flags mean ``default_dir``, anything else is the path itself. Whether the
-    path is *usable* is the consumer's concern — ProgramStore/compile_cache
-    warn and degrade on an unwritable directory (the serve-export contract:
+    path is *usable* is the consumer's concern — ProgramStore
+    warns and degrades on an unwritable directory (the serve-export contract:
     a garbage value must never crash serve at app-import time)."""
     raw = os.environ.get(name)
     if raw is None:
@@ -478,14 +478,6 @@ def _env_path_flag(name: str, default_dir: str) -> "str | None":
     if value.lower() in _TRUTHY_FLAGS:
         return default_dir
     return value
-
-
-def serve_compile_cache() -> "str | None":
-    """The persistent XLA compilation cache directory
-    (``UNIONML_TPU_COMPILE_CACHE``); None = off. The package-import hook in
-    compile_cache.py is the normal consumer — this reader exists for code
-    that wants the resolved path (the cold-start bench, diagnostics)."""
-    return _env_path_flag(SERVE_COMPILE_CACHE_ENV_VAR, "~/.cache/unionml_tpu/xla")
 
 
 def serve_aot_preload() -> "str | None":

@@ -71,18 +71,12 @@ def maybe_initialize() -> bool:
     import jax
 
     if os.environ.get("JAX_PLATFORMS") == "cpu":
-        # emulated multi-host lane: a TPU plugin on the path would win over the
-        # env var, so pin the platform before the backend initializes
-        jax.config.update("jax_platforms", "cpu")
-        try:
-            # CROSS-PROCESS computations on the CPU backend need the gloo
-            # collectives implementation picked before the backend forms —
-            # without it every multiprocess dispatch (multihost_utils
-            # broadcasts included) fails with "Multiprocess computations
-            # aren't implemented on the CPU backend"
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # older/newer jax without the knob: leave the default
-            pass
+        # emulated multi-host lane: CROSS-PROCESS computations on the CPU
+        # backend need the gloo collectives implementation picked before the
+        # backend forms — without it every multiprocess dispatch
+        # (multihost_utils broadcasts included) fails with "Multiprocess
+        # computations aren't implemented on the CPU backend"
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     num_processes = distributed_num_processes()
     process_id = distributed_process_id()
     jax.distributed.initialize(

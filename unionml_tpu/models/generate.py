@@ -656,24 +656,14 @@ class Generator:
             vs = tuple(kvs[f"layer_{i}"]["attn"]["v"][0] for i in range(n_layers))
             return hidden, ks, vs
 
-        try:
-            from jax import shard_map
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
-
         tok_spec = P(data_axes, "sequence")
         act_spec = P(data_axes, "sequence", None)
         kv_spec = P(data_axes, "sequence", None, None)
         out_specs = (act_spec, (kv_spec,) * n_layers, (kv_spec,) * n_layers)
         in_specs = (tok_spec, tok_spec, P())
-        try:
-            wrapped = shard_map(
-                local_fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
-            )
-        except TypeError:  # older API spells the replication-check flag differently
-            wrapped = shard_map(
-                local_fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
-            )
+        wrapped = jax.shard_map(
+            local_fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+        )
 
         def sp_prefill(p, tokens, lengths, cache, key, row_valid, *cstate):
             self.prefill_traces += 1

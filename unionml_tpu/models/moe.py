@@ -147,25 +147,17 @@ def _constrain(x: jax.Array, spec: P) -> jax.Array:
     for entry in spec:
         if entry is not None:
             names.update(entry if isinstance(entry, tuple) else (entry,))
-    # mesh discovery may drift across jax versions — degrade to "no mesh visible";
-    # but once a mesh with the right axes is found, constraint errors must surface
+    # once a mesh with the right axes is found, constraint errors must surface
     # (a swallowed error here silently turns expert parallelism into replication)
-    abstract = None
-    try:
-        abstract = jax.sharding.get_abstract_mesh()  # set by jax.sharding.use_mesh
-    except AttributeError:
-        pass
-    if abstract is not None and not abstract.empty:
+    abstract = jax.sharding.get_abstract_mesh()  # set by jax.sharding.use_mesh
+    if not abstract.empty:
         if not names.issubset(abstract.axis_names):
             return x
         return jax.lax.with_sharding_constraint(x, spec)
-    try:
-        # `with mesh:` (Mesh context manager) sets only the physical mesh
-        from jax._src.mesh import thread_resources
+    # `with mesh:` (Mesh context manager) sets only the physical mesh
+    from jax._src.mesh import thread_resources
 
-        mesh = thread_resources.env.physical_mesh
-    except ImportError:
-        return x
+    mesh = thread_resources.env.physical_mesh
     if mesh.empty or not names.issubset(mesh.axis_names):
         return x
     return jax.lax.with_sharding_constraint(x, jax.sharding.NamedSharding(mesh, spec))

@@ -9,9 +9,11 @@ sequential over its innermost dimension, so scratch persists across the k-block 
 
 Layout decisions (each mandated by the TPU memory system):
 
-- the kernel indexes ``[B, L, H, D]`` inputs directly with a 4D grid
-  ``(batch, heads, q_blocks, k_blocks)`` — no head-folding transpose, so Q/K/V
-  never take an extra HBM round trip before/after the kernel;
+- the wrapper views ``[B, L, H, D]`` inputs heads-major (``[B, H, L, D]``) and
+  the 4D grid ``(batch, heads, q_blocks, k_blocks)`` takes ``(block, D)`` tiles
+  with batch and head squeezed: Mosaic tiles a block's last two dims (multiples
+  of 8 and 128, or the whole axis), so a size-1 head block cannot sit
+  second-to-last;
 - grouped-query attention happens in the K/V index maps (query head ``h`` reads
   KV head ``h * n_kv // n_heads``) — repeated KV heads are never materialized;
 - ``dimension_semantics`` marks batch/head/q-block dims parallel and the k-block
@@ -20,7 +22,7 @@ Layout decisions (each mandated by the TPU memory system):
   buffer pads to a full lane register anyway and forces relayouts.
 
 Backward: fused FlashAttention-2-style pallas kernels. The forward additionally
-saves the per-row logsumexp (``[B, H, Lq]``, lane-major blocks); the backward
+saves the per-row logsumexp (``[B, H, 1, Lq]``, ``(1, block_q)`` lane-major blocks); the backward
 recomputes scores blockwise from it (``P = exp(S - lse)``), so the ``[L, L]``
 matrix never exists in HBM in either direction — training memory stays
 O(L * D + L), which is the whole point for long context. Two kernels:
@@ -45,10 +47,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU plugin module; without it the kernel (interpret mode included) is unusable
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -77,9 +76,9 @@ def _flash_fwd_kernel(
         # PV hits the MXU's native rate, while an up-front f32 cast would halve
         # it — the whole reason the hand kernel can beat XLA's fused attention.
         # Scale is applied to the f32 scores, not the bf16 operands.
-        q = q_ref[0, :, 0, :]  # [block_q, D]
-        k = k_ref[0, :, 0, :]  # [block_k, D]
-        v = v_ref[0, :, 0, :]  # [block_k, D]
+        q = q_ref[...]  # [block_q, D]
+        k = k_ref[...]  # [block_k, D]
+        v = v_ref[...]  # [block_k, D]
         scores = (
             jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
             * scale
@@ -117,13 +116,13 @@ def _flash_fwd_kernel(
     def _finalize():
         l_final = l_scratch[:, :1]
         denom = jnp.where(l_final == 0.0, 1.0, l_final)
-        o_ref[0, :, 0, :] = (acc_scratch[:] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_scratch[:] / denom).astype(o_ref.dtype)
         # logsumexp per row, saved for the fused backward: P = exp(S - lse).
         # Fully-masked rows get +BIG so the backward's exp underflows to 0.
         lse = jnp.where(
             l_final == 0.0, jnp.float32(_BIG), m_scratch[:, :1] + jnp.log(denom)
         )
-        lse_ref[0, 0, :] = lse[:, 0]
+        lse_ref[0, :] = lse[:, 0]
 
 
 def _flash_forward(
@@ -140,18 +139,17 @@ def _flash_forward(
         raise ValueError(f"blocks ({block_q}, {block_k}) do not tile lengths ({q_len}, {k_len})")
     scale = head_dim**-0.5
 
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU backend unavailable; use impl='xla' attention instead")
-
-    # 4D grid over [B, L, H, D] directly — no head-folding transpose; KV heads are
-    # resolved in the index maps (GQA without materializing repeats)
+    # heads-major [B, H, L, D] views: Mosaic tiles a block's last two dims, so
+    # (L, D) must be the trailing pair; KV heads are resolved in the index maps
+    # (GQA without materializing repeats)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     grid = (batch, n_heads, q_len // block_q, k_len // block_k)
 
     def q_index(b, h, qi, ki):
-        return (b, qi, h, 0)
+        return (b, h, qi, 0)
 
     def kv_index(b, h, qi, ki):
-        return (b, ki, h * n_kv // n_heads, 0)
+        return (b, h * n_kv // n_heads, ki, 0)
 
     kernel = functools.partial(
         _flash_fwd_kernel, causal=causal, block_q=block_q, block_k=block_k, scale=scale, offset=k_len - q_len
@@ -161,17 +159,17 @@ def _flash_forward(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((batch, n_heads, q_len), jnp.float32),
+            jax.ShapeDtypeStruct((batch, n_heads, 1, q_len), jnp.float32),
         ),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, head_dim), q_index),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index),
+            pl.BlockSpec((None, None, block_q, head_dim), q_index),
+            pl.BlockSpec((None, None, block_k, head_dim), kv_index),
+            pl.BlockSpec((None, None, block_k, head_dim), kv_index),
         ],
         out_specs=(
-            pl.BlockSpec((1, block_q, 1, head_dim), q_index),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((None, None, block_q, head_dim), q_index),
+            pl.BlockSpec((None, None, 1, block_q), _stats_index),
         ),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -181,7 +179,12 @@ def _flash_forward(
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out.transpose(0, 2, 1, 3), lse
+
+
+def _stats_index(b, h, qi, ki):
+    """Block index of the per-row statistics (lse, delta), stored ``[B, H, 1, Lq]``."""
+    return (b, h, 0, qi)
 
 
 def _compiler_params(interpret: bool):
@@ -195,12 +198,12 @@ def _bwd_recompute(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *, c
     and return (q, k, ds, p, do) — operands in the input dtype (MXU-native),
     p/ds in f32 — the dq and dk/dv kernels consume the same quantities, so
     masking/recompute fixes land in exactly one place."""
-    q = q_ref[0, :, 0, :]
-    k = k_ref[0, :, 0, :]
-    v = v_ref[0, :, 0, :]
-    do = do_ref[0, :, 0, :]
-    lse = lse_ref[0, 0, :][:, None]  # [block_q, 1]
-    delta = delta_ref[0, 0, :][:, None]
+    q = q_ref[...]
+    k = k_ref[...]
+    v = v_ref[...]
+    do = do_ref[...]
+    lse = lse_ref[0, :][:, None]  # [block_q, 1]
+    delta = delta_ref[0, :][:, None]
 
     scores = scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -244,7 +247,7 @@ def _flash_bwd_dq_kernel(
 
     @pl.when(ki == num_k - 1)
     def _finalize():
-        dq_ref[0, :, 0, :] = dq_acc[:].astype(dq_ref.dtype)
+        dq_ref[...] = dq_acc[:].astype(dq_ref.dtype)
 
 
 def _flash_bwd_dkv_kernel(
@@ -282,8 +285,8 @@ def _flash_bwd_dkv_kernel(
 
     @pl.when(qi == num_q - 1)
     def _finalize():
-        dk_ref[0, :, 0, :] = dk_acc[:].astype(dk_ref.dtype)
-        dv_ref[0, :, 0, :] = dv_acc[:].astype(dv_ref.dtype)
+        dk_ref[...] = dk_acc[:].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[:].astype(dv_ref.dtype)
 
 
 def _flash_backward(q, k, v, out, lse, g, causal: bool, interpret: bool, blocks=None):
@@ -301,66 +304,47 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, interpret: bool, blocks=
     scale = head_dim**-0.5
     offset = k_len - q_len
 
-    # delta_i = rowsum(dO_i * O_i), the dS correction term; [B, H, Lq] like lse
+    # delta_i = rowsum(dO_i * O_i), the dS correction term; [B, H, 1, Lq] like lse
     delta = jnp.einsum(
         "blhd,blhd->bhl", g.astype(jnp.float32), out.astype(jnp.float32)
-    )
+    )[:, :, None, :]
+    q, k, v, g = (x.transpose(0, 2, 1, 3) for x in (q, k, v, g))  # heads-major, as the forward
 
-    def q_index(b, h, qi, ki):
-        return (b, qi, h, 0)
-
-    def kv_index_dq(b, h, qi, ki):
-        return (b, ki, h * n_kv // n_heads, 0)
-
-    def stats_index(b, h, qi, ki):
-        return (b, h, qi)
-
+    # dq: grid (b, h, q blocks, k blocks), accumulating over k blocks (ki innermost)
+    q_spec = pl.BlockSpec((None, None, block_q, head_dim), lambda b, h, qi, ki: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, head_dim), lambda b, h, qi, ki: (b, h * n_kv // n_heads, ki, 0))
+    stats_spec = pl.BlockSpec((None, None, 1, block_q), _stats_index)
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, causal=causal, block_q=block_q, block_k=block_k, scale=scale, offset=offset
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid=(batch, n_heads, q_len // block_q, k_len // block_k),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, head_dim), q_index),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index_dq),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index_dq),
-            pl.BlockSpec((1, block_q, 1, head_dim), q_index),
-            pl.BlockSpec((1, 1, block_q), stats_index),
-            pl.BlockSpec((1, 1, block_q), stats_index),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, 1, head_dim), q_index),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stats_spec, stats_spec],
+        out_specs=q_spec,
         scratch_shapes=[pltpu.VMEM((block_q, head_dim), jnp.float32)],
         compiler_params=_compiler_params(interpret),
         interpret=interpret,
     )(q, k, v, g, lse, delta)
 
-    # dk/dv accumulate over q blocks (qi innermost); computed at full query-head
-    # resolution, then group-summed for GQA (repeat's transpose is a sum)
-    def kv_index_dkv(b, h, ki, qi):
-        return (b, ki, h * n_kv // n_heads, 0)
-
+    # dk/dv: grid (b, h, k blocks, q blocks), accumulating over q blocks (qi
+    # innermost); computed at full query-head resolution, then group-summed for
+    # GQA (repeat's transpose is a sum)
+    q_spec = pl.BlockSpec((None, None, block_q, head_dim), lambda b, h, ki, qi: (b, h, qi, 0))
+    kv_spec = pl.BlockSpec((None, None, block_k, head_dim), lambda b, h, ki, qi: (b, h * n_kv // n_heads, ki, 0))
+    stats_spec = pl.BlockSpec((None, None, 1, block_q), lambda b, h, ki, qi: (b, h, 0, qi))
+    dkv_spec = pl.BlockSpec((None, None, block_k, head_dim), lambda b, h, ki, qi: (b, h, ki, 0))
     dk_full, dv_full = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, causal=causal, block_q=block_q, block_k=block_k, scale=scale, offset=offset
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((batch, k_len, n_heads, head_dim), k.dtype),
-            jax.ShapeDtypeStruct((batch, k_len, n_heads, head_dim), v.dtype),
+            jax.ShapeDtypeStruct((batch, n_heads, k_len, head_dim), k.dtype),
+            jax.ShapeDtypeStruct((batch, n_heads, k_len, head_dim), v.dtype),
         ),
         grid=(batch, n_heads, k_len // block_k, q_len // block_q),
-        in_specs=[
-            pl.BlockSpec((1, block_q, 1, head_dim), lambda b, h, ki, qi: (b, qi, h, 0)),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index_dkv),
-            pl.BlockSpec((1, block_k, 1, head_dim), kv_index_dkv),
-            pl.BlockSpec((1, block_q, 1, head_dim), lambda b, h, ki, qi: (b, qi, h, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi: (b, h, qi)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, ki, qi: (b, h, qi)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block_k, 1, head_dim), lambda b, h, ki, qi: (b, ki, h, 0)),
-            pl.BlockSpec((1, block_k, 1, head_dim), lambda b, h, ki, qi: (b, ki, h, 0)),
-        ),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, stats_spec, stats_spec],
+        out_specs=(dkv_spec, dkv_spec),
         scratch_shapes=[
             pltpu.VMEM((block_k, head_dim), jnp.float32),
             pltpu.VMEM((block_k, head_dim), jnp.float32),
@@ -371,11 +355,9 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, interpret: bool, blocks=
 
     if n_kv != n_heads:
         group = n_heads // n_kv
-        dk = dk_full.reshape(batch, k_len, n_kv, group, head_dim).sum(axis=3).astype(k.dtype)
-        dv = dv_full.reshape(batch, k_len, n_kv, group, head_dim).sum(axis=3).astype(v.dtype)
-    else:
-        dk, dv = dk_full, dv_full
-    return dq, dk, dv
+        dk_full = dk_full.reshape(batch, n_kv, group, k_len, head_dim).sum(axis=2).astype(k.dtype)
+        dv_full = dv_full.reshape(batch, n_kv, group, k_len, head_dim).sum(axis=2).astype(v.dtype)
+    return tuple(x.transpose(0, 2, 1, 3) for x in (dq, dk_full, dv_full))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

@@ -15,23 +15,19 @@ to a fused form that beats this kernel (~1.4x vs ~1.2x over bf16), so — same
 policy as the flash-attention kernel — the generation path keeps the XLA dequant
 (:func:`unionml_tpu.ops.quant.dequantize_tree` inside the step) and this kernel
 stays **opt-in** via :func:`quantized_matmul(..., impl="pallas")` until it wins
-its benchmark. Off-TPU (or for shapes with no block-aligned tiling) it falls
-back to dequant + ``jnp.dot`` with identical numerics.
+its benchmark. Asking for the kernel where it cannot run (off-TPU without
+``interpret=True``, or a weight with no block-aligned tiling) is an error, never
+a quiet dequant + ``jnp.dot``.
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU plugin module; interpret mode works without it
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["int8_matmul", "quantized_matmul"]
 
@@ -109,10 +105,10 @@ def int8_matmul(
             pl.BlockSpec((1, block_f), lambda mi, fi, ki: (0, fi)),
         ],
         out_specs=pl.BlockSpec((block_m, block_f), lambda mi, fi, ki: (mi, fi)),
-        scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)] if pltpu else [],
+        scratch_shapes=[pltpu.VMEM((block_m, block_f), jnp.float32)],
         compiler_params=(
             None
-            if interpret or pltpu is None
+            if interpret
             else pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"))
         ),
         interpret=interpret,
@@ -120,31 +116,20 @@ def int8_matmul(
     return out[:m] if padded_m != m else out
 
 
-@functools.lru_cache(maxsize=1)
-def _on_tpu() -> bool:
-    try:
-        return pltpu is not None and jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
-
-
 def quantized_matmul(x: jax.Array, qt: Any, *, out_dtype: Any = None, impl: str = "xla") -> jax.Array:
     """Matmul against a :class:`~unionml_tpu.ops.quant.QuantizedTensor` weight.
 
     ``impl="xla"`` (default — currently faster, see module docstring) dequantizes
-    in-graph and lets XLA fuse; ``impl="pallas"`` uses the kernel (TPU only,
-    block-tileable shapes; silently falls back otherwise). ``x`` may carry
-    leading batch dims; the weight must be 2D.
+    in-graph and lets XLA fuse; ``impl="pallas"`` uses the kernel and raises
+    where it cannot run (off-TPU, or a weight shape with no block tiling).
+    ``x`` may carry leading batch dims; the weight must be 2D.
     """
+    if impl not in ("xla", "pallas"):
+        raise ValueError(f"unknown impl {impl!r}; expected 'xla' or 'pallas'")
     out_dtype = out_dtype or x.dtype
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
-    if (
-        impl == "pallas"
-        and _on_tpu()
-        and _pick_block(qt.q.shape[0], _K_CANDIDATES)
-        and _pick_block(qt.q.shape[1], _F_CANDIDATES)
-    ):
+    if impl == "pallas":
         out = int8_matmul(x2, qt.q, qt.scale, out_dtype=out_dtype)
     else:
         w = (qt.q.astype(jnp.float32) * qt.scale).astype(out_dtype)

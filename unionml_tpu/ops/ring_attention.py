@@ -145,18 +145,10 @@ def sequence_sharded_attention(
     """Jit-level sequence-parallel attention: shards sequence over ``sequence_axis``,
     batch over ``batch_axes``, runs :func:`ring_attention` (``impl="ring"``) or
     :func:`ulysses_attention` (``impl="ulysses"``) under ``shard_map``."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-
     present_batch = tuple(a for a in batch_axes if mesh.shape.get(a, 1) > 1) or None
     spec = P(present_batch, sequence_axis, None, None)
 
     sp_attention = {"ring": ring_attention, "ulysses": ulysses_attention}[impl]
     fn = functools.partial(sp_attention, axis=sequence_axis, causal=causal)
-    try:
-        wrapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
-    except TypeError:  # older API spells the replication-check flag differently
-        wrapped = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+    wrapped = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return wrapped(q, k, v)

@@ -33,10 +33,17 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.experimental import mesh_utils
 from jax.sharding import Mesh
+
+from unionml_tpu._logging import logger
 
 #: Canonical axis ordering — outermost (slowest-varying, DCN-adjacent) first.
 AXIS_ORDER: Tuple[str, ...] = ("dcn_data", "data", "fsdp", "pipe", "sequence", "expert", "model")
+
+#: what ``mesh_utils`` raises for a device set it has no assignment for (a hole
+#: in the cuboid, an unsupported shape); anything else is a bug and propagates
+_TOPOLOGY_ERRORS = (AssertionError, NotImplementedError, ValueError)
 
 #: Axes over which the batch dimension is sharded.
 BATCH_AXES: Tuple[str, ...] = ("dcn_data", "data", "fsdp")
@@ -86,10 +93,13 @@ class MeshSpec:
         sizes = self.axis_sizes(len(devices))
         shape = tuple(sizes[name] for name in AXIS_ORDER)
         try:
-            from jax.experimental import mesh_utils
-
             device_array = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
+        except _TOPOLOGY_ERRORS as exc:
+            # e.g. a replica submesh that is not a contiguous cuboid of the slice
+            logger.warning(
+                f"no topology-aware layout for mesh {shape} over {len(devices)} devices "
+                f"({type(exc).__name__}: {exc}); using id order — collectives may leave ICI neighbours"
+            )
             device_array = np.asarray(devices).reshape(shape)
         return Mesh(device_array, AXIS_ORDER)
 
@@ -107,8 +117,8 @@ class MeshSpec:
         serving fleet that is ``dcn_data`` (or ``data``), exactly the
         per-replica split :func:`unionml_tpu.serving.replicas.slice_mesh`
         cuts along, so each host's replicas are host-local by construction.
-        Falls back to a process-grouped reshape when ``mesh_utils`` cannot
-        build the topology (CPU emulation without locality metadata)."""
+        Falls back to a process-grouped reshape, with a warning that names
+        the reason, when ``mesh_utils`` cannot build the topology."""
         devices = list(jax.devices()) if devices is None else list(devices)
         sizes = self.axis_sizes(len(devices))
         n_processes = len({d.process_index for d in devices})
@@ -132,15 +142,16 @@ class MeshSpec:
         ici_shape = tuple(1 if name in dcn_axes else sizes[name] for name in AXIS_ORDER)
         dcn_shape = tuple(sizes[name] if name in dcn_axes else 1 for name in AXIS_ORDER)
         try:
-            from jax.experimental import mesh_utils
-
             device_array = mesh_utils.create_hybrid_device_mesh(
                 ici_shape, dcn_shape, devices=devices, process_is_granule=True
             )
-        except Exception:
-            # emulated/CPU fallback: group by process (the granule), keep
-            # process-id order on the DCN dims so the mesh is deterministic
-            # across every process building it
+        except _TOPOLOGY_ERRORS as exc:
+            # group by process (the granule), keep process-id order on the DCN
+            # dims so the mesh is deterministic across every process building it
+            logger.warning(
+                f"no topology-aware hybrid layout for ici {ici_shape} x dcn {dcn_shape} "
+                f"({type(exc).__name__}: {exc}); grouping devices by process in id order"
+            )
             ordered = sorted(devices, key=lambda d: (d.process_index, d.id))
             device_array = np.asarray(ordered).reshape(dcn_shape + ici_shape)
             # interleave [dcn..., ici...] -> AXIS_ORDER: dim i of the final

@@ -34,17 +34,6 @@ from unionml_tpu.parallel.collectives import ring_permute
 from unionml_tpu.parallel.mesh import BATCH_AXES
 
 
-def _shard_map(fn, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    except TypeError:  # older API spells the replication-check flag differently
-        return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False)
-
-
 def init_stage_params(
     stage_module: Any, rng: jax.Array, sample: jax.Array, n_stages: int
 ) -> Any:
@@ -185,7 +174,9 @@ def pipeline_apply(
     else:
         leaves_treedef = jax.tree_util.tree_structure(stage_params)
         params_in_spec = jax.tree_util.tree_unflatten(leaves_treedef, spec_leaves)
-    wrapped = _shard_map(local, mesh, in_specs=(params_in_spec, x_spec), out_specs=x_spec)
+    wrapped = jax.shard_map(
+        local, mesh=mesh, in_specs=(params_in_spec, x_spec), out_specs=x_spec, check_vma=False
+    )
     return wrapped(stage_params, x)
 
 

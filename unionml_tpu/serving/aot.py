@@ -405,6 +405,18 @@ class AOTFunction:
         if trace is not None:
             trace.event("engine.aot_preload", program=self.program, source=source, ms=round(ms, 3))
 
+    def _execution_devices(self) -> "list[Any]":
+        """The devices this wrapper's entries were compiled for: the mesh's (the
+        key pins their ids), or the default device when there is no mesh."""
+        import jax
+
+        mesh = self._context.get("mesh")
+        if mesh is None:
+            default = jax.config.jax_default_device  # a Device, a platform name, or None
+            return [default if isinstance(default, jax.Device) else jax.devices()[0]]
+        by_id = {d.id: d for d in jax.devices()}
+        return [by_id[i] for i in mesh["device_ids"]]
+
     def _load(self, key: str) -> Optional[Any]:
         from jax.experimental import serialize_executable
 
@@ -413,7 +425,9 @@ class AOTFunction:
             return None
         start = time.perf_counter()
         try:
-            exe = serialize_executable.deserialize_and_load(*payload)
+            exe = serialize_executable.deserialize_and_load(
+                *payload, execution_devices=self._execution_devices()
+            )
         except Exception as exc:
             self.store.note_load_failure(self.program, key, exc)
             return None

@@ -209,29 +209,6 @@ def _pin_accum_shardings(step_fn: Any, state_shardings: Any, mesh) -> None:
     step_fn.pinned_shardings = (param_sh, micro_sh, batch_axis_size(mesh))
 
 
-def _sync_fence(tree: Any) -> None:
-    """Force a real device-queue sync by fetching one element to the host.
-
-    ``jax.block_until_ready`` is unreliable on some experimental PJRT plugins (it can
-    return while work is still queued); a literal transfer cannot lie.
-    """
-    leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves:
-        return
-    leaf = leaves[0]
-    try:
-        shards = getattr(leaf, "addressable_shards", None)
-        if shards:
-            # a multi-process global array is not fully addressable — fetch from
-            # a local shard instead of the (possibly remote) global index 0
-            local = shards[0].data
-            np.asarray(local if getattr(local, "ndim", 0) == 0 else local.ravel()[0])
-        else:
-            np.asarray(leaf if getattr(leaf, "ndim", 0) == 0 else leaf.ravel()[0])
-    except Exception:
-        jax.block_until_ready(leaf)
-
-
 def _tree_device_shardings(state: Any, mesh, rules: Optional[PartitionRules], min_weight: int, logical_rules=None):
     return combine_fsdp_tp(state, mesh, rules, min_weight_size=min_weight, logical_rules=logical_rules)
 
@@ -343,7 +320,7 @@ def fit(
                 data_dev = jax.tree_util.tree_map(lambda leaf: place_global_array(leaf, batch_sh), host_tree)
             except Exception:
                 data_dev = jax.device_put(host_tree)
-            _sync_fence(data_dev)  # keep the (possibly multi-second) H2D out of the timed loop
+            jax.block_until_ready(data_dev)  # keep the (possibly multi-second) H2D out of the timed loop
 
             # shuffling = ONE on-device permutation per epoch; batches are then
             # contiguous dynamic slices — ~2 orders of magnitude faster than a
@@ -466,14 +443,14 @@ def fit(
                     if loop_start is None:
                         t0 = time.perf_counter()
                         state, last_metrics = run_step(state, payload)
-                        _sync_fence(last_metrics)
+                        jax.block_until_ready(last_metrics)
                         compile_time = time.perf_counter() - t0
                         loop_start = time.perf_counter()
                         first_batch_samples = batch_n
                     else:
                         state, last_metrics = run_step(state, payload)
                         if serialize_dispatch:
-                            _sync_fence(last_metrics)
+                            jax.block_until_ready(last_metrics)
                 # drop the payload reference before the generator's next epoch-boundary
                 # permute runs — otherwise the old permuted copy stays live and peak
                 # HBM hits 3x the dataset in device_data mode
@@ -503,7 +480,7 @@ def fit(
                 jax.config.update("jax_debug_nans", prev_debug_nans)
 
         if last_metrics is not None:
-            _sync_fence(last_metrics)
+            jax.block_until_ready(last_metrics)
             host_metrics = {k: float(v) for k, v in last_metrics.items()}
             if not history or history[-1].get("step") != step_idx:
                 history.append({"step": step_idx, **host_metrics})
