@@ -12,7 +12,7 @@ prefill tokens / total prompt tokens submitted, 0..1, higher is better). The col
 rides along (the acceptance signal: >= 2x on this workload).
 
 CPU-substrate by design (a ratio of two same-substrate runs through one warm
-engine, like the ``continuous_stall`` and ``observability`` lanes): the win
+engine, like the ``continuous_stall`` lane): the win
 measured is scheduling work avoided, not chip throughput.
 """
 

@@ -70,6 +70,7 @@ MODULES = [
     "unionml_tpu.workloads.verdicts",
     "unionml_tpu.observability.trace",
     "unionml_tpu.observability.recorder",
+    "unionml_tpu.observability.engine_log",
     "unionml_tpu.observability.prometheus",
     "unionml_tpu.observability.timeseries",
     "unionml_tpu.observability.slo",

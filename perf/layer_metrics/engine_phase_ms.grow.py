@@ -1,0 +1,10 @@
+"""engine (serving/continuous.py): milliseconds an iteration of the window spent in the ``grow`` phase — block growth
+before the decode dispatch: the allocator, the residents' table updates on the device, preemption — that phase's
+seconds over the iteration records that start in the window, divided by their number (the program's own spans, host
+clock)."""
+
+from perf.layer_metrics import _engine_log
+
+
+def read(facts, trace, peak):
+    return _engine_log.phase_ms(facts, "grow")

@@ -327,6 +327,35 @@ def test_fit_reports_memory_stats_or_none():
     )
 
 
+def test_memory_stats_report_the_fullest_local_device(monkeypatch):
+    """On several devices the reported counters are those of the device whose
+    peak use is highest — not device 0's — and a device that reports nothing
+    (or raises) is passed over. The emulated CPU devices report no byte
+    counters themselves, so each is given a reading of its own."""
+    from unionml_tpu.train import driver
+
+    devices = jax.local_devices()
+    assert len(devices) == 8
+
+    class Reporting:
+        def __init__(self, device, stats):
+            self.device, self.stats = device, stats
+
+        def memory_stats(self):
+            if isinstance(self.stats, Exception):
+                raise self.stats
+            return self.stats
+
+    def reading(i):
+        return {"bytes_in_use": 100 + i, "peak_bytes_in_use": 1000 + 10 * i, "bytes_limit": 16_000, "num_allocs": 7}
+
+    readings = [reading(0), None, reading(2), RuntimeError("no stats"), reading(6), {}, reading(3), reading(1)]
+    monkeypatch.setattr(jax, "local_devices", lambda: [Reporting(d, r) for d, r in zip(devices, readings)])
+    assert driver._device_memory_stats() == {"bytes_in_use": 106, "peak_bytes_in_use": 1060, "bytes_limit": 16_000}
+    monkeypatch.setattr(jax, "local_devices", lambda: [Reporting(d, None) for d in devices])
+    assert driver._device_memory_stats() is None
+
+
 def test_evaluate_keeps_existing_placement_of_trained_state(monkeypatch):
     """The state fit() returns (logical-metadata layout, boxes already stripped)
     must be consumed in place by evaluate(): the shardings handed to placement

@@ -1,6 +1,6 @@
 """End-to-end request observability for the serving stack.
 
-Three layers, each usable alone (docs/observability.md):
+Layers, each usable alone (docs/observability.md):
 
 - :mod:`~unionml_tpu.observability.trace` — request ids (always on: honored
   from ``X-Request-Id``, generated otherwise, echoed on every response) and
@@ -11,6 +11,10 @@ Three layers, each usable alone (docs/observability.md):
   :class:`~unionml_tpu.observability.recorder.FlightRecorder` ring of the last
   N completed timelines plus the live in-flight table, served at
   ``GET /debug/requests`` and dumped to the log on drain / engine failure;
+- :mod:`~unionml_tpu.observability.engine_log` — where the engine thread's
+  time goes, always on: the loop's six phases as spans on the profiler's clock
+  and the host's, a ring of iteration records and a ring of request
+  life-cycle records per engine (``stats()["loop"]``, ``GET /debug/engine``);
 - :mod:`~unionml_tpu.observability.prometheus` — the Prometheus text
   exposition of the ``/metrics`` snapshot
   (``GET /metrics?format=prometheus``);
@@ -31,6 +35,7 @@ Knobs flow the established serving path: engine/app kwargs <- ``serve
 ``UNIONML_TPU_*`` env vars via :mod:`unionml_tpu.defaults`.
 """
 
+from unionml_tpu.observability.engine_log import EngineLog, engine_logs
 from unionml_tpu.observability.health import engine_health, fleet_debug, fleet_health
 from unionml_tpu.observability.prometheus import render as render_prometheus
 from unionml_tpu.observability.recorder import FlightRecorder, active_recorder, set_active_recorder
@@ -49,6 +54,7 @@ from unionml_tpu.observability.trace import (
 
 __all__ = [
     "BucketRing",
+    "EngineLog",
     "EngineTimeseries",
     "FlightRecorder",
     "REQUEST_ID_HEADER",
@@ -62,6 +68,7 @@ __all__ = [
     "current_request_id",
     "current_trace",
     "engine_health",
+    "engine_logs",
     "fleet_debug",
     "fleet_health",
     "new_request_id",

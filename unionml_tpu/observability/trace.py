@@ -107,22 +107,20 @@ def unbind(tokens: "Tuple[Any, Any]") -> None:
 
 @dataclasses.dataclass(frozen=True)
 class Span:
-    """One named interval (or instant) on a request's timeline.
+    """One named instant on a request's timeline.
 
-    ``t`` is seconds since the trace's start (monotonic clock); instants have
-    ``dur_ms`` of ``None``. ``attrs`` carry stage-specific detail — the routed
-    replica and the load it saw, a prefill chunk's position, an emission's
-    token count."""
+    ``t`` is seconds since the trace's start (monotonic clock). ``attrs``
+    carry stage-specific detail — the routed replica and the load it saw, a
+    prefill chunk's position, an emission's token count. (Intervals of the
+    engine thread's own work are not request events: they are the phase spans
+    of :mod:`~unionml_tpu.observability.engine_log`.)"""
 
     name: str
     t: float
-    dur_ms: Optional[float] = None
     attrs: "Optional[Dict[str, Any]]" = None
 
     def render(self) -> "Dict[str, Any]":
         out: "Dict[str, Any]" = {"event": self.name, "t_ms": round(self.t * 1e3, 3)}
-        if self.dur_ms is not None:
-            out["dur_ms"] = round(self.dur_ms, 3)
         if self.attrs:
             out.update(self.attrs)
         return out
@@ -181,24 +179,7 @@ class RequestTrace:
             if len(self._events) >= _MAX_EVENTS:
                 self.dropped_events += 1
                 return
-            self._events.append(Span(name, now - self.t0, None, attrs or None))
-
-    def span(self, name: str, **attrs: Any) -> "_SpanRecorder":
-        """Context manager recording ``name`` as an interval with ``dur_ms``::
-
-            with trace.span("engine.prefill", tokens=512):
-                ...
-        """
-        return _SpanRecorder(self, name, attrs)
-
-    def _add_span(self, name: str, start: float, end: float, attrs: "Dict[str, Any]") -> None:
-        with self._lock:
-            if len(self._events) >= _MAX_EVENTS:
-                self.dropped_events += 1
-                return
-            self._events.append(
-                Span(name, start - self.t0, (end - start) * 1e3, attrs or None)
-            )
+            self._events.append(Span(name, now - self.t0, attrs or None))
 
     def mark_slo_breach(self, objective: str, observed_ms: float, target_ms: float) -> None:
         """Stamp this timeline as an SLO-breach exemplar (first breach records
@@ -262,25 +243,6 @@ class RequestTrace:
             if self.slo_breach:
                 out["slo_breach"] = dict(self.slo_breach)
             return out
-
-
-class _SpanRecorder:
-    """The object :meth:`RequestTrace.span` returns (plain class, no
-    contextlib overhead on the traced path)."""
-
-    __slots__ = ("_trace", "_name", "_attrs", "_start")
-
-    def __init__(self, trace: RequestTrace, name: str, attrs: "Dict[str, Any]"):
-        self._trace = trace
-        self._name = name
-        self._attrs = attrs
-
-    def __enter__(self) -> "_SpanRecorder":
-        self._start = time.monotonic()
-        return self
-
-    def __exit__(self, *exc_info: Any) -> None:
-        self._trace._add_span(self._name, self._start, time.monotonic(), self._attrs)
 
 
 class Tracer:

@@ -209,6 +209,7 @@ class ServingApp:
         register_openai_routes(self)
         self.server.route_prefix("GET", "/debug/requests/", self._debug_request_by_id)
         self.server.route("GET", "/debug/fleet", self._debug_fleet)
+        self.server.route("GET", "/debug/engine", self._debug_engine)
         self.server.route("POST", "/debug/scale", self._debug_scale)
         self.server.route("POST", "/debug/profile", self._debug_profile)
 
@@ -707,6 +708,23 @@ class ServingApp:
         )
         snapshot["tracing"] = self.tracer.enabled
         return 200, snapshot, "application/json"
+
+    async def _debug_engine(self, body: bytes):
+        """Where the engine's time goes (observability/engine_log.py): for each
+        generation engine that ran in this process — every replica of a fleet,
+        closed engines included — the cumulative loop totals plus the newest
+        ``?limit=`` (default 50) iteration records and request life-cycle
+        records, newest first. Always on; needs no ``--trace``."""
+        from unionml_tpu.observability.engine_log import engine_logs
+
+        query = current_query()
+        limit = 50
+        if query.get("limit"):
+            try:
+                limit = max(int(query["limit"]), 0)
+            except ValueError:
+                raise HTTPError(400, f"limit must be an integer, got {query['limit']!r}")
+        return 200, {"engines": [log.snapshot(limit) for log in engine_logs()]}, "application/json"
 
     async def _debug_request_by_id(self, body: bytes, request_id: str):
         """One request's full timeline by id (the value every response echoes

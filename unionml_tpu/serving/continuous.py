@@ -71,7 +71,8 @@ from unionml_tpu.defaults import (
     serve_prefix_cache,
     serve_replica_roles,
 )
-from unionml_tpu.observability.trace import current_trace
+from unionml_tpu.observability.engine_log import EngineLog, RequestRecord, register_engine_log
+from unionml_tpu.observability.trace import current_request_id, current_trace
 from unionml_tpu.observability.slo import SLOConfig, SLOTracker, TenantSLORegistry
 from unionml_tpu.observability.timeseries import EngineTimeseries
 from unionml_tpu.serving.aot import AOTFunction, resolve_store
@@ -212,6 +213,18 @@ class _Session:
     #: just doesn't copy it host-side.
     want_logprobs: bool = False
     lp: "List[float]" = dataclasses.field(default_factory=list)
+    #: the engine's life-cycle record (observability/engine_log.py), written
+    #: once when the request ends, tracing on or off: the submitting request's
+    #: id, the prompt's length and the part of it the radix cache served, the
+    #: ``time.monotonic()`` of the first admission start and of the first
+    #: token (``created_at`` is the submit stamp), and the index of the engine
+    #: iteration that emitted that token
+    request_id: Optional[str] = None
+    prompt_tokens: int = 0
+    cached_tokens: int = 0
+    admission_started: Optional[float] = None
+    first_token_at: Optional[float] = None
+    first_iteration: Optional[int] = None
 
 
 @dataclasses.dataclass(eq=False)  # identity semantics: fields hold device arrays
@@ -764,6 +777,10 @@ class ContinuousBatcher:
             self._paged_spec_admit_fn = AOTFunction(
                 self._paged_spec_admit_fn, "paged_spec_admit", self._aot, ectx
             )
+        #: where the engine thread's time goes (observability/engine_log.py):
+        #: phase spans on the profiler's clock and the host's, the iteration
+        #: ring and the request life-cycle ring — always on, one per engine
+        self.engine_log = EngineLog()
         #: dispatch/utilization counters for benchmarks and /metrics
         self.decode_dispatches = 0
         self.decoded_rows = 0
@@ -1292,7 +1309,9 @@ class ContinuousBatcher:
                 )[:, 0]
 
             self._lp0_fn = jax.jit(impl)
-        return float(np.asarray(self._lp0_fn(gen.params, adm.last, adm.tok0, *adm.cstate))[0])
+        lp0 = self._lp0_fn(gen.params, adm.last, adm.tok0, *adm.cstate)
+        with self.engine_log.phase("fetch"):
+            return float(np.asarray(lp0)[0])
 
     def submit(
         self, prompt: Sequence[int], *, max_new_tokens: Optional[int] = None,
@@ -1396,6 +1415,7 @@ class ContinuousBatcher:
             tenant=tenant, priority=priority, want_logprobs=bool(logprobs),
             # the original prompt is retained only where preemption can resume it
             prompt=list(prompt) if self.block_size is not None else [],
+            request_id=current_request_id(), prompt_tokens=len(prompt),
         )
         with self._lock:
             if self._closed:
@@ -1492,6 +1512,7 @@ class ContinuousBatcher:
             priority=int(payload.get("priority", PRIORITY_NORMAL)),
             prompt=list(payload["prompt"]) if self.block_size is not None else [],
             echo=list(payload["echo"]) if self.block_size is not None else [],
+            request_id=current_request_id(), prompt_tokens=len(payload["prompt"]),
         )
         session.pending_import = dict(payload)
         with self._lock:
@@ -1505,6 +1526,34 @@ class ContinuousBatcher:
                 self._thread.start()
             self._lock.notify_all()
         return _TokenStream(self, session)
+
+    def _record_end(self, session: _Session, outcome: str) -> None:
+        """Write a request's life-cycle record into the engine log: called
+        once, where the request ends (finish, cancel, shed, export, error),
+        from whichever thread ends it."""
+        self.engine_log.request(RequestRecord(
+            session.request_id, session.created_at, session.admission_started,
+            session.first_token_at, time.monotonic(), session.prompt_tokens,
+            session.cached_tokens, session.produced, outcome, session.first_iteration,
+        ))
+
+    def _first_token_locked(self, session: _Session, now: float) -> None:
+        """Stamp a stream's first token EVER (a preemption resume is a later
+        residency, not a first token) on the life-cycle record and feed the
+        TTFT readers (caller holds the lock)."""
+        session.first_token_at = now
+        session.first_iteration = self.engine_log.index
+        self._ttft.observe(now - session.created_at)
+        if self.slo is not None:
+            self.slo.note_ttft(session.trace, (now - session.created_at) * 1e3)
+        if self._tenant_slo is not None and session.tenant is not None:
+            self._tenant_slo.note_ttft(
+                session.tenant, session.trace, now - session.created_at
+            )
+        _tev(
+            session, "engine.first_token",
+            ttft_ms=round((now - session.created_at) * 1e3, 3),
+        )
 
     def _cancel(self, session: _Session) -> None:
         """Stop producing for a session whose consumer went away. Safe from any
@@ -1521,6 +1570,7 @@ class ContinuousBatcher:
             elif session.slot >= 0 and self._sessions.get(session.slot) is session:
                 self._cancelled.append(session)
             _tev(session, "engine.cancel", produced=session.produced)
+            self._record_end(session, "cancel")
             session.out.put(_SENTINEL)
             self._lock.notify_all()
 
@@ -1591,6 +1641,7 @@ class ContinuousBatcher:
                 self._radix_reset_locked()
             self._ttft.clear()  # warmup probes must not skew the percentiles
             self._tbt.clear()
+            self.engine_log.clear()  # nor their compiles the loop's phase totals
             self.handoffs_exported = 0
             self.handoffs_imported = 0
             self._handoff_ms.clear()
@@ -1736,6 +1787,10 @@ class ContinuousBatcher:
                     self.decoded_rows / self.decode_dispatches, 3
                 ) if self.decode_dispatches else None,
                 "speculative": self._spec is not None,
+                # where the engine thread's time went, cumulative: iterations,
+                # idle seconds and seconds per phase (docs/observability.md
+                # "Where the engine's time goes")
+                "loop": self.engine_log.totals(),
                 # stall-free admission: knob echo + chunk counters + the
                 # prefill backlog the token-weighted load() routes on
                 "prefill": {
@@ -1891,8 +1946,16 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------ engine
 
     def _engine_loop(self) -> None:
+        # every pass below is one iteration of the engine log: it starts in
+        # the ``schedule`` phase, the work further down enters its own phase
+        # (``with log.phase(...)``, which suspends the one around it), and
+        # ``log.end()`` records it; a wait with nothing to do is ``idle``,
+        # outside every iteration (docs/observability.md)
+        log = self.engine_log
+        register_engine_log(log)
         try:
             while True:
+                log.begin()
                 with self._lock:
                     while (
                         not self._closed
@@ -1900,13 +1963,16 @@ class ContinuousBatcher:
                         and not self._admissions
                         and not self._sessions
                     ):
+                        log.wait()
                         self._lock.wait()
+                        log.begin()
                     self._apply_cancellations_locked()
                     if self._closed:
                         # no new admissions; residents — and partially
                         # prefilled admissions, which already hold a slot and
                         # paid prefill work — drain to completion
                         for _, session in self._pending:
+                            self._record_end(session, "closed")
                             session.out.put(_SENTINEL)
                         self._pending.clear()
                         if not self._sessions and not self._admissions:
@@ -1914,6 +1980,7 @@ class ContinuousBatcher:
                 self._admit_pending()
                 if self._sessions:
                     self._decode_chunk()
+                log.end()
         except BaseException as exc:  # engine death must not strand consumers
             logger.error(f"continuous-batching engine failed: {exc!r}")
             # postmortem: the timelines that explain the failure leave the
@@ -1925,16 +1992,20 @@ class ContinuousBatcher:
             with self._lock:
                 self._closed = True
                 for _, session in self._pending:
+                    self._record_end(session, "error")
                     session.out.put(exc)
                 for adm in self._admissions:
                     if not adm.session.finished:
+                        self._record_end(adm.session, "error")
                         adm.session.out.put(exc)
                 for session in self._sessions.values():
+                    self._record_end(session, "error")
                     session.out.put(exc)
                 self._pending.clear()
                 self._admissions.clear()
                 self._sessions.clear()
         finally:
+            log.stop()
             with self._lock:
                 for _, session in self._pending:
                     session.out.put(_SENTINEL)
@@ -1961,45 +2032,51 @@ class ContinuousBatcher:
         exactly as before."""
         budget = self.prefill_budget
         spent = 0
+        log = self.engine_log
         while True:
-            self._start_admissions()
+            self._start_admissions()  # in the pass's own phase, ``schedule``
             if not self._admissions:
                 return
-            for adm in list(self._admissions):
-                if not self._admission_alive(adm):
-                    continue
-                try:
-                    spent += self._admission_step(adm)
-                except ValueError as exc:
-                    # a bad prompt (e.g. longer than the cache can hold) fails
-                    # its own stream; the engine and other residents keep going
-                    # — admission work builds only a fresh [1, ...] row and
-                    # never touches the shared carry, so continuing is safe.
-                    # The finished flip + enqueue happen under the lock,
-                    # mirroring _cancel's guarded pattern — otherwise a
-                    # concurrent _cancel could interleave its sentinel before
-                    # (or instead of) the error
-                    self._abort_admission(adm, exc)
-                    continue
-                except BaseException as exc:
-                    # engine-fatal: this session is in NEITHER _pending NOR
-                    # _sessions — flag it finished and notify its queue here
-                    # (the death handler skips finished sessions), then let
-                    # the engine die
-                    with self._lock:
-                        if adm in self._admissions:
-                            self._admissions.remove(adm)
-                        if not adm.session.finished:
-                            adm.session.finished = True
-                            adm.session.out.put(exc)
-                    raise
-                if adm.done:
-                    if adm.session.export:
-                        self._export_admission(adm)
-                    else:
-                        self._finalize_admission(adm)
-                if budget is not None and spent >= budget:
-                    return
+            with log.phase("admit"):
+                for adm in list(self._admissions):
+                    if not self._admission_alive(adm):
+                        continue
+                    try:
+                        cost = self._admission_step(adm)
+                    except ValueError as exc:
+                        # a bad prompt (e.g. longer than the cache can hold) fails
+                        # its own stream; the engine and other residents keep going
+                        # — admission work builds only a fresh [1, ...] row and
+                        # never touches the shared carry, so continuing is safe.
+                        # The finished flip + enqueue happen under the lock,
+                        # mirroring _cancel's guarded pattern — otherwise a
+                        # concurrent _cancel could interleave its sentinel before
+                        # (or instead of) the error
+                        self._abort_admission(adm, exc)
+                        continue
+                    except BaseException as exc:
+                        # engine-fatal: this session is in NEITHER _pending NOR
+                        # _sessions — flag it finished and notify its queue here
+                        # (the death handler skips finished sessions), then let
+                        # the engine die
+                        with self._lock:
+                            if adm in self._admissions:
+                                self._admissions.remove(adm)
+                            if not adm.session.finished:
+                                adm.session.finished = True
+                                self._record_end(adm.session, "error")
+                                adm.session.out.put(exc)
+                        raise
+                    spent += cost
+                    log.prefill_tokens += cost
+                    if adm.done:
+                        log.admitted += 1
+                        if adm.session.export:
+                            self._export_admission(adm)
+                        else:
+                            self._finalize_admission(adm)
+                    if budget is not None and spent >= budget:
+                        return
 
     def _start_admissions(self) -> None:
         """Sweep dead/expired waiters, then move head-of-queue prompts into
@@ -2023,6 +2100,7 @@ class ContinuousBatcher:
                         self.timeseries.sheds.add()
                     self._tenant_shed(s.tenant)
                     _tev(s, "engine.shed_deadline", phase="waiting")
+                    self._record_end(s, "shed_deadline")
                     s.out.put(DeadlineExceeded(
                         "deadline exceeded while waiting for a decode slot"
                     ))
@@ -2059,6 +2137,7 @@ class ContinuousBatcher:
                         prompt, session = self._pending.pop(0)
                         if not session.finished:
                             session.finished = True
+                            self._record_end(session, "error")
                             session.out.put(ValueError(
                                 f"prompt needs {len(self._shared_prefix_blocks) + lifetime} KV "
                                 f"blocks but a slot's table holds {self.max_blocks}"
@@ -2127,6 +2206,8 @@ class ContinuousBatcher:
                         gather_row[: len(pins)] = pins
                 self._seed += 1
                 now = time.monotonic()
+                if session.admission_started is None:  # a preemption resume keeps the first
+                    session.admission_started = now
                 _tev(
                     session, "engine.admission_start", slot=slot,
                     queue_wait_ms=round((now - session.created_at) * 1e3, 3),
@@ -2294,6 +2375,7 @@ class ContinuousBatcher:
                     self.timeseries.sheds.add()
                 self._tenant_shed(session.tenant)
                 _tev(session, "engine.shed_deadline", phase="prefill")
+                self._record_end(session, "shed_deadline")
                 session.out.put(DeadlineExceeded(
                     "deadline exceeded mid-prefill; admission abandoned"
                 ))
@@ -2315,6 +2397,7 @@ class ContinuousBatcher:
             self._release_blocks_locked(adm.slot, adm.session)
             if not adm.session.finished:
                 adm.session.finished = True
+                self._record_end(adm.session, "error")
                 adm.session.out.put(exc)
 
     def _admission_begin(self, adm: _Admission) -> int:
@@ -2534,6 +2617,7 @@ class ContinuousBatcher:
         with self._lock:
             self.prefix_cache_hits += 1
             self.prefix_cache_tokens_avoided += start - p0
+            session.cached_tokens += start - p0
             if start % self.block_size:
                 # the partially shared tail block: its matched prefix was
                 # gathered into the row and will scatter back into THIS
@@ -2592,10 +2676,15 @@ class ContinuousBatcher:
         there is nothing left to decode anywhere."""
         cfg = self.gen.config
         session, slot = adm.session, adm.slot
-        first = np.asarray(adm.tok0)
+        with self.engine_log.phase("fetch"):
+            first = np.asarray(adm.tok0)
         hit_eos = cfg.eos_id is not None and int(first[0]) == cfg.eos_id
         done_now = hit_eos or session.produced + 1 >= session.max_new
-        row_cache, row_len = adm.row_cache, adm.row_len
+        row_len_host = 0
+        if not done_now:  # the handoff payload's length
+            with self.engine_log.phase("fetch"):
+                row_len_host = int(np.asarray(adm.row_len)[0])
+        row_cache = adm.row_cache
         adm.row_cache = adm.last = None
         pages = None
         if self.block_size is not None and not done_now:
@@ -2604,7 +2693,7 @@ class ContinuousBatcher:
             # keyed by their position in the block run — a long-context
             # engine's handoff no longer pays cache_len-wide rows per
             # transfer, in-process or across hosts
-            n_blocks = -(-int(np.asarray(row_len)[0]) // self.block_size)
+            n_blocks = -(-row_len_host // self.block_size)
             pages = self._export_pages_fn(row_cache, n_blocks, self.block_size)
             row_cache = None  # the dense row never leaves a paged engine
         with self._lock:
@@ -2619,17 +2708,7 @@ class ContinuousBatcher:
             session.out.put(first)
             now = time.monotonic()
             if session.produced == 0:
-                self._ttft.observe(now - session.created_at)
-                if self.slo is not None:
-                    self.slo.note_ttft(session.trace, (now - session.created_at) * 1e3)
-                if self._tenant_slo is not None and session.tenant is not None:
-                    self._tenant_slo.note_ttft(
-                        session.tenant, session.trace, now - session.created_at
-                    )
-                _tev(
-                    session, "engine.first_token",
-                    ttft_ms=round((now - session.created_at) * 1e3, 3),
-                )
+                self._first_token_locked(session, now)
             _tev(session, "engine.emit", tokens=1, produced=session.produced + 1)
             session.last_emit = now
             if self.block_size is not None:
@@ -2646,6 +2725,7 @@ class ContinuousBatcher:
                 registry.charge_tokens(session.tenant, 1)
             session.finished = True
             if done_now:
+                self.engine_log.finished += 1
                 _tev(session, "engine.finish", produced=session.produced)
             else:
                 self.handoffs_exported += 1
@@ -2659,7 +2739,7 @@ class ContinuousBatcher:
                         if pages is not None
                         else {"row": row_cache}
                     ),
-                    "lengths": int(np.asarray(row_len)[0]),
+                    "lengths": row_len_host,
                     "max_new": session.max_new,
                     "produced": session.produced,
                     "echo": [int(first[0])],
@@ -2673,8 +2753,9 @@ class ContinuousBatcher:
                 }
                 _tev(
                     session, "engine.handoff_export",
-                    tokens=int(np.asarray(row_len)[0]), produced=session.produced,
+                    tokens=row_len_host, produced=session.produced,
                 )
+            self._record_end(session, "finish" if done_now else "export")
             session.out.put(_SENTINEL)
 
     def _finalize_admission(self, adm: _Admission) -> None:
@@ -2695,7 +2776,8 @@ class ContinuousBatcher:
         try:
             if self._carry is None:
                 self._carry = self._init_carry()
-            first = np.asarray(adm.tok0)
+            with self.engine_log.phase("fetch"):
+                first = np.asarray(adm.tok0)
             hit_eos = cfg.eos_id is not None and int(first[0]) == cfg.eos_id
             # produced carries across preemptions; this residency adds one token.
             # An imported handoff's first token was emitted (and its eos/budget
@@ -2757,6 +2839,7 @@ class ContinuousBatcher:
                     self._admissions.remove(adm)
                 if not session.finished:
                     session.finished = True
+                    self._record_end(session, "error")
                     session.out.put(exc)
             raise
         with self._lock:
@@ -2796,19 +2879,7 @@ class ContinuousBatcher:
                 session.out.put(first)
                 now = time.monotonic()
                 if session.produced == 0:
-                    # first token EVER for this stream; a preemption resume is a
-                    # later residency, not a first token
-                    self._ttft.observe(now - session.created_at)
-                    if self.slo is not None:
-                        self.slo.note_ttft(session.trace, (now - session.created_at) * 1e3)
-                    if self._tenant_slo is not None and session.tenant is not None:
-                        self._tenant_slo.note_ttft(
-                            session.tenant, session.trace, now - session.created_at
-                        )
-                    _tev(
-                        session, "engine.first_token",
-                        ttft_ms=round((now - session.created_at) * 1e3, 3),
-                    )
+                    self._first_token_locked(session, now)
                 _tev(session, "engine.emit", tokens=1, produced=session.produced + 1)
                 if session.last_emit is not None:
                     self._tbt.observe(now - session.last_emit)
@@ -3057,6 +3128,7 @@ class ContinuousBatcher:
                     alloc = [self._free_blocks.pop(0) for _ in range(extra)]
                     self._slot_blocks[slot].extend(alloc)
                     self._extend_tables(slot, session.table_len, alloc)
+                    self.engine_log.blocks_grown += extra
                     session.table_len += extra
                     session.table.extend(alloc)
                 return
@@ -3071,7 +3143,10 @@ class ContinuousBatcher:
 
     def _finish_locked(self, slot: int, *, device_done: bool) -> None:
         session = self._sessions.pop(slot)
+        if not session.finished:  # a cancelled row reaped here has its record already
+            self._record_end(session, "finish")
         session.finished = True
+        self.engine_log.finished += 1
         _tev(session, "engine.finish", produced=session.produced)
         self._free.append(slot)
         if self._radix is not None:
@@ -3090,20 +3165,24 @@ class ContinuousBatcher:
         session.out.put(_SENTINEL)
 
     def _decode_chunk(self) -> None:
-        with self._lock:
+        log = self.engine_log
+        with log.phase("grow"), self._lock:
             self._ensure_capacity_locked()
             if not self._sessions:
                 return  # growth preempted the last resident; re-admission next loop
+            log.rows = len(self._sessions)
         if self._spec is not None:
             return self._spec_chunk()
         cfg = self.gen.config
-        toks, lps, carry = self.gen._decode(self.gen.params, *self._carry, steps=self.decode_chunk)
+        with log.phase("dispatch"):
+            toks, lps, carry = self.gen._decode(self.gen.params, *self._carry, steps=self.decode_chunk)
         self._carry = carry
-        toks_np = np.asarray(toks)  # [S, chunk]; also fences the dispatch
-        lps_np = np.asarray(lps)  # [S, chunk] f32: each sampled token's logprob
-        done_np = np.asarray(carry[3])
+        with log.phase("fetch"):
+            toks_np = np.asarray(toks)  # [S, chunk]; also fences the dispatch
+            lps_np = np.asarray(lps)  # [S, chunk] f32: each sampled token's logprob
+            done_np = np.asarray(carry[3])
         registry = self._registry()
-        with self._lock:
+        with log.phase("emit"), self._lock:
             self.decode_dispatches += 1
             self.decoded_rows += len(self._sessions)
             now = time.monotonic()
@@ -3153,29 +3232,32 @@ class ContinuousBatcher:
         resident row by >= decode_chunk tokens or to completion — concurrent
         streams share BOTH the draft and the verify dispatches."""
         spec = self._spec
-        if spec._round_fn is None:
-            spec._round_fn = spec._build_round()
-        with self._lock:
-            budget_np = np.zeros((self.slots,), np.int32)
-            for slot, session in self._sessions.items():
-                # device counters are per-RESIDENCY: a resumed (preempted)
-                # session's out_buf restarted at its re-admission, so its
-                # device budget is the tokens remaining at that point
-                budget_np[slot] = session.max_new - session.resident_base
-        budget = jnp.asarray(budget_np)
-        # per-row floor: every unfinished row gains >= decode_chunk tokens this
-        # dispatch (capped by its budget); free slots are done and ignored
-        floor = jnp.minimum(self._carry[5] + self.decode_chunk, budget)
-        state = spec._round_fn(
-            spec._target.params, spec._draft.params, self._carry, floor, budget
-        )
+        log = self.engine_log
+        with log.phase("dispatch"):
+            if spec._round_fn is None:
+                spec._round_fn = spec._build_round()
+            with self._lock:
+                budget_np = np.zeros((self.slots,), np.int32)
+                for slot, session in self._sessions.items():
+                    # device counters are per-RESIDENCY: a resumed (preempted)
+                    # session's out_buf restarted at its re-admission, so its
+                    # device budget is the tokens remaining at that point
+                    budget_np[slot] = session.max_new - session.resident_base
+            budget = jnp.asarray(budget_np)
+            # per-row floor: every unfinished row gains >= decode_chunk tokens this
+            # dispatch (capped by its budget); free slots are done and ignored
+            floor = jnp.minimum(self._carry[5] + self.decode_chunk, budget)
+            state = spec._round_fn(
+                spec._target.params, spec._draft.params, self._carry, floor, budget
+            )
         self._carry = state
-        out_np = np.asarray(state[6])  # also fences the dispatch
-        prod_np = np.asarray(state[5])
-        done_np = np.asarray(state[4])
-        rounds_total, accepted_total = int(state[7]), int(state[8])
+        with log.phase("fetch"):
+            out_np = np.asarray(state[6])  # also fences the dispatch
+            prod_np = np.asarray(state[5])
+            done_np = np.asarray(state[4])
+            rounds_total, accepted_total = int(state[7]), int(state[8])
         registry = self._registry()
-        with self._lock:
+        with log.phase("emit"), self._lock:
             # fold the ride-along counters into the engine's acceptance
             # telemetry under the lock, so a concurrent stats() snapshot never
             # sees rounds advanced without the matching accepted count

@@ -141,7 +141,7 @@ class HTTPServer:
         #: while a drain is stuck)
         self._drain_exempt = {
             ("GET", "/health"), ("GET", "/healthz"), ("GET", "/metrics"),
-            ("GET", "/debug/requests"), ("GET", "/debug/fleet"),
+            ("GET", "/debug/requests"), ("GET", "/debug/fleet"), ("GET", "/debug/engine"),
         }
         self._stop_serving: Optional[asyncio.Event] = None
 

@@ -100,15 +100,24 @@ class FitResult:
 
 
 def _device_memory_stats() -> Optional[Dict[str, int]]:
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-    except Exception:
-        return None
-    if not stats:
-        return None
+    """Byte counters of the FULLEST local device (the one whose peak use was
+    highest): on a sharded run the devices differ — uneven shards, a replicated
+    array one device materializes — and the one nearest its limit is the one
+    that decides whether the run fits."""
     keep = ("bytes_in_use", "peak_bytes_in_use", "bytes_limit", "largest_alloc_size")
-    filtered = {k: int(v) for k, v in stats.items() if k in keep}
-    return filtered or None  # a stats dict without byte counters is as good as none
+    fullest: Optional[Dict[str, int]] = None
+    for device in jax.local_devices():
+        try:
+            stats = device.memory_stats()
+        except Exception:
+            continue
+        filtered = {k: int(v) for k, v in (stats or {}).items() if k in keep}
+        if filtered and (
+            fullest is None
+            or filtered.get("peak_bytes_in_use", 0) > fullest.get("peak_bytes_in_use", 0)
+        ):
+            fullest = filtered
+    return fullest  # None where no device reports byte counters (the CPU backend)
 
 
 def make_train_step(
