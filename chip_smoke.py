@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Prove that the serving engine, the trainer and the shipped kernels run on the chip.
 
-    python chip_smoke.py              # one TPU chip: kernels, fence, serve, train
+    python chip_smoke.py              # one TPU chip: kernels, fence, serve, decode paths, train
     python chip_smoke.py --chips 4    # one four-chip host: TP=4 serving, four replicas, sharded fit
     python chip_smoke.py --rehearse   # tiny sizes on whatever backend there is; can never pass
 
@@ -508,6 +508,53 @@ def phase_serve(sz: types.SimpleNamespace, seed: int, errors: EngineErrors) -> D
     }
 
 
+def phase_decode_paths(sz: types.SimpleNamespace, seed: int, errors: EngineErrors) -> Dict[str, Any]:
+    """The two reads of a paged cache against each other, through the engine: the same
+    requests with ``attention_impl="auto"`` (on a TPU the paged-attention kernel) and
+    ``"xla"`` (the gather), greedy, at the serve phase's widths. Per request the served
+    log-probabilities are compared over the tokens both engines emitted alike (a greedy
+    near-tie may part two bf16 paths; what follows it is another sequence)."""
+    import dataclasses
+
+    import jax
+
+    from unionml_tpu.models import Llama
+
+    config, module, params = make_decoder(sz, seed)
+    first, second = make_prompts(sz, config.vocab_size, seed)
+    prompts = first + second
+    served: Dict[str, types.SimpleNamespace] = {}
+    for impl in ("auto", "xla"):
+        _, batcher, _ = make_engine(Llama(dataclasses.replace(config, attention_impl=impl)), params, sz)
+        streams = [batcher.submit(p, logprobs=True) for p in prompts]
+        tokens = [[int(t) for chunk in stream for t in np.asarray(chunk).ravel()] for stream in streams]
+        served[impl] = types.SimpleNamespace(
+            tokens=tokens, logprobs=[list(stream.logprobs) for stream in streams], path=batcher.stats()["decode_attention_path"]
+        )
+        batcher.close()
+        gc.collect()
+    check(not errors.messages, f"the package logged errors: {errors.messages}")
+
+    auto, xla = served["auto"], served["xla"]
+    on_tpu = jax.default_backend() == "tpu"
+    check(auto.path == ("paged_kernel" if on_tpu else "gather"), f"impl='auto' decoded through {auto.path}")
+    check(xla.path == "gather", f"impl='xla' decoded through {xla.path}")
+    alike, worst = [], []
+    for a_tokens, a_lps, x_tokens, x_lps in zip(auto.tokens, auto.logprobs, xla.tokens, xla.logprobs):
+        check(len(a_tokens) == len(x_tokens) == len(a_lps) == len(x_lps) == sz.max_new, "a request came back short")
+        same = next((i for i, (a, x) in enumerate(zip(a_tokens, x_tokens)) if a != x), sz.max_new)
+        alike.append(same)
+        worst.append(float(np.max(np.abs(np.asarray(a_lps[:same]) - np.asarray(x_lps[:same])))) if same else 0.0)
+    check(max(worst) <= LOGPROB_ATOL, f"the two decode reads differ by {worst} nats (> {LOGPROB_ATOL})")
+    check(2 * sum(alike) >= sz.max_new * len(prompts), f"the two decode reads part early: {alike} of {sz.max_new} tokens alike")
+    return {
+        "paths": {impl: served[impl].path for impl in served},
+        "requests": len(prompts),
+        "tokens_alike": alike,
+        "logprob_max_abs_diff": [round(w, 4) for w in worst],
+    }
+
+
 # --------------------------------------------------------------------------- train
 
 
@@ -800,6 +847,7 @@ def main() -> int:
             ("kernels", lambda: phase_kernels(sz, args.seed, interpret=not on_tpu)),
             ("fence", lambda: phase_fence(sz, args.seed, judge=on_tpu)),
             ("serve", lambda: phase_serve(sz, args.seed, errors)),
+            ("decode_paths", lambda: phase_decode_paths(sz, args.seed, errors)),
             ("train", lambda: phase_train(sz, args.seed)),
         ]
 

@@ -5,6 +5,7 @@ not). Nothing executes; a compile that passes is not a chip run. Also: the smoke
 itself must fail, and claim nothing, where there is no TPU."""
 
 import os
+import re
 import shutil
 import subprocess
 import sys
@@ -87,6 +88,46 @@ def test_kernel_compiles_for_v5e(chip, case):
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip) for shape, dtype in shapes]
     compiled = jax.jit(fn).lower(*args).compile()  # raises what the chip's compiler would raise
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the compiled program"
+
+
+#: the serving cells' decode carries (perf/workloads/*.json): slots, pages a row, pool pages (scratch included)
+DECODE_SHAPES = {"chat_sat_32x25": (32, 25, 513), "docs_16x56": (16, 56, 769)}
+
+
+@pytest.mark.parametrize("shape", sorted(DECODE_SHAPES))
+def test_decode_steps_compiles_for_v5e_without_a_gathered_copy(chip, shape):
+    """The whole decode program at Mistral-7B's widths over the cells' paged caches, through
+    the kernel read (forced: the backend here is the CPU, so ``auto`` would gather): Mosaic
+    takes it, one kernel a layer, and no temporary as large as the gather path's logical copy
+    (``[B, pages * 64, 32, 128]`` bf16) is left — nor a re-laid-out copy of a pool."""
+    from unionml_tpu.models import GenerationConfig, Generator, Llama, LlamaConfig
+    from unionml_tpu.models.generate import init_paged_cache
+
+    slots, pages, pool = DECODE_SHAPES[shape]
+    layers, page = 2, 64
+    config = LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=layers, n_heads=32, n_kv_heads=8, hidden_dim=14336,
+        max_seq_len=32768, rope_theta=1e6, attention_impl="flash", param_dtype=jnp.bfloat16,
+    )
+    module = Llama(config)
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), jax.eval_shape(make))
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
+    tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
+    gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
+    compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), steps=8).compile()
+
+    text = compiled.as_text()
+    assert gen.decode_attention_path == "paged_kernel"
+    assert text.count("tpu_custom_call") == layers
+    # left at two layers: the hoisted q/k/v projection transposes (96 MB) and a step's activations
+    gathered = slots * pages * page * 32 * 128 * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < gathered
+    # the kernel reads the pools row-major; a write that made XLA keep them otherwise would copy each one, each step
+    assert not re.search(rf"= bf16\[8,{pool},{page},128\]\S* copy\(", text)
 
 
 @pytest.mark.parametrize("where", ["checkout", "alone"])

@@ -375,6 +375,11 @@ class Generator:
         self.quantize = quantize
         self.prefill_traces = 0
         self.decode_traces = 0
+        #: how the decode program reads a PAGED cache, recorded when it is traced:
+        #: ``"paged_kernel"`` or ``"gather"`` (ops/paged_attention.py); ``None``
+        #: before the first trace and for a contiguous cache
+        self.decode_attention_path: Optional[str] = None
+        self._paged_read_traced: Optional[str] = None  # the newest traced apply's paged read
         compute_dtype = getattr(getattr(module, "config", None), "dtype", jnp.bfloat16)
 
         if quantize not in (None, "int8"):
@@ -429,15 +434,24 @@ class Generator:
 
         self._constrain = constrain  # shared by sp_prefill and beam search
 
+        from unionml_tpu.ops.paged_attention import paged_read_scope
+
+        # a paged pool shards its KV heads over the mesh (_place_paged_cache);
+        # the layers see tracers, not placements, so the trace is told
+        pools_sharded = mesh is not None and mesh.devices.size > 1
+
         def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
-            hidden, cache = module.apply(
-                {"params": p},
-                tokens,
-                positions=positions,
-                return_hidden=True,
-                cache=cache,
-                token_mask=token_mask,
-            )
+            with paged_read_scope(sharded=pools_sharded) as paths:
+                hidden, cache = module.apply(
+                    {"params": p},
+                    tokens,
+                    positions=positions,
+                    return_hidden=True,
+                    cache=cache,
+                    token_mask=token_mask,
+                )
+            # which way a paged cache was read (None: a contiguous one), for whoever traces a decode program
+            self._paged_read_traced = "+".join(sorted(set(paths))) or None
             return hidden, cache
 
         def head(p: Any, hidden: jax.Array) -> jax.Array:
@@ -518,6 +532,7 @@ class Generator:
             carry, (toks, lps) = jax.lax.scan(
                 body, (cache, tok, lengths, done, key, *cstate), None, length=steps
             )
+            self.decode_attention_path = self._paged_read_traced
             # the advanced carry (incl. cache) is returned so the donated input
             # buffers have outputs to alias with — one cache in HBM throughout
             return toks.T, lps.T, carry

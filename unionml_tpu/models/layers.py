@@ -173,12 +173,14 @@ class LoRADense(nn.Module):
 class Attention(nn.Module):
     """Multi-head (optionally grouped-query) attention with RoPE and impl dispatch.
 
-    ``impl``: ``"auto"`` (currently XLA — flash stays opt-in until the pallas kernel
-    beats XLA's fused attention on its benchmark; see
-    :func:`unionml_tpu.ops.attention.multihead_attention`), ``"xla"``, ``"flash"``, or
-    ``"ring"`` (sequence-parallel exact attention; requires running inside shard_map
-    with a ``sequence`` axis), or ``"ulysses"`` (all-to-all sequence parallelism —
-    same shard_map requirement, cheaper collectives when heads divide the axis).
+    ``impl``: ``"auto"``, ``"xla"``, ``"flash"``, ``"ring"`` (sequence-parallel exact
+    attention; requires running inside shard_map with a ``sequence`` axis), or
+    ``"ulysses"`` (all-to-all sequence parallelism — same shard_map requirement,
+    cheaper collectives when heads divide the axis). ``"auto"`` is XLA for the
+    uncached forward (the hand-written flash kernel stays opt-in until it beats XLA's
+    fused attention; see :func:`unionml_tpu.ops.attention.multihead_attention`) and,
+    for a single-token read of a paged cache on a TPU, the pallas paged-attention
+    kernel (:func:`unionml_tpu.ops.paged_attention.paged_read_path`).
     """
 
     n_heads: int
@@ -300,14 +302,21 @@ class Attention(nn.Module):
 
     def _paged_cached_attention(self, q, k, v, positions, cache):
         """The paged write+read: scatter new rows through the block table, then
-        attend — via the pallas paged-attention kernel (``impl="flash"`` on TPU,
-        single-token decode: pages stream block-by-block, no gathered copy) or
-        the portable gather path (``pool[:, table]`` back to the logical layout
-        under the same ``slot <= position`` visibility mask as the contiguous
-        branch — numerically identical to it). Pools are heads-major
-        ``[H_kv, n_pages, page_size, last]``. Scatter indices collide only on
-        the scratch block (finished rows), where the winning value is
-        irrelevant — real slots own disjoint blocks."""
+        attend. Which read serves it is decided by
+        :func:`unionml_tpu.ops.paged_attention.paged_read_path` from what the
+        trace can observe: on a TPU a single-token read over bf16 pages held on
+        one device goes through the pallas paged-attention kernel (each row's
+        named pages stream block by block, up to the row's length, at KV-head
+        width; no gathered copy); a CPU or GPU backend, ``L > 1``, int8 pages
+        and pools sharded over a mesh take the portable gather (``pool[:,
+        table]`` back to the logical layout under the same ``slot <= position``
+        visibility mask as the contiguous branch — numerically identical to
+        it). ``impl="xla"`` forces the gather, ``impl="flash"`` the kernel.
+        Pools are heads-major ``[H_kv, n_pages, page_size, last]``. Scatter
+        indices collide only on the scratch block (finished rows), where the
+        winning value is irrelevant — real slots own disjoint blocks."""
+        from unionml_tpu.ops.paged_attention import PAGED_KERNEL, paged_decode_attention, paged_read_path
+
         table = cache["table"]  # [B, max_blocks] int32
         block_size = cache["k"].shape[2]
         blk = jnp.take_along_axis(table, positions // block_size, axis=1)  # [B, L]
@@ -317,12 +326,23 @@ class Attention(nn.Module):
             # rows [B, L, H_kv, last] -> pool[:, blk, off] has shape [H_kv, B, L, last]
             return pool.at[:, blk, off].set(jnp.moveaxis(rows, 2, 0).astype(pool.dtype))
 
+        def scatter_rows(pool: jax.Array, rows: jax.Array) -> jax.Array:
+            # the kernel path's write (L == 1). The kernel reads the pools row-major; the
+            # scatter of [H_kv, last] slabs above makes XLA keep them heads-minor and copy
+            # every pool, every layer, every step. As H_kv * B rows of ``last`` it leaves them be.
+            n_kv, n_pages = pool.shape[:2]
+            at = (jnp.arange(n_kv)[:, None] * n_pages + blk[:, 0]) * block_size + off[:, 0]  # [H_kv, B]
+            flat = pool.reshape(-1, pool.shape[-1]).at[at.reshape(-1)].set(
+                jnp.moveaxis(rows[:, 0], 1, 0).reshape(-1, rows.shape[-1]).astype(pool.dtype)
+            )
+            return flat.reshape(pool.shape)
+
         def logical(pool: jax.Array) -> jax.Array:
             rows = pool[:, table]  # [H_kv, B, MB, bs, last]
             rows = rows.reshape(rows.shape[0], rows.shape[1], -1, rows.shape[-1])
             return jnp.transpose(rows, (1, 2, 0, 3))  # [B, MB * bs, H_kv, last]
 
-        use_kernel = self.impl == "flash" and q.shape[1] == 1
+        path = paged_read_path(self.impl, q, cache["k"], quantized="k_scale" in cache)
         if "k_scale" in cache:
             kq, k_scale = quantize_kv_rows(k)
             vq, v_scale = quantize_kv_rows(v)
@@ -333,25 +353,15 @@ class Attention(nn.Module):
                 "v_scale": scatter(cache["v_scale"], v_scale),
                 "table": table,
             }
-            # int8 pages stay on the gather path even under impl="flash": the
-            # library kernel broadcasts the per-position scales to FULL head
-            # width and DMAs them alongside the int8 pages (5 B/elem vs bf16's
-            # 2), so routing int8 through it would RAISE page traffic — the
-            # shootout (bench_paged_attention.py) measures the kernel's int8
-            # mode anyway, and this gate flips only if hardware disagrees
             keys = (logical(cache["k"]).astype(jnp.float32) * logical(cache["k_scale"])).astype(q.dtype)
             values = (logical(cache["v"]).astype(jnp.float32) * logical(cache["v_scale"])).astype(q.dtype)
         else:
-            cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v), "table": table}
-            if use_kernel:
-                # single-token decode through the pallas kernel (TPU only); the
-                # row's visible length includes the token just scattered
-                from unionml_tpu.ops.paged_attention import paged_decode_attention
-
-                out = paged_decode_attention(
-                    q[:, 0], cache["k"], cache["v"], positions[:, 0] + 1, table
-                )
+            if path == PAGED_KERNEL:
+                cache = {"k": scatter_rows(cache["k"], k), "v": scatter_rows(cache["v"], v), "table": table}
+                # the row's visible length includes the token just scattered
+                out = paged_decode_attention(q[:, 0], cache["k"], cache["v"], positions[:, 0] + 1, table)
                 return out[:, None], cache
+            cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v), "table": table}
             keys = logical(cache["k"]).astype(q.dtype)
             values = logical(cache["v"]).astype(q.dtype)
         visible = (

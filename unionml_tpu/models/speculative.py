@@ -235,6 +235,8 @@ class SpeculativeGenerator:
             # over the full [B, gamma+1] verify width
             verify_mask = jnp.broadcast_to((~done)[:, None], inputs.shape)
             logits, t_cache = target_apply(tp, inputs, positions, t_cache, verify_mask)
+            # the verify pass is this engine's decode read of the target's cache
+            target.decode_attention_path = target._paged_read_traced
             if cs is not None:
                 # target logits at position i masked by the state its row
                 # reached after drafts[:i] — p becomes the constrained policy

@@ -135,6 +135,9 @@ class EngineLog:
         self.admitted = 0
         self.finished = 0
         self.blocks_grown = 0
+        #: how the engine's decode program reads its paged cache (``"paged_kernel"`` / ``"gather"``), set by the
+        #: engine after a decode dispatch; a fact about the program, not a counter: :meth:`clear` leaves it
+        self.decode_attention_path: Optional[str] = None
 
     # ------------------------------------------------------------------ the engine thread's clock
 
@@ -253,6 +256,7 @@ class EngineLog:
             requests = requests[-limit:] if limit > 0 else []
         return {
             "capacity": self.capacity,
+            "decode_attention_path": self.decode_attention_path,
             **self.totals(),
             "iterations_log": [record.render() for record in reversed(iterations)],
             "requests_log": [record.render() for record in reversed(requests)],

@@ -74,7 +74,11 @@ def multihead_attention(
     ``impl``: ``"xla"`` (reference), ``"flash"`` (pallas kernel, TPU only), or
     ``"auto"``. Measured on v5e (B=4, L=1024, H=8, D=128, bf16) the hand-written
     flash kernel currently trails XLA's fused attention (2.6ms vs 1.6ms), so ``auto``
-    resolves to XLA; flash stays opt-in until the kernel wins its benchmark.
+    resolves to XLA here; flash stays opt-in until the kernel wins its benchmark.
+    This speaks for the uncached forward and the masked reads alone: the
+    single-token read of a PAGED cache never comes through this function on a TPU
+    (:func:`unionml_tpu.ops.paged_attention.paged_read_path` sends it to the
+    paged-attention kernel, which won that comparison).
 
     ``mask`` (boolean, broadcastable to ``[B, H, Lq, Lk]``, True = attend) routes to
     the XLA path — the flash kernel has no arbitrary-mask support.

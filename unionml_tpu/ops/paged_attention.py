@@ -1,39 +1,102 @@
-"""Paged-attention decode over heads-major block pools.
+"""The paged decode read: which path serves it, and the kernel path itself.
 
-The gather path in :meth:`unionml_tpu.models.layers.Attention._paged_cached_attention`
-materializes ``pool[table]`` — a full logical-layout copy of every resident
-row's K/V per layer per step — before attending. This module routes the decode
-read through the pallas paged-attention kernel that ships with JAX
-(``jax.experimental.pallas.ops.tpu.paged_attention``, the production TPU
-serving kernel): it DMAs exactly the pages each row's table names, streams them
-block-by-block through flash-style online softmax, and never materializes the
-gathered copy — decode KV traffic drops to one pool read.
+A decoder's cached attention over a paged pool
+(:meth:`unionml_tpu.models.layers.Attention._paged_cached_attention`) has two
+reads. The portable one gathers ``pool[:, table]`` — every row's whole block
+table — back into the logical layout and attends under a visibility mask; its
+traffic follows the cache's *capacity*. The other routes a single-token read
+through the pallas paged-attention kernel that ships with JAX
+(``jax.experimental.pallas.ops.tpu.paged_attention``): it DMAs the pages a
+row's table names, up to the row's length, at KV-head width, streams them
+block by block through an online softmax, and keeps no gathered copy — its
+traffic follows the cache's *contents*.
 
 The pool layout (``[H_kv, n_pages, page_size, D]``,
-:func:`unionml_tpu.models.generate.init_paged_cache`) matches the kernel's
-expectation, so dispatch is zero-copy. TPU-only (the kernel has no interpret
-mode); the portable gather path remains the default until the kernel wins its
-shootout (``benchmarks/bench_paged_attention.py``) — the same auto policy as
-:mod:`unionml_tpu.ops.flash_attention`.
+:func:`unionml_tpu.models.generate.init_paged_cache`) is the kernel's own, so
+dispatch is zero-copy. The kernel is TPU-only (no interpret mode).
+:func:`paged_read_path` is the policy: on a TPU the kernel is the decode path
+wherever it can serve (measured on a v5e at Mistral-7B's widths, PERF.md
+section 6 "PR 25"); everything else keeps the gather.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+import contextlib
+import threading
+from typing import Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["paged_decode_attention"]
+__all__ = ["GATHER", "PAGED_KERNEL", "paged_decode_attention", "paged_read_path", "paged_read_scope"]
+
+#: the two paths a paged read can take, as ``stats()["decode_attention_path"]`` names them
+PAGED_KERNEL = "paged_kernel"
+GATHER = "gather"
+
+_scope = threading.local()
 
 
-def _pages_per_block(pages_per_sequence: int, target: int = 8) -> int:
-    """Largest divisor of ``pages_per_sequence`` that is <= ``target`` (the
-    kernel requires an exact tiling of the table width)."""
-    for candidate in range(min(target, pages_per_sequence), 0, -1):
-        if pages_per_sequence % candidate == 0:
-            return candidate
-    return 1
+@contextlib.contextmanager
+def paged_read_scope(*, sharded: bool) -> Iterator[List[str]]:
+    """Entered, at trace time, by whoever traces a model over a paged cache
+    (:class:`unionml_tpu.models.generate.Generator` does, around every
+    ``module.apply``). It tells the layer the one thing it cannot see from its
+    operands — whether the pools are ``sharded`` over a mesh of several devices
+    (a tracer carries no placement) — and yields the list to which each paged
+    read traced inside appends the path it took."""
+    previous = getattr(_scope, "current", None)
+    paths: List[str] = []
+    _scope.current = (sharded, paths)
+    try:
+        yield paths
+    finally:
+        _scope.current = previous
+
+
+def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: bool) -> str:
+    """Which read serves ``q: [B, L, H, D]`` over ``k_pages: [H_kv, n_pages,
+    page_size, D]``, decided from what the trace can observe and recorded in
+    the enclosing :func:`paged_read_scope`.
+
+    The kernel serves one query token over unquantized pages: ``L > 1``
+    (speculative verify, chunked prefill) and int8 pages (the library widens
+    the per-position scales to head width and DMAs them with the pages: 5 B an
+    element against bf16's 2) always gather. ``impl="flash"`` forces the kernel
+    for such a read and ``"xla"`` the gather; ``"auto"`` takes the kernel where
+    it is known to run and to win: a TPU backend, bf16 pages with a lane-wide
+    head (``D % 128 == 0``), and pools on one device (outside any scope a
+    caller is taken to hold its pools on one device).
+    """
+    sharded, paths = getattr(_scope, "current", None) or (False, None)
+    kernel = q.shape[1] == 1 and not quantized and impl in ("flash", "auto")
+    if kernel and impl == "auto":
+        kernel = (
+            jax.default_backend() == "tpu"
+            and k_pages.dtype == jnp.bfloat16
+            and k_pages.shape[-1] % 128 == 0
+            and not sharded
+        )
+    path = PAGED_KERNEL if kernel else GATHER
+    if paths is not None:
+        paths.append(path)
+    return path
+
+
+def _pages_per_block(pages_per_sequence: int, page_size: int) -> int:
+    """Pages one compute block of the kernel streams, from the table's width.
+
+    The kernel walks a row in blocks of this many pages and fetches a block
+    whole, so a block is a trade between steps (each costs about half a
+    microsecond a row and KV head besides its bytes) and positions fetched past
+    the row's length. Measured on a v5e at 8 KV heads of 128 and pages of 64 (PERF.md
+    section 6, "PR 25"): rows of ~350 positions in a table of 25 pages ran
+    fastest at 8 pages a block (4 and 5: +16 %, 16: +7 %), rows of ~2,700 in a
+    table of 56 at 16 (8: +10 %, 14: +4 %, 28: no better). So: 512 positions a
+    block where a row can hold at most 2,048, else 1,024. The table is widened
+    to a multiple (:func:`paged_decode_attention`), so any width is served."""
+    positions = 512 if pages_per_sequence * page_size <= 2048 else 1024
+    return max(1, min(positions // page_size, pages_per_sequence))
 
 
 def paged_decode_attention(
@@ -59,8 +122,8 @@ def paged_decode_attention(
     page path; our scales map exactly via ``h = scale * 127.5`` (the kernel
     dequantizes ``int8 * h / 127.5``). CAVEAT: the library broadcasts the
     scales to FULL head width before launch and DMAs them per page, so int8
-    pages cost ~5 B/elem of traffic vs bf16's 2 — the mode exists for the
-    shootout's measurement, not as a recommended production path.
+    pages cost ~5 B/elem of traffic vs bf16's 2 — no model path takes this
+    mode (:func:`paged_read_path` gathers int8 pages); ``chip_smoke.py`` checks it.
 
     The library kernel computes RAW ``qk`` logits (no softmax scale anywhere in
     ``paged_flash_attention_kernel``), so ``q`` is pre-scaled by
@@ -72,6 +135,8 @@ def paged_decode_attention(
 
     if (k_scales is None) != (v_scales is None):
         raise ValueError("k_scales and v_scales must be passed together")
+    page_size = k_pages.shape[2]
+    ppcb = pages_per_compute_block or _pages_per_block(page_indices.shape[1], page_size)
     if k_scales is not None:
         k_pages = quantization_utils.QuantizedTensor(
             weight=k_pages, scales=(k_scales * quantization_utils.MAX_INT8).astype(jnp.float32)
@@ -79,13 +144,19 @@ def paged_decode_attention(
         v_pages = quantization_utils.QuantizedTensor(
             weight=v_pages, scales=(v_scales * quantization_utils.MAX_INT8).astype(jnp.float32)
         )
-    ppcb = pages_per_compute_block or _pages_per_block(page_indices.shape[1])
+    if page_indices.shape[1] % ppcb:
+        # the kernel tiles the table exactly: widen it with page 0, which no length reaches
+        page_indices = jnp.pad(page_indices, ((0, 0), (0, -page_indices.shape[1] % ppcb)))
+    # f32 in, so the softmax scale is not rounded into a bf16 query; the kernel
+    # returns its launch dtype (f32 here), the caller gets the query's own
     scale = q.shape[-1] ** -0.5
-    return paged_attention(
-        (q * scale).astype(q.dtype),
+    out = paged_attention(
+        q.astype(jnp.float32) * scale,
         k_pages,
         v_pages,
-        lengths.astype(jnp.int32),
+        # a length past the table's end would send the kernel's DMAs off the row's pages
+        jnp.minimum(lengths.astype(jnp.int32), page_indices.shape[1] * page_size),
         page_indices,
         pages_per_compute_block=ppcb,
     )
+    return out.astype(q.dtype)

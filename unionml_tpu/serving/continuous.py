@@ -1783,6 +1783,10 @@ class ContinuousBatcher:
                 "shed_deadline": self.shed_deadline,
                 "draining": self._closed,
                 "decode_dispatches": self.decode_dispatches,
+                # how the decode program reads the paged cache, recorded when it
+                # was traced: "paged_kernel" or "gather" (None: not traced yet,
+                # or a contiguous cache)
+                "decode_attention_path": self.gen.decode_attention_path,
                 "rows_per_dispatch": round(
                     self.decoded_rows / self.decode_dispatches, 3
                 ) if self.decode_dispatches else None,
@@ -3177,6 +3181,7 @@ class ContinuousBatcher:
         with log.phase("dispatch"):
             toks, lps, carry = self.gen._decode(self.gen.params, *self._carry, steps=self.decode_chunk)
         self._carry = carry
+        log.decode_attention_path = self.gen.decode_attention_path
         with log.phase("fetch"):
             toks_np = np.asarray(toks)  # [S, chunk]; also fences the dispatch
             lps_np = np.asarray(lps)  # [S, chunk] f32: each sampled token's logprob
@@ -3251,6 +3256,7 @@ class ContinuousBatcher:
                 spec._target.params, spec._draft.params, self._carry, floor, budget
             )
         self._carry = state
+        log.decode_attention_path = self.gen.decode_attention_path
         with log.phase("fetch"):
             out_np = np.asarray(state[6])  # also fences the dispatch
             prod_np = np.asarray(state[5])
