@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Prove that the serving engine, the trainer and the shipped kernels run on the chip.
 
-    python chip_smoke.py              # one TPU chip: kernels, fence, serve, decode paths, train
+    python chip_smoke.py              # one TPU chip: kernels, fence, serve, decode paths, afmoe, train
     python chip_smoke.py --chips 4    # one four-chip host: TP=4 serving, four replicas, sharded fit
     python chip_smoke.py --rehearse   # tiny sizes on whatever backend there is; can never pass
 
@@ -76,6 +76,12 @@ def sizes(rehearse: bool) -> types.SimpleNamespace:
             train_batch=8, train_seq=32, train_steps=6, learning_rate=1e-3, fit_steps=6, fit_learning_rate=1e-3,
             flash=(1, 256, 4, 2, 128), matmul=(8, 256, 512), paged=(4, 2, 2, 8, 16, 128),
             fence=(256, 8),
+            afmoe=dict(
+                hidden_size=128, head_dim=16, num_attention_heads=8, num_key_value_heads=4, vocab_size=512,
+                intermediate_size=256, moe_intermediate_size=64, num_experts=4, router_experts=8, experts_first=2,
+                num_experts_per_tok=2, sliding_window=32,
+            ),
+            afmoe_prompts=(100, 20, 24, 28, 60, 40), afmoe_buckets=(32, 128),
         )
     return types.SimpleNamespace(
         # LlamaConfig.llama3_8b's published widths; depth cut 32 -> 8 (2.8 B parameters,
@@ -90,6 +96,15 @@ def sizes(rehearse: bool) -> types.SimpleNamespace:
         # (batch, length, heads, kv_heads, head_dim); (M, K, F); (rows, heads, kv_heads, pages/row, page, head_dim)
         flash=(4, 1024, 32, 8, 128), matmul=(8, 4096, 14336), paged=(8, 32, 8, 64, 64, 128),
         fence=(8192, 100),  # matrix side, chain length: ~110 TFLOP, most of a second
+        # Trinity-Large-Preview's published widths, one of 8 expert-parallel chips' share (32 of 256 experts, an
+        # eighth of the vocabulary), depth cut to a sliding and a full expert layer: 2.1 B parameters, 4.3 GB
+        afmoe=dict(
+            hidden_size=3072, head_dim=128, num_attention_heads=48, num_key_value_heads=8, vocab_size=25024,
+            intermediate_size=12288, moe_intermediate_size=3072, num_experts=32, router_experts=256, experts_first=0,
+            num_experts_per_tok=4, sliding_window=4096,
+        ),
+        # two requests pass the 4,096-token window (one in its prompt, one while it decodes)
+        afmoe_prompts=(4200, 190, 200, 210, 1900, 4070), afmoe_buckets=(256, 2048, 4352),
     )
 
 
@@ -555,6 +570,63 @@ def phase_decode_paths(sz: types.SimpleNamespace, seed: int, errors: EngineError
     }
 
 
+def phase_afmoe(sz: types.SimpleNamespace, seed: int, errors: EngineErrors) -> Dict[str, Any]:
+    """Six requests through ``AfmoeTransformer`` (a sliding and a full expert layer at the published widths, one
+    chip's share of the experts) on the engine as deployments run it, against the benchmark's plain float32
+    reference given the same share and the same weights. A routed layer's comparison is heavy-tailed (a choice
+    of expert that lay within the bfloat16 stream's rounding moves that token by tenths of a nat), so the
+    middle of the distribution is held to the rounding tolerance and nine tokens in ten to half a nat; a wrong
+    window, page or expert moves every later token by whole nats."""
+    import jax
+
+    from perf.reference import afmoe_decoder as reference
+    from perf.systems.afmoe_serving import module_config
+    from unionml_tpu.models import AfmoeTransformer, Generator
+    from unionml_tpu.serving import ContinuousBatcher
+
+    cfg = dict(
+        sz.afmoe, num_hidden_layers=2, num_dense_layers=0, layer_types=["sliding_attention", "full_attention"],
+        num_shared_experts=1, rope_theta=10000.0, rms_norm_eps=1e-5, score_func="sigmoid", route_norm=True,
+        route_scale=2.448, mup_enabled=True, max_position_embeddings=8192, precision={"compute_dtype": "bfloat16"},
+    )
+    weights = reference.make_weights(cfg, seed)
+    gen = Generator(AfmoeTransformer(module_config(cfg)), weights, generation_config(sz, sz.afmoe_buckets))
+    blocks = sum(-(-(n + sz.max_new) // sz.block_size) for n in sz.afmoe_prompts) + sz.slots
+    batcher = ContinuousBatcher(gen, **engine_options(sz, blocks))
+    batcher.warmup()
+    rng = np.random.default_rng(seed)
+    prompts = [[int(t) for t in rng.integers(1, cfg["vocab_size"], size=n)] for n in sz.afmoe_prompts]
+    streams = [batcher.submit(p, logprobs=True) for p in prompts]
+    served = [[int(t) for chunk in stream for t in np.asarray(chunk).ravel()] for stream in streams]
+    stats = batcher.stats()
+    batcher.close()
+    del gen, batcher
+    gc.collect()
+    check(not errors.messages, f"the package logged errors: {errors.messages}")
+
+    on_tpu = jax.default_backend() == "tpu"
+    diffs: List[float] = []
+    for prompt, tokens, stream in zip(prompts, served, streams):
+        check(len(tokens) == len(stream.logprobs) == sz.max_new, f"{len(tokens)} tokens, {len(stream.logprobs)} log-probs")
+        rows = [len(prompt) - 1 + i for i in range(len(tokens))]
+        logits = reference.logits_at(weights, cfg, prompt + tokens[:-1], rows, pad_to=sz.afmoe_buckets[0])
+        reference_lp = jax.nn.log_softmax(logits, axis=-1)[np.arange(len(tokens)), np.asarray(tokens)]
+        diffs.extend(np.abs(np.asarray(stream.logprobs, np.float32) - np.asarray(reference_lp)).tolist())
+    median, within = float(np.median(diffs)), float(np.mean(np.asarray(diffs) <= 0.5))
+    check(median <= LOGPROB_ATOL / 2, f"served log-probs off the reference by {median} nats at the median (> {LOGPROB_ATOL / 2})")
+    check(within >= 0.9, f"only {within:.0%} of the served log-probs lie within half a nat of the reference")
+    moe = stats["moe"]
+    check(moe["routed_pairs"] > moe["local_pairs"] > 0 and moe["decode"]["experts_hit"] > 0, f"routing counters {moe}")
+    check(stats["decode_attention_path"] == ("paged_kernel" if on_tpu else "gather"), f"decoded through {stats['decode_attention_path']}")
+    # the kernel read of the sliding layer starts at the window's first page; the gather read masks instead
+    check((stats["decode_window_pages_skipped"] > 0) == on_tpu, f"window pages skipped: {stats['decode_window_pages_skipped']}")
+    return {
+        "requests": len(prompts), "prompt_tokens": list(sz.afmoe_prompts), "logprob_abs_diff_median": round(median, 5),
+        "logprob_abs_diff_max": round(max(diffs), 4), "within_half_a_nat": round(within, 4), "moe": moe,
+        "decode_attention_path": stats["decode_attention_path"], "decode_window_pages_skipped": stats["decode_window_pages_skipped"],
+    }
+
+
 # --------------------------------------------------------------------------- train
 
 
@@ -848,6 +920,7 @@ def main() -> int:
             ("fence", lambda: phase_fence(sz, args.seed, judge=on_tpu)),
             ("serve", lambda: phase_serve(sz, args.seed, errors)),
             ("decode_paths", lambda: phase_decode_paths(sz, args.seed, errors)),
+            ("afmoe", lambda: phase_afmoe(sz, args.seed, errors)),
             ("train", lambda: phase_train(sz, args.seed)),
         ]
 

@@ -1,0 +1,282 @@
+"""Plain reference for the afmoe decoder (Arcee Trinity family), given one chip's share.
+
+Straight ``jax.numpy`` in float32 with matmuls at ``highest`` precision: no cache,
+no batching, no kernels, no sort, nothing imported from the program. One
+sequence at a time, one layer at a time (each layer's bfloat16 weights are upcast
+on the way in), the held experts by a Python loop over them. Equations, with
+``h`` the residual stream, ``eps`` the file's ``rms_norm_eps`` and ``R`` an RMS
+norm with a learned scale::
+
+    h0 = E[tokens] * sqrt(hidden)                                    (mup_enabled)
+    a = R(h); q = Wq a; k = Wk a; v = Wv a; g = sigmoid(Wg a)
+    q, k <- R over each head's channels (one scale vector for q, one for k)
+    sliding layer: rotary on q, k; key j visible to query i iff i - window < j <= i
+    full layer:    no rotary;       key j visible to query i iff j <= i
+    h <- h + R(Wo (softmax(q k^T / sqrt(head_dim)) v * g))
+    m = R(h)
+    layer < num_dense_layers:  f = SwiGLU_intermediate(m)
+    else:  s = sigmoid(Wr m) over all router_experts; sel = top-k of s + b
+           w = s[sel] / (sum s[sel] + 1e-20) * route_scale
+           f = SwiGLU_shared(m) + sum over e in sel, e held here, of w_e SwiGLU_e(m)
+    h <- h + R(f)
+    logits = W_head R(h)
+
+**The share.** The configuration's ``num_experts`` counts the experts held here,
+``experts_first`` says where they start among the router's ``router_experts``
+outputs. The routing (scores, choice, weights) is over all of them; what the
+absent experts would have added is left out, here as in the program.
+
+Weights are the benchmark's own (``make_weights``): the harness hands the same
+arrays to the program. The tree's layout is the interface both sides agree on::
+
+    embed/embedding [V, D]; final_norm/scale [D]; lm_head/kernel [D, V]
+    layer_i/{attn_norm,post_attn_norm,mlp_norm,post_mlp_norm}/scale [D]
+    layer_i/attn/{q_proj,gate_proj}/kernel [D, H*hd]; {k_proj,v_proj}/kernel [D, KV*hd]; o_proj/kernel [H*hd, D]
+    layer_i/attn/{q_norm,k_norm}/scale [hd]
+    dense layers:  layer_i/mlp/{wg,wi,wo}/kernel
+    expert layers: layer_i/shared/{wg,wi,wo}/kernel; layer_i/moe/router/kernel [D, router_experts];
+                   layer_i/moe/router_bias [router_experts]; layer_i/moe/experts/{wg,wi}/kernel [held, D, F], wo [held, F, D]
+
+Departures from the published model are listed in the configuration's file
+(adjacent-pair rotary, the assumed elementwise parts); the reference follows the file.
+
+``int8_weights=True`` is the lower-precision control: the same forward with every
+matrix the program's int8 path would quantize (attention, dense, shared and
+expert kernels and the head; not the embedding, not the router) rounded to
+symmetric int8 with one scale an output channel, and an expert's own scales.
+"""
+
+from __future__ import annotations
+
+from functools import partial
+from typing import Any, Dict, Mapping, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+
+def shapes(cfg: Mapping[str, Any]) -> Dict[str, Any]:
+    d, hd = cfg["hidden_size"], cfg["head_dim"]
+    h, kv, v = cfg["num_attention_heads"], cfg["num_key_value_heads"], cfg["vocab_size"]
+    ff, mf = cfg["intermediate_size"], cfg["moe_intermediate_size"]
+    held, routed = cfg["num_experts"], cfg["router_experts"]
+    swiglu = lambda width: {"wg": {"kernel": (d, width)}, "wi": {"kernel": (d, width)}, "wo": {"kernel": (width, d)}}  # noqa: E731
+    tree: Dict[str, Any] = {"embed": {"embedding": (v, d)}, "final_norm": {"scale": (d,)}, "lm_head": {"kernel": (d, v)}}
+    for i in range(cfg["num_hidden_layers"]):
+        layer: Dict[str, Any] = {
+            "attn_norm": {"scale": (d,)}, "post_attn_norm": {"scale": (d,)},
+            "mlp_norm": {"scale": (d,)}, "post_mlp_norm": {"scale": (d,)},
+            "attn": {
+                "q_proj": {"kernel": (d, h * hd)}, "k_proj": {"kernel": (d, kv * hd)}, "v_proj": {"kernel": (d, kv * hd)},
+                "gate_proj": {"kernel": (d, h * hd)}, "o_proj": {"kernel": (h * hd, d)},
+                "q_norm": {"scale": (hd,)}, "k_norm": {"scale": (hd,)},
+            },
+        }
+        if i < cfg["num_dense_layers"]:
+            layer["mlp"] = swiglu(ff)
+        else:
+            layer["shared"] = swiglu(mf * cfg["num_shared_experts"])
+            layer["moe"] = {
+                "router": {"kernel": (d, routed)}, "router_bias": (routed,),
+                "experts": {"wg": {"kernel": (held, d, mf)}, "wi": {"kernel": (held, d, mf)}, "wo": {"kernel": (held, mf, d)}},
+            }
+        tree[f"layer_{i}"] = layer
+    return tree
+
+
+def make_weights(cfg: Mapping[str, Any], seed: int, dtype: Any = jnp.bfloat16) -> Dict[str, Any]:
+    """Seeded random weights, made on the device in one jitted call, in the type
+    they are served in. Matrices are normal(0, 1/sqrt(fan_in)) (an expert's from
+    its own fan-in); the embedding is normal(0, 1) after its multiplier (so
+    normal(0, 1/sqrt(hidden)) under ``mup_enabled``: the stream the layers add to
+    has unit scale, and a layer's part is not lost under it); norm scales ones and the
+    router's selection bias normal(0, 0.02) so that it changes some choices
+    (both float32, as the program keeps them)."""
+    tree = shapes(cfg)
+    is_shape = lambda x: isinstance(x, tuple)  # noqa: E731
+    flat, treedef = jax.tree_util.tree_flatten_with_path(tree, is_leaf=is_shape)
+    names = ["/".join(str(k.key) for k in path) for path, _ in flat]
+
+    @jax.jit
+    def build(key):
+        out = []
+        for i, (name, (_, shape)) in enumerate(zip(names, flat)):
+            sub = jax.random.fold_in(key, i)
+            if name.endswith("router_bias"):
+                out.append(jax.random.normal(sub, shape, jnp.float32) * 0.02)
+            elif len(shape) == 1:
+                out.append(jnp.ones(shape, jnp.float32))
+            else:
+                std = shape[-2] ** -0.5
+                if name.startswith("embed"):
+                    std = shape[-1] ** -0.5 if cfg.get("mup_enabled") else 1.0
+                out.append((jax.random.normal(sub, shape, jnp.float32) * std).astype(dtype))
+        return out
+
+    key = jax.random.fold_in(jax.random.PRNGKey(seed & 0x7FFFFFFF), seed >> 31)
+    return jax.tree_util.tree_unflatten(treedef, build(key))
+
+
+def _matrix(a, int8):
+    """A stored matrix ``[..., K, N]`` as float32; with ``int8`` rounded to 127 levels a side, a scale per
+    output channel (the largest magnitude over the contraction axis), and read back."""
+    a = a.astype(jnp.float32)
+    if not int8:
+        return a
+    scale = jnp.maximum(jnp.max(jnp.abs(a), axis=-2, keepdims=True), 1e-8) / 127.0
+    return jnp.clip(jnp.round(a / scale), -127, 127) * scale
+
+
+def _rms_norm(x, scale, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def _rope(x, positions, theta):
+    """x [L, H, D]; rotates adjacent channel pairs (2i, 2i+1) by position * theta**(-2i/D)."""
+    d = x.shape[-1]
+    freqs = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    angles = positions.astype(jnp.float32)[:, None, None] * freqs
+    cos, sin = jnp.cos(angles), jnp.sin(angles)
+    x1, x2 = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1).reshape(x.shape)
+
+
+def _swiglu(m, w, int8=False):
+    mat = lambda a: _matrix(a, int8)  # noqa: E731
+    return (jax.nn.silu(m @ mat(w["wg"]["kernel"])) * (m @ mat(w["wi"]["kernel"]))) @ mat(w["wo"]["kernel"])
+
+
+def route(m, router, bias, *, top_k, route_norm, route_scale):
+    """``(chosen [L, k], weights [L, k])`` over all of the router's experts."""
+    scores = jax.nn.sigmoid(m @ router.astype(jnp.float32))
+    _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if route_norm:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen, weights * route_scale
+
+
+@partial(jax.jit, static_argnames=("n_heads", "n_kv", "head_dim", "theta", "eps", "window", "q_block", "int8"))
+def _attention(x, w, *, n_heads, n_kv, head_dim, theta, eps, window, q_block, int8=False):
+    """The attention half of a block on one sequence: x [L, D] float32. ``window``
+    None: a full layer (causal, no rotary); else a sliding layer."""
+    with jax.default_matmul_precision("highest"):
+        f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+        mat = lambda a: _matrix(a, int8)  # noqa: E731
+        length = x.shape[0]
+        positions = jnp.arange(length)
+        a = _rms_norm(x, f32(w["attn_norm"]["scale"]), eps)
+        q = (a @ mat(w["attn"]["q_proj"]["kernel"])).reshape(length, n_heads, head_dim)
+        k = (a @ mat(w["attn"]["k_proj"]["kernel"])).reshape(length, n_kv, head_dim)
+        v = (a @ mat(w["attn"]["v_proj"]["kernel"])).reshape(length, n_kv, head_dim)
+        gate = jax.nn.sigmoid(a @ mat(w["attn"]["gate_proj"]["kernel"]))
+        q = _rms_norm(q, f32(w["attn"]["q_norm"]["scale"]), eps)
+        k = _rms_norm(k, f32(w["attn"]["k_norm"]["scale"]), eps)
+        if window is not None:
+            q, k = _rope(q, positions, theta), _rope(k, positions, theta)
+        q = q.reshape(length, n_kv, n_heads // n_kv, head_dim)
+        outs = []
+        for start in range(0, length, q_block):  # query blocks bound the [H, q, L] score tensor
+            rows = positions[start : start + q_block, None]
+            scores = jnp.einsum("qkgd,skd->kgqs", q[start : start + q_block], k) * head_dim**-0.5
+            visible = positions[None, :] <= rows
+            if window is not None:
+                visible = visible & (positions[None, :] > rows - window)
+            scores = jnp.where(visible[None, None], scores, -jnp.inf)
+            outs.append(jnp.einsum("kgqs,skd->qkgd", jax.nn.softmax(scores, axis=-1), v))
+        attn = jnp.concatenate(outs, axis=0).reshape(length, n_heads * head_dim) * gate
+        return x + _rms_norm(attn @ mat(w["attn"]["o_proj"]["kernel"]), f32(w["post_attn_norm"]["scale"]), eps)
+
+
+@partial(jax.jit, static_argnames=("eps", "int8"))
+def _dense_ffn(x, w, *, eps, int8=False):
+    with jax.default_matmul_precision("highest"):
+        m = _rms_norm(x, w["mlp_norm"]["scale"].astype(jnp.float32), eps)
+        return x + _rms_norm(_swiglu(m, w["mlp"], int8), w["post_mlp_norm"]["scale"].astype(jnp.float32), eps)
+
+
+@partial(jax.jit, static_argnames=("eps", "top_k", "route_norm", "route_scale", "int8"))
+def _routing(x, w, *, eps, top_k, route_norm, route_scale, int8=False):
+    with jax.default_matmul_precision("highest"):
+        m = _rms_norm(x, w["mlp_norm"]["scale"].astype(jnp.float32), eps)
+        chosen, weights = route(m, w["moe"]["router"]["kernel"], w["moe"]["router_bias"], top_k=top_k,
+                                route_norm=route_norm, route_scale=route_scale)
+        return m, chosen, weights, _swiglu(m, w["shared"], int8)
+
+
+@partial(jax.jit, static_argnames=("int8",))
+def _one_expert(m, wg, wi, wo, weight, int8=False):
+    """``weight [L]`` (zero where the token did not choose this expert) times the expert's SwiGLU."""
+    with jax.default_matmul_precision("highest"):
+        return weight[:, None] * ((jax.nn.silu(m @ _matrix(wg, int8)) * (m @ _matrix(wi, int8))) @ _matrix(wo, int8))
+
+
+@partial(jax.jit, static_argnames=("eps",))
+def _close_ffn(x, f, scale, *, eps):
+    return x + _rms_norm(f, scale.astype(jnp.float32), eps)
+
+
+def expert_layer(x, w, cfg: Mapping[str, Any], int8: bool = False):
+    """The feed-forward half of an expert layer on one sequence, the held experts one at a time.
+    Returns ``(h, chosen [L, k])`` (the choice, for whoever counts the routing)."""
+    eps = float(cfg["rms_norm_eps"])
+    m, chosen, weights, f = _routing(x, w, eps=eps, top_k=int(cfg["num_experts_per_tok"]), int8=int8,
+                                     route_norm=bool(cfg["route_norm"]), route_scale=float(cfg["route_scale"]))
+    first = int(cfg.get("experts_first", 0))
+    experts = w["moe"]["experts"]
+    for local in range(int(cfg["num_experts"])):
+        weight = jnp.sum(jnp.where(chosen == first + local, weights, 0.0), axis=-1)
+        f = f + _one_expert(m, experts["wg"]["kernel"][local], experts["wi"]["kernel"][local],
+                            experts["wo"]["kernel"][local], weight, int8=int8)
+    return _close_ffn(x, f, w["post_mlp_norm"]["scale"], eps=eps), chosen
+
+
+@partial(jax.jit, static_argnames=("eps", "int8"))
+def _head(x, rows, scale, kernel, *, eps, int8=False):
+    with jax.default_matmul_precision("highest"):
+        h = _rms_norm(x[rows], scale.astype(jnp.float32), eps)
+        return h @ _matrix(kernel, int8)
+
+
+def hidden_states(weights: Mapping[str, Any], cfg: Mapping[str, Any], ids: np.ndarray, routing: Any = None,
+                  int8_weights: bool = False):
+    """The residual stream after the last layer, ``[len(ids), D]`` float32. ``routing``,
+    a list, receives each expert layer's choice ``[L, k]``."""
+    x = jnp.take(weights["embed"]["embedding"], jnp.asarray(ids), axis=0).astype(jnp.float32)
+    if cfg.get("mup_enabled"):
+        x = x * float(cfg["hidden_size"]) ** 0.5
+    eps = float(cfg["rms_norm_eps"])
+    for i in range(cfg["num_hidden_layers"]):
+        w = weights[f"layer_{i}"]
+        sliding = cfg["layer_types"][i] == "sliding_attention"
+        x = _attention(
+            x, w, n_heads=cfg["num_attention_heads"], n_kv=cfg["num_key_value_heads"], head_dim=cfg["head_dim"],
+            theta=float(cfg["rope_theta"]), eps=eps, window=int(cfg["sliding_window"]) if sliding else None, q_block=1024,
+            int8=int8_weights,
+        )
+        if i < cfg["num_dense_layers"]:
+            x = _dense_ffn(x, w, eps=eps, int8=int8_weights)
+        else:
+            x, chosen = expert_layer(x, w, cfg, int8_weights)
+            if routing is not None:
+                routing.append(np.asarray(chosen))
+    return x
+
+
+def logits_at(weights: Mapping[str, Any], cfg: Mapping[str, Any], tokens: Sequence[int], rows: Sequence[int],
+              pad_to: int = 512, int8_weights: bool = False) -> np.ndarray:
+    """Logits ``[len(rows), vocab]`` (float32, on the host) of one full forward
+    pass over ``tokens`` at sequence positions ``rows``. The sequence is padded
+    on the right to a multiple of ``pad_to`` (causal attention never sees the
+    padding, and one token's routing never depends on another's), so few shapes compile."""
+    n = len(tokens)
+    width = -(-n // pad_to) * pad_to
+    ids = np.zeros((width,), np.int32)
+    ids[:n] = np.asarray(tokens, np.int32)
+    x = hidden_states(weights, cfg, ids, int8_weights=int8_weights)
+    row_ids = np.zeros((-(-len(rows) // 64) * 64,), np.int32)
+    row_ids[: len(rows)] = np.asarray(rows, np.int32)
+    out = _head(x, jnp.asarray(row_ids), weights["final_norm"]["scale"], weights["lm_head"]["kernel"],
+                eps=float(cfg["rms_norm_eps"]), int8=int8_weights)
+    return np.asarray(out)[: len(rows)]

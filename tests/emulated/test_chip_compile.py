@@ -19,7 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from unionml_tpu.ops.flash_attention import flash_attention
 from unionml_tpu.ops.int8_matmul import int8_matmul
-from unionml_tpu.ops.paged_attention import paged_decode_attention
+from unionml_tpu.ops.paged_attention import paged_decode_attention, paged_window_decode_attention
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -72,6 +72,26 @@ def _paged(int8):
     return quantized, args + [scales, scales]
 
 
+def _paged_window():
+    """A sliding layer's decode read at the afmoe cell's shape: 128 rows, 48 query / 8 KV heads of 128, a table of
+    84 pages of 64 over a pool of 3,073, window 4,096 (the kernel's part: 64 pages, 16 a block)."""
+    def windowed(q, k, v, lengths, table):
+        return paged_window_decode_attention(q, k, v, lengths, table, window=4096)
+
+    pages = ((8, 3073, 64, 128), jnp.bfloat16)
+    return windowed, [((128, 48, 128), jnp.bfloat16), pages, pages, ((128,), jnp.int32), ((128, 84), jnp.int32)]
+
+
+def _grouped_matmul(rows):
+    """The held experts' product on the chip: the pallas grouped matmul, at 32 experts of 3072 x 3072 (a decode step of 128 slots: 512 rows; a chunk of 256 tokens: 1,024)."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    def product(a, w, sizes):
+        return gmm(a, w, sizes, preferred_element_type=jnp.bfloat16, tiling=(128, 1024, 1024))
+
+    return product, [((rows, 3072), jnp.bfloat16), ((32, 3072, 3072), jnp.bfloat16), ((32,), jnp.int32)]
+
+
 CASES = {
     "flash_fwd": _flash("fwd"),
     "flash_bwd": _flash("bwd"),
@@ -79,6 +99,9 @@ CASES = {
     "int8_matmul_m1": _int8_matmul(1),
     "paged_decode_bf16": _paged(int8=False),
     "paged_decode_int8": _paged(int8=True),
+    "paged_window_decode": _paged_window(),
+    "grouped_matmul_decode_512": _grouped_matmul(512),
+    "grouped_matmul_chunk_1024": _grouped_matmul(1024),
 }
 
 
@@ -128,6 +151,39 @@ def test_decode_steps_compiles_for_v5e_without_a_gathered_copy(chip, shape):
     assert compiled.memory_analysis().temp_size_in_bytes < gathered
     # the kernel reads the pools row-major; a write that made XLA keep them otherwise would copy each one, each step
     assert not re.search(rf"= bf16\[8,{pool},{page},128\]\S* copy\(", text)
+
+
+def test_afmoe_decode_steps_compiles_for_v5e(chip):
+    """The afmoe decode program at published widths (a dense sliding layer, a sliding and a full expert layer,
+    32 held of 256 experts) over the cell's paged cache, through the kernel reads (forced, as above): Mosaic
+    takes the windowed read, the plain read and — the trace's backend being the CPU, the routed product is
+    ``ragged_dot`` here — XLA's own grouped kernel; the program counts its five counters into the carry."""
+    from unionml_tpu.models import AfmoeConfig, AfmoeTransformer, GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_paged_cache
+
+    slots, pages, pool, page = 128, 84, 3073, 64
+    config = AfmoeConfig(
+        vocab_size=25024, n_layers=3, n_dense_layers=1, experts_held=(0, 32), attention_impl="flash",
+        layer_types=("sliding_attention", "sliding_attention", "full_attention"), param_dtype=jnp.bfloat16,
+    )
+    module = AfmoeTransformer(config)
+
+    def on_chip(make):
+        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), jax.eval_shape(make))
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
+    assert cache[0]["k"].shape == (8, pool, page, 128)  # the published head width, not dim // n_heads = 64
+    tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
+    counts = jax.ShapeDtypeStruct((5,), jnp.int32, sharding=chip)
+    gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
+    compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
+    assert gen.decode_attention_path == "paged_kernel" and gen.counter_names[-1] == "decode_window_pages_skipped"
+    text = compiled.as_text()
+    assert text.count("paged_window_attention") >= 2  # the two sliding layers' reads
+    assert not re.search(rf"= bf16\[8,{pool},{page},128\]\S* copy\(", text)  # no pool re-laid for a kernel
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
 
 
 @pytest.mark.parametrize("where", ["checkout", "alone"])

@@ -422,3 +422,20 @@ def test_debug_engine_endpoint_answers(tiny_gen, sklearn_model):
         assert ("GET", "/debug/engine") in app.server._drain_exempt  # answers while a drain is stuck
     finally:
         batcher.close()
+
+
+@pytest.mark.parametrize("kind,want", [(None, {"pairs": 12, "max_load": 7}), ("decode", {"pairs": 5, "max_load": 3}), ("absent", {"pairs": 0, "max_load": 0})])
+def test_model_counters_add_up_by_kind_and_max_names_keep_the_largest(kind, want):
+    """What a counting model reports per dispatch: sums, except ``max_*`` names; by kind of dispatch and over
+    all; served by ``snapshot`` (``GET /debug/engine``) and zeroed with the totals."""
+    from unionml_tpu.observability.engine_log import EngineLog
+
+    log = EngineLog()
+    assert "model_counters" not in log.snapshot(0)  # a model that counts nothing adds no key
+    log.count("decode", ("pairs", "max_load"), [2, 3])
+    log.count("decode", ("pairs", "max_load"), [3, 1])
+    log.count("prefill", ("pairs", "max_load"), [7, 7])
+    assert log.counted(("pairs", "max_load"), kind) == want
+    assert log.snapshot(0)["model_counters"] == {"decode": {"pairs": 5, "max_load": 3}, "prefill": {"pairs": 7, "max_load": 7}}
+    log.clear()
+    assert log.counted(("pairs",)) == {"pairs": 0} and "model_counters" not in log.snapshot(0)

@@ -1,5 +1,6 @@
 """Model library: flagship flax models for the benchmark configs (BASELINE.json)."""
 
+from unionml_tpu.models.afmoe import AfmoeConfig, AfmoeTransformer, afmoe_partition_rules  # noqa: F401
 from unionml_tpu.models.bert import BertConfig, BertEncoder, bert_partition_rules, classification_loss  # noqa: F401
 from unionml_tpu.models.generate import (  # noqa: F401
     DraftSpec,
@@ -30,6 +31,7 @@ from unionml_tpu.models.llama import (  # noqa: F401
 )
 from unionml_tpu.models.mlp import MLPClassifier, MLPConfig  # noqa: F401
 from unionml_tpu.models.moe import (  # noqa: F401
+    ExpertShare,
     MoEConfig,
     MoELayer,
     MoETransformer,

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -128,13 +128,20 @@ def chunk_aligned(length: int, chunk: int) -> int:
     return -(-length // chunk) * chunk
 
 
+def _head_dim(config: Any) -> int:
+    """A head's width: the configuration's ``head_dim`` where it states one (a
+    published head width need not be ``dim // n_heads``), else that quotient."""
+    return int(getattr(config, "head_dim", None) or config.dim // config.n_heads)
+
+
 def init_cache(config: Any, batch: int, cache_len: int, kv_dtype: Optional[str] = None) -> Tuple[Any, ...]:
     """Zeroed per-layer KV buffers for a decoder with ``config.n_layers`` layers,
-    ``config.n_kv_heads`` KV heads and head_dim ``dim // n_heads``, stored in the
+    ``config.n_kv_heads`` KV heads and the configuration's own head width
+    (``head_dim``, else ``dim // n_heads``), stored in the
     compute dtype (bf16 on TPU — halves cache HBM vs f32). ``kv_dtype="int8"``
     adds per-(position, head) scale planes and stores values int8 (see
     :class:`~unionml_tpu.models.layers.Attention`'s cached branch)."""
-    head_dim = config.dim // config.n_heads
+    head_dim = _head_dim(config)
     shape = (batch, cache_len, config.n_kv_heads, head_dim)
     if kv_dtype == "int8":
         scale_shape = (batch, cache_len, config.n_kv_heads, 1)
@@ -180,7 +187,7 @@ def init_paged_cache(
     layer (same values; a few hundred bytes). See
     :meth:`unionml_tpu.models.layers.Attention._paged_cached_attention` for the
     read/write contract; HBM scales with the pool, not slots x worst-case."""
-    head_dim = config.dim // config.n_heads
+    head_dim = _head_dim(config)
     shape = (config.n_kv_heads, n_blocks, block_size, head_dim)
     # one table PER layer (same values): the cache is donated through admission
     # and decode, and donating an array aliased across layers is an XLA error
@@ -380,6 +387,13 @@ class Generator:
         #: before the first trace and for a contiguous cache
         self.decode_attention_path: Optional[str] = None
         self._paged_read_traced: Optional[str] = None  # the newest traced apply's paged read
+        #: what the module counts per call (its ``counters`` attribute: names it sows into
+        #: the ``counters`` collection; ``max_*`` merge by maximum, the rest add up). With
+        #: any, ``prefill_chunk`` returns the chunk's counts as its fourth output and the
+        #: decode carry ends in the counts of the dispatch that returned it (``[len(names)]`` int32)
+        self.counter_names: Tuple[str, ...] = tuple(getattr(module, "counters", ()))
+        #: how the module wants them served (its ``counter_views``: stats key -> the names under it)
+        self.counter_views: Dict[str, Tuple[str, ...]] = dict(getattr(module, "counter_views", {}))
         compute_dtype = getattr(getattr(module, "config", None), "dtype", jnp.bfloat16)
 
         if quantize not in (None, "int8"):
@@ -440,19 +454,43 @@ class Generator:
         # the layers see tracers, not placements, so the trace is told
         pools_sharded = mesh is not None and mesh.devices.size > 1
 
-        def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
+        names = self.counter_names
+        by_max = np.array([name.startswith("max_") for name in names], bool)
+
+        def merge_counts(a: jax.Array, b: jax.Array) -> jax.Array:
+            return jnp.where(by_max, jnp.maximum(a, b), a + b)
+
+        def collect_counts(sown: Any) -> jax.Array:
+            """One call's counts, ``[len(names)]`` int32, from what its layers sowed under each name."""
+            found: dict = {name: [] for name in names}
+            for path, leaf in jax.tree_util.tree_flatten_with_path(sown)[0]:
+                name = [k.key for k in path if isinstance(k, jax.tree_util.DictKey)][-1]
+                if name in found:
+                    found[name].append(leaf.astype(jnp.int32))
+            merged = [
+                (jnp.max if name.startswith("max_") else jnp.sum)(jnp.stack(found[name])) if found[name] else jnp.int32(0)
+                for name in names
+            ]
+            return jnp.stack(merged) if merged else jnp.zeros((0,), jnp.int32)
+
+        def apply_counted(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
             with paged_read_scope(sharded=pools_sharded) as paths:
-                hidden, cache = module.apply(
+                out = module.apply(
                     {"params": p},
                     tokens,
                     positions=positions,
                     return_hidden=True,
                     cache=cache,
                     token_mask=token_mask,
+                    mutable=["counters"] if names else False,
                 )
+            (hidden, cache), sown = out if names else (out, {})
             # which way a paged cache was read (None: a contiguous one), for whoever traces a decode program
             self._paged_read_traced = "+".join(sorted(set(paths))) or None
-            return hidden, cache
+            return hidden, cache, collect_counts(sown.get("counters", {}))
+
+        def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
+            return apply_counted(p, tokens, positions, cache, token_mask)[:2]
 
         def head(p: Any, hidden: jax.Array) -> jax.Array:
             kernel = p["lm_head"]["kernel"]
@@ -475,16 +513,17 @@ class Generator:
             """One chunk of a long-context prefill: columns [start, start+C) of the
             padded prompt flow through the cache (attention sees all previously
             written slots). Also extracts the hidden row of each example's last
-            real token if it falls inside this chunk."""
+            real token if it falls inside this chunk. The fourth output is what
+            the module counted over the chunk (``counter_names``; empty without)."""
             self.prefill_traces += 1
             p = dequant(p)
             batch, chunk = tokens.shape
             positions = start + jnp.broadcast_to(jnp.arange(chunk)[None], (batch, chunk))
             token_mask = (positions < lengths[:, None]) & row_valid[:, None]
-            hidden, cache = apply(p, tokens, positions, cache, token_mask)
+            hidden, cache, counts = apply_counted(p, tokens, positions, cache, token_mask)
             sel = positions == (lengths - 1)[:, None]  # at most one true column per row
             chunk_last = jnp.einsum("blc,bl->bc", hidden.astype(jnp.float32), sel.astype(jnp.float32))
-            return chunk_last, sel.any(axis=1), cache
+            return chunk_last, sel.any(axis=1), cache, counts
 
         def first_token(p, last, key, *cstate):
             """Sample the first generated token from accumulated last-row hiddens
@@ -501,16 +540,23 @@ class Generator:
             value — __call__ always uses max_new_tokens - 1 and stream() a
             fixed chunk size, so the trace set stays tiny. With constraints the
             carry gains each row's DFA state as its tail element; ``steps`` is
-            keyword-only so both carry layouts share this signature."""
+            keyword-only so both carry layouts share this signature. A module
+            that counts (``counter_names``) ends the carry in one more element:
+            what it counted over this dispatch's steps (the incoming value, the
+            dispatch before's, is dropped)."""
             self.decode_traces += 1
             eos = config.eos_id
+            if names:
+                cstate = (*cstate[:-1], jnp.zeros((len(names),), jnp.int32))
 
             def body(carry, _):
                 cache, tok, lengths, done, key, *cst = carry
                 key, sub = jax.random.split(key)
                 ps = dequant(p)  # per-step so int8, not bf16, is the steady-state HBM read
                 positions = lengths[:, None]  # each example's next free cache slot
-                hidden, cache = apply(ps, tok[:, None], positions, cache, (~done)[:, None])
+                hidden, cache, counts = apply_counted(ps, tok[:, None], positions, cache, (~done)[:, None])
+                if names:
+                    cst[-1] = merge_counts(cst[-1], counts)
                 logits = constrain(head(ps, hidden[:, 0]), cst)
                 nxt = sample_tokens(logits, sub, config)
                 # the chosen token's logprob rides along (one gather + one
@@ -522,7 +568,7 @@ class Generator:
                 lp = jnp.where(done, jnp.float32(0.0), lp)
                 if cs is not None:
                     # done rows hold their state (their sampled token is a pad)
-                    cst = (jnp.where(done, cst[0], self._cs_trans[cst[0], nxt]),)
+                    cst[0] = jnp.where(done, cst[0], self._cs_trans[cst[0], nxt])
                 nxt = jnp.where(done, jnp.int32(config.pad_id), nxt)
                 lengths = lengths + jnp.where(done, 0, 1)
                 if eos is not None:
@@ -571,7 +617,7 @@ class Generator:
             # bumped when a program's OUTPUT signature changes (the decode
             # scan gained a logprobs output): stale serialized executables
             # from an older layout must miss and recompile, not load
-            "program_abi": "decode-logprobs-v2",
+            "program_abi": "decode-logprobs-v3-chunk-counts",
             **mesh_context(self.mesh),
         }
         if self._cs is not None:
@@ -885,7 +931,7 @@ class Generator:
         accumulating each row's last-real-token hidden state."""
         last = jnp.zeros((tokens.shape[0], self.module.config.dim), jnp.float32)
         for c in range(0, tokens.shape[1], chunk):
-            chunk_last, has, cache = self._prefill_chunk(
+            chunk_last, has, cache, _ = self._prefill_chunk(
                 self.params,
                 jnp.asarray(tokens[:, c : c + chunk]),
                 jnp.int32(start + c),
@@ -921,6 +967,8 @@ class Generator:
             # advance each row's DFA past its (constrained) first token; the
             # state rides as the carry's tail through the decode scan
             carry = carry + (self._cs_trans[cstate[0], tok0],)
+        if self.counter_names:
+            carry = carry + (jnp.zeros((len(self.counter_names),), jnp.int32),)
         return n, tok0, last, carry
 
     def _start_with_prefix(

@@ -181,6 +181,16 @@ class Attention(nn.Module):
     fused attention; see :func:`unionml_tpu.ops.attention.multihead_attention`) and,
     for a single-token read of a paged cache on a TPU, the pallas paged-attention
     kernel (:func:`unionml_tpu.ops.paged_attention.paged_read_path`).
+
+    ``window``: sliding-window attention — key ``j`` is visible to the query at
+    position ``i`` iff ``i - window < j <= i`` (on top of causality). Every read
+    masks it; the kernel read of a paged cache starts at the window's first page
+    instead (:func:`unionml_tpu.ops.paged_attention.paged_window_decode_attention`),
+    and counts the pages it never touched for rows live in ``token_mask`` under
+    ``counters/decode_window_pages_skipped``. ``qk_norm``: an RMS norm with a learned
+    scale over each query and key head's channels, before the rotary embedding.
+    ``gated``: the heads' output is multiplied by ``sigmoid(gate_proj(x))``
+    before ``o_proj``. All three default off, and add no parameter when off.
     """
 
     n_heads: int
@@ -193,6 +203,10 @@ class Attention(nn.Module):
     lora_rank: int = 0
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
+    window: Optional[int] = None
+    qk_norm: bool = False
+    gated: bool = False
+    norm_epsilon: float = 1e-6
 
     @nn.compact
     def __call__(
@@ -201,6 +215,7 @@ class Attention(nn.Module):
         positions: Optional[jax.Array] = None,
         mask: Optional[jax.Array] = None,
         cache: Optional[LayerCache] = None,
+        token_mask: Optional[jax.Array] = None,
     ) -> Any:
         features = x.shape[-1]
         n_kv = self.n_kv_heads or self.n_heads
@@ -217,6 +232,16 @@ class Attention(nn.Module):
         q = q.reshape(batch, length, self.n_heads, head_dim)
         k = k.reshape(batch, length, n_kv, head_dim)
         v = v.reshape(batch, length, n_kv, head_dim)
+
+        if self.qk_norm:
+            q = RMSNorm(epsilon=self.norm_epsilon, dtype=self.dtype, name="q_norm")(q)
+            k = RMSNorm(epsilon=self.norm_epsilon, dtype=self.dtype, name="k_norm")(k)
+
+        def project(out: jax.Array) -> jax.Array:
+            out = out.reshape(batch, length, self.n_heads * head_dim)
+            if self.gated:
+                out = out * jax.nn.sigmoid(dense(self.n_heads * head_dim, "gate_proj")(x))
+            return dense(features, "o_proj")(out)
 
         if self.rope:
             if positions is None:
@@ -249,9 +274,8 @@ class Attention(nn.Module):
                 # finished/free slots are repointed to a scratch block by the
                 # engine that owns the pool (see serving/continuous.py), which
                 # is what makes their ride-along writes harmless.
-                out, cache = self._paged_cached_attention(q, k, v, positions, cache)
-                out = out.reshape(batch, length, self.n_heads * head_dim)
-                return dense(features, "o_proj")(out), cache
+                out, cache = self._paged_cached_attention(q, k, v, positions, cache, token_mask)
+                return project(out), cache
             starts = positions[:, 0]
             if "k_scale" in cache:
                 # int8 KV cache: symmetric per-(position, head) quantization on
@@ -275,17 +299,21 @@ class Attention(nn.Module):
                 }
                 keys = cache["k"].astype(q.dtype)
                 values = cache["v"].astype(q.dtype)
-            slot = jnp.arange(cache["k"].shape[1])
-            visible = slot[None, None, None, :] <= positions[:, None, :, None]  # [B,1,L,S_max]
+            visible = self._visible(jnp.arange(cache["k"].shape[1]), positions)  # [B,1,L,S_max]
             out = multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla")
-            out = out.reshape(batch, length, self.n_heads * head_dim)
-            return dense(features, "o_proj")(out), cache
+            return project(out), cache
 
         # uncached forward: expose post-RoPE K/V for cache assembly (materialized
         # only when the caller passes mutable=["kvs"], e.g. the sequence-parallel
         # prefill; a plain apply pays nothing)
         self.sow("kvs", "k", k)
         self.sow("kvs", "v", v)
+
+        if self.window is not None:
+            at = jnp.arange(length) if positions is None else positions
+            band = at[..., :, None] - at[..., None, :] < self.window  # [L, L] or [B, L, L]
+            band = band[None, None] if band.ndim == 2 else band[:, None]
+            mask = band if mask is None else jnp.logical_and(mask, band)
 
         if self.impl in ("ring", "ulysses"):
             if mask is not None:
@@ -297,10 +325,19 @@ class Attention(nn.Module):
         else:
             out = multihead_attention(q, k, v, causal=self.causal, mask=mask, impl=self.impl)
 
-        out = out.reshape(batch, length, self.n_heads * head_dim)
-        return dense(features, "o_proj")(out)
+        return project(out)
 
-    def _paged_cached_attention(self, q, k, v, positions, cache):
+    def _visible(self, slots: jax.Array, positions: jax.Array) -> jax.Array:
+        """``[B, 1, L, S]``: cache slot ``j`` is visible to the query at absolute
+        position ``p`` iff ``j <= p`` — causal over everything written so far,
+        hiding slots not yet (re)written — and, under a window, ``j > p - window``."""
+        at = positions[:, None, :, None]
+        visible = slots[None, None, None, :] <= at
+        if self.window is not None:
+            visible = visible & (slots[None, None, None, :] > at - self.window)
+        return visible
+
+    def _paged_cached_attention(self, q, k, v, positions, cache, token_mask=None):
         """The paged write+read: scatter new rows through the block table, then
         attend. Which read serves it is decided by
         :func:`unionml_tpu.ops.paged_attention.paged_read_path` from what the
@@ -315,7 +352,13 @@ class Attention(nn.Module):
         Pools are heads-major ``[H_kv, n_pages, page_size, last]``. Scatter
         indices collide only on the scratch block (finished rows), where the
         winning value is irrelevant — real slots own disjoint blocks."""
-        from unionml_tpu.ops.paged_attention import PAGED_KERNEL, paged_decode_attention, paged_read_path
+        from unionml_tpu.ops.paged_attention import (
+            PAGED_KERNEL,
+            paged_decode_attention,
+            paged_read_path,
+            paged_window_decode_attention,
+            window_split,
+        )
 
         table = cache["table"]  # [B, max_blocks] int32
         block_size = cache["k"].shape[2]
@@ -359,14 +402,23 @@ class Attention(nn.Module):
             if path == PAGED_KERNEL:
                 cache = {"k": scatter_rows(cache["k"], k), "v": scatter_rows(cache["v"], v), "table": table}
                 # the row's visible length includes the token just scattered
-                out = paged_decode_attention(q[:, 0], cache["k"], cache["v"], positions[:, 0] + 1, table)
+                lengths = positions[:, 0] + 1
+                if self.window is None or self.window >= table.shape[1] * block_size:  # no row can outgrow it
+                    out = paged_decode_attention(q[:, 0], cache["k"], cache["v"], lengths, table)
+                else:
+                    with jax.named_scope("afmoe.attn_window"):
+                        out = paged_window_decode_attention(
+                            q[:, 0], cache["k"], cache["v"], lengths, table, window=self.window
+                        )
+                    skipped = window_split(lengths, self.window, block_size)[0]
+                    if token_mask is not None:
+                        skipped = jnp.where(token_mask[:, 0], skipped, 0)
+                    self.sow("counters", "decode_window_pages_skipped", jnp.sum(skipped, dtype=jnp.int32))
                 return out[:, None], cache
             cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v), "table": table}
             keys = logical(cache["k"]).astype(q.dtype)
             values = logical(cache["v"]).astype(q.dtype)
-        visible = (
-            jnp.arange(keys.shape[1])[None, None, None, :] <= positions[:, None, :, None]
-        )  # [B, 1, L, MB * bs]
+        visible = self._visible(jnp.arange(keys.shape[1]), positions)  # [B, 1, L, MB * bs]
         return multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla"), cache
 
 

@@ -19,6 +19,12 @@ Switch/Mixtral dense-dispatch formulation rather than per-rank alltoall calls:
 Load balancing uses the Switch aux loss (fraction-of-tokens x mean-router-prob per
 expert, scaled by n_experts); the layer ``sow``s it under the ``"losses"``
 collection and :func:`moe_lm_loss` adds it to the LM loss.
+
+:class:`ExpertShare` is the other formulation, for serving wide models: a
+DROPLESS layer that is told which experts it holds. It routes over every expert
+of the model, sorts the token-expert pairs that fall on held experts by expert
+and runs one grouped matrix product per projection over them; what the absent
+experts would have added is left out (their chips add it, in a deployment).
 """
 
 from __future__ import annotations
@@ -139,6 +145,153 @@ class MoELayer(nn.Module):
 
         out = jnp.einsum("nec,ecd->nd", combine.astype(self.dtype), expert_out)
         return out.reshape(batch, length, dim)
+
+
+#: what one :class:`ExpertShare` call counts (``counters`` collection; per call, over the rows live in ``token_mask``)
+MOE_COUNTERS = ("routed_pairs", "local_pairs", "experts_hit", "max_expert_load")
+
+
+def route_top_k(
+    scores: jax.Array, bias: jax.Array, k: int, *, normalize: bool = True, scale: float = 1.0
+) -> Tuple[jax.Array, jax.Array]:
+    """Choose ``k`` experts a token by ``scores + bias`` and weigh them by the
+    scores alone (the bias steers the choice, never the mixture): ``(experts [N,
+    k] int32, weights [N, k] f32)``. ``normalize`` divides by the chosen scores'
+    sum — over all ``k``, wherever their experts live — before ``scale``."""
+    _, chosen = jax.lax.top_k(scores + bias, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * scale
+
+
+def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``rows [M, K]`` sorted by group, ``kernels [G, K, N]``, ``group_sizes [G]``:
+    each group's rows times its own kernel, ``[M, N]``. Rows past the groups'
+    total belong to no group: what comes back for them is unspecified (callers
+    mask them).
+
+    On a TPU this is the pallas grouped matmul that ships with JAX
+    (``jax.experimental.pallas.ops.tpu.megablox.gmm``, ``gmm.<n>`` in a device
+    trace): it walks the row tiles each non-empty group touches and streams that
+    group's kernel once, so a step reads the weights of the experts that were
+    hit and of no other. Elsewhere ``jax.lax.ragged_dot``, which every backend
+    has. Measured on a v5e at 32 held experts of 3072 x 3072, all three SwiGLU
+    products (PERF.md section 6, "PR 26"): 640 rows of which 67 held (a decode
+    step of 160 slots) 2.84 ms against ragged_dot's 3.32; 1,024 rows of which
+    124 held (a prefill chunk of 256) 3.22 against 6.51 — ragged_dot, itself a
+    Mosaic kernel there, pays for the rows that belong to no group."""
+    group_sizes = group_sizes.astype(jnp.int32)
+    if jax.default_backend() != "tpu":
+        return jax.lax.ragged_dot(rows, kernels, group_sizes)
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    m, tile = rows.shape[0], 128  # the kernel tiles the rows exactly
+    padded = jnp.pad(rows, ((0, -m % tile), (0, 0)))
+    # 1024-wide tiles: of those tried (512 .. 3072) none was more than 3 % faster, and these fit any width's VMEM
+    out = gmm(padded, kernels, group_sizes, preferred_element_type=rows.dtype, tiling=(tile, 1024, 1024))
+    return out[:m]
+
+
+class _Kernel(nn.Module):
+    """A bare ``kernel`` parameter under its own name (``experts/wg/kernel``): the
+    paths the partition rules and the int8 weight quantizer match on."""
+
+    shape: Tuple[int, ...]
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        stacked = tuple(range(len(self.shape) - 2))  # an expert's fan-in is its own
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=stacked)
+        return self.param("kernel", init, self.shape, self.param_dtype)
+
+
+class _HeldExperts(nn.Module):
+    """The held experts' stacked SwiGLU weights: ``wg``, ``wi`` ``[count, D, F]``, ``wo`` ``[count, F, D]``."""
+
+    count: int
+    dim: int
+    hidden_dim: int
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> Tuple[jax.Array, jax.Array, jax.Array]:
+        up, down = (self.count, self.dim, self.hidden_dim), (self.count, self.hidden_dim, self.dim)
+        return tuple(
+            _Kernel(shape, self.param_dtype, name=name)() for name, shape in (("wg", up), ("wi", up), ("wo", down))
+        )
+
+
+class ExpertShare(nn.Module):
+    """One chip's share of a routed-experts feed-forward layer, dropless.
+
+    The router (``router/kernel [D, n_experts]``, float32 compute, sigmoid scores, and the
+    selection-only ``router_bias``) scores all ``n_experts``; ``experts_held =
+    (first, count)`` says which of them live here, as stacked SwiGLU weights
+    under ``experts/{wg,wi,wo}/kernel`` with a leading ``[count]`` dim. Every token
+    chooses ``k`` experts; the pairs whose expert is held are sorted by expert,
+    pass through one grouped product per projection (no capacity, no ``[N, E,
+    C]`` tensor, nothing dropped however skewed the routing), are un-sorted and
+    summed under their routing weights. Pairs on experts held elsewhere add
+    nothing here, and no code stands in for them. Rows masked out by
+    ``token_mask`` (padding, finished slots) route nowhere and count nowhere.
+
+    Counts :data:`MOE_COUNTERS` into the ``counters`` collection when the
+    caller makes it mutable.
+    """
+
+    n_experts: int
+    experts_held: Tuple[int, int]
+    hidden_dim: int
+    k: int = 2
+    route_norm: bool = True
+    route_scale: float = 1.0
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x: jax.Array, token_mask: Optional[jax.Array] = None) -> jax.Array:
+        batch, length, dim = x.shape
+        first, count = self.experts_held
+        if not (0 <= first and count >= 1 and first + count <= self.n_experts):
+            raise ValueError(f"experts_held {self.experts_held} lies outside the router's {self.n_experts} experts")
+        n = batch * length
+        tokens = x.reshape(n, dim)
+        live = jnp.ones((n,), bool) if token_mask is None else token_mask.reshape(n)
+
+        with jax.named_scope("afmoe.router"):
+            router = _Kernel((dim, self.n_experts), self.param_dtype, name="router")()
+            bias = self.param("router_bias", nn.initializers.zeros, (self.n_experts,), jnp.float32)
+            # float32 at full precision: the choice is discrete, a rounded score flips it
+            logits = jnp.dot(
+                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+            )
+            chosen, weights = route_top_k(jax.nn.sigmoid(logits), bias, self.k, normalize=self.route_norm, scale=self.route_scale)
+            held = (chosen >= first) & (chosen < first + count) & live[:, None]  # [N, k]
+            # pairs on absent experts (and masked rows) sort behind every held expert
+            group = jnp.where(held, chosen - first, count).reshape(n * self.k)
+            order = jnp.argsort(group, stable=True)
+            sizes = jnp.zeros((count + 1,), jnp.int32).at[group].add(1)[:count]
+            n_local = jnp.sum(sizes)
+
+        wg, wi, wo = _HeldExperts(count, dim, self.hidden_dim, self.param_dtype, name="experts")()
+        with jax.named_scope("afmoe.experts"):
+            rows = tokens.astype(self.dtype)[order // self.k]  # [N * k, D], held pairs first, by expert
+            gate = jax.nn.silu(grouped_matmul(rows, wg.astype(self.dtype), sizes))
+            up = grouped_matmul(rows, wi.astype(self.dtype), sizes)
+            out = grouped_matmul(gate * up, wo.astype(self.dtype), sizes)
+            weight = jnp.where(held, weights, 0.0).reshape(n * self.k)[order]
+            out = jnp.where((jnp.arange(n * self.k) < n_local)[:, None], out.astype(jnp.float32), 0.0) * weight[:, None]
+            # un-sort: row i of the pair list is token i // k; sum a token's k pairs
+            back = jnp.zeros((n * self.k,), jnp.int32).at[order].set(jnp.arange(n * self.k, dtype=jnp.int32))
+            out = jnp.sum(out[back].reshape(n, self.k, dim), axis=1)
+
+        self.sow("counters", "routed_pairs", jnp.sum(live, dtype=jnp.int32) * self.k)
+        self.sow("counters", "local_pairs", n_local)
+        self.sow("counters", "experts_hit", jnp.sum(sizes > 0, dtype=jnp.int32))
+        self.sow("counters", "max_expert_load", jnp.max(sizes))
+        return out.astype(self.dtype).reshape(batch, length, dim)
 
 
 def _constrain(x: jax.Array, spec: P) -> jax.Array:

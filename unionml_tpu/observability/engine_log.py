@@ -30,7 +30,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 
@@ -138,6 +138,26 @@ class EngineLog:
         #: how the engine's decode program reads its paged cache (``"paged_kernel"`` / ``"gather"``), set by the
         #: engine after a decode dispatch; a fact about the program, not a counter: :meth:`clear` leaves it
         self.decode_attention_path: Optional[str] = None
+        #: what the served model counted over the engine's dispatches (routed-expert pairs, window pages not
+        #: read, ...: the names its ``counters`` attribute declares), cumulative, by kind of dispatch
+        #: (``"decode"`` / ``"prefill"``); ``max_*`` hold the largest seen. Empty for a model that counts
+        #: nothing. Fed by :meth:`count`, zeroed by :meth:`clear`
+        self.model_counters: Dict[str, Dict[str, int]] = {}
+
+    def count(self, kind: str, names: Sequence[str], values: Sequence[int]) -> None:
+        """Add one dispatch's counts (engine thread)."""
+        with self._lock:
+            held = self.model_counters.setdefault(kind, {})
+            for name, value in zip(names, values):
+                held[name] = max(held.get(name, 0), int(value)) if name.startswith("max_") else held.get(name, 0) + int(value)
+
+    def counted(self, names: Sequence[str], kind: Optional[str] = None) -> Dict[str, int]:
+        """The counts under ``names`` (zero where nothing was counted yet), of one kind of dispatch or of all."""
+        with self._lock:
+            kinds = [self.model_counters.get(kind, {})] if kind else list(self.model_counters.values())
+            return {
+                n: (max if n.startswith("max_") else sum)([k.get(n, 0) for k in kinds] or [0]) for n in names
+            }
 
     # ------------------------------------------------------------------ the engine thread's clock
 
@@ -218,6 +238,7 @@ class EngineLog:
             self._idle_s = 0.0
             self._phase_s = [0.0] * len(PHASES)
             self._epoch += 1
+            self.model_counters = {}
 
     # ------------------------------------------------------------------ requests
 
@@ -257,6 +278,7 @@ class EngineLog:
         return {
             "capacity": self.capacity,
             "decode_attention_path": self.decode_attention_path,
+            **({"model_counters": {k: dict(v) for k, v in self.model_counters.items()}} if self.model_counters else {}),
             **self.totals(),
             "iterations_log": [record.render() for record in reversed(iterations)],
             "requests_log": [record.render() for record in reversed(requests)],

@@ -28,7 +28,15 @@ from typing import Iterator, List, Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["GATHER", "PAGED_KERNEL", "paged_decode_attention", "paged_read_path", "paged_read_scope"]
+__all__ = [
+    "GATHER",
+    "PAGED_KERNEL",
+    "paged_decode_attention",
+    "paged_read_path",
+    "paged_read_scope",
+    "paged_window_decode_attention",
+    "window_split",
+]
 
 #: the two paths a paged read can take, as ``stats()["decode_attention_path"]`` names them
 PAGED_KERNEL = "paged_kernel"
@@ -160,3 +168,135 @@ def paged_decode_attention(
         pages_per_compute_block=ppcb,
     )
     return out.astype(q.dtype)
+
+
+def window_split(lengths: jax.Array, window: int, page_size: int):
+    """Where a sliding layer's decode read begins: ``(edge_page, edge_start,
+    tail_lengths)``, each ``[B] int32``.
+
+    A row of ``lengths`` visible positions (the token just written included)
+    sees keys ``[max(0, length - window), length)``. ``edge_page =
+    floor(max(0, length - window) / page_size)`` is the first page of the table
+    the read touches: the pages before it are never read. Of that page only the
+    positions from ``edge_start`` (the window's start, an absolute position) on
+    are visible; the pages after it, ``tail_lengths`` positions in all, are
+    visible whole up to the row's length."""
+    lengths = lengths.astype(jnp.int32)
+    edge_start = jnp.maximum(lengths - window, 0)
+    edge_page = edge_start // page_size
+    tail_lengths = jnp.maximum(lengths - (edge_page + 1) * page_size, 0)
+    return edge_page, edge_start, tail_lengths
+
+
+def _paged_attention_stats(q, k_pages, v_pages, lengths, page_indices, *, pages_per_compute_block: int):
+    """The library's paged-attention kernel launched as the library launches it
+    (bf16 pages, no megacore, the sequence loop inlined), keeping the two
+    outputs its wrapper drops: returns ``(o, m, l)`` with ``o: [B, H, D]`` the
+    normalized output, ``m: [B, H, 1]`` each head's running maximum of the raw
+    ``q.k`` logits and ``l: [B, H, 1]`` the sum of ``exp(logit - m)`` — what a
+    caller needs to merge the read with keys the kernel was not shown. A row of
+    length 0 reads nothing: ``o = 0``, ``l = 0``, ``m = -inf``."""
+    import functools
+
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention_kernel as lib
+
+    batch, n_heads, head_dim = q.shape
+    n_kv, _, page_size, _ = k_pages.shape
+    pages_per_sequence = page_indices.shape[1]
+    groups = n_heads // n_kv
+    if groups % 8:
+        # as the library does: a [groups, 1, D] block keeps the <1x128> layout Mosaic wants
+        q = q.reshape(batch, n_heads, 1, head_dim).astype(jnp.float32)
+        block = pl.BlockSpec((None, groups, None, head_dim), lambda core, b, h, *_: (b, h, 0, 0))
+    else:
+        block = pl.BlockSpec((None, groups, head_dim), lambda core, b, h, *_: (b, h, 0))
+    page_buffer = pltpu.VMEM((2, pages_per_compute_block, page_size, head_dim), k_pages.dtype)
+    o, m, l = pl.pallas_call(
+        functools.partial(
+            lib.paged_flash_attention_kernel_inline_seq_dim,
+            pages_per_sequence=pages_per_sequence,
+            batch_size=batch,
+            pages_per_compute_block=pages_per_compute_block,
+            mask_value=lib.DEFAULT_MASK_VALUE,
+            attn_logits_soft_cap=None,
+            megacore_mode=None,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,  # lengths, page indices, buffer index, init flag
+            in_specs=[block, pl.BlockSpec(memory_space=pl.ANY), None, pl.BlockSpec(memory_space=pl.ANY), None],
+            out_specs=[block, block, block],
+            grid=(1, batch, n_kv),
+            scratch_shapes=(
+                page_buffer, None, page_buffer, None,
+                pltpu.SemaphoreType.DMA((2,)), pltpu.SemaphoreType.DMA((2,)),
+            ),
+        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct((*q.shape[:-1], 1), jnp.float32),
+            jax.ShapeDtypeStruct((*q.shape[:-1], 1), jnp.float32),
+        ],
+        name="paged_window_attention",
+    )(lengths, page_indices.reshape(-1), jnp.zeros((1,), jnp.int32), jnp.ones((1,), jnp.int32), q, k_pages, None, v_pages, None)
+    return o.reshape(batch, n_heads, head_dim), m.reshape(batch, n_heads, 1), l.reshape(batch, n_heads, 1)
+
+
+def paged_window_decode_attention(
+    q: jax.Array,
+    k_pages: jax.Array,
+    v_pages: jax.Array,
+    lengths: jax.Array,
+    page_indices: jax.Array,
+    *,
+    window: int,
+) -> jax.Array:
+    """One decode step of SLIDING-WINDOW attention over paged K/V: key ``j`` is
+    visible to the row's query (at position ``length - 1``) iff ``length -
+    window <= j < length``. Shapes as :func:`paged_decode_attention`.
+
+    The library kernel masks a row's END (its length) and nothing else, so the
+    read is split at the first page boundary inside the window
+    (:func:`window_split`). The whole pages after it go through the kernel, by a
+    table shifted to start there (``window / page_size`` entries at most, so the
+    read follows the window, not the row's length); the one page the window's
+    start falls in is gathered (a page a row: ``H_kv * page_size * D`` values)
+    and masked to the window's start here; the two parts merge by their softmax
+    statistics. A row shorter than the window reads all its pages, the first of
+    them through the gathered part."""
+    n_kv, _, page_size, head_dim = k_pages.shape
+    batch, n_heads, _ = q.shape
+    groups = n_heads // n_kv
+    edge_page, edge_start, tail_lengths = window_split(lengths, window, page_size)
+    scaled = q.astype(jnp.float32) * head_dim**-0.5
+
+    # the whole pages after the edge page: at most window / page_size of them hold visible keys
+    width = min(page_indices.shape[1], -(-window // page_size))
+    ppcb = _pages_per_block(width, page_size)
+    width = -(-width // ppcb) * ppcb  # the kernel tiles the table exactly; no length reaches the padding
+    column = jnp.minimum(edge_page[:, None] + 1 + jnp.arange(width)[None], page_indices.shape[1] - 1)
+    shifted = jnp.take_along_axis(page_indices, column, axis=1)
+    o_tail, m_tail, l_tail = _paged_attention_stats(
+        scaled, k_pages, v_pages, jnp.minimum(tail_lengths, width * page_size), shifted, pages_per_compute_block=ppcb
+    )
+
+    # the edge page, masked to the window's start (and to the row's length, where the row ends inside it)
+    edge = jnp.take_along_axis(page_indices, edge_page[:, None], axis=1)[:, 0]  # [B]
+    k_edge = jnp.moveaxis(k_pages[:, edge], 0, 1).astype(jnp.float32)  # [B, H_kv, page, D]
+    v_edge = jnp.moveaxis(v_pages[:, edge], 0, 1).astype(jnp.float32)
+    grouped = scaled.reshape(batch, n_kv, groups, head_dim)
+    logits = jnp.einsum("bhgd,bhtd->bhgt", grouped, k_edge)
+    position = edge_page[:, None] * page_size + jnp.arange(page_size)[None]  # [B, page]
+    visible = (position >= edge_start[:, None]) & (position < lengths.astype(jnp.int32)[:, None])
+    logits = jnp.where(visible[:, None, None], logits, -jnp.inf)
+    m_tail = m_tail.reshape(batch, n_kv, groups, 1)
+    l_tail = l_tail.reshape(batch, n_kv, groups, 1)
+    m = jnp.maximum(jnp.max(logits, axis=-1, keepdims=True), m_tail)  # the edge page always shows a key: finite
+    p_edge = jnp.exp(logits - m)
+    tail_weight = l_tail * jnp.exp(m_tail - m)
+    out = tail_weight * o_tail.astype(jnp.float32).reshape(batch, n_kv, groups, head_dim)
+    out = out + jnp.einsum("bhgt,bhtd->bhgd", p_edge, v_edge)
+    out = out / (tail_weight + jnp.sum(p_edge, axis=-1, keepdims=True))
+    return out.reshape(batch, n_heads, head_dim).astype(q.dtype)

@@ -256,6 +256,8 @@ class _Admission:
     row_cache: Any = None  # target model's [1, cache_len] row (filling up)
     last: Any = None  # accumulated last-real-token hidden state
     d_row_cache: Any = None  # draft model's row, chunked in lockstep
+    #: what a counting model counted over each chunk (device arrays, gen.counter_names)
+    counts: list = dataclasses.field(default_factory=list)
     # radix prefix cache (prefix_cache=True engines): tokens of the logical
     # sequence already cached (> prefix length on a hit) and the matched block
     # ids, scratch-padded, that the dense-row gather reads
@@ -1077,11 +1079,13 @@ class ContinuousBatcher:
         # jit outputs NamedSharding)
         key = jax.jit(jax.random.PRNGKey)(self._seed)
         if self._spec is None:
-            if self.gen._cs is not None:
-                # per-slot DFA state rides as the decode carry's tail, exactly
-                # as in Generator._finish_prefill (free slots sit at FREE's 0)
-                return (cache, tok, lengths, done, key, jnp.zeros((self.slots,), jnp.int32))
-            return (cache, tok, lengths, done, key)
+            # the carry's tail, as Generator._finish_prefill builds it: the per-slot
+            # DFA state of a constrained generator (free slots sit at FREE's 0), then
+            # the last dispatch's counts of a model that counts (gen.counter_names)
+            tail = (jnp.zeros((self.slots,), jnp.int32),) if self.gen._cs is not None else ()
+            if self.gen.counter_names:
+                tail += (jnp.zeros((len(self.gen.counter_names),), jnp.int32),)
+            return (cache, tok, lengths, done, key, *tail)
         draft_gen = self._spec._draft
         if self.block_size is not None:
             # the draft's pool has the same BLOCK COUNT (different shapes), so
@@ -1855,6 +1859,17 @@ class ContinuousBatcher:
                     "pinned_blocks": self._radix.pinned_blocks(),
                     "nodes": self._radix.nodes(),
                 }
+            if self.gen.counter_names:
+                # what the served model counted (ints, zero until it has run), read from
+                # the engine log's one record of it (GET /debug/engine: model_counters),
+                # under the keys the model declares: a group of its ``counter_views``
+                # over all dispatches and, under "decode", over the decode dispatches
+                # alone; a name in no group under its own name
+                counted = self.engine_log.counted
+                for key, names in self.gen.counter_views.items():
+                    snapshot[key] = {**counted(names), "decode": counted(names, "decode")}
+                grouped = {n for names in self.gen.counter_views.values() for n in names}
+                snapshot.update(counted([n for n in self.gen.counter_names if n not in grouped]))
             if self.role is not None:
                 snapshot["role"] = self.role
             if self.role is not None or self.handoffs_exported or self.handoffs_imported:
@@ -2646,13 +2661,15 @@ class ContinuousBatcher:
                 return cost
         c = adm.pos
         sl = jnp.asarray(adm.tokens[:, c : c + adm.chunk])
-        chunk_last, has, adm.row_cache = gen._prefill_chunk(
+        chunk_last, has, adm.row_cache, counts = gen._prefill_chunk(
             gen.params, sl, jnp.int32(adm.start + c), adm.lengths, adm.row_cache, adm.row_valid
         )
         adm.last = jnp.where(has[:, None], chunk_last, adm.last)
+        if gen.counter_names:
+            adm.counts.append(counts)  # read with the admission's first token: no fetch of their own
         if self._spec is not None:
             draft = self._spec._draft
-            _, _, adm.d_row_cache = draft._prefill_chunk(
+            _, _, adm.d_row_cache, _ = draft._prefill_chunk(
                 draft.params, sl, jnp.int32(adm.start + c), adm.lengths,
                 adm.d_row_cache, adm.row_valid,
             )
@@ -2782,6 +2799,9 @@ class ContinuousBatcher:
                 self._carry = self._init_carry()
             with self.engine_log.phase("fetch"):
                 first = np.asarray(adm.tok0)
+                for counts in jax.device_get(adm.counts):  # long since computed: the first token came after them
+                    self.engine_log.count("prefill", self.gen.counter_names, counts)
+                adm.counts = []
             hit_eos = cfg.eos_id is not None and int(first[0]) == cfg.eos_id
             # produced carries across preemptions; this residency adds one token.
             # An imported handoff's first token was emitted (and its eos/budget
@@ -2829,9 +2849,11 @@ class ContinuousBatcher:
             if adm.dfa_state is not None:
                 # advance past the (constrained) prompt-sampled token and
                 # activate the slot's DFA state — the carry TAIL in both the
-                # plain and speculative layouts (one copy of the rule)
+                # plain and speculative layouts (one copy of the rule), ahead of
+                # a counting model's counts in the plain one
                 state = list(self._carry)
-                state[-1] = state[-1].at[slot].set(
+                at = -2 if self._spec is None and self.gen.counter_names else -1
+                state[at] = state[at].at[slot].set(
                     int(self.gen._cs.trans[adm.dfa_state, int(first[0])])
                 )
                 self._carry = tuple(state)
@@ -3186,6 +3208,8 @@ class ContinuousBatcher:
             toks_np = np.asarray(toks)  # [S, chunk]; also fences the dispatch
             lps_np = np.asarray(lps)  # [S, chunk] f32: each sampled token's logprob
             done_np = np.asarray(carry[3])
+            if self.gen.counter_names:
+                log.count("decode", self.gen.counter_names, np.asarray(carry[-1]))
         registry = self._registry()
         with log.phase("emit"), self._lock:
             self.decode_dispatches += 1
