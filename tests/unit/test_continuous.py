@@ -1139,3 +1139,196 @@ def test_overload_admission_deadline_and_disconnect(tiny_gen, sklearn_model):
         assert len(out) == 4
     finally:
         batcher.close()
+
+
+# ------------------------------------------------------------------ the carry's edits between dispatches
+#
+# The engine records what it writes into the carry between dispatches (a grown
+# table entry, a released slot) on the host and one jitted program applies the
+# lot (``_sync_carry``): the device must agree with the host's account whenever
+# the engine thread is not inside an iteration, and the program must run at
+# most twice an iteration, whatever the number of rows, blocks and layers.
+
+
+def _speculative(cfg):
+    import dataclasses
+
+    from unionml_tpu.models import DraftSpec
+
+    draft, dp = _draft_for(97)
+    return dataclasses.replace(cfg, draft=DraftSpec(module=draft, params=dp, gamma=3))
+
+
+def _check_account_at_every_iteration_end(batcher):
+    """Wrap the engine log's ``end`` (the bottom of the engine loop, on the
+    engine thread) with a comparison of every layer's device table, ``done`` and
+    ``lengths`` against the host's books; returns the list the disagreements
+    are written to and the count of iterations checked."""
+    problems, checked = [], [0]
+    log = batcher.engine_log
+    real_end = log.end
+
+    def end():
+        carry = batcher._carry
+        if carry is not None:
+            spec = batcher._spec is not None
+            lengths = np.asarray(carry[3 if spec else 2])
+            done = np.asarray(carry[4 if spec else 3])
+            scratch = batcher._scratch_block
+            with batcher._lock:
+                resident = {slot: list(session.table) for slot, session in batcher._sessions.items()}
+            expected = np.full((batcher.slots, batcher.max_blocks), scratch, np.int32)
+            for slot, table in resident.items():
+                expected[slot, : len(table)] = table
+            if not (batcher._table_host == expected).all():
+                problems.append((log.index, "the host's mirror left the sessions' tables"))
+            for cache in carry[: 2 if spec else 1]:
+                for n, layer in enumerate(cache):
+                    if not (np.asarray(layer["table"]) == expected).all():
+                        problems.append((log.index, f"layer {n}: the device table left the host's account"))
+            for slot in range(batcher.slots):
+                if slot not in resident and not (done[slot] and lengths[slot] == 0):
+                    problems.append((log.index, f"free slot {slot}: done {done[slot]}, lengths {lengths[slot]}"))
+                if slot in resident and lengths[slot] == 0:
+                    problems.append((log.index, f"resident slot {slot} has no length"))
+            checked[0] += 1
+        real_end()
+
+    log.end = end
+    return problems, checked
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative"])
+def test_device_tables_follow_the_hosts_account_every_iteration(tiny_gen, mode):
+    """Growth, finish, cancel and preemption in one paged run: after every
+    iteration each layer's device table (both caches' in speculative mode)
+    equals the host's account, and a free slot's row is all scratch, done and
+    of length 0 — so the decode read streams one block for it, not its stale
+    length. The streams stay token-exact throughout."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=16, temperature=0.0, prompt_buckets=(16,))
+    expected = _sequential_expected(module, params, cfg, PROMPTS[:4])
+    gen = Generator(module, params, cfg if mode == "plain" else _speculative(cfg))
+    probe = ContinuousBatcher(gen, slots=3, decode_chunk=2, block_size=8)
+    min_pool = probe.max_blocks  # one worst-case request: long residents must preempt each other
+    probe.close()
+    batcher = ContinuousBatcher(gen, slots=3, decode_chunk=2, block_size=8, pool_blocks=min_pool)
+    problems, checked = _check_account_at_every_iteration_end(batcher)
+    try:
+        doomed = batcher.submit(PROMPTS[3])
+        next(doomed)
+        doomed.close()  # a resident cancelled mid-stream: reaped at the top of an iteration
+        results = [None] * 3
+
+        def worker(i):
+            results[i] = _drain(batcher.submit(PROMPTS[i]))
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(3)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert results == expected[:3]
+        assert _drain(batcher.submit(PROMPTS[3], max_new_tokens=1)) == expected[3][:1]  # ends at admission
+        batcher.close()  # joins the engine thread: every iteration has been checked
+        assert problems == []
+        assert checked[0] >= 8
+        records = batcher.engine_log.iteration_records()
+        assert sum(r.blocks_grown for r in records) > 0 and sum(r.finished for r in records) >= 4
+        assert batcher.stats()["kv_blocks"]["preemptions"] > 0
+        assert max(r.table_syncs for r in records) <= 2
+        assert (batcher._table_host == batcher._scratch_block).all()  # everyone left: nothing points at a live block
+    finally:
+        batcher.close()
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative"])
+def test_released_slot_readmitted_at_once_decodes_token_exact(tiny_gen, mode):
+    """The ordering hazard of deferred releases: one slot, so every finish (in
+    ``emit``) and every reaped cancel (at the top of the next iteration) is
+    followed at once by an admission into the SAME slot, whose paste writes the
+    slot's done flag, length and table row. A release applied after it would
+    mask the new row out; a release never applied would let the old row's
+    ride-along write land in a reallocated block."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=10, temperature=0.0, prompt_buckets=(16,))
+    expected = _sequential_expected(module, params, cfg, PROMPTS[:5])
+    gen = Generator(module, params, cfg if mode == "plain" else _speculative(cfg))
+    batcher = ContinuousBatcher(gen, slots=1, decode_chunk=3, block_size=8, pool_blocks=6)
+    problems, _ = _check_account_at_every_iteration_end(batcher)
+    try:
+        results = [None] * 4
+
+        def worker(i):
+            results[i] = _drain(batcher.submit(PROMPTS[i]))
+
+        threads = [threading.Thread(target=worker, args=(i,)) for i in range(4)]  # three wait for the one slot
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=300)
+        assert results == expected[:4]
+        records = batcher.engine_log.iteration_records()
+        assert any(a.finished and b.admitted for a, b in zip(records, records[1:]))  # the hazard was met
+        doomed = batcher.submit(PROMPTS[0])
+        next(doomed)
+        waiter = batcher.submit(PROMPTS[4])  # queued behind the doomed row
+        doomed.close()  # reaped and its slot re-admitted within one iteration, before any sync
+        assert _drain(waiter) == expected[4]
+        batcher.close()
+        assert problems == []
+        assert batcher.stats()["kv_blocks"]["used"] == 0
+    finally:
+        batcher.close()
+
+
+def test_carry_edits_cost_at_most_two_dispatches_an_iteration(tiny_gen, monkeypatch):
+    """Counting dispatches: four rows grow their tables in one iteration and
+    finish in one iteration, and the program that carries the edits to the
+    device runs at most twice in any iteration (once at the end of ``grow``,
+    once at the end of ``emit``), never in an iteration that grew and released
+    nothing — and is traced once over warm-up and traffic together."""
+    traces = []
+    impl = ContinuousBatcher._sync_impl
+
+    def counted(*args):
+        traces.append(1)
+        return impl(*args)
+
+    monkeypatch.setattr(ContinuousBatcher, "_sync_impl", staticmethod(counted))
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=14, temperature=0.0, prompt_buckets=(16,))
+    prompts = [[3 + i, 14, 15, 92, 6] for i in range(4)]  # one length: the rows cross block edges together
+    expected = _sequential_expected(module, params, cfg, prompts)
+    batcher = ContinuousBatcher(Generator(module, params, cfg), slots=4, decode_chunk=4, block_size=8)
+    try:
+        batcher.warmup()
+        assert len(traces) == 1
+        programs = batcher._sync_fn._cache_size()
+        assert batcher.stats()["loop"]["table_syncs"] == 0  # warm-up's passes are not traffic
+        inner, late = batcher.gen._decode, []
+
+        def decode(params, cache, *carry, steps):
+            """The growths reach the device BEFORE the dispatch that writes into the grown blocks."""
+            with batcher._lock:
+                tables = {slot: list(session.table) for slot, session in batcher._sessions.items()}
+            for layer in cache:
+                device = np.asarray(layer["table"])
+                late.extend(slot for slot, table in tables.items() if list(device[slot, : len(table)]) != table)
+            return inner(params, cache, *carry, steps=steps)
+
+        monkeypatch.setattr(batcher.gen, "_decode", decode)
+        with batcher._lock:  # re-entrant: the engine sees all four at once, and admits them in one iteration
+            streams = [batcher.submit(p) for p in prompts]
+        assert [_drain(s) for s in streams] == expected
+        batcher.close()
+        assert late == []
+        records = batcher.engine_log.iteration_records()
+        assert any(r.blocks_grown >= 4 for r in records) and any(r.finished == 4 for r in records)
+        for r in records:
+            assert r.table_syncs <= (r.blocks_grown > 0) + (r.finished > 0) <= 2
+            assert (r.table_syncs == 0) == (r.blocks_grown == 0 and r.finished == 0)
+        assert sum(r.table_syncs for r in records) == batcher.stats()["loop"]["table_syncs"] > 0
+        assert len(traces) == 1 and batcher._sync_fn._cache_size() == programs  # nothing new after warm-up
+    finally:
+        batcher.close()

@@ -276,6 +276,23 @@ def test_quiesced_engine_sheds_and_keeps_draining(tiny):
         engine.close()
 
 
+def test_quiesced_engine_still_bounces_once_the_resize_closed_it(tiny):
+    """The second phase of a scale-down closes the drained engine; a routing
+    snapshot taken before the engine left the fleet may submit to it even
+    later, and must be walked to a sibling (``QueueFullError``), not failed
+    (``RuntimeError``: what an engine closed for good answers)."""
+    module, params = tiny
+    engine = ContinuousBatcher._single(Generator(module, params, _cfg()), slots=2)
+    engine.quiesce()
+    engine.close()
+    with pytest.raises(QueueFullError):
+        engine.submit(PROMPTS[0])
+    closed = ContinuousBatcher._single(Generator(module, params, _cfg()), slots=2)
+    closed.close()
+    with pytest.raises(RuntimeError, match="closed"):
+        closed.submit(PROMPTS[0])
+
+
 # ------------------------------------------------------ decode-side insertion
 
 

@@ -742,6 +742,7 @@ class ContinuousBatcher:
         self._sessions: Dict[int, _Session] = {}
         self._free = list(range(slots))
         self._cancelled: "List[_Session]" = []  # resident sessions whose consumer went away
+        self._ended: "List[_Session]" = []  # finished in the emit under way; owed the sentinel (_end_streams_locked)
         self._closed = False
         #: scale-down quiesce (replicas.py): a quiesced engine sheds NEW
         #: submits with QueueFullError — the replica scheduler walks past it —
@@ -749,6 +750,19 @@ class ContinuousBatcher:
         #: resize never truncates a stream a stale routing snapshot sent here
         self._quiesced = False
         self._carry: Optional[tuple] = None  # (cache, tok, lengths, done, key)
+        #: the host's account of what it writes into the carry between
+        #: dispatches (engine thread only): every slot's table row as the device
+        #: should hold it (paged mode), the rows edited since the last sync and
+        #: the slots released since. ``_extend_tables`` / ``_mask_slot_done``
+        #: write here and ``_sync_carry`` carries the lot to the device in one
+        #: dispatch, whatever the number of rows, blocks and layers
+        paged = block_size is not None
+        self._table_host = np.full(
+            (slots, self.max_blocks if paged else 0), self._scratch_block if paged else 0, np.int32
+        )
+        self._edited_host = np.zeros((slots,), bool)
+        self._released_host = np.zeros((slots,), bool)
+        self._sync_fn = jax.jit(self._sync_impl, donate_argnums=(0, 1, 2))
         self._seed = 0
         self._thread: Optional[threading.Thread] = None
         # donate only the pool-side buffers: the [1, ...] row caches can't alias
@@ -1031,6 +1045,20 @@ class ContinuousBatcher:
         out_buf, done, produced = cls._spec_activate(out_buf, done, produced, slot, row_tok, row_done, pad)
         return t_cache, d_cache, out_buf, tok, lengths, done, produced
 
+    @staticmethod
+    def _sync_impl(tables, lengths, done, table, edited, released):
+        """Apply the host's edits to the carry (:meth:`_sync_carry`): every
+        layer's table of every cache (``tables``, a tuple per cache of its
+        layers' ``[slots, max_blocks]`` tables; empty for a dense cache) takes
+        the ``edited`` rows of the host's ``table``, and a ``released`` slot is
+        done. In paged mode its length falls to 0 as well: a free row's table
+        points at the scratch block, and the decode read streams as many
+        positions of it as the row's length says, on every step."""
+        tables = jax.tree_util.tree_map(lambda t: jnp.where(edited[:, None], table.astype(t.dtype), t), tables)
+        if tables:
+            lengths = jnp.where(released, 0, lengths)
+        return tables, lengths, done | released
+
     def _seed_shared_prefix(self, cache: Any, prefix_layers: Any) -> Any:
         """Write the prefix's FULL blocks into a pool once; every admission's
         table then points at these ids and nothing ever writes them again
@@ -1072,7 +1100,9 @@ class ContinuousBatcher:
                 init_cache(self.gen.module.config, self.slots, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
             )
         tok = jnp.zeros((self.slots,), jnp.int32)
-        lengths = jnp.ones((self.slots,), jnp.int32)
+        # a free paged row has length 0, before its first admission as after a
+        # release (_sync_impl): the decode read streams one block of scratch for it
+        lengths = jnp.full((self.slots,), 0 if self.block_size is not None else 1, jnp.int32)
         done = jnp.ones((self.slots,), bool)  # every slot starts free (= masked out)
         # built inside jit so the key's sharding provenance matches the decode
         # outputs it cycles through (an eager key carries SingleDeviceSharding,
@@ -1422,13 +1452,16 @@ class ContinuousBatcher:
             request_id=current_request_id(), prompt_tokens=len(prompt),
         )
         with self._lock:
-            if self._closed:
-                raise RuntimeError("ContinuousBatcher is closed")
             if self._quiesced:
                 # draining for a scale-down: bounce the request back to the
                 # replica scheduler (which walks to a live sibling) without
-                # polluting the overload counters — this is routing, not load
+                # polluting the overload counters — this is routing, not load.
+                # Checked before ``_closed``: the resize closes the engine once
+                # it reads empty, and a routing snapshot older than that must
+                # still walk on, not fail its caller
                 raise QueueFullError("replica is quiescing for a fleet resize")
+            if self._closed:
+                raise RuntimeError("ContinuousBatcher is closed")
             # admission control: count LIVE waiters (cancelled heads awaiting
             # reap don't hold capacity against new arrivals)
             waiting = sum(1 for _, s in self._pending if not s.finished)
@@ -1520,10 +1553,10 @@ class ContinuousBatcher:
         )
         session.pending_import = dict(payload)
         with self._lock:
+            if self._quiesced:  # before ``_closed``, as in :meth:`submit`
+                raise QueueFullError("replica is quiescing for a fleet resize")
             if self._closed:
                 raise RuntimeError("ContinuousBatcher is closed")
-            if self._quiesced:
-                raise QueueFullError("replica is quiescing for a fleet resize")
             self._pending.append((list(payload["prompt"]), session))
             if self._thread is None:
                 self._thread = threading.Thread(target=self._engine_loop, daemon=True)
@@ -1999,6 +2032,8 @@ class ContinuousBatcher:
                 self._admit_pending()
                 if self._sessions:
                     self._decode_chunk()
+                else:
+                    self._sync_carry()  # a reaped cancel, a row that ended at admission
                 log.end()
         except BaseException as exc:  # engine death must not strand consumers
             logger.error(f"continuous-batching engine failed: {exc!r}")
@@ -2031,6 +2066,8 @@ class ContinuousBatcher:
                 for adm in self._admissions:
                     adm.session.out.put(_SENTINEL)
                 for session in self._sessions.values():
+                    session.out.put(_SENTINEL)
+                for session in self._ended:  # died between a row's finish and its emit's close
                     session.out.put(_SENTINEL)
 
     def _admit_pending(self) -> None:
@@ -2846,6 +2883,13 @@ class ContinuousBatcher:
                         jnp.asarray([start_done]), jnp.int32(cfg.pad_id),
                     )
                 self._carry = (t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst)
+            # the paste wrote this slot's done flag, length and table row: what
+            # the account still held for the slot (a release not yet synced)
+            # is overwritten, not owed
+            self._released_host[slot] = False
+            if blocks_row is not None:
+                self._table_host[slot] = blocks_row
+                self._edited_host[slot] = False
             if adm.dfa_state is not None:
                 # advance past the (constrained) prompt-sampled token and
                 # activate the slot's DFA state — the carry TAIL in both the
@@ -2937,26 +2981,49 @@ class ContinuousBatcher:
                 # prompt-sampled tok0 is not one of them, so without masking
                 # the freed slot would keep decoding as a zombie row (and
                 # claim routed-expert capacity)
-                self._finish_locked(slot, device_done=self._spec is not None)
+                self._finish_locked(slot, device_done=self._spec is not None).out.put(_SENTINEL)
 
     def _mask_slot_done(self, slot: int) -> None:
-        """Set the device-side done flag of a slot (engine thread only). In
-        paged mode also repoint its table row at the scratch block: the freed
-        blocks may be reallocated immediately, and the done row keeps issuing a
-        ride-along K/V write per step — scratch is where it must land."""
-        if self._carry is None:
+        """Release a slot on the device (engine thread only): its done flag is
+        set and, in paged mode, its table row points at the scratch block and
+        its length is 0 — the freed blocks may be reallocated immediately, and
+        the done row keeps issuing a ride-along K/V write per step, so scratch
+        is where it must land. Recorded here, on the device with the next
+        :meth:`_sync_carry`."""
+        self._released_host[slot] = True
+        if self.block_size is not None:
+            self._table_host[slot] = self._scratch_block
+            self._edited_host[slot] = True
+
+    def _sync_carry(self) -> None:
+        """Carry the edits recorded since the last call to the device: one
+        dispatch of :meth:`_sync_impl`, none with nothing recorded (engine
+        thread only). Called before every decode dispatch (a released row's
+        ride-along write must find scratch, a grown row its new blocks) and at
+        the end of every iteration, so the device agrees with the host's
+        account whenever the engine thread is not inside an iteration. An
+        admission that lands in between writes its slot's row itself and
+        strikes the slot from the account (:meth:`_finalize_admission`)."""
+        if self._carry is None or not (self._edited_host.any() or self._released_host.any()):
             return
         state = list(self._carry)
-        done_idx = 3 if self._spec is None else 4
-        state[done_idx] = state[done_idx].at[slot].set(True)
-        if self.block_size is not None:
-            # speculative mode repoints BOTH caches (carry slots 0 and 1)
-            for cache_idx in (0,) if self._spec is None else (0, 1):
-                state[cache_idx] = tuple(
-                    {**layer, "table": layer["table"].at[slot].set(self._scratch_block)}
-                    for layer in state[cache_idx]
-                )
+        # speculative mode keeps BOTH caches' tables (carry slots 0 and 1)
+        caches = () if self.block_size is None else (0,) if self._spec is None else (0, 1)
+        at = 2 if self._spec is None else 3  # lengths, then done
+        tables = tuple(tuple(layer["table"] for layer in state[c]) for c in caches)
+        # the pools are never passed: their buffers stay where they are. The
+        # program is handed copies: a backend may read a numpy argument in
+        # place, after this thread has gone on editing it
+        tables, state[at], state[at + 1] = self._sync_fn(
+            tables, state[at], state[at + 1], self._table_host.copy(), self._edited_host, self._released_host
+        )
+        for c, synced in zip(caches, tables):
+            state[c] = tuple({**layer, "table": t} for layer, t in zip(state[c], synced))
         self._carry = tuple(state)
+        self._edited_host = np.zeros_like(self._edited_host)
+        self._released_host = np.zeros_like(self._released_host)
+        log = self.engine_log  # engine thread only, like the pass's other counters
+        log.table_syncs += 1
 
     def _release_blocks_locked(self, slot: int, session: Optional[_Session] = None) -> None:
         """Return a slot's PRIVATE pool blocks to the allocator and release the
@@ -3081,18 +3148,11 @@ class ContinuousBatcher:
         return max(0, min(m, total - 1) - p0)
 
     def _extend_tables(self, slot: int, start_idx: int, ids: "List[int]") -> None:
-        """Append freshly allocated block ids to a resident slot's table row in
-        every cache (engine thread only)."""
-        if not ids or self._carry is None:
-            return
-        ids_arr = jnp.asarray(ids, jnp.int32)
-        state = list(self._carry)
-        for cache_idx in (0,) if self._spec is None else (0, 1):
-            state[cache_idx] = tuple(
-                {**layer, "table": layer["table"].at[slot, start_idx : start_idx + len(ids)].set(ids_arr)}
-                for layer in state[cache_idx]
-            )
-        self._carry = tuple(state)
+        """Append freshly allocated block ids to a resident slot's table row
+        (engine thread only): recorded here, in every cache's tables with the
+        next :meth:`_sync_carry`."""
+        self._table_host[slot, start_idx : start_idx + len(ids)] = ids
+        self._edited_host[slot] = True
 
     def _preempt_locked(self, slot: int, reason: str = "capacity") -> None:
         """Evict a resident under pool exhaustion (or for a higher-priority
@@ -3167,7 +3227,10 @@ class ContinuousBatcher:
             )
             self._preempt_locked(victim)
 
-    def _finish_locked(self, slot: int, *, device_done: bool) -> None:
+    def _finish_locked(self, slot: int, *, device_done: bool) -> _Session:
+        """End a resident stream (caller holds the lock) and return its
+        session: the caller owes it the sentinel, last, once the engine state
+        is consistent."""
         session = self._sessions.pop(slot)
         if not session.finished:  # a cancelled row reaped here has its record already
             self._record_end(session, "finish")
@@ -3187,13 +3250,26 @@ class ContinuousBatcher:
             # Paged mode masks unconditionally — the table repoint to scratch
             # must happen even when the device already flagged done
             self._mask_slot_done(slot)
-        # sentinel last: once the consumer wakes, the engine state is consistent
-        session.out.put(_SENTINEL)
+        return session
+
+    def _end_streams_locked(self) -> None:
+        """Close a dispatch's ``emit`` (engine thread, lock held): the finished rows'
+        releases go to the device in one program, then their consumers get the
+        sentinel — last, so that whoever it wakes finds the engine state
+        consistent."""
+        try:
+            self._sync_carry()
+        finally:
+            ended, self._ended = self._ended, []
+            for session in ended:
+                session.out.put(_SENTINEL)
 
     def _decode_chunk(self) -> None:
         log = self.engine_log
         with log.phase("grow"), self._lock:
             self._ensure_capacity_locked()
+            # the growths, and every release since the last dispatch, in one program
+            self._sync_carry()
             if not self._sessions:
                 return  # growth preempted the last resident; re-admission next loop
             log.rows = len(self._sessions)
@@ -3253,7 +3329,8 @@ class ContinuousBatcher:
                     _tev(session, "engine.emit", tokens=take, produced=session.produced)
                 device_done = bool(done_np[slot])
                 if session.produced >= session.max_new or device_done:
-                    self._finish_locked(slot, device_done=device_done)
+                    self._ended.append(self._finish_locked(slot, device_done=device_done))
+            self._end_streams_locked()
 
     def _spec_chunk(self) -> None:
         """Speculative shared dispatch: one floor-driven round loop (draft gamma
@@ -3322,4 +3399,6 @@ class ContinuousBatcher:
                         self._tenant_slo.tokens(session.tenant, int(new.size))
                     _tev(session, "engine.emit", tokens=int(new.size), produced=session.produced)
                 if bool(done_np[slot]):
-                    self._finish_locked(slot, device_done=True)
+                    self._ended.append(self._finish_locked(slot, device_done=True))
+            self._end_streams_locked()
+
