@@ -20,6 +20,8 @@ handles and a real WorkerAgent control server in the same process:
   loses zero in-flight streams.
 """
 
+import io
+import json
 import threading
 import time
 
@@ -130,6 +132,28 @@ def test_handoff_wire_round_trip(tiny):
                 np.testing.assert_array_equal(np.asarray(sent[name]), received[name])
     finally:
         engine.close(wait=False)
+
+
+def test_handoff_wire_rejects_a_row_payload(tiny):
+    """Pages are the one KV payload: a ``kind`` the decoder does not know (the
+    ``cache_len``-wide row of earlier engines) is refused like any malformed input."""
+    engine = _engine(tiny, _cfg(), role="prefill")
+    try:
+        stream = engine.submit(PROMPTS[1], export_handoff=True)
+        _drain(stream)
+        data = serialize_handoff(stream.handoff)
+    finally:
+        engine.close(wait=False)
+    with np.load(io.BytesIO(data)) as bundle:
+        arrays = {key: bundle[key] for key in bundle.files}
+    meta = json.loads(bytes(arrays["__meta__"]).decode())
+    assert meta["kind"] == "pages"
+    meta["kind"] = "row"
+    arrays["__meta__"] = np.frombuffer(json.dumps(meta).encode(), np.uint8)
+    out = io.BytesIO()
+    np.savez(out, **arrays)
+    with pytest.raises(ValueError, match="kind 'row'"):
+        deserialize_handoff(out.getvalue())
 
 
 # -------------------------------------------------------------------- coordination

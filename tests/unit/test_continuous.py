@@ -72,6 +72,11 @@ def test_concurrent_streams_match_sequential(tiny_gen):
         assert results == expected
         # concurrency actually shared dispatches: far fewer than per-request loops
         assert batcher.decoded_rows > batcher.decode_dispatches
+        # built with no sizes: blocks of 64 and a pool that holds every slot at
+        # its worst case, so nobody waited for blocks and nobody was preempted
+        stats = batcher.stats()["kv_blocks"]
+        assert stats["block_size"] == 64 and stats["total"] == batcher.slots * batcher.max_blocks
+        assert stats["used"] == 0 and stats["preemptions"] == 0
     finally:
         batcher.close()
 
@@ -124,17 +129,30 @@ def test_eos_frees_slot_early(tiny_gen):
         batcher.close()
 
 
-def test_oversized_prompt_fails_only_its_stream(tiny_gen):
+@pytest.mark.parametrize(
+    "sizes, prompt",
+    [
+        # one block a row (blocks of 64, a 16-position row): 39 tokens fit the
+        # slot's one block and still overflow the row its prefill fills
+        ({}, list(range(1, 40))),
+        # several blocks a row: the prompt's block need exceeds a table row
+        ({"block_size": 8}, list(range(1, 80))),
+    ],
+    ids=["one_block_a_row", "blocks_of_8"],
+)
+def test_oversized_prompt_fails_only_its_stream(tiny_gen, sizes, prompt):
+    """A prompt a slot cannot hold fails ITS stream, with one wording, without
+    wedging the FIFO; later requests proceed."""
     module, params = tiny_gen
     cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(8,))
-    batcher = ContinuousBatcher(Generator(module, params, cfg), slots=2, decode_chunk=2)
+    expected = _sequential_expected(module, params, cfg, PROMPTS[:1])
+    batcher = ContinuousBatcher(Generator(module, params, cfg), slots=2, decode_chunk=2, **sizes)
     try:
-        bad = batcher.submit(list(range(1, 80)))  # bucket 128 >> cache_len
-        with pytest.raises(ValueError, match="cache_len"):
-            _drain(bad)
-        good = _drain(batcher.submit(PROMPTS[0]))
-        expected = _sequential_expected(module, params, cfg, PROMPTS[:1])
-        assert good == expected[0]
+        doomed = batcher.submit(prompt)
+        ok = batcher.submit(PROMPTS[0])
+        with pytest.raises(ValueError, match=r"KV positions \(\d+ blocks of \d+ .* cache_len 16"):
+            _drain(doomed)
+        assert _drain(ok) == expected[0]
     finally:
         batcher.close()
 
@@ -350,7 +368,7 @@ def test_paged_kv_matches_sequential_with_undersized_pool(tiny_gen):
     """Paged KV capacity win: requests with small budgets are allocated only the
     blocks they need, so a pool FAR smaller than slots x worst-case admits a
     full house concurrently — and every stream is still token-exact against the
-    sequential dense run (paged == contiguous == sequential)."""
+    sequential run."""
     module, params = tiny_gen
     cfg = GenerationConfig(max_new_tokens=12, temperature=0.0, prompt_buckets=(16,))
     expected = _sequential_expected(module, params, cfg, PROMPTS[:4])
@@ -434,24 +452,6 @@ def test_paged_kv_with_prefix_and_int8(tiny_gen):
     try:
         results = [_drain(batcher.submit(s)) for s in suffixes]
         assert results == expected
-    finally:
-        batcher.close()
-
-
-def test_paged_kv_oversized_prompt_fails_cleanly(tiny_gen):
-    """A prompt whose block need exceeds a table row fails ITS stream without
-    wedging the FIFO; later requests proceed."""
-    module, params = tiny_gen
-    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(16,))
-    expected = _sequential_expected(module, params, cfg, PROMPTS[:1])
-
-    batcher = ContinuousBatcher(Generator(module, params, cfg), slots=2, decode_chunk=3, block_size=8)
-    try:
-        doomed = batcher.submit(list(range(1, 40)))  # buckets to 64 > cache_len
-        ok = batcher.submit(PROMPTS[0])
-        with pytest.raises(ValueError, match="blocks"):
-            _drain(doomed)
-        assert _drain(ok) == expected[0]
     finally:
         batcher.close()
 

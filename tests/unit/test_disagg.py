@@ -6,7 +6,8 @@ The pinned contracts:
 - **handoff exactness**: a role-split fleet's streams (prefill replica runs
   the prefill, decode replica adopts the KV at admission-complete) are
   token-identical — the first token included — to a single mixed engine
-  serving the same prompts, in dense AND paged mode;
+  serving the same prompts, at one block a row and at several, and where
+  the row ends inside a block;
 - **zero-loss resize**: ``scale_to`` up/down mid-traffic completes every
   in-flight stream exactly (counts asserted), and the autoscaler thread is
   owned and joined by ``close()`` (the TPU008 contract, held to live);
@@ -143,28 +144,42 @@ def test_scheduler_resize_keeps_counts_and_bounds():
 # ------------------------------------------------------------------ handoff
 
 
-def test_role_split_fleet_token_identical_dense(tiny):
+#: a 100-token prompt in a 116-position row of 64-position blocks: its second
+#: page reaches past the row's end (the export pads it)
+LONG_PROMPT = [1 + i % 90 for i in range(100)]
+
+
+@pytest.mark.parametrize(
+    "cfg_overrides, engine_kwargs, prompts",
+    [
+        ({}, {"decode_chunk": 4}, PROMPTS),  # no sizes: one 64-position block a 28-position row
+        ({}, {"decode_chunk": 4, "block_size": 4}, PROMPTS),
+        ({"prompt_buckets": (100,)}, {"decode_chunk": 8, "block_size": 64}, [LONG_PROMPT, PROMPTS[1]]),
+    ],
+    ids=["no_sizes", "blocks_of_4", "row_ends_inside_a_block"],
+)
+def test_role_split_fleet_token_identical(tiny, cfg_overrides, engine_kwargs, prompts):
     module, params = tiny
-    cfg = _cfg()
-    expected = _expected(module, params, cfg, PROMPTS)
+    cfg = _cfg(**cfg_overrides)
+    expected = _expected(module, params, cfg, prompts)
     fleet = ReplicaSet.build(
         module, params, cfg, replicas=2, roles={"prefill": 1, "decode": 1},
-        slots=2, decode_chunk=4, prefill_threshold=0,
+        slots=2, prefill_threshold=0, **engine_kwargs,
     )
     try:
         assert fleet.roles == ["prefill", "decode"]
-        got = [_drain(fleet.submit(p)) for p in PROMPTS]
+        got = [_drain(fleet.submit(p)) for p in prompts]
         assert got == expected  # first token included: the handoff is exact
         stats = fleet.stats()
         assert stats["roles"] == {"prefill": 1, "decode": 1, "mixed": 0}
-        assert stats["handoffs"]["routed"] == len(PROMPTS)
-        assert stats["handoffs"]["exported"] == len(PROMPTS)
-        assert stats["handoffs"]["imported"] == len(PROMPTS)
+        assert stats["handoffs"]["routed"] == len(prompts)
+        assert stats["handoffs"]["exported"] == len(prompts)
+        assert stats["handoffs"]["imported"] == len(prompts)
         prefill_stats, decode_stats = stats["per_replica"]
         assert prefill_stats["role"] == "prefill" and decode_stats["role"] == "decode"
-        assert prefill_stats["handoff"]["exported"] == len(PROMPTS)
-        assert decode_stats["handoff"]["imported"] == len(PROMPTS)
-        assert decode_stats["handoff"]["transfer_ms"]["window"] == len(PROMPTS)
+        assert prefill_stats["handoff"]["exported"] == len(prompts)
+        assert decode_stats["handoff"]["imported"] == len(prompts)
+        assert decode_stats["handoff"]["transfer_ms"]["window"] == len(prompts)
         # every decoded token ran on the decode replica; the prefill replica
         # never spent a decode dispatch on these streams
         assert prefill_stats["decode_dispatches"] == 0
@@ -251,6 +266,9 @@ def test_export_requires_no_speculative_and_handoff_attr_surface(tiny):
         assert len(first) == 1
         payload = stream.handoff
         assert payload is not None
+        # an engine built with no sizes ships pages: one 64-position block here
+        assert "row" not in payload and payload["block_size"] == 64
+        assert all(layer["k"].shape[1:3] == (1, 64) for layer in payload["pages"])
         assert payload["first"] == first[0]
         assert payload["prompt"] == PROMPTS[0]
         assert payload["produced"] == 1 and payload["echo"] == first
@@ -401,6 +419,10 @@ def test_autoscaler_scales_on_pressure_and_close_joins(tiny, monkeypatch):
         while fleet.replicas > 1 and time.monotonic() < deadline:
             time.sleep(0.05)
         assert fleet.replicas == 1
+        # the replica leaves the routing list first and counts as scaled down
+        # once it has drained and closed
+        while fleet.stats()["resize"]["scaled_down"] < 1 and time.monotonic() < deadline:
+            time.sleep(0.05)
         stats = fleet.stats()
         assert stats["resize"]["scaled_up"] >= 1 and stats["resize"]["scaled_down"] >= 1
         assert stats["resize"]["autoscaler"]["high"] == 1.0

@@ -121,30 +121,43 @@ def test_tree_insert_alignment_guard():
 PROMPTS_SHARED = [list(range(1, 21)) + [70 + i] for i in range(4)]
 
 
-def test_cached_prefix_streams_match_cold_and_sequential(tiny_gen):
-    """The headline contract: warm (cache-hit) streams == cold (first-visit)
-    streams == sequential Generator runs, token for token."""
-    module, params = tiny_gen
-    cfg = GenerationConfig(max_new_tokens=10, temperature=0.0, prompt_buckets=(32,))
-    expected = _sequential_expected(module, params, cfg, PROMPTS_SHARED)
+#: 70 shared tokens: past one block of the 64 an engine built with no sizes gets
+PROMPTS_SHARED_LONG = [[1 + i % 60 for i in range(70)] + [70 + i] for i in range(4)]
 
-    batcher = ContinuousBatcher(
-        Generator(module, params, cfg), slots=2, decode_chunk=4,
-        block_size=8, admit_chunk=8, prefix_cache=True,
-    )
-    try:
-        results = [_drain(batcher.submit(p)) for p in PROMPTS_SHARED]
-        assert results == expected
-        stats = batcher.stats()["prefix_cache"]
-        assert stats["hits"] == len(PROMPTS_SHARED) - 1  # all but the first
-        assert stats["misses"] == 1
+
+@pytest.mark.parametrize(
+    "sizes, bucket, prompts, avoided",
+    [
         # decode-side insertion publishes the first stream's prompt+generated
         # run, so later prompts match their WHOLE 20-token shared prefix (the
         # partial third block rides CoW), not just the 2 fully-shared blocks
-        assert stats["tokens_avoided"] == 20 * (len(PROMPTS_SHARED) - 1)
-        # a finished prompt's own full sequence is cached: the probe caps at
-        # total-1 (the last token always prefills)
-        assert batcher.cached_prefix_tokens(PROMPTS_SHARED[0]) == len(PROMPTS_SHARED[0]) - 1
+        ({"block_size": 8, "admit_chunk": 8}, 32, PROMPTS_SHARED, 20),
+        # no sizes: blocks of 64, so the tree holds the one full block
+        ({}, 128, PROMPTS_SHARED_LONG, 64),
+    ],
+    ids=["blocks_of_8", "no_sizes"],
+)
+def test_cached_prefix_streams_match_cold_and_sequential(tiny_gen, sizes, bucket, prompts, avoided):
+    """The headline contract: warm (cache-hit) streams == cold (first-visit)
+    streams == sequential Generator runs, token for token."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=10, temperature=0.0, prompt_buckets=(bucket,))
+    expected = _sequential_expected(module, params, cfg, prompts)
+
+    batcher = ContinuousBatcher(
+        Generator(module, params, cfg), slots=2, decode_chunk=4, prefix_cache=True, **sizes
+    )
+    try:
+        results = [_drain(batcher.submit(p)) for p in prompts]
+        assert results == expected
+        stats = batcher.stats()["prefix_cache"]
+        assert stats["hits"] == len(prompts) - 1  # all but the first
+        assert stats["misses"] == 1
+        assert stats["tokens_avoided"] == avoided * (len(prompts) - 1)
+        # a finished prompt's own full sequence is cached as far as whole
+        # blocks reach: the probe caps at total-1 (the last token always prefills)
+        full = (len(prompts[0]) + cfg.max_new_tokens - 1) // batcher.block_size * batcher.block_size
+        assert batcher.cached_prefix_tokens(prompts[0]) == min(full, len(prompts[0]) - 1)
     finally:
         batcher.close()
 
@@ -368,17 +381,18 @@ def test_disabled_cache_leaves_engine_and_stats_untouched(tiny_gen):
 def test_prefix_cache_knob_validation(tiny_gen, monkeypatch):
     module, params = tiny_gen
     cfg = GenerationConfig(max_new_tokens=4, temperature=0.0, prompt_buckets=(16,))
-    # explicit True without paged mode is a usage error
-    with pytest.raises(ValueError, match="paged"):
-        ContinuousBatcher(Generator(module, params, cfg), slots=1, prefix_cache=True)
-    # the env export enables paged engines and is ignored (warn) on dense ones
+    # every engine has a pool, so the keyword and the env export both work
+    # on an engine built with no sizes
+    unsized = ContinuousBatcher(Generator(module, params, cfg), slots=1, prefix_cache=True)
+    assert unsized._radix is not None
+    unsized.close()
     monkeypatch.setenv("UNIONML_TPU_PREFIX_CACHE", "1")
-    dense = ContinuousBatcher(Generator(module, params, cfg), slots=1)
-    assert dense._radix is None
-    dense.close()
-    paged = ContinuousBatcher(Generator(module, params, cfg), slots=1, block_size=8)
-    assert paged._radix is not None
-    paged.close()
+    unsized = ContinuousBatcher(Generator(module, params, cfg), slots=1)
+    assert unsized._radix is not None
+    unsized.close()
+    sized = ContinuousBatcher(Generator(module, params, cfg), slots=1, block_size=8)
+    assert sized._radix is not None
+    sized.close()
     monkeypatch.setenv("UNIONML_TPU_PREFIX_CACHE", "0")
     off = ContinuousBatcher(Generator(module, params, cfg), slots=1, block_size=8)
     assert off._radix is None

@@ -714,8 +714,8 @@ def test_speculative_constrained_composes_with_prefix(tiny, cs):
     assert np.array_equal(out, full)
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_continuous_speculative_constrained_matches_solo(tiny, cs, paged):
+@pytest.mark.parametrize("sizes", [{}, {"block_size": 4}], ids=["one_block_a_row", "blocks_of_4"])
+def test_continuous_speculative_constrained_matches_solo(tiny, cs, sizes):
     """The last matrix cell: concurrent speculative streams with per-request
     grammars through the shared batcher equal their solo constrained runs."""
     from unionml_tpu.serving import ContinuousBatcher
@@ -732,9 +732,7 @@ def test_continuous_speculative_constrained_matches_solo(tiny, cs, paged):
     prompts = [[3, 14, 15], [7, 7, 9], [1, 2]]
     gids = [1, 2, 0]
     solo = [_solo_until_eos(gen, p, g) for p, g in zip(prompts, gids)]
-    batcher = ContinuousBatcher(
-        gen, slots=2, decode_chunk=2, **(dict(block_size=4) if paged else {})
-    )
+    batcher = ContinuousBatcher(gen, slots=2, decode_chunk=2, **sizes)
     try:
         streams = [batcher.submit(p, constraint=g) for p, g in zip(prompts, gids)]
         for got_stream, ref in zip(streams, solo):
@@ -760,8 +758,8 @@ def _solo_until_eos(gen, prompt, gid, prefix=None) -> List[int]:
     return out
 
 
-@pytest.mark.parametrize("paged", [False, True], ids=["dense", "paged"])
-def test_continuous_constrained_streams_match_solo(tiny, cs, paged):
+@pytest.mark.parametrize("sizes", [{}, {"block_size": 4}], ids=["one_block_a_row", "blocks_of_4"])
+def test_continuous_constrained_streams_match_solo(tiny, cs, sizes):
     from unionml_tpu.serving import ContinuousBatcher
 
     module, params, _ = tiny
@@ -773,9 +771,7 @@ def test_continuous_constrained_streams_match_solo(tiny, cs, paged):
     prompts = [[3, 14, 15], [7, 7, 9], [1, 2]]
     gids = [1, 2, 0]
     solo = [_solo_until_eos(gen, p, g) for p, g in zip(prompts, gids)]
-    batcher = ContinuousBatcher(
-        gen, slots=2, decode_chunk=2, **(dict(block_size=4) if paged else {})
-    )
+    batcher = ContinuousBatcher(gen, slots=2, decode_chunk=2, **sizes)
     try:
         # more streams than slots: admission contention + slot reuse under
         # per-request grammars

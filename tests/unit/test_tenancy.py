@@ -376,7 +376,10 @@ def _slow_decode(engine, dispatch_s=0.02):
     engine.gen._decode = slow
 
 
-def test_high_priority_preempts_exactly_one_lowest_priority_resident(tiny):
+@pytest.mark.parametrize(
+    "sizes", [{"block_size": 16, "pool_blocks": 24}, {}], ids=["blocks_of_16", "no_sizes"]
+)
+def test_high_priority_preempts_exactly_one_lowest_priority_resident(tiny, sizes):
     module, params = tiny
     cfg = _cfg(max_new_tokens=32)
     gen = Generator(module, params, cfg)
@@ -384,7 +387,7 @@ def test_high_priority_preempts_exactly_one_lowest_priority_resident(tiny):
         tuple(p): list(map(int, gen([p])[0]))
         for p in ([3, 1, 4, 1, 5], [9, 2, 6, 5], [7, 7, 1])
     }
-    engine = ContinuousBatcher(gen, slots=2, decode_chunk=2, block_size=16, pool_blocks=24)
+    engine = ContinuousBatcher(gen, slots=2, decode_chunk=2, **sizes)
     try:
         engine.warmup()
         _slow_decode(engine)

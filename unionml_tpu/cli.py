@@ -379,7 +379,7 @@ def fetch_model(
 )
 @click.option(
     "--prefix-cache/--no-prefix-cache", "prefix_cache", default=None,
-    help="radix prefix cache on paged continuous engines: prompts extending a "
+    help="radix prefix cache on continuous engines: prompts extending a "
     "previously-seen prefix (system prompt, multi-turn history) reuse its cached KV "
     "blocks and prefill only the suffix; off (the default) keeps today's behavior exactly",
 )
@@ -408,8 +408,8 @@ def fetch_model(
 )
 @click.option(
     "--kv-cache-dtype", "kv_cache_dtype", default=None, type=click.Choice(["int8", "none"]),
-    help="KV-cache storage dtype for generation serving: int8 stores K/V rows (dense "
-    "rows and paged pools alike) symmetric-quantized per (position, head) with f32 "
+    help="KV-cache storage dtype for generation serving: int8 stores K/V (the engine's "
+    "page pools and a solo Generator's rows alike) symmetric-quantized per (position, head) with f32 "
     "scales — roughly doubling resident streams per chip; none forces the compute dtype",
 )
 @click.option(
@@ -589,7 +589,7 @@ def serve(
     same early-export contract as ``--dp-replicas``.
 
     ``--prefix-cache`` (docs/serving.md "Prefix caching") enables the radix
-    prefix cache on paged continuous engines: any prompt extending a
+    prefix cache on continuous engines: any prompt extending a
     previously-seen prefix skips prefill for the cached portion, bit-identical
     to a cold prefill; same early-export contract as ``--dp-replicas``.
 
@@ -728,7 +728,7 @@ def serve(
                 raise click.ClickException(f"{flag} must be >= {floor}")
             os.environ[getattr(_defaults, env_name)] = repr(cast(value))
     if prefix_cache is not None:
-        # same early-export contract as --dp-replicas: paged engines built at
+        # same early-export contract as --dp-replicas: engines built at
         # app-module import time must see the knob
         from unionml_tpu.defaults import SERVE_PREFIX_CACHE_ENV_VAR
 

@@ -92,7 +92,7 @@ SERVE_PREFILL_BUDGET_ENV_VAR = "UNIONML_TPU_PREFILL_BUDGET"
 SERVE_MAX_ADMISSIONS_ENV_VAR = "UNIONML_TPU_MAX_ADMISSIONS"
 
 #: 1 = enable the radix prefix cache (automatic cross-request KV reuse over
-#: paged blocks, serving/prefix_cache.py) on paged continuous engines; 0/unset
+#: paged blocks, serving/prefix_cache.py) on continuous engines; 0/unset
 #: = off, which keeps the engine byte-for-byte the pre-cache one. Same
 #: early-export contract as the admission knobs.
 SERVE_PREFIX_CACHE_ENV_VAR = "UNIONML_TPU_PREFIX_CACHE"
@@ -166,8 +166,8 @@ SERVE_AOT_PRELOAD_ENV_VAR = "UNIONML_TPU_AOT_PRELOAD"
 #: "unsupported quantize mode" ValueError.
 SERVE_QUANTIZE_ENV_VAR = "UNIONML_TPU_QUANTIZE"
 
-#: "int8" = int8 KV cache (per-(position, head) symmetric scales — dense rows
-#: and paged pools both, models/generate.init_cache/init_paged_cache);
+#: "int8" = int8 KV cache (per-(position, head) symmetric scales — the engine's
+#: page pools and a solo Generator's rows both, models/generate.init_paged_cache/init_cache);
 #: "none"/unset = the compute dtype. Same warn-and-fall-back contract.
 SERVE_KV_CACHE_DTYPE_ENV_VAR = "UNIONML_TPU_KV_CACHE_DTYPE"
 

@@ -271,7 +271,7 @@ def lease_expired(lease: "Optional[Dict[str, Any]]", *, grace_s: float = 0.0) ->
 
 def serialize_handoff(payload: Dict[str, Any]) -> bytes:
     """Encode a handoff payload (``_export_admission``'s dict) for the wire:
-    KV pages/rows as an uncompressed ``npz``, the scalar metadata as JSON
+    KV pages as an uncompressed ``npz``, the scalar metadata as JSON
     riding inside it. The ``trace`` never crosses (request timelines are
     per-process); the absolute-monotonic ``deadline``/``created_at`` are
     rebased to RELATIVE seconds so the importing host's clock domain applies
@@ -289,20 +289,13 @@ def serialize_handoff(payload: Dict[str, Any]) -> bytes:
         "deadline_remaining_s": remaining_s(payload.get("deadline")),
         "age_s": time.monotonic() - payload.get("created_at", time.monotonic()),
         "block_size": payload.get("block_size"),
+        "kind": "pages",
+        "layers": len(payload["pages"]),
     }
     arrays: Dict[str, np.ndarray] = {}
-    if payload.get("pages") is not None:
-        meta["kind"] = "pages"
-        for i, layer in enumerate(payload["pages"]):
-            for name, buf in layer.items():
-                arrays[f"p{i}.{name}"] = np.asarray(buf)
-        meta["layers"] = len(payload["pages"])
-    else:
-        meta["kind"] = "row"
-        for i, layer in enumerate(payload["row"]):
-            for name, buf in layer.items():
-                arrays[f"p{i}.{name}"] = np.asarray(buf)
-        meta["layers"] = len(payload["row"])
+    for i, layer in enumerate(payload["pages"]):
+        for name, buf in layer.items():
+            arrays[f"p{i}.{name}"] = np.asarray(buf)
     arrays["__meta__"] = np.frombuffer(json.dumps(meta).encode(), np.uint8)
     out = io.BytesIO()
     np.savez(out, **arrays)
@@ -315,6 +308,9 @@ def deserialize_handoff(data: bytes) -> Dict[str, Any]:
     importing engine places them onto its own submesh)."""
     with np.load(io.BytesIO(data)) as bundle:
         meta = json.loads(bytes(bundle["__meta__"]).decode())
+        kind = meta.pop("kind")
+        if kind != "pages":
+            raise ValueError(f"unknown handoff payload kind {kind!r}: pages are the one KV payload")
         layers = [
             {
                 key.split(".", 1)[1]: bundle[key]
@@ -325,10 +321,9 @@ def deserialize_handoff(data: bytes) -> Dict[str, Any]:
         ]
     remaining = meta.pop("deadline_remaining_s")
     age = meta.pop("age_s")
-    kind = meta.pop("kind")
     meta.pop("layers")
     payload: Dict[str, Any] = dict(meta)
-    payload["pages" if kind == "pages" else "row"] = tuple(layers)
+    payload["pages"] = tuple(layers)
     payload["deadline"] = None if remaining is None else time.monotonic() + remaining
     payload["created_at"] = time.monotonic() - max(age, 0.0)
     payload["trace"] = None

@@ -10,13 +10,14 @@ queueing behind each other.
 
 Design (classic continuous batching, expressed in fixed XLA shapes):
 
-- the engine owns a fixed pool of ``slots`` cache rows (``[S, cache_len, ...]``
-  per layer) plus the decode carry (``tok/lengths/done`` per slot) — all shapes
-  static, so XLA compiles exactly one decode program and one admission program;
+- the engine owns one pool of KV pages per layer (``pool_blocks`` blocks of
+  ``block_size`` positions), a block table row per slot (``[S, max_blocks]``)
+  and the decode carry (``tok/lengths/done`` per slot) — all shapes static, so
+  XLA compiles exactly one decode program and one admission program;
 - **join at prefill**: an arriving prompt prefills through the Generator's own
   jitted prefill at batch 1 (same numerics, same bucket set) into a fresh
-  ``[1, cache_len]`` cache, which a jitted scatter pastes into a free slot row
-  between decode chunks;
+  ``[1, cache_len]`` row, which a jitted scatter pastes into the blocks
+  allocated to a free slot between decode chunks;
 - **stall-free admission**: with ``admit_chunk`` set the admission prefill is
   sliced into fixed-size chunks through the Generator's chunked-prefill program
   and the engine alternates chunks with decode dispatches under a
@@ -106,6 +107,11 @@ __all__ = ["ContinuousBatcher"]
 
 _SENTINEL = object()
 
+#: positions a KV block holds unless the deployment sizes it: the size every
+#: benchmark cell runs and ``ops/paged_attention.py`` ``_pages_per_block`` was
+#: measured at
+KV_BLOCK_SIZE = 64
+
 
 def _tev(session: "_Session", name: str, **attrs: Any) -> None:
     """Record an event on a session's request trace, if it carries one — the
@@ -182,7 +188,7 @@ class _Session:
     #: finish/cancel/preempt via ``_release_blocks_locked``
     pins: "List[int]" = dataclasses.field(default_factory=list)
     #: the ACTUAL block ids behind the first ``table_len`` table entries, in
-    #: table order (paged mode only) — the decode-side radix publish needs the
+    #: table order — the decode-side radix publish needs the
     #: ids covering the finished stream's prompt + generated tokens, which
     #: ``_slot_blocks`` alone cannot reconstruct once ownership of prompt
     #: blocks moved to the tree
@@ -240,7 +246,7 @@ class _Admission:
     slot: int
     seed: int
     budget: int  # this request's remaining generation budget
-    blocks_row: Optional[np.ndarray]  # paged-mode block table row (None = dense)
+    blocks_row: np.ndarray  # the slot's block table row, scratch-padded
     started_at: float
     # chunked-prefill progress (populated by _admission_begin)
     chunk: int = 0  # 0 = monolithic (single-step) admission
@@ -263,9 +269,9 @@ class _Admission:
     # ids, scratch-padded, that the dense-row gather reads
     cached: int = 0
     gather_row: Optional[np.ndarray] = None
-    # block-native handoff import (paged engines): the payload's KV pages in
-    # pool layout, placed on this engine's submesh — finalize scatters them
-    # whole-block into the allocation instead of the dense per-position paste
+    # handoff import: the payload's KV pages in pool layout, placed on this
+    # engine's submesh — finalize scatters them whole-block into the
+    # allocation instead of the row's per-position paste
     import_pages: Optional[tuple] = None
     # completion products consumed by _finalize_admission
     tok0: Any = None
@@ -355,18 +361,21 @@ class ContinuousBatcher:
     prefill cost is paid once at ``cache_prefix`` time, not per request; every
     submitted prompt is then a suffix after it.
 
-    ``block_size`` switches the KV cache to PAGED mode: instead of every slot
-    owning a worst-case ``[cache_len]`` row, K/V live in a shared pool of
-    ``pool_blocks`` blocks of ``block_size`` positions and each admission is
-    allocated only the blocks ITS prompt + budget need — HBM scales with
-    resident tokens, so a pool far smaller than ``slots x cache_len`` still
-    admits a full house of typical requests (vLLM's insight, expressed in
-    static XLA shapes; no reference analog). Admission blocks FIFO while the
-    pool is exhausted and resumes as residents finish; ``stats()`` reports
-    occupancy. Decoded tokens are exactly the dense path's (the test ring pins
-    paged == contiguous == sequential).
+    The KV cache is PAGED: K/V live in a shared pool of ``pool_blocks`` blocks
+    of ``block_size`` positions and each admission is allocated only the
+    blocks ITS prompt + budget need — HBM scales with resident tokens, so a
+    pool far smaller than ``slots x cache_len`` still admits a full house of
+    typical requests (vLLM's insight, expressed in static XLA shapes; no
+    reference analog). Both are deployment sizing. ``pool_blocks`` defaults to
+    ``slots x max_blocks``, a pool that holds every slot at its worst case:
+    such an engine never waits for blocks and never preempts for space. With a
+    smaller pool admission blocks FIFO while the pool is exhausted and resumes
+    as residents finish, and residents that cannot grow are preempted and
+    resumed token-identically; ``stats()["kv_blocks"]`` reports occupancy.
+    Decoded tokens are exactly a sequential ``Generator`` run's (the test ring
+    pins paged == sequential at one block a row and at several).
 
-    ``prefix_cache=True`` (paged mode only; env default
+    ``prefix_cache=True`` (env default
     ``UNIONML_TPU_PREFIX_CACHE`` / serve ``--prefix-cache``) turns on the
     **radix prefix cache** (serving/prefix_cache.py): completed admissions
     publish their prompts' full KV blocks into a per-engine radix tree, and
@@ -376,9 +385,10 @@ class ContinuousBatcher:
     references them, copied-on-write when a request diverges inside a shared
     tail block, and LRU-evicted back into the allocator under pool pressure
     (admission never deadlocks against a full cache). Cached-prefix output is
-    bit-identical to a cold prefill; with the flag off the engine is
-    byte-for-byte the pre-cache one. ``stats()["prefix_cache"]`` carries
-    hit/miss/eviction/CoW counters and ``tokens_avoided``.
+    bit-identical to a cold prefill; with the flag off nothing is published or
+    matched and ``stats()`` has no ``prefix_cache`` section.
+    ``stats()["prefix_cache"]`` carries hit/miss/eviction/CoW counters and
+    ``tokens_avoided``.
 
     ``slo`` arms the **fleet health & SLO engine** (observability/{timeseries,
     slo,health}.py, docs/observability.md "SLOs and fleet health"): windowed
@@ -387,19 +397,19 @@ class ContinuousBatcher:
     ``health()`` score the replica scheduler routes on. ``None`` (default)
     reads the ``serve --slo-*`` env exports, an
     :class:`~unionml_tpu.observability.slo.SLOConfig` overrides them, and
-    ``False`` disables the layer entirely (the pre-health engine, byte for
-    byte). ``stats()`` gains ``rates`` (and ``slo`` when targets are armed).
+    ``False`` disables the layer entirely (no windowed telemetry, no SLO
+    tracking). ``stats()`` gains ``rates`` (and ``slo`` when targets are armed).
 
     ``role`` (disaggregated serving, docs/serving.md "Disaggregated and
     elastic serving") tags the engine ``prefill``/``decode``/``mixed`` for the
     replica layer and unlocks the KV handoff pair:
     ``submit(..., export_handoff=True)`` runs ONLY the prefill here — the
-    stream emits the prompt-sampled token, ends, and carries the prefilled
-    dense KV row on its ``handoff`` attribute — and :meth:`import_handoff` on
-    a sibling engine adopts that row into freshly allocated blocks without
+    stream emits the prompt-sampled token, ends, and carries the prompt's KV
+    pages on its ``handoff`` attribute — and :meth:`import_handoff` on a
+    sibling engine adopts those pages into freshly allocated blocks without
     re-running any prefill. Output across the pair is bit-identical to a
-    single mixed engine serving the same request. ``None`` (the default)
-    keeps ``stats()`` byte-for-byte the role-less ones.
+    single mixed engine serving the same request. With ``None`` (the default)
+    ``stats()`` carries no role or handoff section.
     """
 
     def __new__(cls, generator: Optional[Generator] = None, **engine_kwargs: Any):
@@ -454,7 +464,7 @@ class ContinuousBatcher:
         slots: int = 4,
         decode_chunk: int = 8,
         prefix: Optional[PrefixCache] = None,
-        block_size: Optional[int] = None,
+        block_size: int = KV_BLOCK_SIZE,
         pool_blocks: Optional[int] = None,
         max_waiting: Optional[int] = None,
         admit_chunk: Optional[int] = None,
@@ -475,7 +485,7 @@ class ContinuousBatcher:
             )
         if decode_chunk < 1:
             raise ValueError("decode_chunk must be >= 1")
-        if block_size is not None and block_size < 1:
+        if block_size < 1:
             raise ValueError("block_size must be >= 1")
         if max_waiting is not None and max_waiting < 1:
             raise ValueError("max_waiting must be >= 1")
@@ -573,7 +583,7 @@ class ContinuousBatcher:
         #: plus overshoot: one chunk of decode, or one round's gamma+1 verify
         #: writes in speculative mode (which never runs the plain decode)
         overshoot = (self._spec.gamma + 1) if self._spec is not None else decode_chunk
-        self._overshoot = overshoot  # also bounds per-request paged block needs
+        self._overshoot = overshoot  # also bounds per-request block needs
         p0 = prefix.length if prefix is not None else 0
         widest = max(cfg.prompt_buckets, default=64)
         self.cache_len = p0 + widest + cfg.max_new_tokens + overshoot
@@ -608,26 +618,11 @@ class ContinuousBatcher:
                 chunk_aligned(b, self.admit_chunk) for b in (cfg.prompt_buckets or (widest,))
             )
             self.cache_len = max(self.cache_len, p0 + aligned)
-        #: radix prefix cache (automatic cross-request KV reuse over paged
-        #: blocks, serving/prefix_cache.py). Resolution mirrors admit_chunk:
-        #: constructor kwarg, then the serve CLI's UNIONML_TPU_PREFIX_CACHE
-        #: export; off (the default) keeps the engine's behavior and stats
-        #: byte-for-byte the pre-cache ones. Requires paged mode — an
-        #: explicit True without block_size is a usage error, while the
-        #: env-derived default degrades with a warning (a fleet-wide export
-        #: must not crash dense engines).
-        if prefix_cache is None:
-            enable_radix = serve_prefix_cache()
-            if enable_radix and block_size is None:
-                logger.warning(
-                    "UNIONML_TPU_PREFIX_CACHE is set but this engine is not paged "
-                    "(block_size=None); prefix caching disabled"
-                )
-                enable_radix = False
-        else:
-            enable_radix = bool(prefix_cache)
-            if enable_radix and block_size is None:
-                raise ValueError("prefix_cache=True requires paged KV (block_size=...)")
+        #: radix prefix cache (automatic cross-request KV reuse over the
+        #: pool's blocks, serving/prefix_cache.py). Resolution mirrors
+        #: admit_chunk: constructor kwarg, then the serve CLI's
+        #: UNIONML_TPU_PREFIX_CACHE export; off is the default.
+        enable_radix = serve_prefix_cache() if prefix_cache is None else bool(prefix_cache)
         if enable_radix:
             if cfg.draft is not None:
                 raise ValueError(
@@ -652,10 +647,6 @@ class ContinuousBatcher:
             self.cache_len = max(self.cache_len, p0 + aligned + self._radix_chunk)
         else:
             self._radix_chunk = 0
-        #: paged-KV mode (block_size set): a host-side allocator hands pool
-        #: blocks to admissions; block index ``pool_blocks`` is the SCRATCH
-        #: block — unused/finished table entries point there, so their
-        #: ride-along writes land harmlessly outside every live allocation
         if generator.mesh is not None:
             # TP (model-axis) serving is supported: params and KV heads shard,
             # XLA inserts the collectives, and admission's batch-1 row prefill
@@ -670,53 +661,53 @@ class ContinuousBatcher:
                         f"{int(generator.mesh.shape[axis])} (batch-1 admission prefills cannot split a "
                         "batch axis) — serve a dp mesh through serving.ReplicaSet"
                     )
+        #: a host-side allocator hands pool blocks to admissions; block index
+        #: ``pool_blocks`` is the SCRATCH block — unused/finished table entries
+        #: point there, so their ride-along writes land harmlessly outside every
+        #: live allocation
         self.block_size = block_size
-        if block_size is not None:
-            # paged x TP composes: the heads-major pools shard over the model
-            # axis (Generator._place_paged_cache), tables replicate, and
-            # admission's row scatter touches only unsharded pool dims
-            self.max_blocks = -(-self.cache_len // block_size)
-            self.pool_blocks = pool_blocks if pool_blocks is not None else slots * self.max_blocks
-            if self.pool_blocks < self.max_blocks:
-                raise ValueError(
-                    f"pool_blocks ({self.pool_blocks}) must cover one worst-case request "
-                    f"({self.max_blocks} blocks of {block_size}) or admission could deadlock"
-                )
-            self._scratch_block = self.pool_blocks
-            #: bytes one pool block occupies across the target model's layers
-            #: at the POOL dtype — int8 pools carry f32 k/v scale planes (4 B
-            #: per (position, head) each) next to the 1-byte values, so the
-            #: int8-aware byte gauges on /metrics reflect what HBM actually
-            #: holds, not a naive values-only halving
-            mcfg = generator.module.config
-            head_dim = mcfg.dim // mcfg.n_heads
-            if cfg.kv_cache_dtype == "int8":
-                kv_itemsize, scale_bytes = 1, 8  # k_scale + v_scale, f32 each
-            else:
-                kv_itemsize, scale_bytes = jnp.dtype(mcfg.dtype).itemsize, 0
-            self._block_bytes = int(
-                mcfg.n_layers * mcfg.n_kv_heads * block_size
-                * (2 * head_dim * kv_itemsize + scale_bytes)
+        # the pool composes with TP: the heads-major pools shard over the model
+        # axis (Generator._place_paged_cache), tables replicate, and
+        # admission's row scatter touches only unsharded pool dims
+        self.max_blocks = -(-self.cache_len // block_size)
+        self.pool_blocks = pool_blocks if pool_blocks is not None else slots * self.max_blocks
+        if self.pool_blocks < self.max_blocks:
+            raise ValueError(
+                f"pool_blocks ({self.pool_blocks}) must cover one worst-case request "
+                f"({self.max_blocks} blocks of {block_size}) or admission could deadlock"
             )
-            self._kv_dtype_label = cfg.kv_cache_dtype or str(jnp.dtype(mcfg.dtype))
-            self._free_blocks: "List[int]" = list(range(self.pool_blocks))
-            self._slot_blocks: Dict[int, "List[int]"] = {}
-            #: shared-prefix pages: the system prompt's FULL blocks are written
-            #: once and every slot's table points at the same ids — nothing ever
-            #: writes positions < p0, so sharing is safe read-only reuse and
-            #: each request allocates only blocks past the shared region (its
-            #: partial prefix tail, its prompt, its budget). The pool must hold
-            #: the shared blocks plus one worst-case request's PRIVATE blocks.
-            self._shared_prefix_blocks: "List[int]" = []
-            if prefix is not None:
-                # (pool >= max_blocks already covers shared + worst-case private)
-                n_shared = prefix.length // block_size
-                self._shared_prefix_blocks = [self._free_blocks.pop(0) for _ in range(n_shared)]
-        elif pool_blocks is not None:
-            raise ValueError("pool_blocks requires block_size (paged mode)")
-        #: the radix tree over paged blocks; None = prefix caching off (every
-        #: radix code path below is gated on this, so the off-mode engine is
-        #: byte-for-byte the historical one)
+        self._scratch_block = self.pool_blocks
+        #: bytes one pool block occupies across the target model's layers
+        #: at the POOL dtype — int8 pools carry f32 k/v scale planes (4 B
+        #: per (position, head) each) next to the 1-byte values, so the
+        #: int8-aware byte gauges on /metrics reflect what HBM actually
+        #: holds, not a naive values-only halving
+        mcfg = generator.module.config
+        head_dim = mcfg.dim // mcfg.n_heads
+        if cfg.kv_cache_dtype == "int8":
+            kv_itemsize, scale_bytes = 1, 8  # k_scale + v_scale, f32 each
+        else:
+            kv_itemsize, scale_bytes = jnp.dtype(mcfg.dtype).itemsize, 0
+        self._block_bytes = int(
+            mcfg.n_layers * mcfg.n_kv_heads * block_size
+            * (2 * head_dim * kv_itemsize + scale_bytes)
+        )
+        self._kv_dtype_label = cfg.kv_cache_dtype or str(jnp.dtype(mcfg.dtype))
+        self._free_blocks: "List[int]" = list(range(self.pool_blocks))
+        self._slot_blocks: Dict[int, "List[int]"] = {}
+        #: shared-prefix pages: the system prompt's FULL blocks are written
+        #: once and every slot's table points at the same ids — nothing ever
+        #: writes positions < p0, so sharing is safe read-only reuse and
+        #: each request allocates only blocks past the shared region (its
+        #: partial prefix tail, its prompt, its budget). The pool must hold
+        #: the shared blocks plus one worst-case request's PRIVATE blocks.
+        self._shared_prefix_blocks: "List[int]" = []
+        if prefix is not None:
+            # (pool >= max_blocks already covers shared + worst-case private)
+            n_shared = prefix.length // block_size
+            self._shared_prefix_blocks = [self._free_blocks.pop(0) for _ in range(n_shared)]
+        #: the radix tree over the pool's blocks; None = prefix caching off
+        #: (every radix code path below is gated on this)
         self._radix: Optional[RadixPrefixCache] = None
         if enable_radix:
             self._radix = RadixPrefixCache(block_size)
@@ -752,14 +743,11 @@ class ContinuousBatcher:
         self._carry: Optional[tuple] = None  # (cache, tok, lengths, done, key)
         #: the host's account of what it writes into the carry between
         #: dispatches (engine thread only): every slot's table row as the device
-        #: should hold it (paged mode), the rows edited since the last sync and
-        #: the slots released since. ``_extend_tables`` / ``_mask_slot_done``
+        #: should hold it, the rows edited since the last sync and the slots
+        #: released since. ``_extend_tables`` / ``_mask_slot_done``
         #: write here and ``_sync_carry`` carries the lot to the device in one
         #: dispatch, whatever the number of rows, blocks and layers
-        paged = block_size is not None
-        self._table_host = np.full(
-            (slots, self.max_blocks if paged else 0), self._scratch_block if paged else 0, np.int32
-        )
+        self._table_host = np.full((slots, self.max_blocks), self._scratch_block, np.int32)
         self._edited_host = np.zeros((slots,), bool)
         self._released_host = np.zeros((slots,), bool)
         self._sync_fn = jax.jit(self._sync_impl, donate_argnums=(0, 1, 2))
@@ -767,8 +755,6 @@ class ContinuousBatcher:
         self._thread: Optional[threading.Thread] = None
         # donate only the pool-side buffers: the [1, ...] row caches can't alias
         # any output shape, so donating them would just trigger warnings
-        self._admit_fn = jax.jit(self._admit_impl, donate_argnums=(0,))
-        self._spec_admit_fn = jax.jit(self._spec_admit_impl, donate_argnums=(0, 1, 2))
         self._paged_admit_fn = jax.jit(self._paged_admit_impl, donate_argnums=(0,))
         self._paged_spec_admit_fn = jax.jit(
             self._paged_spec_admit_impl, donate_argnums=(0, 1, 2)
@@ -783,10 +769,8 @@ class ContinuousBatcher:
         self._paged_page_admit_fn = jax.jit(self._paged_page_admit_impl, donate_argnums=(0,))
         if self._aot is not None:
             # the admission scatter helpers preload too — on a cold TPU the
-            # paged scatter over a big pool is its own multi-second compile
+            # scatter over a big pool is its own multi-second compile
             ectx = self.gen._aot_context()
-            self._admit_fn = AOTFunction(self._admit_fn, "admit", self._aot, ectx)
-            self._spec_admit_fn = AOTFunction(self._spec_admit_fn, "spec_admit", self._aot, ectx)
             self._paged_admit_fn = AOTFunction(
                 self._paged_admit_fn, "paged_admit", self._aot, ectx
             )
@@ -907,28 +891,14 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------ device fns
 
     @staticmethod
-    def _admit_impl(cache: Any, row_cache: Any, tok: jax.Array, lengths: jax.Array,
-                    done: jax.Array, slot: jax.Array, row_tok: jax.Array, row_len: jax.Array):
-        """Paste a freshly prefilled [1, cache_len, ...] cache row into slot row
-        ``slot`` of the pool and activate its carry entries. One compile total:
-        ``slot`` is a traced scalar."""
-        def paste(buf: jax.Array, row: jax.Array) -> jax.Array:
-            start = (slot,) + (0,) * (buf.ndim - 1)
-            return jax.lax.dynamic_update_slice(buf, row.astype(buf.dtype), start)
-
-        cache = jax.tree_util.tree_map(paste, cache, row_cache)
-        tok = jax.lax.dynamic_update_slice(tok, row_tok.astype(tok.dtype), (slot,))
-        lengths = jax.lax.dynamic_update_slice(lengths, row_len.astype(lengths.dtype), (slot,))
-        done = jax.lax.dynamic_update_slice(done, jnp.zeros((1,), bool), (slot,))
-        return cache, tok, lengths, done
-
-    @staticmethod
     def _paged_admit_impl(cache, row_cache, tok, lengths, done, slot, row_tok, row_len, blocks_row,
                           skip=0):
-        """Paged admission: point slot ``slot``'s table row at ``blocks_row`` in
-        every layer and scatter the dense ``[1, cache_len]`` prefilled row into
-        those blocks. ``blocks_row`` ([max_blocks] int32) is scratch-padded past
-        the request's allocation, so the dense row's unused tail lands in the
+        """Admission: point slot ``slot``'s table row at ``blocks_row`` in
+        every layer, scatter the prefilled ``[1, cache_len]`` row into those
+        blocks and activate the slot's carry entries. One compile total:
+        ``slot`` is a traced scalar. ``blocks_row`` ([max_blocks] int32) is
+        scratch-padded past the request's allocation, so the row's unused tail
+        lands in the
         scratch block, never in another request's pages. ``skip`` (traced, so
         per-request cached-run lengths don't multiply compiles) diverts the
         first ``skip`` blocks' writes to scratch: those table entries are
@@ -940,7 +910,7 @@ class ContinuousBatcher:
         scratch = cache[0]["k"].shape[1] - 1  # scratch is the last pool block
         new_layers = []
         for layer, row in zip(cache, row_cache):
-            pos = jnp.arange(row["k"].shape[1])  # the dense row is [1, cache_len, H, last]
+            pos = jnp.arange(row["k"].shape[1])  # the row is [1, cache_len, H, last]
             blk, off = blocks_row[pos // block_size], pos % block_size
             blk = jnp.where(pos < skip * block_size, scratch, blk)
             new_layer = {"table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
@@ -956,19 +926,24 @@ class ContinuousBatcher:
 
     @staticmethod
     def _export_pages_impl(row_cache, n_blocks, block_size):
-        """Slice a prefilled dense ``[1, cache_len, H, last]`` row into its
-        first ``n_blocks`` block-sized pages in POOL layout
-        (``[H, n_blocks, block_size, last]``) — the block-native handoff
-        payload. ``n_blocks``/``block_size`` are static (one small compile per
-        distinct page count); the page contents are byte-identical to what the
-        dense admission scatter would have written into those blocks, which is
-        what makes the pages path bit-identical to the dense one."""
+        """Slice a prefilled ``[1, cache_len, H, last]`` row into its first
+        ``n_blocks`` block-sized pages in POOL layout
+        (``[H, n_blocks, block_size, last]``) — the handoff payload.
+        ``n_blocks``/``block_size`` are static (one small compile per distinct
+        page count); the page contents are byte-identical to what the
+        admission scatter would have written into those blocks, which is what
+        makes an imported stream bit-identical to a locally prefilled one.
+        ``cache_len`` need not be a block multiple: where the last page reaches
+        past the row's end it is zero-padded (the importer's ``lengths`` says
+        how many positions are live; the decode read masks the rest)."""
         width = n_blocks * block_size
         pages = []
         for layer in row_cache:
             page = {}
             for name, buf in layer.items():
-                sliced = jnp.swapaxes(buf[0, :width], 0, 1)  # [H, width, last]
+                sliced = buf[0, :width]
+                sliced = jnp.pad(sliced, ((0, width - sliced.shape[0]), (0, 0), (0, 0)))
+                sliced = jnp.swapaxes(sliced, 0, 1)  # [H, width, last]
                 page[name] = sliced.reshape(sliced.shape[0], n_blocks, block_size, sliced.shape[-1])
             pages.append(page)
         return tuple(pages)
@@ -976,14 +951,14 @@ class ContinuousBatcher:
     @staticmethod
     def _paged_page_admit_impl(cache, pages, tok, lengths, done, slot, row_tok, row_len,
                                blocks_row, skip=0):
-        """Block-native import: point slot ``slot``'s table at ``blocks_row``
+        """Handoff import: point slot ``slot``'s table at ``blocks_row``
         and write the payload's pages WHOLE-BLOCK into the first
-        ``n_blocks`` allocated blocks — no ``cache_len``-wide dense row is
+        ``n_blocks`` allocated blocks — no ``cache_len``-wide row is
         ever materialized on the importing engine. ``skip`` (traced) diverts
         the first ``skip`` pages to the scratch block: those table entries are
         SHARED (the static prefix's blocks), already holding exactly the
         pages' content, and tree-shared pages must never be re-written under
-        their other readers — the same contract as the dense scatter's
+        their other readers — the same contract as the row scatter's
         ``skip``."""
         n_blocks = pages[0]["k"].shape[1]
         scratch = cache[0]["k"].shape[1] - 1  # scratch is the last pool block
@@ -1002,7 +977,7 @@ class ContinuousBatcher:
     @classmethod
     def _paged_spec_admit_impl(cls, t_cache, d_cache, out_buf, t_row, d_row, tok, lengths, done,
                                produced, slot, row_tok, row_len, row_done, pad, blocks_row, skip=0):
-        """Paged speculative admission: the SAME block ids serve both models —
+        """Speculative admission: the SAME block ids serve both models —
         their pools are sized in identical block counts (shapes differ), and a
         slot's logical positions are identical in both caches, so one
         allocation drives two scatters."""
@@ -1017,47 +992,26 @@ class ContinuousBatcher:
 
     @staticmethod
     def _spec_activate(out_buf, done, produced, slot, row_tok, row_done, pad):
-        """Speculative activation tail shared by the dense and paged admit
-        impls: reset the slot's out_buf row (pad everywhere, tok0 at 0), set
-        the start-done flag, and start the produced counter at 1."""
+        """Speculative activation tail: reset the slot's out_buf row (pad
+        everywhere, tok0 at 0), set an explicit start-done flag (a tok0 that is
+        already eos, or a budget of 1), and start the produced counter at 1."""
         row = jnp.full((out_buf.shape[1],), pad, out_buf.dtype).at[0].set(row_tok[0])
         out_buf = jax.lax.dynamic_update_slice(out_buf, row[None], (slot, 0))
         done = jax.lax.dynamic_update_slice(done, row_done, (slot,))
         produced = jax.lax.dynamic_update_slice(produced, jnp.ones((1,), produced.dtype), (slot,))
         return out_buf, done, produced
 
-    @classmethod
-    def _spec_admit_impl(cls, t_cache, d_cache, out_buf, t_row, d_row, tok, lengths, done,
-                         produced, slot, row_tok, row_len, row_done, pad):
-        """Speculative-mode admission: the shared paste/activate body
-        (:meth:`_admit_impl`) handles the target cache and carry entries; this
-        adds the draft cache row, the out_buf row reset (pad everywhere, tok0 at
-        0), the produced counter, and an explicit start-done flag (a tok0 that
-        is already eos, or a budget of 1)."""
-        t_cache, tok, lengths, done = cls._admit_impl(
-            t_cache, t_row, tok, lengths, done, slot, row_tok, row_len
-        )
-        def paste(buf: jax.Array, row: jax.Array) -> jax.Array:
-            start = (slot,) + (0,) * (buf.ndim - 1)
-            return jax.lax.dynamic_update_slice(buf, row.astype(buf.dtype), start)
-
-        d_cache = jax.tree_util.tree_map(paste, d_cache, d_row)
-        out_buf, done, produced = cls._spec_activate(out_buf, done, produced, slot, row_tok, row_done, pad)
-        return t_cache, d_cache, out_buf, tok, lengths, done, produced
-
     @staticmethod
     def _sync_impl(tables, lengths, done, table, edited, released):
         """Apply the host's edits to the carry (:meth:`_sync_carry`): every
         layer's table of every cache (``tables``, a tuple per cache of its
-        layers' ``[slots, max_blocks]`` tables; empty for a dense cache) takes
-        the ``edited`` rows of the host's ``table``, and a ``released`` slot is
-        done. In paged mode its length falls to 0 as well: a free row's table
-        points at the scratch block, and the decode read streams as many
-        positions of it as the row's length says, on every step."""
+        layers' ``[slots, max_blocks]`` tables) takes the ``edited`` rows of
+        the host's ``table``, and a ``released`` slot is done. Its length falls
+        to 0 as well: a free row's table points at the scratch block, and the
+        decode read streams as many positions of it as the row's length says,
+        on every step."""
         tables = jax.tree_util.tree_map(lambda t: jnp.where(edited[:, None], table.astype(t.dtype), t), tables)
-        if tables:
-            lengths = jnp.where(released, 0, lengths)
-        return tables, lengths, done | released
+        return tables, jnp.where(released, 0, lengths), done | released
 
     def _seed_shared_prefix(self, cache: Any, prefix_layers: Any) -> Any:
         """Write the prefix's FULL blocks into a pool once; every admission's
@@ -1083,26 +1037,21 @@ class ContinuousBatcher:
 
     def _init_carry(self) -> tuple:
         cfg = self.gen.config
-        if self.block_size is not None:
-            # pool_blocks + 1: the extra block is scratch (see __init__); tables
-            # start all-scratch so never-admitted slots' ride-along writes are
-            # harmless from the first dispatch
-            cache = self.gen._place_paged_cache(
-                init_paged_cache(
-                    self.gen.module.config, self.slots, self.pool_blocks + 1, self.block_size,
-                    self.max_blocks, kv_dtype=cfg.kv_cache_dtype, fill_block=self._scratch_block,
-                )
+        # pool_blocks + 1: the extra block is scratch (see __init__); tables
+        # start all-scratch so never-admitted slots' ride-along writes are
+        # harmless from the first dispatch
+        cache = self.gen._place_paged_cache(
+            init_paged_cache(
+                self.gen.module.config, self.slots, self.pool_blocks + 1, self.block_size,
+                self.max_blocks, kv_dtype=cfg.kv_cache_dtype, fill_block=self._scratch_block,
             )
-            if self._shared_prefix_blocks:
-                cache = self._seed_shared_prefix(cache, self.prefix.layers)
-        else:
-            cache = self.gen._place_cache(
-                init_cache(self.gen.module.config, self.slots, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
-            )
+        )
+        if self._shared_prefix_blocks:
+            cache = self._seed_shared_prefix(cache, self.prefix.layers)
         tok = jnp.zeros((self.slots,), jnp.int32)
-        # a free paged row has length 0, before its first admission as after a
+        # a free row has length 0, before its first admission as after a
         # release (_sync_impl): the decode read streams one block of scratch for it
-        lengths = jnp.full((self.slots,), 0 if self.block_size is not None else 1, jnp.int32)
+        lengths = jnp.zeros((self.slots,), jnp.int32)
         done = jnp.ones((self.slots,), bool)  # every slot starts free (= masked out)
         # built inside jit so the key's sharding provenance matches the decode
         # outputs it cycles through (an eager key carries SingleDeviceSharding,
@@ -1117,21 +1066,16 @@ class ContinuousBatcher:
                 tail += (jnp.zeros((len(self.gen.counter_names),), jnp.int32),)
             return (cache, tok, lengths, done, key, *tail)
         draft_gen = self._spec._draft
-        if self.block_size is not None:
-            # the draft's pool has the same BLOCK COUNT (different shapes), so
-            # one host allocation addresses both caches
-            d_cache = draft_gen._place_paged_cache(
-                init_paged_cache(
-                    draft_gen.module.config, self.slots, self.pool_blocks + 1, self.block_size,
-                    self.max_blocks, kv_dtype=cfg.kv_cache_dtype, fill_block=self._scratch_block,
-                )
+        # the draft's pool has the same BLOCK COUNT (different shapes), so
+        # one host allocation addresses both caches
+        d_cache = draft_gen._place_paged_cache(
+            init_paged_cache(
+                draft_gen.module.config, self.slots, self.pool_blocks + 1, self.block_size,
+                self.max_blocks, kv_dtype=cfg.kv_cache_dtype, fill_block=self._scratch_block,
             )
-            if self._shared_prefix_blocks:
-                d_cache = self._seed_shared_prefix(d_cache, self._draft_prefix.layers)
-        else:
-            d_cache = draft_gen._place_cache(
-                init_cache(draft_gen.module.config, self.slots, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
-            )
+        )
+        if self._shared_prefix_blocks:
+            d_cache = self._seed_shared_prefix(d_cache, self._draft_prefix.layers)
         cap = cfg.max_new_tokens + self._spec.gamma + 1
         out_buf = jnp.full((self.slots, cap), cfg.pad_id, jnp.int32)
         produced = jnp.zeros((self.slots,), jnp.int32)
@@ -1180,17 +1124,11 @@ class ContinuousBatcher:
         if p0 + bucket + budget > self.cache_len:
             # a PREEMPTED request resumes as prompt + emitted tokens, which can
             # outgrow every configured bucket while still fitting the cache
-            # contiguously (prompt + remaining budget <= cache_len by
-            # construction) — prefill at the exact width instead of failing the
-            # stream; the extra compile is bounded by preemptions being rare
-            exact = max(len(prompt), 1)
-            if p0 + exact + budget <= self.cache_len:
-                bucket = exact
-            else:
-                raise ValueError(
-                    f"prompt of length {len(prompt)} needs prefix {p0} + bucket {bucket} + "
-                    f"{budget} new tokens > cache_len {self.cache_len}"
-                )
+            # contiguously (_start_admissions checked that prompt + remaining
+            # budget <= cache_len) — prefill at the exact width instead of
+            # failing the stream; the extra compile is bounded by preemptions
+            # being rare
+            bucket = max(len(prompt), 1)
         tokens = np.full((1, bucket), cfg.pad_id, np.int32)
         tokens[0, : len(prompt)] = np.asarray(prompt, np.int32)
         lengths = jnp.asarray([p0 + max(len(prompt), 1)], jnp.int32)
@@ -1231,7 +1169,7 @@ class ContinuousBatcher:
             # through the Generator's own ring/ulysses shard_map machinery
             # (columns split over the sequence axis; data/fsdp axes are 1 by the
             # mesh guard above), then the row pastes into the pool exactly like
-            # a dense admission — same numerics, same bounded compile set.
+            # any admission — same numerics, same bounded compile set.
             # When the sequence-aligned width would overflow the cache — a
             # PREEMPTION RESUME's exact-width bucket can outgrow every
             # configured bucket while fitting contiguously — the row falls
@@ -1378,7 +1316,7 @@ class ContinuousBatcher:
         token bucket is shed with :class:`TenantThrottled` (HTTP 429 whose
         ``Retry-After`` is the bucket's actual refill time), waiting prompts
         are admitted deficit-round-robin across tenants within strict priority
-        tiers, and a high-priority admission on a full paged engine preempts
+        tiers, and a high-priority admission on a full engine preempts
         the lowest-priority resident (which resumes token-identically)."""
         if len(prompt) == 0:
             raise ValueError("prompt must be non-empty")
@@ -1447,8 +1385,7 @@ class ContinuousBatcher:
             slot=-1, out=queue.Queue(), max_new=budget, grammar=grammar, deadline=deadline,
             created_at=time.monotonic(), trace=req_trace, export=export_handoff,
             tenant=tenant, priority=priority, want_logprobs=bool(logprobs),
-            # the original prompt is retained only where preemption can resume it
-            prompt=list(prompt) if self.block_size is not None else [],
+            prompt=list(prompt),
             request_id=current_request_id(), prompt_tokens=len(prompt),
         )
         with self._lock:
@@ -1523,7 +1460,7 @@ class ContinuousBatcher:
 
     def import_handoff(self, payload: Dict[str, Any]) -> Iterator[np.ndarray]:
         """Adopt a sibling replica's exported prefill (disaggregated serving,
-        the decode-role path): the payload's dense KV row is ``device_put``
+        the decode-role path): the payload's KV pages are ``device_put``
         onto this engine's submesh and scattered into freshly allocated blocks
         at admission time — no prefill runs here, so the import costs one
         paste dispatch. The returned stream carries every token AFTER the
@@ -1547,8 +1484,8 @@ class ContinuousBatcher:
             trace=trace,
             tenant=payload.get("tenant"),
             priority=int(payload.get("priority", PRIORITY_NORMAL)),
-            prompt=list(payload["prompt"]) if self.block_size is not None else [],
-            echo=list(payload["echo"]) if self.block_size is not None else [],
+            prompt=list(payload["prompt"]),
+            echo=list(payload["echo"]),
             request_id=current_request_id(), prompt_tokens=len(payload["prompt"]),
         )
         session.pending_import = dict(payload)
@@ -1820,9 +1757,8 @@ class ContinuousBatcher:
                 "shed_deadline": self.shed_deadline,
                 "draining": self._closed,
                 "decode_dispatches": self.decode_dispatches,
-                # how the decode program reads the paged cache, recorded when it
-                # was traced: "paged_kernel" or "gather" (None: not traced yet,
-                # or a contiguous cache)
+                # how the decode program reads the cache, recorded when it
+                # was traced: "paged_kernel" or "gather" (None: not traced yet)
                 "decode_attention_path": self.gen.decode_attention_path,
                 "rows_per_dispatch": round(
                     self.decoded_rows / self.decode_dispatches, 3
@@ -1845,32 +1781,31 @@ class ContinuousBatcher:
                     "backlog_tokens": backlog,
                 },
             }
-            if self.block_size is not None:
-                # "used" includes the permanently resident shared-prefix pages
-                used = self.pool_blocks - len(self._free_blocks)
-                snapshot["kv_blocks"] = {
-                    "total": self.pool_blocks,
-                    "used": used,
-                    "shared_prefix": len(self._shared_prefix_blocks),
-                    "block_size": self.block_size,
-                    "preemptions": self.preemptions,
-                    # byte gauges at the POOL dtype (int8 pools include their
-                    # f32 scale planes) — ints always, never None, so the
-                    # Prometheus exposition stays clean; the dtype label is a
-                    # string, which the exposition skips by design
-                    "block_bytes": self._block_bytes,
-                    "used_bytes": used * self._block_bytes,
-                    "kv_dtype": self._kv_dtype_label,
-                }
-                if self.prefix is not None:
-                    # the static prefix's partial tail block is NOT among the
-                    # seeded shared pages — each admission re-scatters those
-                    # tokens into a private block (the radix cache, when on,
-                    # caches the tail like any other run); surface the count
-                    # so a misaligned prefix/block_size choice is visible
-                    snapshot["kv_blocks"]["shared_prefix_tail_tokens"] = (
-                        self.prefix.length - len(self._shared_prefix_blocks) * self.block_size
-                    )
+            # "used" includes the permanently resident shared-prefix pages
+            used = self.pool_blocks - len(self._free_blocks)
+            snapshot["kv_blocks"] = {
+                "total": self.pool_blocks,
+                "used": used,
+                "shared_prefix": len(self._shared_prefix_blocks),
+                "block_size": self.block_size,
+                "preemptions": self.preemptions,
+                # byte gauges at the POOL dtype (int8 pools include their
+                # f32 scale planes) — ints always, never None, so the
+                # Prometheus exposition stays clean; the dtype label is a
+                # string, which the exposition skips by design
+                "block_bytes": self._block_bytes,
+                "used_bytes": used * self._block_bytes,
+                "kv_dtype": self._kv_dtype_label,
+            }
+            if self.prefix is not None:
+                # the static prefix's partial tail block is NOT among the
+                # seeded shared pages — each admission re-scatters those
+                # tokens into a private block (the radix cache, when on,
+                # caches the tail like any other run); surface the count
+                # so a misaligned prefix/block_size choice is visible
+                snapshot["kv_blocks"]["shared_prefix_tail_tokens"] = (
+                    self.prefix.length - len(self._shared_prefix_blocks) * self.block_size
+                )
             if self._radix is not None:
                 # radix prefix cache: admission-level hit/miss counters, the
                 # prompt tokens whose prefill the cache skipped, and the
@@ -2141,7 +2076,7 @@ class ContinuousBatcher:
         session past its deadline is shed with DeadlineExceeded — its client
         has given up, so a prefill + full decode would be pure waste (the
         whole list is swept, not just the head: max_waiting bounds it, so
-        this stays cheap). Paged mode allocates only the prompt + first
+        this stays cheap). An admission is allocated only the prompt + first
         dispatch (residents grow lazily); the head-of-line request keeps its
         FIFO position while the pool cannot supply its initial blocks."""
         with self._lock:
@@ -2178,69 +2113,68 @@ class ContinuousBatcher:
                         # high-priority prompt rotates back in front of it
                         continue
                     break
-                blocks_row = None
                 gather_row = None
                 cached = 0
                 pins: "List[int]" = []
                 p0 = self.prefix.length if self.prefix is not None else 0
-                if self.block_size is not None:
-                    head_prompt, head_session = self._pending[0]
-                    head_budget = head_session.max_new - head_session.produced
-                    lifetime = self._blocks_lifetime(head_prompt, head_budget)
-                    if len(self._shared_prefix_blocks) + lifetime > self.max_blocks:
-                        # an oversized prompt can never fit a table row: fail its
-                        # stream now instead of wedging the FIFO head forever
-                        prompt, session = self._pending.pop(0)
-                        if not session.finished:
-                            session.finished = True
-                            self._record_end(session, "error")
-                            session.out.put(ValueError(
-                                f"prompt needs {len(self._shared_prefix_blocks) + lifetime} KV "
-                                f"blocks but a slot's table holds {self.max_blocks}"
-                            ))
-                        continue
-                    # seeded leading table entries: the static prefix's full
-                    # blocks, or (on a radix hit) the matched cached run
-                    seeded = list(self._shared_prefix_blocks)
-                    # imported handoffs skip the radix match: their row arrives
-                    # complete, so there is no prefill to skip — matching would
-                    # only pin blocks the gather path never reads
-                    if self._radix is not None and head_session.pending_import is None:
-                        total = p0 + max(len(head_prompt), 1)
-                        # cap at total - 1: the last prompt token always
-                        # prefills so the first sampled token has its hidden
-                        # state (and stays bit-identical to a cold prefill)
-                        m, mblocks = self._radix.match(self._radix_key(head_prompt))
-                        m = min(m, total - 1)
-                        if m > p0:
-                            cached = m
-                            mblocks = mblocks[: -(-m // self.block_size)]
-                            seeded = mblocks[: m // self.block_size]
-                            # pin every matched block (the partial tail too —
-                            # the gather reads it) until this stream releases
-                            pins = list(mblocks)
-                            self._radix.pin(pins)
-                    try:
-                        needed = self._blocks_initial(head_prompt, head_budget, shared=len(seeded))
-                        if needed > len(self._free_blocks):
-                            # pool pressure: cached-but-idle prefixes are exactly
-                            # the memory the next admission may take back
-                            self._reclaim_blocks_locked(needed - len(self._free_blocks))
-                        if needed > len(self._free_blocks):
-                            if pins:
-                                self._radix.release(pins)
-                            return
-                        prompt, session = self._pending.pop(0)
-                        slot = self._free.pop(0)
-                    except BaseException:
-                        # admission died between pin and handoff: unpin, or the
-                        # matched prefix blocks stay unevictable forever
+                head_prompt, head_session = self._pending[0]
+                head_budget = head_session.max_new - head_session.produced
+                lifetime = len(self._shared_prefix_blocks) + self._blocks_lifetime(head_prompt, head_budget)
+                positions = p0 + max(len(head_prompt), 1) + head_budget
+                if lifetime > self.max_blocks or positions > self.cache_len:
+                    # an oversized prompt can never fit a slot (its table row, or
+                    # the row cache its prefill fills): fail its stream now
+                    # instead of wedging the FIFO head forever
+                    prompt, session = self._pending.pop(0)
+                    if not session.finished:
+                        session.finished = True
+                        self._record_end(session, "error")
+                        session.out.put(ValueError(
+                            f"prompt of length {len(prompt)} with {head_budget} new tokens needs "
+                            f"{positions} KV positions ({lifetime} blocks of {self.block_size} with the "
+                            f"dispatch overshoot) but a slot holds cache_len {self.cache_len} "
+                            f"({self.max_blocks} blocks)"
+                        ))
+                    continue
+                # seeded leading table entries: the static prefix's full
+                # blocks, or (on a radix hit) the matched cached run
+                seeded = list(self._shared_prefix_blocks)
+                # imported handoffs skip the radix match: their row arrives
+                # complete, so there is no prefill to skip — matching would
+                # only pin blocks the gather path never reads
+                if self._radix is not None and head_session.pending_import is None:
+                    total = p0 + max(len(head_prompt), 1)
+                    # cap at total - 1: the last prompt token always
+                    # prefills so the first sampled token has its hidden
+                    # state (and stays bit-identical to a cold prefill)
+                    m, mblocks = self._radix.match(self._radix_key(head_prompt))
+                    m = min(m, total - 1)
+                    if m > p0:
+                        cached = m
+                        mblocks = mblocks[: -(-m // self.block_size)]
+                        seeded = mblocks[: m // self.block_size]
+                        # pin every matched block (the partial tail too —
+                        # the gather reads it) until this stream releases
+                        pins = list(mblocks)
+                        self._radix.pin(pins)
+                try:
+                    needed = self._blocks_initial(head_prompt, head_budget, shared=len(seeded))
+                    if needed > len(self._free_blocks):
+                        # pool pressure: cached-but-idle prefixes are exactly
+                        # the memory the next admission may take back
+                        self._reclaim_blocks_locked(needed - len(self._free_blocks))
+                    if needed > len(self._free_blocks):
                         if pins:
                             self._radix.release(pins)
-                        raise
-                else:
+                        return
                     prompt, session = self._pending.pop(0)
                     slot = self._free.pop(0)
+                except BaseException:
+                    # admission died between pin and handoff: unpin, or the
+                    # matched prefix blocks stay unevictable forever
+                    if pins:
+                        self._radix.release(pins)
+                    raise
                 # the session owns the pins from here: its release path
                 # (_release_slot_locked) unpins them with every other exit
                 session.pins = pins
@@ -2248,18 +2182,17 @@ class ContinuousBatcher:
                 session.admit_seq = self._admit_counter
                 self._admit_counter += 1
                 session.row_start = p0 + max(len(prompt), 1)
-                if self.block_size is not None:
-                    alloc = [self._free_blocks.pop(0) for _ in range(needed)]
-                    self._slot_blocks[slot] = alloc
-                    session.shared_blocks = len(seeded)
-                    session.table_len = len(seeded) + len(alloc)
-                    session.table = list(seeded) + list(alloc)
-                    blocks_row = np.full((self.max_blocks,), self._scratch_block, np.int32)
-                    blocks_row[: len(seeded)] = seeded
-                    blocks_row[len(seeded) : len(seeded) + len(alloc)] = alloc
-                    if cached:
-                        gather_row = np.full((self.max_blocks,), self._scratch_block, np.int32)
-                        gather_row[: len(pins)] = pins
+                alloc = [self._free_blocks.pop(0) for _ in range(needed)]
+                self._slot_blocks[slot] = alloc
+                session.shared_blocks = len(seeded)
+                session.table_len = len(seeded) + len(alloc)
+                session.table = list(seeded) + list(alloc)
+                blocks_row = np.full((self.max_blocks,), self._scratch_block, np.int32)
+                blocks_row[: len(seeded)] = seeded
+                blocks_row[len(seeded) : len(seeded) + len(alloc)] = alloc
+                if cached:
+                    gather_row = np.full((self.max_blocks,), self._scratch_block, np.int32)
+                    gather_row[: len(pins)] = pins
                 self._seed += 1
                 now = time.monotonic()
                 if session.admission_started is None:  # a preemption resume keeps the first
@@ -2368,12 +2301,11 @@ class ContinuousBatcher:
     def _preempt_for_priority_locked(self) -> bool:
         """With no free slot and a HIGH-priority prompt heading the queue,
         preempt exactly one lowest-priority resident (ties: youngest — the
-        block-pressure victim rule) through the engine's existing paged
+        block-pressure victim rule) through the engine's
         preempt/exact-width-resume path: the victim requeues at the FIFO head
-        and later resumes token-identically, never truncated. Paged mode only
-        — dense sessions do not retain the prompt a resume needs. Returns True
+        and later resumes token-identically, never truncated. Returns True
         when a slot was freed (caller re-selects)."""
-        if self.block_size is None or not self._pending:
+        if not self._pending:
             return False
         head = self._pending[0][1]
         if head.finished or head.priority != PRIORITY_HIGH:
@@ -2492,15 +2424,10 @@ class ContinuousBatcher:
         if p0 + bucket + adm.budget > self.cache_len:
             # a PREEMPTED request resumes as prompt + emitted tokens, which
             # can outgrow every configured bucket while still fitting the
-            # cache contiguously — admit at the exact width instead of
-            # failing the stream (_prefill_row applies the same rule)
-            exact = max(len(prompt), 1)
-            if p0 + exact + adm.budget > self.cache_len:
-                raise ValueError(
-                    f"prompt of length {len(prompt)} needs prefix {p0} + bucket {bucket} + "
-                    f"{adm.budget} new tokens > cache_len {self.cache_len}"
-                )
-            bucket = exact
+            # cache contiguously (_start_admissions checked that it does) —
+            # admit at the exact width instead of failing the stream
+            # (_prefill_row applies the same rule)
+            bucket = max(len(prompt), 1)
         sp = cfg.sp_prefill and gen.mesh is not None and self._sp_seq > 1 and self.prefix is None
         chunk = self.admit_chunk
         aligned = chunk_aligned(bucket, chunk) if chunk else bucket
@@ -2563,51 +2490,32 @@ class ContinuousBatcher:
 
     def _import_begin(self, adm: _Admission) -> int:
         """Set up an imported-handoff admission (engine thread): place the
-        exported dense row onto THIS engine's submesh and mark the admission
+        exported pages onto THIS engine's submesh and mark the admission
         complete — no prefill runs, so the cost is one ``device_put``. The
         grammar state is recovered from the payload's emitted tokens exactly
         as a preemption resume recovers it (the DFA is a pure function of the
         emissions), stopping one short so :meth:`_finalize_admission`'s
         standard advance past the first token lands on the right state."""
         payload = adm.session.pending_import
-        pages = payload.get("pages")
-        if pages is not None:
-            # block-native payload: whole KV pages in pool layout, placed onto
-            # this engine's submesh (device_put copies between disjoint device
-            # sets — and accepts the numpy arrays a cross-host wire delivers)
-            if self.block_size is None:
-                raise ValueError(
-                    "a block-native (paged) handoff cannot import into a dense engine; "
-                    "disaggregated replicas must be built with identical engine knobs"
-                )
-            if int(payload.get("block_size") or 0) != self.block_size:
-                raise ValueError(
-                    f"handoff block_size {payload.get('block_size')} != this engine's "
-                    f"{self.block_size}; disaggregated replicas must be built with "
-                    "identical engine knobs"
-                )
-            if int(payload["lengths"]) > self.cache_len:
-                raise ValueError(
-                    f"handoff covers {payload['lengths']} positions but this engine's "
-                    f"cache_len is {self.cache_len}; disaggregated replicas must be "
-                    "built with identical engine knobs"
-                )
-            pages = tuple(
-                {name: jnp.asarray(buf) for name, buf in layer.items()} for layer in pages
+        if int(payload.get("block_size") or 0) != self.block_size:
+            raise ValueError(
+                f"handoff block_size {payload.get('block_size')} != this engine's "
+                f"{self.block_size}; disaggregated replicas must be built with "
+                "identical engine knobs"
             )
-            adm.import_pages = self.gen._place_paged_cache(pages)
-        else:
-            row = payload["row"]
-            width = int(jax.tree_util.tree_leaves(row)[0].shape[1])
-            if width != self.cache_len:
-                raise ValueError(
-                    f"handoff row width {width} != this engine's cache_len {self.cache_len}; "
-                    "disaggregated replicas must be built with identical engine knobs"
-                )
-            # cross-submesh transfer: the exporting replica's [1, cache_len] row
-            # is re-placed under this engine's mesh (device_put copies between
-            # disjoint device sets; a meshless engine keeps the row where it is)
-            adm.row_cache = self.gen._place_cache(row)
+        if int(payload["lengths"]) > self.cache_len:
+            raise ValueError(
+                f"handoff covers {payload['lengths']} positions but this engine's "
+                f"cache_len is {self.cache_len}; disaggregated replicas must be "
+                "built with identical engine knobs"
+            )
+        # whole KV pages in pool layout, placed onto this engine's submesh
+        # (device_put copies between disjoint device sets — and accepts the
+        # numpy arrays a cross-host wire delivers)
+        pages = tuple(
+            {name: jnp.asarray(buf) for name, buf in layer.items()} for layer in payload["pages"]
+        )
+        adm.import_pages = self.gen._place_paged_cache(pages)
         adm.tok0 = jnp.asarray([int(payload["first"])], jnp.int32)
         adm.row_len = jnp.asarray([int(payload["lengths"])], jnp.int32)
         if self.gen._cs is not None:
@@ -2727,7 +2635,7 @@ class ContinuousBatcher:
     def _export_admission(self, adm: _Admission) -> None:
         """Complete an EXPORT admission (the prefill-role path): emit the
         prompt-sampled first token, free the slot/blocks — the row never
-        pastes into this engine's pool — and package the prefilled dense row
+        pastes into this engine's pool — and package the prompt's pages
         as the session's handoff payload for a decode replica's
         :meth:`import_handoff`. A request whose first token already ends the
         stream (eos, or a budget of 1) finishes right here with no handoff —
@@ -2742,18 +2650,15 @@ class ContinuousBatcher:
         if not done_now:  # the handoff payload's length
             with self.engine_log.phase("fetch"):
                 row_len_host = int(np.asarray(adm.row_len)[0])
-        row_cache = adm.row_cache
-        adm.row_cache = adm.last = None
         pages = None
-        if self.block_size is not None and not done_now:
-            # BLOCK-NATIVE payload (the PR 9 follow-on): ship only the
-            # ceil(lengths / block_size) pages the prompt actually occupies,
-            # keyed by their position in the block run — a long-context
-            # engine's handoff no longer pays cache_len-wide rows per
-            # transfer, in-process or across hosts
+        if not done_now:
+            # ship only the ceil(lengths / block_size) pages the prompt
+            # actually occupies, keyed by their position in the block run —
+            # the payload scales with the prompt, not with cache_len,
+            # in-process or across hosts
             n_blocks = -(-row_len_host // self.block_size)
-            pages = self._export_pages_fn(row_cache, n_blocks, self.block_size)
-            row_cache = None  # the dense row never leaves a paged engine
+            pages = self._export_pages_fn(adm.row_cache, n_blocks, self.block_size)
+        adm.row_cache = adm.last = None  # the row never leaves the engine
         with self._lock:
             if adm in self._admissions:
                 self._admissions.remove(adm)
@@ -2769,8 +2674,7 @@ class ContinuousBatcher:
                 self._first_token_locked(session, now)
             _tev(session, "engine.emit", tokens=1, produced=session.produced + 1)
             session.last_emit = now
-            if self.block_size is not None:
-                session.echo.append(int(first[0]))
+            session.echo.append(int(first[0]))
             session.produced += 1
             if self.timeseries is not None:
                 self.timeseries.admissions.add()
@@ -2790,13 +2694,8 @@ class ContinuousBatcher:
                 session.handoff = {
                     "prompt": list(adm.prompt),
                     "first": int(first[0]),
-                    # paged engines ship block-aligned pages keyed by block
-                    # position; dense engines keep the historical full row
-                    **(
-                        {"pages": pages, "block_size": self.block_size}
-                        if pages is not None
-                        else {"row": row_cache}
-                    ),
+                    "pages": pages,  # block-aligned, keyed by block position
+                    "block_size": self.block_size,
                     "lengths": row_len_host,
                     "max_new": session.max_new,
                     "produced": session.produced,
@@ -2849,47 +2748,34 @@ class ContinuousBatcher:
             if self._spec is None:
                 cache, tok, lengths, done, key, *cst = self._carry
                 if adm.import_pages is not None:
-                    # block-native import: whole pages scatter straight into
-                    # the allocated blocks — no dense re-scatter ever runs
+                    # handoff import: whole pages scatter straight into the
+                    # allocated blocks — no per-position re-scatter ever runs
                     cache, tok, lengths, done = self._paged_page_admit_fn(
                         cache, adm.import_pages, tok, lengths, done, jnp.int32(slot),
                         adm.tok0, adm.row_len, jnp.asarray(blocks_row),
                         jnp.int32(session.shared_blocks),
                     )
-                elif blocks_row is not None:
+                else:
                     cache, tok, lengths, done = self._paged_admit_fn(
                         cache, adm.row_cache, tok, lengths, done, jnp.int32(slot), adm.tok0,
                         adm.row_len, jnp.asarray(blocks_row), jnp.int32(session.shared_blocks),
                     )
-                else:
-                    cache, tok, lengths, done = self._admit_fn(
-                        cache, adm.row_cache, tok, lengths, done, jnp.int32(slot),
-                        adm.tok0, adm.row_len,
-                    )
                 self._carry = (cache, tok, lengths, done, key, *cst)
             else:
                 t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst = self._carry
-                if blocks_row is not None:
-                    t_cache, d_cache, out_buf, tok, lengths, done, produced = self._paged_spec_admit_fn(
-                        t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache, tok, lengths,
-                        done, produced, jnp.int32(slot), adm.tok0, adm.row_len,
-                        jnp.asarray([start_done]), jnp.int32(cfg.pad_id),
-                        jnp.asarray(blocks_row), jnp.int32(session.shared_blocks),
-                    )
-                else:
-                    t_cache, d_cache, out_buf, tok, lengths, done, produced = self._spec_admit_fn(
-                        t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache, tok, lengths,
-                        done, produced, jnp.int32(slot), adm.tok0, adm.row_len,
-                        jnp.asarray([start_done]), jnp.int32(cfg.pad_id),
-                    )
+                t_cache, d_cache, out_buf, tok, lengths, done, produced = self._paged_spec_admit_fn(
+                    t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache, tok, lengths,
+                    done, produced, jnp.int32(slot), adm.tok0, adm.row_len,
+                    jnp.asarray([start_done]), jnp.int32(cfg.pad_id),
+                    jnp.asarray(blocks_row), jnp.int32(session.shared_blocks),
+                )
                 self._carry = (t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst)
             # the paste wrote this slot's done flag, length and table row: what
             # the account still held for the slot (a release not yet synced)
             # is overwritten, not owed
             self._released_host[slot] = False
-            if blocks_row is not None:
-                self._table_host[slot] = blocks_row
-                self._edited_host[slot] = False
+            self._table_host[slot] = blocks_row
+            self._edited_host[slot] = False
             if adm.dfa_state is not None:
                 # advance past the (constrained) prompt-sampled token and
                 # activate the slot's DFA state — the carry TAIL in both the
@@ -2915,7 +2801,7 @@ class ContinuousBatcher:
         with self._lock:
             if adm in self._admissions:
                 self._admissions.remove(adm)
-            if self._radix is not None and adm.blocks_row is not None:
+            if self._radix is not None:
                 # the prompt's full blocks now hold exactly the K/V a cold
                 # prefill writes — publish them for every later request that
                 # shares the prefix (even a cancelled stream's prefill work is
@@ -2969,31 +2855,27 @@ class ContinuousBatcher:
                 registry = self._registry()
                 if registry is not None:
                     registry.charge_tokens(session.tenant, 1)
-                if self.block_size is not None:  # echo exists only for preemption resume
-                    session.echo.append(int(first[0]))
+                session.echo.append(int(first[0]))
                 session.resident_base = session.produced
                 session.produced += 1
             self._sessions[slot] = session
             if start_done:
-                # speculative mode already marked the row done on device
-                # (row_done); plain mode must mask it here — the decode body
-                # only flags done on tokens IT samples, and the
-                # prompt-sampled tok0 is not one of them, so without masking
-                # the freed slot would keep decoding as a zombie row (and
-                # claim routed-expert capacity)
-                self._finish_locked(slot, device_done=self._spec is not None).out.put(_SENTINEL)
+                # the decode body only flags done on tokens IT samples, and the
+                # prompt-sampled tok0 is not one of them: _finish_locked masks
+                # the row, or the freed slot would keep decoding as a zombie
+                # row (and claim routed-expert capacity)
+                self._finish_locked(slot).out.put(_SENTINEL)
 
     def _mask_slot_done(self, slot: int) -> None:
         """Release a slot on the device (engine thread only): its done flag is
-        set and, in paged mode, its table row points at the scratch block and
+        set, its table row points at the scratch block and
         its length is 0 — the freed blocks may be reallocated immediately, and
         the done row keeps issuing a ride-along K/V write per step, so scratch
         is where it must land. Recorded here, on the device with the next
         :meth:`_sync_carry`."""
         self._released_host[slot] = True
-        if self.block_size is not None:
-            self._table_host[slot] = self._scratch_block
-            self._edited_host[slot] = True
+        self._table_host[slot] = self._scratch_block
+        self._edited_host[slot] = True
 
     def _sync_carry(self) -> None:
         """Carry the edits recorded since the last call to the device: one
@@ -3008,7 +2890,7 @@ class ContinuousBatcher:
             return
         state = list(self._carry)
         # speculative mode keeps BOTH caches' tables (carry slots 0 and 1)
-        caches = () if self.block_size is None else (0,) if self._spec is None else (0, 1)
+        caches = (0,) if self._spec is None else (0, 1)
         at = 2 if self._spec is None else 3  # lengths, then done
         tables = tuple(tuple(layer["table"] for layer in state[c]) for c in caches)
         # the pools are never passed: their buffers stay where they are. The
@@ -3030,8 +2912,7 @@ class ContinuousBatcher:
         session's radix pins (caller holds the lock). Tree-owned blocks the
         session's table referenced stay cached — unpinning merely makes them
         evictable again."""
-        if self.block_size is not None:
-            self._free_blocks.extend(self._slot_blocks.pop(slot, []))
+        self._free_blocks.extend(self._slot_blocks.pop(slot, []))
         if session is not None and session.pins:
             self._radix.release(session.pins)
             session.pins = []
@@ -3186,8 +3067,6 @@ class ContinuousBatcher:
         preempted and retried — older residents keep their pages (LIFO, so
         long-running streams converge instead of thrashing). A lone resident
         can always grow to its lifetime need (pool >= max_blocks)."""
-        if self.block_size is None:
-            return
         while True:
             deficits = {}
             for slot, session in self._sessions.items():
@@ -3227,7 +3106,7 @@ class ContinuousBatcher:
             )
             self._preempt_locked(victim)
 
-    def _finish_locked(self, slot: int, *, device_done: bool) -> _Session:
+    def _finish_locked(self, slot: int) -> _Session:
         """End a resident stream (caller holds the lock) and return its
         session: the caller owes it the sentinel, last, once the engine state
         is consistent."""
@@ -3244,12 +3123,10 @@ class ContinuousBatcher:
             # conversation cache-hits the whole prior exchange
             self._radix_publish_finished_locked(slot, session)
         self._release_blocks_locked(slot, session)
-        if not device_done or self.block_size is not None:
-            # finished without the device knowing (budget exhausted, or the
-            # prompt-sampled token was eos): mask the row out of future chunks.
-            # Paged mode masks unconditionally — the table repoint to scratch
-            # must happen even when the device already flagged done
-            self._mask_slot_done(slot)
+        # whether or not the device already flagged the row done (it does not
+        # when the budget ran out, or the prompt-sampled token was eos): the
+        # table must repoint to scratch before the blocks are reallocated
+        self._mask_slot_done(slot)
         return session
 
     def _end_streams_locked(self) -> None:
@@ -3319,17 +3196,15 @@ class ContinuousBatcher:
                                 session.tenant, session.trace, now - session.last_emit
                             )
                     session.last_emit = now
-                    if self.block_size is not None:
-                        session.echo.extend(int(t) for t in row[:take])
+                    session.echo.extend(int(t) for t in row[:take])
                     session.produced += take
                     if self.timeseries is not None:
                         self.timeseries.tokens.add(take)
                     if self._tenant_slo is not None and session.tenant is not None:
                         self._tenant_slo.tokens(session.tenant, take)
                     _tev(session, "engine.emit", tokens=take, produced=session.produced)
-                device_done = bool(done_np[slot])
-                if session.produced >= session.max_new or device_done:
-                    self._ended.append(self._finish_locked(slot, device_done=device_done))
+                if session.produced >= session.max_new or bool(done_np[slot]):
+                    self._ended.append(self._finish_locked(slot))
             self._end_streams_locked()
 
     def _spec_chunk(self) -> None:
@@ -3390,8 +3265,7 @@ class ContinuousBatcher:
                                 session.tenant, session.trace, now - session.last_emit
                             )
                     session.last_emit = now
-                    if self.block_size is not None:
-                        session.echo.extend(int(t) for t in new)
+                    session.echo.extend(int(t) for t in new)
                     session.produced = session.resident_base + int(prod_np[slot])
                     if self.timeseries is not None:
                         self.timeseries.tokens.add(int(new.size))
@@ -3399,6 +3273,6 @@ class ContinuousBatcher:
                         self._tenant_slo.tokens(session.tenant, int(new.size))
                     _tev(session, "engine.emit", tokens=int(new.size), produced=session.produced)
                 if bool(done_np[slot]):
-                    self._ended.append(self._finish_locked(slot, device_done=True))
+                    self._ended.append(self._finish_locked(slot))
             self._end_streams_locked()
 

@@ -2,7 +2,7 @@
 
 Chat traffic is overwhelmingly shared-prefix traffic — the system prompt,
 few-shot scaffolding, and multi-turn history repeat across millions of
-requests — yet the paged engine re-prefills every byte of that shared prefix
+requests — yet without it the engine re-prefills every byte of that shared prefix
 per request: ``_shared_prefix_blocks`` in ``serving/continuous.py`` covers one
 static, configured-at-startup prefix only. This module is the general
 mechanism (SGLang's RadixAttention on top of vLLM-style paged KV): a radix

@@ -38,7 +38,7 @@ Design:
   ``prefill``, ``decode``, or ``mixed`` (the default, today's behavior) via
   ``roles=``/``serve --replica-roles``. Prompts above ``prefill_threshold``
   tokens admit on a prefill replica with the engine's ``export_handoff`` and
-  their finished KV row hands off to a decode replica
+  their finished KV pages hand off to a decode replica
   (:meth:`ContinuousBatcher.import_handoff`) — token-identical to a mixed
   replica, but resident decode streams never stall behind the prefill; warm
   multi-turn prompts whose radix-cached run on a decode replica already
@@ -85,7 +85,7 @@ from unionml_tpu.defaults import (
 )
 from unionml_tpu.observability.trace import current_trace
 from unionml_tpu.parallel.mesh import BATCH_AXES
-from unionml_tpu.serving.continuous import ContinuousBatcher
+from unionml_tpu.serving.continuous import KV_BLOCK_SIZE, ContinuousBatcher
 from unionml_tpu.serving.overload import (
     DeadlineExceeded,
     QueueFullError,
@@ -191,7 +191,7 @@ class ReplicaScheduler:
     every path. ``affinity_tokens > 0`` enables prefix-affinity
     routing: requests sharing their first ``affinity_tokens`` prompt tokens are
     steered to the replica that last served that prefix — its KV pool already
-    holds those rows/pages (shared-prefix pages in paged mode), so the prefill
+    holds those pages (the shared prefix's among them), so the prefill
     is warm — unless that replica is more than ``affinity_margin`` requests
     busier than the least-loaded one. The margin keeps a popular prefix from
     turning one replica into a hotspot while the rest idle; the affinity map is
@@ -445,7 +445,7 @@ class ReplicaSet:
         slots: int = 4,
         decode_chunk: int = 8,
         prefix: Optional[Any] = None,
-        block_size: Optional[int] = None,
+        block_size: int = KV_BLOCK_SIZE,
         pool_blocks: Optional[int] = None,
         max_waiting: Optional[int] = None,
         admit_chunk: Optional[int] = None,
