@@ -4,6 +4,7 @@ refuses fails here, at no chip time (interpret mode accepts layouts the chip doe
 not). Nothing executes; a compile that passes is not a chip run. Also: the smoke
 itself must fail, and claim nothing, where there is no TPU."""
 
+import functools
 import os
 import re
 import shutil
@@ -113,6 +114,29 @@ def test_kernel_compiles_for_v5e(chip, case):
     assert "tpu_custom_call" in compiled.as_text(), "the Pallas kernel is not in the compiled program"
 
 
+def _on_chip(chip, make):
+    """The shapes ``make`` would return, as arguments that live on the described chip."""
+    return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), jax.eval_shape(make))
+
+
+def _mistral(layers=2):
+    from unionml_tpu.models import Llama, LlamaConfig
+
+    return Llama(LlamaConfig(
+        vocab_size=32768, dim=4096, n_layers=layers, n_heads=32, n_kv_heads=8, hidden_dim=14336,
+        max_seq_len=32768, rope_theta=1e6, attention_impl="flash", param_dtype=jnp.bfloat16,
+    ))
+
+
+def _afmoe():
+    from unionml_tpu.models import AfmoeConfig, AfmoeTransformer
+
+    return AfmoeTransformer(AfmoeConfig(
+        vocab_size=25024, n_layers=3, n_dense_layers=1, experts_held=(0, 32), attention_impl="flash",
+        layer_types=("sliding_attention", "sliding_attention", "full_attention"), param_dtype=jnp.bfloat16,
+    ))
+
+
 #: the serving cells' decode carries (perf/workloads/*.json): slots, pages a row, pool pages (scratch included)
 DECODE_SHAPES = {"chat_sat_32x25": (32, 25, 513), "docs_16x56": (16, 56, 769)}
 
@@ -123,20 +147,13 @@ def test_decode_steps_compiles_for_v5e_without_a_gathered_copy(chip, shape):
     the kernel read (forced: the backend here is the CPU, so ``auto`` would gather): Mosaic
     takes it, one kernel a layer, and no temporary as large as the gather path's logical copy
     (``[B, pages * 64, 32, 128]`` bf16) is left — nor a re-laid-out copy of a pool."""
-    from unionml_tpu.models import GenerationConfig, Generator, Llama, LlamaConfig
+    from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
 
     slots, pages, pool = DECODE_SHAPES[shape]
     layers, page = 2, 64
-    config = LlamaConfig(
-        vocab_size=32768, dim=4096, n_layers=layers, n_heads=32, n_kv_heads=8, hidden_dim=14336,
-        max_seq_len=32768, rope_theta=1e6, attention_impl="flash", param_dtype=jnp.bfloat16,
-    )
-    module = Llama(config)
-
-    def on_chip(make):
-        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), jax.eval_shape(make))
-
+    module = _mistral(layers)
+    config, on_chip = module.config, functools.partial(_on_chip, chip)
     params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
     tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
@@ -158,19 +175,12 @@ def test_afmoe_decode_steps_compiles_for_v5e(chip):
     32 held of 256 experts) over the cell's paged cache, through the kernel reads (forced, as above): Mosaic
     takes the windowed read, the plain read and — the trace's backend being the CPU, the routed product is
     ``ragged_dot`` here — XLA's own grouped kernel; the program counts its five counters into the carry."""
-    from unionml_tpu.models import AfmoeConfig, AfmoeTransformer, GenerationConfig, Generator
+    from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
 
     slots, pages, pool, page = 128, 84, 3073, 64
-    config = AfmoeConfig(
-        vocab_size=25024, n_layers=3, n_dense_layers=1, experts_held=(0, 32), attention_impl="flash",
-        layer_types=("sliding_attention", "sliding_attention", "full_attention"), param_dtype=jnp.bfloat16,
-    )
-    module = AfmoeTransformer(config)
-
-    def on_chip(make):
-        return jax.tree_util.tree_map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip), jax.eval_shape(make))
-
+    module = _afmoe()
+    config, on_chip = module.config, functools.partial(_on_chip, chip)
     params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
     assert cache[0]["k"].shape == (8, pool, page, 128)  # the published head width, not dim // n_heads = 64
@@ -184,6 +194,114 @@ def test_afmoe_decode_steps_compiles_for_v5e(chip):
     assert not re.search(rf"= bf16\[8,{pool},{page},128\]\S* copy\(", text)  # no pool re-laid for a kernel
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
+
+
+#: the saturated cells' engines (perf/configs/*.json "engine" + perf/workloads/*.json): module, longest prompt, answer
+#: budget, engine options
+ADMISSION_SHAPES = {
+    "chat_sat": (_mistral, 1024, 512, {"slots": 32, "pool_blocks": 512}),
+    "chat_wide_sat": (_afmoe, 4608, 768, {"slots": 160, "pool_blocks": 3072}),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(ADMISSION_SHAPES))
+def test_admission_programs_compile_for_v5e_without_a_copied_row(chip, cell):
+    """The engine's own admission programs at a saturated cell's shapes (fewer layers): the set-up (length, key,
+    flags, a zeroed row cache), a radix hit's set-up (the row gathered from the pool) and the Generator's chunk
+    program (the last-hidden merge inside) compile for the described chip; none leaves a row-shaped ``copy`` (the
+    rows are written once, where they stay), and the hit's copies a pool no more often than the bare gather does."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import gather_paged_rows, init_paged_cache
+    from unionml_tpu.serving import ContinuousBatcher
+
+    make, max_prompt, max_new, options = ADMISSION_SHAPES[cell]
+    module, chunk, page = make(), 256, 64
+
+    on_chip = functools.partial(_on_chip, chip)
+
+    def scalar(dtype, shape=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cfg = GenerationConfig(max_new_tokens=max_new, temperature=0.0, prompt_buckets=tuple(range(chunk, max_prompt + 1, chunk)))
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(gen, decode_chunk=8, block_size=page, admit_chunk=chunk, prefix_cache=True, **options)
+    try:
+        heads, width = module.config.n_kv_heads, 128
+        row_copy = re.compile(rf"= bf16\[1,{batcher.cache_len},{heads},{width}\]\S* copy\(")
+        pool_copy = re.compile(rf"= bf16\[{heads},{batcher.pool_blocks + 1},{page},{width}\]\S* copy\(")
+
+        setup = batcher._setup_fn.lower(scalar(jnp.uint32), scalar(jnp.int32), ()).compile()
+        assert not row_copy.search(setup.as_text())
+        lengths, key, row_valid, (last,), (row,) = on_chip(lambda: batcher._setup_fn(jnp.uint32(0), jnp.int32(0), ()))
+        assert row[0]["k"].shape == (1, batcher.cache_len, heads, width)
+
+        pool = on_chip(lambda: init_paged_cache(
+            module.config, batcher.slots, batcher.pool_blocks + 1, page, batcher.max_blocks, fill_block=batcher.pool_blocks
+        ))
+        table_row = scalar(jnp.int32, (batcher.max_blocks,))
+        hit = batcher._cached_setup_fn.lower(pool, table_row, scalar(jnp.uint32), scalar(jnp.int32)).compile()
+        gather = jax.jit(gather_paged_rows, static_argnums=(2,)).lower(pool, table_row, batcher.cache_len).compile()
+        # (the gather itself re-lays each pool it reads, PERF.md section 7; the set-up around it adds none)
+        assert len(pool_copy.findall(hit.as_text())) <= len(pool_copy.findall(gather.as_text()))
+        assert not row_copy.search(hit.as_text())
+
+        chunk_args = (params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), lengths, row, row_valid, last)
+        step = gen._prefill_chunk.lower(*chunk_args).compile()
+        # the merge's select is a [1, dim] row: the cache row is still written in place (donated), never copied
+        assert not row_copy.search(step.as_text())
+    finally:
+        batcher.close()
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative"])
+def test_warmup_leaves_no_admission_program_uncompiled(mode):
+    """After ``warmup()`` a cold admission of every bucket, a multi-chunk one and (plain mode: the cache does not
+    compose with speculation) a radix-hit one compile nothing: the set-up, the hit's set-up, the chunk program,
+    ``first_token``, the paste and the decode program were all built by the warm-up's own requests."""
+    from unionml_tpu.models import DraftSpec, GenerationConfig, Generator, Llama, LlamaConfig
+    from unionml_tpu.serving import ContinuousBatcher
+
+    def tiny(dim, layers, seed):
+        module = Llama(LlamaConfig.tiny(
+            vocab_size=97, dim=dim, n_layers=layers, n_heads=4, n_kv_heads=2, hidden_dim=2 * dim,
+            dtype=jnp.float32, param_dtype=jnp.float32,
+        ))
+        return module, module.init(jax.random.PRNGKey(seed), jnp.zeros((1, 8), jnp.int32))["params"]
+
+    module, params = tiny(64, 2, 0)
+    draft = None
+    if mode == "speculative":
+        d_module, d_params = tiny(32, 1, 9)
+        draft = DraftSpec(module=d_module, params=d_params, gamma=3)
+    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(16,), draft=draft)
+    batcher = ContinuousBatcher(
+        Generator(module, params, cfg), slots=2, decode_chunk=2, block_size=8, admit_chunk=8, prefix_cache=draft is None
+    )
+    compiled = None
+
+    def on_duration(event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(seconds)
+
+    try:
+        batcher.warmup()  # one bucket: no probe shares a prefix with another, so the warm-up makes its own hit
+        compiled = []
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        long = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7]
+        for prompt in (long, long[:11] + [2, 2], [5, 5, 5]):
+            assert len([t for chunk in batcher.submit(prompt) for t in chunk]) == 6
+        stats = batcher.stats()
+        assert stats["prefill"]["chunks"] >= 4
+        if draft is None:
+            assert stats["prefix_cache"]["hits"] >= 1 and stats["prefix_cache"]["misses"] >= 1
+        assert compiled == []
+    finally:
+        batcher.close()
+        if compiled is not None:  # registered: the listener list is the process's
+            from jax._src import monitoring
+
+            monitoring.unregister_event_duration_listener(on_duration)
 
 
 @pytest.mark.parametrize("where", ["checkout", "alone"])

@@ -455,7 +455,7 @@ def test_sp_prefill_resume_width_falls_back_to_dense():
         # but chunk_aligned(13, 4) = 16 > 14 — the sp branch must not raise
         prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9]
         assert batcher.cache_len == 14
-        tok0, lengths, _, _ = batcher._prefill_row(prompt, 0, budget=1)
+        tok0, *_ = batcher._prefill_row(prompt, 0, budget=1)
         expected = Generator(module, params, base)([prompt])
         assert int(np.asarray(tok0).ravel()[0]) == int(expected[0][0])
     finally:

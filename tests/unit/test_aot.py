@@ -126,6 +126,46 @@ def test_generator_warmup_preloads(tmp_path, tiny):
     assert (gen2.prefill_traces, gen2.decode_traces) == (0, 0)  # the call itself hit too
 
 
+@pytest.mark.parametrize("knob", ["decode_chunk", "admit_chunk"])
+def test_engines_of_different_row_length_share_a_store(tmp_path, tiny, knob):
+    """The admission set-ups close over the row's length, which constructor knobs raise (``decode_chunk``, a chunk
+    width that pads the widest bucket) and none of their arguments' shapes shows (a seed, a length, the pool and a
+    table row): it is part of their key, so a second engine on the first's store, or a restart after the knob
+    changed, builds rows of its own length instead of loading the other's, cold and on a radix hit, and serves the
+    tokens a plain-jit engine of the same knobs serves."""
+    module, params = tiny
+    long = [3, 14, 15, 9, 2, 6, 5, 3, 5, 8, 9]
+
+    def serve(aot, **knobs):
+        options = {"slots": 2, "decode_chunk": 4, "block_size": 8, "admit_chunk": 8, "prefix_cache": True, **knobs}
+        batcher = ContinuousBatcher(Generator(module, params, _cfg()), aot=aot, **options)
+        try:
+            batcher.warmup()
+            outs = [_drain(batcher.submit(p)) for p in (long, long[:9] + [4, 4])]  # a cold admission, a radix hit
+            stats = batcher.stats()
+            assert stats["prefix_cache"]["hits"] >= 1
+        finally:
+            batcher.close()
+        seed, total = np.uint32(0), np.int32(5)
+        *_, (cold_row,) = batcher._setup_fn(seed, total, ())
+        *_, (hit_row,) = batcher._cached_setup_fn(batcher._carry[0], np.zeros(batcher.max_blocks, np.int32), seed, total)
+        widths = {row[0]["k"].shape[1] for row in (cold_row, hit_row)}
+        return batcher.cache_len, widths, outs, stats
+
+    other = {"decode_chunk": 12} if knob == "decode_chunk" else {"admit_chunk": 12}
+    first_len, first_widths, first_outs, _ = serve(str(tmp_path))
+    second_len, second_widths, second_outs, stats = serve(str(tmp_path), **other)
+    _, _, expected, _ = serve(False, **other)
+    assert second_len > first_len  # the knob moved the row's length
+    assert first_widths == {first_len} and second_widths == {second_len}
+    assert second_outs == expected and len(second_outs[0]) == 6
+    assert stats["aot"]["programs_compiled"] >= 2  # its own two set-ups at least: nothing of theirs was there to load
+    # and the same engine again finds every program it needs
+    again_len, again_widths, again_outs, again = serve(str(tmp_path), **other)
+    assert (again_len, again_widths, again_outs) == (second_len, second_widths, second_outs)
+    assert again["aot"]["programs_compiled"] == 0 and again["aot"]["programs_loaded"] > 0
+
+
 # ------------------------------------------------------------------ staleness / corruption
 
 

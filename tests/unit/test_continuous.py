@@ -1332,3 +1332,275 @@ def test_carry_edits_cost_at_most_two_dispatches_an_iteration(tiny_gen, monkeypa
         assert len(traces) == 1 and batcher._sync_fn._cache_size() == programs  # nothing new after warm-up
     finally:
         batcher.close()
+
+
+# --- one program a device step of an admission (set-up, chunk step, first token, paste) ---------------------------
+
+
+def _eager_admission(batcher):
+    """Put back, on one engine, the derivation the admission programs replaced: an eager ``jnp.zeros`` a cache
+    plane (``init_cache``) and the prefix paste on its own, ``fold_in(PRNGKey(seed), seed)`` and the scalars as
+    un-jitted ``jax.numpy`` calls, the chunk program followed by an un-jitted ``where``, the
+    radix hit's gather as a program of its own."""
+    from unionml_tpu.models.generate import _paste_prefix_rows, gather_paged_rows, init_cache
+
+    gen, cfg = batcher.gen, batcher.gen.config
+    models = (gen,) if batcher._spec is None else (gen, batcher._spec._draft)
+    prefixes = (batcher.prefix, batcher._draft_prefix)
+
+    def scalars(seed, total):
+        key = jax.random.fold_in(jax.random.PRNGKey(int(seed)), int(seed))
+        lasts = tuple(jnp.zeros((1, g.module.config.dim), jnp.float32) for g in models)
+        return jnp.asarray([int(total)], jnp.int32), key, jnp.ones((1,), bool), lasts
+
+    def setup(seed, total):
+        rows = []
+        for g, pre in zip(models, prefixes):
+            row = g._place_cache(init_cache(g.module.config, 1, batcher.cache_len, kv_dtype=cfg.kv_cache_dtype))
+            rows.append(row if pre is None else _paste_prefix_rows(row, pre.layers))
+        return (*scalars(seed, total), tuple(rows))
+
+    gather = jax.jit(gather_paged_rows, static_argnums=(2,))
+
+    def cached_setup(pool, gather_row, seed, total):
+        return (*scalars(seed, total), (gather(pool, jnp.asarray(gather_row), batcher.cache_len),))
+
+    def chunk_step(program):
+        def step(p, tokens, start, lengths, cache, row_valid, last):
+            # the chunk's own last-hidden row (merged into zeros), then the merge as the engine used to make it
+            chunk_last, cache, counts = program(
+                p, jnp.asarray(tokens), jnp.int32(start), lengths, cache, row_valid, jnp.zeros_like(last)
+            )
+            at = np.asarray(lengths) - 1
+            has = jnp.asarray((at >= start) & (at < start + tokens.shape[1]))
+            return jnp.where(has[:, None], chunk_last, last), cache, counts
+
+        return step
+
+    batcher._admission_setup = setup
+    batcher._cached_setup_fn = cached_setup
+    for g in models:
+        g._prefill_chunk = chunk_step(g._prefill_chunk)
+
+
+_LONG = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7]
+#: name -> what the case changes: the generation config, the engine's options, the shared prefix, the prompts (served
+#: one after the other unless ``together``), the engine's admission counter set before a prompt (``seed_before``)
+_ADMISSION_CASES = {
+    "plain": {},
+    "int8_kv": {"cfg": {"kv_cache_dtype": "int8"}},
+    "shared_prefix": {"prefix": [7, 7, 3, 9, 11]},
+    "speculative": {"cfg": {"temperature": 0.0}, "speculative": True, "prefix": [7, 7, 3, 9]},
+    "radix_hit": {"engine": {"prefix_cache": True}, "prompts": [_LONG, _LONG[:11] + [2, 2], _LONG[:9] + [4]]},
+    "preemption_resume": {
+        "cfg": {"temperature": 0.0, "max_new_tokens": 16, "prompt_buckets": (16,)},
+        "engine": {"slots": 2, "decode_chunk": 8, "admit_chunk": 8, "pool_blocks": 8},
+        "prompts": [_LONG, [2, 7, 1, 8, 2, 8, 1, 8, 2, 8, 4, 5, 9, 4]], "together": True,
+    },
+    "multi_chunk": {"engine": {"admit_chunk": 4}, "prompts": [[9] * 12, _LONG, [5, 5, 5]]},
+    "monolithic": {"engine": {"admit_chunk": 0}},
+    "monolithic_prefix": {"engine": {"admit_chunk": 0}, "prefix": [7, 7, 3, 9, 11], "cfg": {"prefill_chunk": 4}},
+    # the carry's own key is drawn at the first paste, from a counter that has to fit an int32: the jump comes after
+    "largest_seed": {"prompts": [PROMPTS[1], PROMPTS[0]], "seed_before": {1: 2**32 - 2}},
+}
+
+
+def _serve_admission_case(tiny_gen, case, eager):
+    """One engine of the case, its admission counter set, its prompts served: ``(tokens, logprobs)`` per prompt,
+    the engine's ``stats()`` and iteration records."""
+    import dataclasses
+
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=10, temperature=0.7, prompt_buckets=(8, 16))
+    cfg = dataclasses.replace(cfg, **case.get("cfg", {}))
+    gen = Generator(module, params, _speculative(cfg) if case.get("speculative") else cfg)
+    options = {"slots": 2, "decode_chunk": 3, "block_size": 8, "admit_chunk": 8, **case.get("engine", {})}
+    if "prefix" in case:
+        options["prefix"] = gen.cache_prefix(case["prefix"])
+    batcher = ContinuousBatcher(gen, **options)
+    want_lp = not case.get("speculative")  # logprobs do not compose with speculative decoding
+    try:
+        if eager:
+            _eager_admission(batcher)
+        batcher._seed = 1000
+        prompts = case.get("prompts", PROMPTS[:3])
+        if case.get("together"):
+            with batcher._lock:  # re-entrant: the engine meets them in one pass, so both engines schedule alike
+                streams = [batcher.submit(p, logprobs=want_lp) for p in prompts]
+            served = [(_drain(s), s.logprobs if want_lp else []) for s in streams]
+        else:
+            served = []
+            for i, p in enumerate(prompts):
+                batcher._seed = case.get("seed_before", {}).get(i, batcher._seed)  # the engine is idle: nothing races
+                stream = batcher.submit(p, logprobs=want_lp)
+                served.append((_drain(stream), stream.logprobs if want_lp else []))
+        batcher.close()
+        return served, batcher.stats(), batcher.engine_log.iteration_records()
+    finally:
+        batcher.close()
+
+
+@pytest.mark.parametrize("name", sorted(_ADMISSION_CASES))
+def test_admission_programs_equal_the_eager_derivation(tiny_gen, name):
+    """Same admission counter, same prompts: the streams of the engine whose row cache, key and scalars come from
+    one jitted set-up, whose chunk's arguments ride the chunk step's own dispatch and whose last-hidden merge is
+    inside it are token for token and log-probability for log-probability those of the eager derivation
+    (``init_cache`` + ``fold_in(PRNGKey(seed), seed)`` + an un-jitted ``where``), sampled at a temperature so
+    that the key matters — up to the largest seed the key's derivation takes (2**32 - 1)."""
+    case = _ADMISSION_CASES[name]
+    served, stats, _ = _serve_admission_case(tiny_gen, case, eager=False)
+    expected, eager_stats, _ = _serve_admission_case(tiny_gen, case, eager=True)
+    assert [tokens for tokens, _ in served] == [tokens for tokens, _ in expected]
+    assert [lp for _, lp in served] == [lp for _, lp in expected]
+    assert all(len(tokens) > 1 for tokens, _ in served)
+    # the case met what it is named for
+    if name == "radix_hit":
+        assert stats["prefix_cache"]["hits"] == eager_stats["prefix_cache"]["hits"] == 2
+    if name == "preemption_resume":
+        assert stats["kv_blocks"]["preemptions"] > 0 and eager_stats["kv_blocks"]["preemptions"] > 0
+    if name == "multi_chunk":
+        assert stats["prefill"]["chunks"] == 4 + 4 + 2
+    if name.startswith("monolithic"):
+        assert stats["prefill"]["monolithic_admissions"] == 3 and stats["prefill"]["chunks"] == 0
+
+
+def _tally_admission_events(batcher):
+    """Count, per iteration index and beside the engine's own counter, what the bound on ``admit_dispatches`` is
+    made of: set-ups begun (``_admission_begin`` calls), chunks run (the engine's ``prefill_chunks`` counter read at
+    every iteration's end) and the admissions that asked for log-probabilities."""
+    from collections import Counter
+
+    log = batcher.engine_log
+    begun, chunks, priced = Counter(), Counter(), Counter()
+    real_begin, real_end, real_lp = batcher._admission_begin, log.end, batcher._first_logprob
+    seen = [0]
+
+    def begin(adm):
+        begun[log.index] += 1
+        return real_begin(adm)
+
+    def first_logprob(adm):
+        priced[log.index] += 1
+        return real_lp(adm)
+
+    def end():
+        chunks[log.index] += batcher.prefill_chunks - seen[0]
+        seen[0] = batcher.prefill_chunks
+        real_end()
+
+    batcher._admission_begin, batcher._first_logprob, log.end = begin, first_logprob, end
+    return begun, chunks, priced
+
+
+@pytest.mark.parametrize("mode", ["plain", "speculative"])
+def test_admit_dispatches_stay_within_one_program_a_device_step(tiny_gen, mode):
+    """A run that admits cold, in several chunks, hits the radix cache (plain mode: the cache does not compose with
+    speculation) and finishes: in every iteration the admit phase hands the runtime at most one program per set-up
+    begun, per chunk run (two under speculation: target and draft), per first token sampled (one more where the
+    request asked for log-probabilities) and per paste — 3 + chunks over a cold admission's life, a radix hit's
+    gather being its set-up — and over the whole run exactly that many."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(8, 16))
+    spec = mode == "speculative"
+    gen = Generator(module, params, _speculative(cfg) if spec else cfg)
+    batcher = ContinuousBatcher(gen, slots=2, decode_chunk=2, block_size=8, admit_chunk=4, prefill_budget=8, prefix_cache=not spec)
+    try:
+        batcher.warmup()
+        assert batcher.stats()["loop"]["admit_dispatches"] == 0  # warm-up's passes are not traffic
+        begun, chunks, priced = _tally_admission_events(batcher)
+        prompts = [_LONG, _LONG[:11] + [2, 2], [5, 5, 5], _LONG[:9] + [4], PROMPTS[0], _LONG]
+        with batcher._lock:  # re-entrant: four wait while two are admitted, so iterations carry several events
+            streams = [batcher.submit(p, logprobs=(i % 2 == 0 and not spec)) for i, p in enumerate(prompts)]
+        outs = [_drain(s) for s in streams]
+        assert all(len(o) == 6 for o in outs)
+        batcher.close()
+        records = batcher.engine_log.iteration_records()
+        per_chunk = 2 if spec else 1
+        for r in records:
+            bound = begun[r.index] + per_chunk * chunks[r.index] + 2 * r.admitted + priced[r.index]
+            assert r.admit_dispatches <= bound, (r, bound)
+            assert (r.admit_dispatches == 0) == (begun[r.index] + chunks[r.index] + r.admitted == 0)
+        total = sum(r.admit_dispatches for r in records)
+        stats = batcher.stats()
+        assert total == stats["loop"]["admit_dispatches"]
+        assert sum(begun.values()) == sum(r.admitted for r in records) == len(prompts)
+        # 3 + chunks a request (set-up, chunks, first token, paste), one more for a priced first token
+        assert total == 3 * len(prompts) + per_chunk * stats["prefill"]["chunks"] + sum(priced.values())
+        assert sum(priced.values()) == (0 if spec else 3)
+        if not spec:
+            assert stats["prefix_cache"]["hits"] >= 2  # a hit's gather is its set-up: the bound is the cold one
+        assert max(r.table_syncs for r in records) <= 2
+    finally:
+        batcher.close()
+
+
+@pytest.mark.parametrize("mode", ["plain", "int8_kv", "shared_prefix", "speculative", "constrained"])
+def test_no_unjitted_jax_op_in_the_admit_phase(tiny_gen, monkeypatch, mode):
+    """Once the engine is warm, nothing un-jitted runs on the engine thread between the top of ``_admit_pending``
+    and its return: no eager primitive is applied (every ``jax.numpy`` call outside a jitted program binds at least
+    one) and ``continuous.py`` makes no ``jax.numpy`` call at all — cold, multi-chunk and radix-hit admissions,
+    first tokens with and without log-probabilities, pastes, and the slot's DFA state of a constrained generator."""
+    import dataclasses
+
+    from jax._src import core
+
+    from unionml_tpu.serving import continuous
+
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(8, 16))
+    options = {"slots": 2, "decode_chunk": 2, "block_size": 8, "admit_chunk": 4, "prefill_budget": 8}
+    if mode == "int8_kv":
+        cfg = dataclasses.replace(cfg, kv_cache_dtype="int8")
+    if mode == "constrained":
+        from unionml_tpu.models import ConstraintSet, compile_regex
+
+        vocab = [chr(32 + i) for i in range(96)] + ["<eos>"]
+        cfg = dataclasses.replace(cfg, eos_id=96, constraints=ConstraintSet([compile_regex("[a-z]+", vocab, eos_id=96)]))
+    gen = Generator(module, params, _speculative(cfg) if mode == "speculative" else cfg)
+    if mode == "shared_prefix":
+        options["prefix"] = gen.cache_prefix([7, 7, 3, 9, 11])
+    batcher = ContinuousBatcher(gen, prefix_cache=mode != "speculative", **options)
+    inside, eager, numpy_calls = [False], [], []
+    try:
+        batcher.warmup()
+        want_lp = mode != "speculative"
+        warm = [_LONG, _LONG[:11] + [2, 2], [5, 5, 5]]
+        grammar = {"constraint": 1} if mode == "constrained" else {}
+        for p in warm:  # the first-token log-probability program and (constrained) the slot write compile here
+            _drain(batcher.submit(p, logprobs=want_lp, **grammar))
+        engine = batcher._thread.ident
+        real_admit, real_primitive = batcher._admit_pending, core.EvalTrace.process_primitive
+
+        def admit_pending():
+            inside[0] = True
+            try:
+                return real_admit()
+            finally:
+                inside[0] = False
+
+        def process_primitive(self, primitive, args, params):
+            if inside[0] and threading.get_ident() == engine:
+                eager.append(primitive.name)
+            return real_primitive(self, primitive, args, params)
+
+        class CountedNumpy:
+            def __getattr__(self, name):
+                if inside[0] and threading.get_ident() == engine:
+                    numpy_calls.append(name)
+                return getattr(jnp, name)
+
+        batcher._admit_pending = admit_pending
+        monkeypatch.setattr(core.EvalTrace, "process_primitive", process_primitive)
+        monkeypatch.setattr(continuous, "jnp", CountedNumpy())
+        prompts = [[4] + _LONG[1:], _LONG[:10] + [6, 6, 6], [5, 5, 5, 8], _LONG[:9] + [4], PROMPTS[0]]
+        with batcher._lock:
+            streams = [batcher.submit(p, logprobs=want_lp and i % 2 == 0, **grammar) for i, p in enumerate(prompts)]
+        assert all(len(_drain(s)) >= 1 for s in streams)
+        batcher.close()
+        assert eager == [] and numpy_calls == []
+        stats = batcher.stats()
+        assert stats["loop"]["admit_dispatches"] > 0 and stats["prefill"]["chunks"] >= 10
+        if mode != "speculative":
+            assert stats["prefix_cache"]["hits"] >= 3
+    finally:
+        batcher.close()

@@ -140,6 +140,7 @@ def test_phases_partition_the_iteration_and_idle_lies_outside(monkeypatch):
     with log.phase("emit"):
         clock.now = 119.0
     log.rows, log.prefill_tokens, log.admitted, log.finished, log.blocks_grown, log.table_syncs = 3, 16, 1, 2, 4, 2
+    log.admit_dispatches = 7
     log.end()
     (record,) = log.iteration_records()
     assert record.index == 0 and record.start == 111.0
@@ -148,11 +149,19 @@ def test_phases_partition_the_iteration_and_idle_lies_outside(monkeypatch):
     }
     assert sum(record.phase_s) == 119.0 - 111.0  # the pass's wall time, idle not in it
     assert (record.rows, record.prefill_tokens, record.admitted, record.finished, record.blocks_grown) == (3, 16, 1, 2, 4)
-    assert record.table_syncs == 2 and record._fields[-1] == "table_syncs"  # appended: readers by position keep theirs
+    assert record.table_syncs == 2 and record.admit_dispatches == 7
+    assert record._fields[-2:] == ("table_syncs", "admit_dispatches")  # appended: readers by position keep theirs
     totals = log.totals()
     assert totals["iterations"] == 1 and totals["idle_s"] == 11.0
-    assert totals["phase_s"]["fetch"] == 4.0 and totals["table_syncs"] == 2
+    assert totals["phase_s"]["fetch"] == 4.0 and totals["table_syncs"] == 2 and totals["admit_dispatches"] == 7
     assert log.rows == log.prefill_tokens == log.admitted == log.finished == log.blocks_grown == log.table_syncs == 0
+    assert log.admit_dispatches == 0
+    log.begin()
+    log.admit_dispatches = 3
+    log.end()
+    assert log.totals()["admit_dispatches"] == 10  # summed over the iterations, like table_syncs
+    log.clear()
+    assert log.totals()["admit_dispatches"] == log.totals()["table_syncs"] == 0
 
 
 def test_engine_iterations_sum_to_their_wall_time_within_one_percent(tiny_gen, monkeypatch):
@@ -425,26 +434,26 @@ def test_debug_engine_endpoint_answers(tiny_gen, sklearn_model):
         batcher.close()
 
 
-def _read_iteration_records(batcher, get):
+def _read_iteration_records(batcher, get, field="table_syncs"):
     records = batcher.engine_log.iteration_records()
-    return [r.table_syncs for r in records], sum(r.table_syncs for r in records)
+    return [getattr(r, field) for r in records], sum(getattr(r, field) for r in records)
 
 
-def _read_stats_loop(batcher, get):
-    return None, batcher.stats()["loop"]["table_syncs"]
+def _read_stats_loop(batcher, get, field="table_syncs"):
+    return None, batcher.stats()["loop"][field]
 
 
-def _read_prometheus(batcher, get):
+def _read_prometheus(batcher, get, field="table_syncs"):
     text = render_prometheus({"generation": batcher.stats()})
     samples = dict(line.rsplit(" ", 1) for line in text.splitlines() if not line.startswith("#"))
-    return None, int(samples["unionml_tpu_generation_loop_table_syncs"])
+    return None, int(samples[f"unionml_tpu_generation_loop_{field}"])
 
 
-def _read_debug_engine(batcher, get):
+def _read_debug_engine(batcher, get, field="table_syncs"):
     status, payload, _, _ = get("/debug/engine")
     assert status == 200
     mine = payload["engines"][-1]
-    return [r["table_syncs"] for r in reversed(mine["iterations_log"])], mine["table_syncs"]
+    return [r[field] for r in reversed(mine["iterations_log"])], mine[field]
 
 
 @pytest.mark.parametrize(
@@ -483,6 +492,45 @@ def test_table_syncs_is_served_wherever_the_loop_is_read(tiny_gen, sklearn_model
             assert (r.table_syncs == 0) == (r.blocks_grown == 0 and r.finished == 0)
         batcher.engine_log.clear()
         assert read(batcher, get)[1] == 0
+    finally:
+        batcher.close()
+
+
+@pytest.mark.parametrize(
+    "read", [_read_iteration_records, _read_stats_loop, _read_prometheus, _read_debug_engine],
+    ids=["iteration_records", "stats_loop", "prometheus", "debug_engine"],
+)
+def test_admit_dispatches_is_served_wherever_the_loop_is_read(tiny_gen, sklearn_model, read):
+    """How many programs and transfers the admit phase handed the runtime: per
+    iteration in the record (0 in an iteration that admitted nothing; an
+    admission is its set-up, its chunks, its first token and its paste), cumulative in
+    ``stats()["loop"]``, ``/metrics`` and ``GET /debug/engine``; zeroed with
+    the other totals."""
+    from unionml_tpu.serving.app import ServingApp
+
+    sklearn_model.train(hyperparameters={"max_iter": 500})
+    app = ServingApp(sklearn_model)
+
+    def get(path):
+        async def run():
+            app.startup()
+            return await app.server.dispatch_with_headers("GET", path, b"", None)
+
+        return asyncio.run(run())
+
+    batcher = _engine(tiny_gen, max_new=16, **PAGED)
+    try:
+        _run(batcher, [[3, 14, 15, 92, 6, 5, 3, 5, 9], [27, 1], [8, 2, 8]])
+        _settled(batcher.engine_log, requests=3, finished=3)
+        records = batcher.engine_log.iteration_records()
+        per_iteration, total = read(batcher, get, "admit_dispatches")
+        assert total == sum(r.admit_dispatches for r in records) == 3 * 3 + batcher.stats()["prefill"]["chunks"]
+        if per_iteration is not None:
+            assert per_iteration == [r.admit_dispatches for r in records]
+        for r in records:
+            assert (r.admit_dispatches == 0) == (r.admitted == 0 and r.prefill_tokens == 0)
+        batcher.engine_log.clear()
+        assert read(batcher, get, "admit_dispatches")[1] == 0
     finally:
         batcher.close()
 

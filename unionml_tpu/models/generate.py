@@ -213,10 +213,11 @@ def init_paged_cache(
     )
 
 
-def _paste_prefix_rows(cache: Any, prefix_layers: Any) -> Any:
+def paste_prefix_rows(cache: Any, prefix_layers: Any) -> Any:
     """Broadcast a :class:`PrefixCache`'s ``[1, p0, ...]`` K/V rows into slots
-    ``[0, p0)`` of every row of a freshly allocated cache. Jitted (donating the
-    cache) so the paste is one fused dispatch, not 2 * n_layers eager ops."""
+    ``[0, p0)`` of every row of a freshly allocated cache. ``_paste_prefix_rows``
+    is this jitted (donating the cache), so the paste is one fused dispatch, not
+    2 * n_layers eager ops; a program that builds its own rows calls this."""
 
     def paste(buf: jax.Array, pre: jax.Array) -> jax.Array:
         pre = jnp.broadcast_to(pre.astype(buf.dtype), (buf.shape[0],) + pre.shape[1:])
@@ -225,7 +226,7 @@ def _paste_prefix_rows(cache: Any, prefix_layers: Any) -> Any:
     return jax.tree_util.tree_map(paste, cache, prefix_layers)
 
 
-_paste_prefix_rows = jax.jit(_paste_prefix_rows, donate_argnums=(0,))
+_paste_prefix_rows = jax.jit(paste_prefix_rows, donate_argnums=(0,))
 
 
 def gather_paged_rows(pool_cache: Any, blocks_row: jax.Array, width: int) -> Tuple[Any, ...]:
@@ -509,11 +510,12 @@ class Generator:
             tok0 = sample_tokens(constrain(head(p, last), cstate), key, config)
             return tok0, cache, last.astype(jnp.float32)
 
-        def prefill_chunk(p, tokens, start, lengths, cache, row_valid):
+        def prefill_chunk(p, tokens, start, lengths, cache, row_valid, last):
             """One chunk of a long-context prefill: columns [start, start+C) of the
             padded prompt flow through the cache (attention sees all previously
-            written slots). Also extracts the hidden row of each example's last
-            real token if it falls inside this chunk. The fourth output is what
+            written slots). ``last`` ``[B, dim]`` f32 accumulates the hidden row of
+            each example's last real token: a row whose last token falls inside
+            this chunk takes it, the others pass through. The third output is what
             the module counted over the chunk (``counter_names``; empty without)."""
             self.prefill_traces += 1
             p = dequant(p)
@@ -523,7 +525,7 @@ class Generator:
             hidden, cache, counts = apply_counted(p, tokens, positions, cache, token_mask)
             sel = positions == (lengths - 1)[:, None]  # at most one true column per row
             chunk_last = jnp.einsum("blc,bl->bc", hidden.astype(jnp.float32), sel.astype(jnp.float32))
-            return chunk_last, sel.any(axis=1), cache, counts
+            return jnp.where(sel.any(axis=1)[:, None], chunk_last, last), cache, counts
 
         def first_token(p, last, key, *cstate):
             """Sample the first generated token from accumulated last-row hiddens
@@ -585,7 +587,7 @@ class Generator:
 
         # donate the cache through both stages: one cache lives in HBM, not two
         self._prefill = jax.jit(prefill, donate_argnums=(3,))
-        self._prefill_chunk = jax.jit(prefill_chunk, donate_argnums=(4,))
+        self._prefill_chunk = jax.jit(prefill_chunk, donate_argnums=(4, 6))
         self._first_token = jax.jit(first_token)
         self._decode = jax.jit(decode_steps, static_argnames=("steps",), donate_argnums=(1,))
         self._apply_fn = apply  # for engines composing on top (beam search)
@@ -615,9 +617,10 @@ class Generator:
             "generation_config": repr(self.config),
             "quantize": self.quantize,
             # bumped when a program's OUTPUT signature changes (the decode
-            # scan gained a logprobs output): stale serialized executables
-            # from an older layout must miss and recompile, not load
-            "program_abi": "decode-logprobs-v3-chunk-counts",
+            # scan gained a logprobs output, the prefill chunk merges the
+            # last-hidden row itself): stale serialized executables from an
+            # older layout must miss and recompile, not load
+            "program_abi": "decode-logprobs-v3-chunk-last",
             **mesh_context(self.mesh),
         }
         if self._cs is not None:
@@ -776,19 +779,27 @@ class Generator:
         logger.info(f"prompt length {max_prompt} exceeds configured buckets; padding to {bucket}")
         return bucket
 
-    def _place_cache(self, cache: Any) -> Any:
+    def _cache_shardings(self, cache: Any) -> Any:
+        """Where a contiguous ``[B, L, H, last]`` cache lives on the mesh, leaf for
+        leaf (shapes are enough: a program's ``out_shardings`` are built from
+        these); ``None`` without a mesh."""
         if self.mesh is None:
-            return cache
+            return None
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        def spec(a: jax.Array) -> NamedSharding:
+        def spec(a: Any) -> NamedSharding:
             data = "data" if "data" in self.mesh.axis_names else None
             model = "model" if "model" in self.mesh.axis_names else None
             if model is not None and a.shape[2] % self.mesh.shape["model"] != 0:
                 model = None  # KV heads not divisible by the model axis: replicate heads
             return NamedSharding(self.mesh, P(data, None, model, None))
 
-        return jax.tree_util.tree_map(lambda a: jax.device_put(a, spec(a)), cache)
+        return jax.tree_util.tree_map(spec, cache)
+
+    def _place_cache(self, cache: Any) -> Any:
+        if self.mesh is None:
+            return cache
+        return jax.tree_util.tree_map(jax.device_put, cache, self._cache_shardings(cache))
 
     def _place_paged_cache(self, cache: Any) -> Any:
         """Mesh placement for a PAGED pool (:func:`init_paged_cache`): the
@@ -931,15 +942,15 @@ class Generator:
         accumulating each row's last-real-token hidden state."""
         last = jnp.zeros((tokens.shape[0], self.module.config.dim), jnp.float32)
         for c in range(0, tokens.shape[1], chunk):
-            chunk_last, has, cache, _ = self._prefill_chunk(
+            last, cache, _ = self._prefill_chunk(
                 self.params,
                 jnp.asarray(tokens[:, c : c + chunk]),
                 jnp.int32(start + c),
                 lengths_dev,
                 cache,
                 row_valid,
+                last,
             )
-            last = jnp.where(has[:, None], chunk_last, last)
         return last, cache
 
     def _grammar_ids(self, constraint: Optional[Any], n: int, batch: int) -> np.ndarray:

@@ -62,6 +62,7 @@ class IterationRecord(NamedTuple):
     finished: int  #: rows that finished
     blocks_grown: int  #: KV blocks appended to residents' tables
     table_syncs: int  #: runs of the program that carries the host's table growths and slot releases to the device
+    admit_dispatches: int  #: programs and transfers the admit phase handed the runtime (set-ups, chunks, first tokens, pastes)
 
     def render(self) -> Dict[str, Any]:
         out = self._asdict()
@@ -122,6 +123,7 @@ class EngineLog:
         self._idle_s = 0.0
         self._phase_s = [0.0] * len(PHASES)
         self._table_syncs = 0
+        self._admit_dispatches = 0
         self._epoch = 0  # bumped by clear(): a pass that began before it is not recorded
         # the pass in progress: engine thread only
         self._pass_epoch = 0
@@ -138,6 +140,7 @@ class EngineLog:
         self.finished = 0
         self.blocks_grown = 0
         self.table_syncs = 0
+        self.admit_dispatches = 0
         #: how the engine's decode program reads its paged cache (``"paged_kernel"`` / ``"gather"``), set by the
         #: engine after a decode dispatch; a fact about the program, not a counter: :meth:`clear` leaves it
         self.decode_attention_path: Optional[str] = None
@@ -209,20 +212,22 @@ class EngineLog:
         self._dur[self._current] += now - self._mark
         self._close_span()
         phase_s = tuple(self._dur[: len(PHASES)])
-        table_syncs = self.table_syncs
+        table_syncs, admit_dispatches = self.table_syncs, self.admit_dispatches
         tail = (
             self._t0, phase_s, self.rows, self.prefill_tokens, self.admitted, self.finished, self.blocks_grown,
-            table_syncs,
+            table_syncs, admit_dispatches,
         )
         epoch = self._pass_epoch
         self._t0 = None
-        self.rows = self.prefill_tokens = self.admitted = self.finished = self.blocks_grown = self.table_syncs = 0
+        self.rows = self.prefill_tokens = self.admitted = self.finished = self.blocks_grown = 0
+        self.table_syncs = self.admit_dispatches = 0
         with self._lock:
             if epoch != self._epoch:
                 return  # cleared while this pass ran: it belongs to what was forgotten
             self._iterations.append(IterationRecord(self._count, *tail))
             self._count += 1
             self._table_syncs += table_syncs
+            self._admit_dispatches += admit_dispatches
             for i, seconds in enumerate(phase_s):
                 self._phase_s[i] += seconds
 
@@ -246,6 +251,7 @@ class EngineLog:
             self._idle_s = 0.0
             self._phase_s = [0.0] * len(PHASES)
             self._table_syncs = 0
+            self._admit_dispatches = 0
             self._epoch += 1
             self.model_counters = {}
 
@@ -266,6 +272,7 @@ class EngineLog:
                 "idle_s": self._idle_s,
                 "phase_s": dict(zip(PHASES, self._phase_s)),
                 "table_syncs": self._table_syncs,
+                "admit_dispatches": self._admit_dispatches,
             }
 
     def iteration_records(self) -> List[IterationRecord]:

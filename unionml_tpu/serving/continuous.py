@@ -96,11 +96,11 @@ from unionml_tpu.serving.tenancy import (
 from unionml_tpu.models.generate import (
     Generator,
     PrefixCache,
-    _paste_prefix_rows,
     chunk_aligned,
     gather_paged_rows,
     init_cache,
     init_paged_cache,
+    paste_prefix_rows,
 )
 
 __all__ = ["ContinuousBatcher"]
@@ -262,6 +262,7 @@ class _Admission:
     row_cache: Any = None  # target model's [1, cache_len] row (filling up)
     last: Any = None  # accumulated last-real-token hidden state
     d_row_cache: Any = None  # draft model's row, chunked in lockstep
+    d_last: Any = None  # the draft's: its chunk program (the target's, one for both) takes and donates it
     #: what a counting model counted over each chunk (device arrays, gen.counter_names)
     counts: list = dataclasses.field(default_factory=list)
     # radix prefix cache (prefix_cache=True engines): tokens of the logical
@@ -720,13 +721,6 @@ class ContinuousBatcher:
                     list(self._shared_prefix_blocks),
                 )
                 self._radix.pin(self._shared_prefix_blocks)
-            #: one compile: the dense-row gather at the engine's fixed width
-            self._gather_fn = jax.jit(gather_paged_rows, static_argnums=(2,))
-            if self._aot is not None:
-                self._gather_fn = AOTFunction(
-                    self._gather_fn, "gather_paged_rows", self._aot,
-                    self.gen._aot_context(), static_argnums=(2,),
-                )
         self._lock = threading.Condition()
         self._pending: "List[tuple]" = []  # (prompt, session) awaiting a free slot
         self._admissions: "List[_Admission]" = []  # slot-holding, prefill in flight
@@ -767,6 +761,9 @@ class ContinuousBatcher:
         # reshape/scatter; bounded by max_blocks.
         self._export_pages_fn = jax.jit(self._export_pages_impl, static_argnums=(1, 2))
         self._paged_page_admit_fn = jax.jit(self._paged_page_admit_impl, donate_argnums=(0,))
+        # a constrained generator's DFA state enters the carry's tail at admission
+        self._slot_set_fn = jax.jit(lambda arr, slot, value: arr.at[slot].set(value), donate_argnums=(0,))
+        self._build_admission_programs()
         if self._aot is not None:
             # the admission scatter helpers preload too — on a cold TPU the
             # scatter over a big pool is its own multi-second compile
@@ -1013,6 +1010,87 @@ class ContinuousBatcher:
         tables = jax.tree_util.tree_map(lambda t: jnp.where(edited[:, None], table.astype(t.dtype), t), tables)
         return tables, jnp.where(released, 0, lengths), done | released
 
+    def _build_admission_programs(self) -> None:
+        """The set-up programs of an admission (its chunks and first token are
+        the Generator's own programs, the paste is further down), built once from
+        what the engine knows at construction (module configurations,
+        ``cache_len``, KV dtype, mesh, speculation): the engine thread then talks
+        to the device once per device step of an admission, with host values as
+        arguments and no ``jax.numpy`` call of its own.
+
+        - ``_setup_fn(seed, total, prefixes)``: what every admission starts
+          from — ``lengths`` ``[1]``, the sampling key (``fold_in(PRNGKey(seed),
+          seed)``, bit for bit the eager derivation for every seed under 2**32),
+          ``row_valid``, a zeroed last-hidden row and a zeroed ``[1, cache_len]``
+          row cache per model (target, then the draft under speculation), the
+          shared prefix's rows pasted at ``[0, p0)``. On a mesh the rows come
+          out where :meth:`Generator._place_cache` would put them.
+        - ``_cached_setup_fn(pool, gather_row, seed, total)``: the same for a
+          radix hit, the row gathered from the pool's cached blocks instead."""
+        gen, cfg = self.gen, self.gen.config
+        #: the models an admission prefills: the target, then the draft under speculation
+        self._models = models = (gen,) if self._spec is None else (gen, self._spec._draft)
+        cache_len, kv_dtype = self.cache_len, cfg.kv_cache_dtype
+
+        def scalars(seed, total):
+            key = jax.random.fold_in(jax.random.PRNGKey(seed), seed)
+            lasts = tuple(jnp.zeros((1, g.module.config.dim), jnp.float32) for g in models)
+            return jnp.reshape(total, (1,)), key, jnp.ones((1,), bool), lasts
+
+        def rows():
+            return tuple(init_cache(g.module.config, 1, cache_len, kv_dtype=kv_dtype) for g in models)
+
+        def admit_setup(seed, total, prefixes):
+            # prefixes: one PrefixCache's layers per model, or () without a shared
+            # prefix (arguments, not constants: a closed-over array is compiled in)
+            made = rows()
+            if prefixes:
+                made = tuple(paste_prefix_rows(row, pre) for row, pre in zip(made, prefixes))
+            return (*scalars(seed, total), made)
+
+        def admit_setup_cached(pool_cache, gather_row, seed, total):
+            return (*scalars(seed, total), (gather_paged_rows(pool_cache, gather_row, cache_len),))
+
+        shardings, cached_shardings = None, None
+        if gen.mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            # committed outputs must live where the programs that take them run
+            whole = NamedSharding(gen.mesh, PartitionSpec())
+            placed = tuple(g._cache_shardings(row) for g, row in zip(models, jax.eval_shape(rows)))
+            shardings = (whole, whole, whole, (whole,) * len(models), placed)
+            cached_shardings = (whole, whole, whole, (whole,), None)  # the gathered row: as the pool's heads lie
+        self._setup_fn = jax.jit(admit_setup, out_shardings=shardings)
+        self._cached_setup_fn = jax.jit(admit_setup_cached, out_shardings=cached_shardings)
+        if self._aot is not None:
+            # what the two programs close over and no argument's shape shows is part
+            # of their key: the row's length (constructor knobs raise it: decode_chunk,
+            # admit_chunk, prefix_cache), and the draft whose row the set-up also builds
+            # (the KV dtype and the target's module are in the Generator's context)
+            ectx = {
+                **gen._aot_context(),
+                "cache_len": cache_len,
+                "row_modules": [repr(g.module.config) for g in models],
+            }
+            self._setup_fn = AOTFunction(self._setup_fn, "admit_setup", self._aot, ectx)
+            self._cached_setup_fn = AOTFunction(self._cached_setup_fn, "admit_setup_cached", self._aot, ectx)
+        #: the shared prefix's rows per model, the set-up program's third argument
+        self._setup_prefixes = tuple(
+            pre.layers for pre in (self.prefix, self._draft_prefix)[: len(models)] if pre is not None
+        )
+
+    def _issue(self, fn: Any, *args: Any) -> Any:
+        """Hand the runtime one program or transfer of the admit phase (engine
+        thread): counted on the iteration's record as ``admit_dispatches``."""
+        log = self.engine_log  # engine thread only, like the pass's other counters
+        log.admit_dispatches += 1
+        return fn(*args)
+
+    def _admission_setup(self, seed: int, total: int) -> tuple:
+        """A cold admission's starting state, one dispatch:
+        ``(lengths, key, row_valid, lasts, rows)``, the last two per model."""
+        return self._issue(self._setup_fn, np.uint32(seed), np.int32(total), self._setup_prefixes)
+
     def _seed_shared_prefix(self, cache: Any, prefix_layers: Any) -> Any:
         """Write the prefix's FULL blocks into a pool once; every admission's
         table then points at these ids and nothing ever writes them again
@@ -1090,8 +1168,6 @@ class ContinuousBatcher:
         self,
         prompt: Sequence[int],
         seed: int,
-        gen: Optional[Generator] = None,
-        prefix: Optional[PrefixCache] = None,
         budget: Optional[int] = None,
         dfa_state: Optional[int] = None,
         allow_sp: bool = True,
@@ -1101,95 +1177,101 @@ class ContinuousBatcher:
         bounded set of prefill compiles (one per bucket at batch 1). With a
         shared ``prefix``, its rows are pasted at slots [0, p0) and the prompt
         (a suffix) flows through the offset chunked path, exactly like
-        ``Generator.__call__(..., prefix=...)``. ``gen``/``prefix`` override the
-        model and its prefix rows (speculative mode prefills the draft's row
-        with the DRAFT's prefix). ``budget`` is THIS request's remaining token
-        budget (default: the config's) — feasibility and the resume-width
-        fallback below depend on it, not on the config worst case.
+        ``Generator.__call__(..., prefix=...)``. Under speculation the draft's
+        row is prefilled here too: the same prompt through the draft model,
+        seeded with the DRAFT's prefix rows (its prompt-sampled token is
+        discarded — emission #1 is the target's, exactly as in
+        ``SpeculativeGenerator._start_state``; ``dfa_state`` rides along, since
+        the draft Generator shares the constraints config). ``budget`` is THIS
+        request's remaining token budget (default: the config's) — feasibility
+        and the resume-width fallback below depend on it, not on the config
+        worst case. The rows, key and scalars come from one
+        :meth:`_admission_setup`, as a chunked admission's do, dispatched only
+        once every model's width is known to fit.
 
-        Returns ``(tok0, lengths, row_cache, last)`` — ``last`` is the
-        prompt's last-token hidden row (``None`` only on the sequence-parallel
+        Returns ``(tok0, lengths, row_cache, last, d_row_cache)`` — ``last`` is
+        the prompt's last-token hidden row (``None`` only on the sequence-parallel
         path, which does not surface it); a ``logprobs=True`` admission reads
         it to price the prompt-sampled token, and ``allow_sp=False`` keeps
         such admissions on the dense prefill (token-identical by the
-        sp==dense contract) so the row is always available."""
+        sp==dense contract) so the row is always available. ``d_row_cache`` is
+        the draft's row (``None`` without speculation)."""
         cfg = self.gen.config
-        if gen is None:
-            gen, prefix = self.gen, self.prefix
         if budget is None:
             budget = cfg.max_new_tokens
         # draft and target prefixes have the same length (same token ids)
         p0 = self.prefix.length if self.prefix is not None else 0
-        bucket = gen._bucket(max(len(prompt), 1))
-        if p0 + bucket + budget > self.cache_len:
-            # a PREEMPTED request resumes as prompt + emitted tokens, which can
-            # outgrow every configured bucket while still fitting the cache
-            # contiguously (_start_admissions checked that prompt + remaining
-            # budget <= cache_len) — prefill at the exact width instead of
-            # failing the stream; the extra compile is bounded by preemptions
-            # being rare
-            bucket = max(len(prompt), 1)
-        tokens = np.full((1, bucket), cfg.pad_id, np.int32)
-        tokens[0, : len(prompt)] = np.asarray(prompt, np.int32)
-        lengths = jnp.asarray([p0 + max(len(prompt), 1)], jnp.int32)
-        row_cache = gen._place_cache(
-            init_cache(gen.module.config, 1, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
-        )
-        # keyed on the admission's own seed (identical to the historical
-        # fold_in(PRNGKey(self._seed), seed): the two were always equal at
-        # dispatch time) so overlapping chunked admissions stay deterministic
-        key = jax.random.fold_in(jax.random.PRNGKey(seed), seed)
-        row_valid = jnp.ones((1,), bool)
+
+        def plan(gen: Generator, sp_ok: bool) -> tuple:
+            """How ``gen`` prefills this prompt: ``(mode, padded tokens, chunk)``."""
+            bucket = gen._bucket(max(len(prompt), 1))
+            if p0 + bucket + budget > self.cache_len:
+                # a PREEMPTED request resumes as prompt + emitted tokens, which can
+                # outgrow every configured bucket while still fitting the cache
+                # contiguously (_start_admissions checked that prompt + remaining
+                # budget <= cache_len) — prefill at the exact width instead of
+                # failing the stream; the extra compile is bounded by preemptions
+                # being rare
+                bucket = max(len(prompt), 1)
+            seq = int(gen.mesh.shape.get("sequence", 1)) if gen.mesh is not None else 1
+            mode, chunk, width = "dense", 0, bucket
+            if self.prefix is not None:
+                chunk = cfg.prefill_chunk or bucket
+                # ragged tails would cost one extra prefill compile per bucket remainder
+                mode, width = "chunks", chunk_aligned(bucket, chunk)
+                if p0 + width > self.cache_len:  # __init__ sizes for every bucket;
+                    raise ValueError(  # this guards out-of-set prompt widths
+                        f"chunk-aligned prefill width {width} + prefix {p0} exceeds cache_len {self.cache_len}"
+                    )
+            elif sp_ok and gen.config.sp_prefill and seq > 1 and chunk_aligned(bucket, seq) <= self.cache_len:
+                # long-context admission: the batch-1 row prefills SEQUENCE-PARALLEL
+                # through the Generator's own ring/ulysses shard_map machinery
+                # (columns split over the sequence axis; data/fsdp axes are 1 by the
+                # mesh guard above), then the row pastes into the pool exactly like
+                # any admission — same numerics, same bounded compile set.
+                # When the sequence-aligned width would overflow the cache — a
+                # PREEMPTION RESUME's exact-width bucket can outgrow every
+                # configured bucket while fitting contiguously — the row stays on
+                # the dense prefill instead of failing the stream: dense and sp
+                # prefill are token-identical, so the resume stays invisible to
+                # the consumer (the contract docs/generation.md states)
+                mode, width = "sp", chunk_aligned(bucket, seq)
+            tokens = np.full((1, width), cfg.pad_id, np.int32)
+            tokens[0, : len(prompt)] = np.asarray(prompt, np.int32)
+            return mode, tokens, chunk
+
+        # the draft's last-hidden row is never read: it may always prefill sequence-parallel
+        plans = [plan(gen, allow_sp or i > 0) for i, gen in enumerate(self._models)]
+        # keyed on the admission's own seed (fold_in(PRNGKey(seed), seed), the
+        # set-up program's derivation) so overlapping chunked admissions stay
+        # deterministic; the rows come zeroed, prefix-seeded and placed
+        lengths, key, row_valid, lasts, rows = self._admission_setup(seed, p0 + max(len(prompt), 1))
         # the request's current DFA state masks the prompt-sampled token, same
         # as Generator._start's cstate tail (batch-1 row here)
-        cstate = () if dfa_state is None else (jnp.asarray([dfa_state], jnp.int32),)
-        last = None
-        if prefix is not None:
-            chunk = cfg.prefill_chunk or bucket
-            aligned = chunk_aligned(bucket, chunk)  # ragged tails would cost one
-            if p0 + aligned > self.cache_len:  # __init__ sizes for every bucket;
-                raise ValueError(  # this guards out-of-set prompt widths
-                    f"chunk-aligned prefill width {aligned} + prefix {p0} exceeds cache_len {self.cache_len}"
+        cstate = () if dfa_state is None else (np.asarray([dfa_state], np.int32),)
+        filled = []
+        for gen, (mode, tokens, chunk), last, row_cache in zip(self._models, plans, lasts, rows):
+            if mode == "chunks":
+                for c in range(0, tokens.shape[1], chunk):
+                    last, row_cache, _ = self._issue(
+                        gen._prefill_chunk, gen.params, tokens[:, c : c + chunk], np.int32(p0 + c),
+                        lengths, row_cache, row_valid, last,
+                    )
+                tok0 = self._issue(gen._first_token, gen.params, last, key, *cstate)
+            elif mode == "sp":
+                if gen._sp_prefill_fn is None:
+                    gen._sp_prefill_fn = gen._build_sp_prefill()
+                last = None
+                tok0, row_cache, _ = self._issue(
+                    gen._sp_prefill_fn, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
                 )
-            if aligned > bucket:  # extra prefill compile per bucket remainder
-                tokens = np.pad(tokens, ((0, 0), (0, aligned - bucket)), constant_values=cfg.pad_id)
-            row_cache = _paste_prefix_rows(row_cache, prefix.layers)
-            last, row_cache = gen._chunked_prefill_loop(
-                tokens, lengths, row_cache, row_valid, chunk, start=p0
-            )
-            tok0 = gen._first_token(gen.params, last, key, *cstate)
-        elif (
-            allow_sp
-            and gen.config.sp_prefill
-            and gen.mesh is not None
-            and int(gen.mesh.shape.get("sequence", 1)) > 1
-            and chunk_aligned(bucket, int(gen.mesh.shape["sequence"])) <= self.cache_len
-        ):
-            # long-context admission: the batch-1 row prefills SEQUENCE-PARALLEL
-            # through the Generator's own ring/ulysses shard_map machinery
-            # (columns split over the sequence axis; data/fsdp axes are 1 by the
-            # mesh guard above), then the row pastes into the pool exactly like
-            # any admission — same numerics, same bounded compile set.
-            # When the sequence-aligned width would overflow the cache — a
-            # PREEMPTION RESUME's exact-width bucket can outgrow every
-            # configured bucket while fitting contiguously — the row falls
-            # through to the dense prefill below instead of failing the stream:
-            # dense and sp prefill are token-identical, so the resume stays
-            # invisible to the consumer (the contract docs/generation.md states)
-            seq = int(gen.mesh.shape["sequence"])
-            aligned = chunk_aligned(bucket, seq)
-            if aligned > bucket:
-                tokens = np.pad(tokens, ((0, 0), (0, aligned - bucket)), constant_values=cfg.pad_id)
-            if gen._sp_prefill_fn is None:
-                gen._sp_prefill_fn = gen._build_sp_prefill()
-            tok0, row_cache, _ = gen._sp_prefill_fn(
-                gen.params, jnp.asarray(tokens), lengths, row_cache, key, row_valid, *cstate
-            )
-        else:
-            tok0, row_cache, last = gen._prefill(
-                gen.params, jnp.asarray(tokens), lengths, row_cache, key, row_valid, *cstate
-            )
-        return tok0, lengths, row_cache, last
+            else:
+                tok0, row_cache, last = self._issue(
+                    gen._prefill, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
+                )
+            filled.append((tok0, row_cache, last))
+        tok0, row_cache, last = filled[0]
+        return tok0, lengths, row_cache, last, (filled[1][1] if len(filled) > 1 else None)
 
     def _table_entries(self, tokens: int) -> int:
         """Block-table entries covering positions ``[0, tokens)``."""
@@ -1281,7 +1363,7 @@ class ContinuousBatcher:
                 )[:, 0]
 
             self._lp0_fn = jax.jit(impl)
-        lp0 = self._lp0_fn(gen.params, adm.last, adm.tok0, *adm.cstate)
+        lp0 = self._issue(self._lp0_fn, gen.params, adm.last, adm.tok0, *adm.cstate)
         with self.engine_log.phase("fetch"):
             return float(np.asarray(lp0)[0])
 
@@ -1583,6 +1665,14 @@ class ContinuousBatcher:
             prompt = [cfg.pad_id + 1] * bucket
             for _ in self.submit(prompt, max_new_tokens=1):
                 pass
+        if self._radix is not None and cfg.prompt_buckets:
+            with self._lock:
+                hit = self.prefix_cache_hits > 0
+            if not hit:
+                # a radix hit's set-up is a program of its own (the row gathered
+                # from the pool): the widest probe again finds its own blocks cached
+                for _ in self.submit([cfg.pad_id + 1] * max(cfg.prompt_buckets), max_new_tokens=1):
+                    pass
         if cfg.max_new_tokens >= 2:
             # an eos-emitting model can finish a junk prompt at admission
             # (start_done) without ever decoding — vary the prompt a few times.
@@ -2413,7 +2503,7 @@ class ContinuousBatcher:
             for t in session.echo:
                 dfa_state = int(cs.trans[dfa_state, t])
         adm.dfa_state = dfa_state
-        adm.cstate = () if dfa_state is None else (jnp.asarray([dfa_state], jnp.int32),)
+        adm.cstate = () if dfa_state is None else (np.asarray([dfa_state], np.int32),)
         p0 = self.prefix.length if self.prefix is not None else 0
         if adm.gather_row is not None and self._begin_cached(adm):
             return 0
@@ -2437,23 +2527,12 @@ class ContinuousBatcher:
             # the shard_map), or an exact-width resume whose chunk-aligned
             # width would overflow the cache (the fallback keeps the resume's
             # token-exactness guarantee instead of failing the stream)
-            adm.tok0, adm.row_len, adm.row_cache, adm.last = self._prefill_row(
+            adm.tok0, adm.row_len, adm.row_cache, adm.last, adm.d_row_cache = self._prefill_row(
                 prompt, adm.seed, budget=adm.budget, dfa_state=dfa_state,
                 # logprobs admissions keep the dense prefill (token-identical
                 # to sp) so the last-hidden row is retained for tok0's logprob
                 allow_sp=not session.want_logprobs,
             )
-            if self._spec is not None:
-                # the draft's cache row: same prompt through the draft model
-                # with the DRAFT's prefix rows (its prompt-sampled token is
-                # discarded — emission #1 is the target's, exactly as in
-                # SpeculativeGenerator._start_state). dfa_state rides along:
-                # the draft Generator shares the constraints config, so its
-                # prefill closure requires the state argument too
-                _, _, adm.d_row_cache, _ = self._prefill_row(
-                    prompt, adm.seed, gen=self._spec._draft, prefix=self._draft_prefix,
-                    budget=adm.budget, dfa_state=dfa_state,
-                )
             adm.done = True
             with self._lock:
                 self.prefill_monolithic += 1
@@ -2463,29 +2542,18 @@ class ContinuousBatcher:
         tokens = np.full((1, aligned), cfg.pad_id, np.int32)
         tokens[0, : len(prompt)] = np.asarray(prompt, np.int32)
         adm.tokens = tokens
-        adm.lengths = jnp.asarray([p0 + max(len(prompt), 1)], jnp.int32)
-        # the same key derivation as _prefill_row, so chunked and monolithic
-        # admission sample the identical first token
-        adm.key = jax.random.fold_in(jax.random.PRNGKey(adm.seed), adm.seed)
-        adm.row_valid = jnp.ones((1,), bool)
-        adm.last = jnp.zeros((1, gen.module.config.dim), jnp.float32)
-        row_cache = gen._place_cache(
-            init_cache(gen.module.config, 1, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
+        # the same set-up program as _prefill_row's (one for both models: the
+        # admission's length, key, flags and zeroed, prefix-seeded rows), so
+        # chunked and monolithic admission sample the identical first token;
+        # under speculation the draft's row chunks in LOCKSTEP with the
+        # target's (same columns per step), so speculative admissions stall
+        # residents no longer than plain ones
+        adm.lengths, adm.key, adm.row_valid, lasts, rows = self._admission_setup(
+            adm.seed, p0 + max(len(prompt), 1)
         )
-        if self.prefix is not None:
-            row_cache = _paste_prefix_rows(row_cache, self.prefix.layers)
-        adm.row_cache = row_cache
+        adm.last, adm.row_cache = lasts[0], rows[0]
         if self._spec is not None:
-            # the draft's row chunks in LOCKSTEP with the target's (same
-            # columns per step), so speculative admissions stall residents no
-            # longer than plain ones
-            draft = self._spec._draft
-            d_row = draft._place_cache(
-                init_cache(draft.module.config, 1, self.cache_len, kv_dtype=cfg.kv_cache_dtype)
-            )
-            if self._draft_prefix is not None:
-                d_row = _paste_prefix_rows(d_row, self._draft_prefix.layers)
-            adm.d_row_cache = d_row
+            adm.d_last, adm.d_row_cache = lasts[1], rows[1]
         return 0
 
     def _import_begin(self, adm: _Admission) -> int:
@@ -2512,19 +2580,18 @@ class ContinuousBatcher:
         # whole KV pages in pool layout, placed onto this engine's submesh
         # (device_put copies between disjoint device sets — and accepts the
         # numpy arrays a cross-host wire delivers)
-        pages = tuple(
-            {name: jnp.asarray(buf) for name, buf in layer.items()} for layer in payload["pages"]
-        )
-        adm.import_pages = self.gen._place_paged_cache(pages)
-        adm.tok0 = jnp.asarray([int(payload["first"])], jnp.int32)
-        adm.row_len = jnp.asarray([int(payload["lengths"])], jnp.int32)
+        place = jax.device_put if self.gen.mesh is None else self.gen._place_paged_cache
+        adm.import_pages = self._issue(place, tuple(dict(layer) for layer in payload["pages"]))
+        # host values: they ride the paste's own dispatch
+        adm.tok0 = np.asarray([int(payload["first"])], np.int32)
+        adm.row_len = np.asarray([int(payload["lengths"])], np.int32)
         if self.gen._cs is not None:
             cs = self.gen._cs
             state = int(cs.starts[adm.session.grammar])
             for t in list(payload["echo"])[:-1]:
                 state = int(cs.trans[state, int(t)])
             adm.dfa_state = state
-            adm.cstate = (jnp.asarray([state], jnp.int32),)
+            adm.cstate = (np.asarray([state], np.int32),)
         adm.done = True
         exported_at = payload.get("exported_at")
         if exported_at is not None:
@@ -2563,8 +2630,10 @@ class ContinuousBatcher:
         # inverse of the admission scatter, one fused gather dispatch; stale
         # positions past the cached run are overwritten by the suffix prefill
         # before anything can attend to them
-        adm.row_cache = self._gather_fn(
-            self._carry[0], jnp.asarray(adm.gather_row), self.cache_len
+        # (the same program hands out the length, key and flags a cold set-up does:
+        # the first sampled token is bit-identical to a cold admission's)
+        adm.lengths, adm.key, adm.row_valid, (adm.last,), (adm.row_cache,) = self._issue(
+            self._cached_setup_fn, self._carry[0], adm.gather_row, np.uint32(adm.seed), np.int32(total)
         )
         tokens = np.full((1, width), cfg.pad_id, np.int32)
         tokens[0, : len(suffix)] = np.asarray(suffix, np.int32)
@@ -2572,12 +2641,6 @@ class ContinuousBatcher:
         adm.chunk, adm.width = chunk, width
         adm.start = start
         adm.pos = 0
-        adm.lengths = jnp.asarray([total], jnp.int32)
-        # same key derivation as the cold paths: the first sampled token is
-        # bit-identical to a cold (chunked or monolithic) admission's
-        adm.key = jax.random.fold_in(jax.random.PRNGKey(adm.seed), adm.seed)
-        adm.row_valid = jnp.ones((1,), bool)
-        adm.last = jnp.zeros((1, gen.module.config.dim), jnp.float32)
         with self._lock:
             self.prefix_cache_hits += 1
             self.prefix_cache_tokens_avoided += start - p0
@@ -2605,18 +2668,18 @@ class ContinuousBatcher:
             if adm.done:
                 return cost
         c = adm.pos
-        sl = jnp.asarray(adm.tokens[:, c : c + adm.chunk])
-        chunk_last, has, adm.row_cache, counts = gen._prefill_chunk(
-            gen.params, sl, jnp.int32(adm.start + c), adm.lengths, adm.row_cache, adm.row_valid
+        # host values: the chunk's columns and its offset travel with the dispatch
+        sl, start = adm.tokens[:, c : c + adm.chunk], np.int32(adm.start + c)
+        adm.last, adm.row_cache, counts = self._issue(
+            gen._prefill_chunk, gen.params, sl, start, adm.lengths, adm.row_cache, adm.row_valid, adm.last
         )
-        adm.last = jnp.where(has[:, None], chunk_last, adm.last)
         if gen.counter_names:
             adm.counts.append(counts)  # read with the admission's first token: no fetch of their own
         if self._spec is not None:
             draft = self._spec._draft
-            _, _, adm.d_row_cache, _ = draft._prefill_chunk(
-                draft.params, sl, jnp.int32(adm.start + c), adm.lengths,
-                adm.d_row_cache, adm.row_valid,
+            adm.d_last, adm.d_row_cache, _ = self._issue(
+                draft._prefill_chunk, draft.params, sl, start, adm.lengths,
+                adm.d_row_cache, adm.row_valid, adm.d_last,
             )
         adm.pos = c + adm.chunk
         with self._lock:
@@ -2627,7 +2690,7 @@ class ContinuousBatcher:
             pos=adm.pos, width=adm.width, chunk=adm.chunk,
         )
         if adm.pos >= adm.width:
-            adm.tok0 = gen._first_token(gen.params, adm.last, adm.key, *adm.cstate)
+            adm.tok0 = self._issue(gen._first_token, gen.params, adm.last, adm.key, *adm.cstate)
             adm.row_len = adm.lengths
             adm.done = True
         return adm.chunk
@@ -2657,7 +2720,7 @@ class ContinuousBatcher:
             # the payload scales with the prompt, not with cache_len,
             # in-process or across hosts
             n_blocks = -(-row_len_host // self.block_size)
-            pages = self._export_pages_fn(adm.row_cache, n_blocks, self.block_size)
+            pages = self._issue(self._export_pages_fn, adm.row_cache, n_blocks, self.block_size)
         adm.row_cache = adm.last = None  # the row never leaves the engine
         with self._lock:
             if adm in self._admissions:
@@ -2744,30 +2807,30 @@ class ContinuousBatcher:
             # endings handled) by the EXPORTING replica — it is never start-done
             imported = session.pending_import is not None
             start_done = not imported and (hit_eos or session.produced + 1 >= session.max_new)
+            # the slot, its table row and the shared count are host values: the
+            # runtime moves them with the paste's own dispatch (blocks_row is
+            # this admission's alone: nothing edits it while the call reads it)
             blocks_row = adm.blocks_row
+            row_args = (np.int32(slot), adm.tok0, adm.row_len)
+            table_args = (blocks_row, np.int32(session.shared_blocks))
             if self._spec is None:
                 cache, tok, lengths, done, key, *cst = self._carry
                 if adm.import_pages is not None:
                     # handoff import: whole pages scatter straight into the
                     # allocated blocks — no per-position re-scatter ever runs
-                    cache, tok, lengths, done = self._paged_page_admit_fn(
-                        cache, adm.import_pages, tok, lengths, done, jnp.int32(slot),
-                        adm.tok0, adm.row_len, jnp.asarray(blocks_row),
-                        jnp.int32(session.shared_blocks),
+                    cache, tok, lengths, done = self._issue(
+                        self._paged_page_admit_fn, cache, adm.import_pages, tok, lengths, done, *row_args, *table_args
                     )
                 else:
-                    cache, tok, lengths, done = self._paged_admit_fn(
-                        cache, adm.row_cache, tok, lengths, done, jnp.int32(slot), adm.tok0,
-                        adm.row_len, jnp.asarray(blocks_row), jnp.int32(session.shared_blocks),
+                    cache, tok, lengths, done = self._issue(
+                        self._paged_admit_fn, cache, adm.row_cache, tok, lengths, done, *row_args, *table_args
                     )
                 self._carry = (cache, tok, lengths, done, key, *cst)
             else:
                 t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst = self._carry
-                t_cache, d_cache, out_buf, tok, lengths, done, produced = self._paged_spec_admit_fn(
-                    t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache, tok, lengths,
-                    done, produced, jnp.int32(slot), adm.tok0, adm.row_len,
-                    jnp.asarray([start_done]), jnp.int32(cfg.pad_id),
-                    jnp.asarray(blocks_row), jnp.int32(session.shared_blocks),
+                t_cache, d_cache, out_buf, tok, lengths, done, produced = self._issue(
+                    self._paged_spec_admit_fn, t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache,
+                    tok, lengths, done, produced, *row_args, np.asarray([start_done]), np.int32(cfg.pad_id), *table_args,
                 )
                 self._carry = (t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst)
             # the paste wrote this slot's done flag, length and table row: what
@@ -2783,12 +2846,13 @@ class ContinuousBatcher:
                 # a counting model's counts in the plain one
                 state = list(self._carry)
                 at = -2 if self._spec is None and self.gen.counter_names else -1
-                state[at] = state[at].at[slot].set(
-                    int(self.gen._cs.trans[adm.dfa_state, int(first[0])])
+                state[at] = self._issue(
+                    self._slot_set_fn, state[at], np.int32(slot),
+                    np.int32(self.gen._cs.trans[adm.dfa_state, int(first[0])]),
                 )
                 self._carry = tuple(state)
             # drop the row references promptly: the donated buffers are dead
-            adm.row_cache = adm.d_row_cache = adm.last = adm.import_pages = None
+            adm.row_cache = adm.d_row_cache = adm.last = adm.d_last = adm.import_pages = None
         except BaseException as exc:
             with self._lock:
                 if adm in self._admissions:
