@@ -46,6 +46,7 @@ MODULES = [
     "unionml_tpu.models.vit",
     "unionml_tpu.models.mlp",
     "unionml_tpu.models.moe",
+    "unionml_tpu.models.glm4_moe_lite",
     "unionml_tpu.ops.attention",
     "unionml_tpu.ops.ring_attention",
     "unionml_tpu.ops.quant",

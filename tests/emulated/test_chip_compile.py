@@ -196,6 +196,65 @@ def test_afmoe_decode_steps_compiles_for_v5e(chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
 
 
+def _glm():
+    from unionml_tpu.models import Glm4MoeLiteConfig, Glm4MoeLiteTransformer
+
+    return Glm4MoeLiteTransformer(Glm4MoeLiteConfig(
+        vocab_size=19360, n_layers=3, experts_held=(0, 8), attention_impl="flash", param_dtype=jnp.bfloat16,
+    ))
+
+
+def test_latent_decode_steps_compile_for_v5e_over_one_plane(chip):
+    """The glm4_moe_lite decode program at published widths (a dense and two expert layers, 8 held of 64 experts) over
+    the long_sat cell's paged latent cache — 48 slots, 140 pages a row, one plane of 640 (576 in whole lanes) a
+    layer, no ``"v"`` — through the kernel read (forced, as above): Mosaic takes the library kernel with the latent
+    pages as K and as V, one launch a layer; no pool is re-laid for it and no gathered copy of the rows' tables
+    (``[48, 8960, 640]`` bf16) is left; the program counts its seven counters into the carry."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_paged_cache
+
+    slots, pages, pool, page = 48, 140, 3585, 64
+    module = _glm()
+    config, on_chip = module.config, functools.partial(_on_chip, chip)
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
+    assert set(cache[0]) == {"k", "table"} and cache[0]["k"].shape == (1, pool, page, 640)
+    tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
+    counts = jax.ShapeDtypeStruct((7,), jnp.int32, sharding=chip)
+    gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
+    compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
+    assert gen.decode_attention_path == "latent_paged_kernel" and gen.counter_names[-3] == "latent_positions_read"
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 3  # one read a layer (the routed product is XLA's own here)
+    assert not re.search(rf"= bf16\[1,{pool},{page},640\]\S* copy\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < slots * pages * page * 640 * 2
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
+
+
+def test_latent_prefill_chunk_compiles_for_v5e(chip):
+    """A 256-token chunk over an 8,960-position latent row cache (the cell's admission), by the absorbed read: the row
+    is one plane ``[1, 8960, 1, 640]`` a layer (11 MB; XLA re-lays it once a layer on the way out, 0.03 ms at the
+    chip's peak: PERF.md section 7), and the program's temporaries stay under 0.5 GB (30 MB in this compile: the
+    scores ``[20, 256, 8960]`` never stand whole; the expanded read's up-projected row left 273 MB)."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_cache
+
+    module, chunk, cache_len = _glm(), 256, 8960
+    on_chip = functools.partial(_on_chip, chip)
+
+    def scalar(dtype, shape=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    row = on_chip(lambda: init_cache(module.config, 1, cache_len))
+    assert set(row[0]) == {"k"} and row[0]["k"].shape == (1, cache_len, 1, 640)
+    gen = Generator(module, params, GenerationConfig(max_new_tokens=768, temperature=0.0, prompt_buckets=(256,)))
+    args = (params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), scalar(jnp.int32, (1,)), row, scalar(jnp.bool_, (1,)), scalar(jnp.float32, (1, 2048)))
+    compiled = gen._prefill_chunk.lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
 #: the saturated cells' engines (perf/configs/*.json "engine" + perf/workloads/*.json): module, longest prompt, answer
 #: budget, engine options
 ADMISSION_SHAPES = {

@@ -10,6 +10,11 @@ from unionml_tpu.models.generate import (  # noqa: F401
     init_cache,
     sample_tokens,
 )
+from unionml_tpu.models.glm4_moe_lite import (  # noqa: F401
+    Glm4MoeLiteConfig,
+    Glm4MoeLiteTransformer,
+    glm4_moe_lite_partition_rules,
+)
 from unionml_tpu.models.speculative import SpeculativeGenerator  # noqa: F401
 from unionml_tpu.models.structured import (  # noqa: F401
     ConstraintSet,

@@ -134,30 +134,43 @@ def _head_dim(config: Any) -> int:
     return int(getattr(config, "head_dim", None) or config.dim // config.n_heads)
 
 
+def cache_layout(config: Any, kv_dtype: Optional[str] = None) -> Dict[str, Tuple[int, int, Any]]:
+    """A layer's cache planes by name, each ``(heads, width, dtype)``: what the
+    configuration states (``config.cache_layout``: ``{name: (heads, width)}`` in
+    the compute dtype — a latent layer's ``{"k": (1, 640)}``, no ``"v"``), else
+    keys and values at ``n_kv_heads`` heads of the configuration's own head
+    width. ``kv_dtype="int8"`` stores those two int8 beside per-(position, head)
+    float32 scale planes; a stated layout has no such form."""
+    if kv_dtype not in (None, "int8"):
+        raise ValueError(f"unsupported kv_cache_dtype {kv_dtype!r}; expected None or 'int8'")
+    stated = getattr(config, "cache_layout", None)
+    if stated:
+        if kv_dtype == "int8":
+            raise ValueError(
+                f"kv_cache_dtype='int8' over a stated cache layout ({dict(stated)}): int8 pages are "
+                "per-head keys and values with a scale a (position, head); a latent plane has none"
+            )
+        return {name: (int(heads), int(width), config.dtype) for name, (heads, width) in stated.items()}
+    heads, width = config.n_kv_heads, _head_dim(config)
+    if kv_dtype == "int8":
+        return {
+            "k": (heads, width, jnp.int8), "v": (heads, width, jnp.int8),
+            "k_scale": (heads, 1, jnp.float32), "v_scale": (heads, 1, jnp.float32),
+        }
+    return {"k": (heads, width, config.dtype), "v": (heads, width, config.dtype)}
+
+
 def init_cache(config: Any, batch: int, cache_len: int, kv_dtype: Optional[str] = None) -> Tuple[Any, ...]:
-    """Zeroed per-layer KV buffers for a decoder with ``config.n_layers`` layers,
-    ``config.n_kv_heads`` KV heads and the configuration's own head width
-    (``head_dim``, else ``dim // n_heads``), stored in the
+    """Zeroed per-layer cache buffers ``[batch, cache_len, heads, width]`` for a
+    decoder with ``config.n_layers`` layers, one per plane of
+    :func:`cache_layout`: by default ``config.n_kv_heads`` KV heads and the
+    configuration's own head width (``head_dim``, else ``dim // n_heads``), stored in the
     compute dtype (bf16 on TPU — halves cache HBM vs f32). ``kv_dtype="int8"``
     adds per-(position, head) scale planes and stores values int8 (see
     :class:`~unionml_tpu.models.layers.Attention`'s cached branch)."""
-    head_dim = _head_dim(config)
-    shape = (batch, cache_len, config.n_kv_heads, head_dim)
-    if kv_dtype == "int8":
-        scale_shape = (batch, cache_len, config.n_kv_heads, 1)
-        return tuple(
-            {
-                "k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(scale_shape, jnp.float32),
-                "v_scale": jnp.zeros(scale_shape, jnp.float32),
-            }
-            for _ in range(config.n_layers)
-        )
-    if kv_dtype is not None:
-        raise ValueError(f"unsupported kv_cache_dtype {kv_dtype!r}; expected None or 'int8'")
+    layout = cache_layout(config, kv_dtype)
     return tuple(
-        {"k": jnp.zeros(shape, config.dtype), "v": jnp.zeros(shape, config.dtype)}
+        {name: jnp.zeros((batch, cache_len, heads, width), dtype) for name, (heads, width, dtype) in layout.items()}
         for _ in range(config.n_layers)
     )
 
@@ -177,7 +190,8 @@ def init_paged_cache(
     initialized to ``fill_block``. Pools are HEADS-MAJOR
     (``[H_kv, n_blocks, block_size, D]``) — the layout
     ``jax.experimental.pallas.ops.tpu.paged_attention`` consumes directly, so
-    the kernel path needs no transpose.
+    the kernel path needs no transpose. The planes are :func:`cache_layout`'s:
+    a latent layer has one, ``{"k": [1, n_blocks, block_size, W], "table"}``.
     ``fill_block`` is REQUIRED and must be a reserved scratch block (allocate
     ``n_blocks = real + 1`` and pass ``fill_block = real``, as
     ``ContinuousBatcher._init_carry`` does): free and finished slots keep
@@ -187,28 +201,15 @@ def init_paged_cache(
     layer (same values; a few hundred bytes). See
     :meth:`unionml_tpu.models.layers.Attention._paged_cached_attention` for the
     read/write contract; HBM scales with the pool, not slots x worst-case."""
-    head_dim = _head_dim(config)
-    shape = (config.n_kv_heads, n_blocks, block_size, head_dim)
+    layout = cache_layout(config, kv_dtype)
     # one table PER layer (same values): the cache is donated through admission
     # and decode, and donating an array aliased across layers is an XLA error
     # ("donate the same buffer twice"); the duplication is a few hundred bytes
-    table = lambda: jnp.full((slots, max_blocks), fill_block, jnp.int32)  # noqa: E731
-    if kv_dtype == "int8":
-        scale_shape = (config.n_kv_heads, n_blocks, block_size, 1)
-        return tuple(
-            {
-                "k": jnp.zeros(shape, jnp.int8),
-                "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(scale_shape, jnp.float32),
-                "v_scale": jnp.zeros(scale_shape, jnp.float32),
-                "table": table(),
-            }
-            for _ in range(config.n_layers)
-        )
-    if kv_dtype is not None:
-        raise ValueError(f"unsupported kv_cache_dtype {kv_dtype!r}; expected None or 'int8'")
     return tuple(
-        {"k": jnp.zeros(shape, config.dtype), "v": jnp.zeros(shape, config.dtype), "table": table()}
+        {
+            **{name: jnp.zeros((heads, n_blocks, block_size, width), dtype) for name, (heads, width, dtype) in layout.items()},
+            "table": jnp.full((slots, max_blocks), fill_block, jnp.int32),
+        }
         for _ in range(config.n_layers)
     )
 
@@ -700,6 +701,8 @@ class Generator:
         mesh = self.mesh
         sp_module = type(self.module)(_dc.replace(self.module.config, attention_impl=cfg.sp_prefill))
         n_layers = self.module.config.n_layers
+        #: what each layer's attention sows for the cache: its layout's planes (keys and values; a latent layer's one)
+        planes = tuple(cache_layout(self.module.config))
         compute_dtype = getattr(self.module.config, "dtype", jnp.bfloat16)
         data_axes = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1) or None
 
@@ -716,14 +719,12 @@ class Generator:
                 mutable=["kvs"],
             )
             kvs = variables["kvs"]
-            ks = tuple(kvs[f"layer_{i}"]["attn"]["k"][0] for i in range(n_layers))
-            vs = tuple(kvs[f"layer_{i}"]["attn"]["v"][0] for i in range(n_layers))
-            return hidden, ks, vs
+            return hidden, tuple({name: kvs[f"layer_{i}"]["attn"][name][0] for name in planes} for i in range(n_layers))
 
         tok_spec = P(data_axes, "sequence")
         act_spec = P(data_axes, "sequence", None)
         kv_spec = P(data_axes, "sequence", None, None)
-        out_specs = (act_spec, (kv_spec,) * n_layers, (kv_spec,) * n_layers)
+        out_specs = (act_spec, ({name: kv_spec for name in planes},) * n_layers)
         in_specs = (tok_spec, tok_spec, P())
         wrapped = jax.shard_map(
             local_fwd, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
@@ -735,29 +736,19 @@ class Generator:
             # pad columns and synthetic batch rows must not claim routed-expert
             # capacity — same contract as the dense prefill's token_mask
             token_mask = (jnp.arange(tokens.shape[1])[None] < lengths[:, None]) & row_valid[:, None]
-            hidden, ks, vs = wrapped(tokens, token_mask, p)
+            hidden, sown = wrapped(tokens, token_mask, p)
             new_cache = []
-            for i in range(n_layers):
-                layer = cache[i]
-                if "k_scale" in layer:
-                    kq, k_scale = quantize_kv_rows(ks[i])
-                    vq, v_scale = quantize_kv_rows(vs[i])
-                    layer = {
-                        "k": jax.lax.dynamic_update_slice(layer["k"], kq, (0, 0, 0, 0)),
-                        "v": jax.lax.dynamic_update_slice(layer["v"], vq, (0, 0, 0, 0)),
-                        "k_scale": jax.lax.dynamic_update_slice(layer["k_scale"], k_scale, (0, 0, 0, 0)),
-                        "v_scale": jax.lax.dynamic_update_slice(layer["v_scale"], v_scale, (0, 0, 0, 0)),
-                    }
-                else:
-                    layer = {
-                        "k": jax.lax.dynamic_update_slice(
-                            layer["k"], ks[i].astype(layer["k"].dtype), (0, 0, 0, 0)
-                        ),
-                        "v": jax.lax.dynamic_update_slice(
-                            layer["v"], vs[i].astype(layer["v"].dtype), (0, 0, 0, 0)
-                        ),
-                    }
-                new_cache.append(layer)
+            for layer, rows in zip(cache, sown):
+                new_layer = {}
+                for name in planes:
+                    if f"{name}_scale" in layer:
+                        values, scales = quantize_kv_rows(rows[name])
+                        written = {name: values, f"{name}_scale": scales}
+                    else:
+                        written = {name: rows[name].astype(layer[name].dtype)}
+                    for plane, value in written.items():  # a sown row narrower than its plane leaves the tail zeros
+                        new_layer[plane] = jax.lax.dynamic_update_slice(layer[plane], value, (0, 0, 0, 0))
+                new_cache.append(new_layer)
             last = jnp.take_along_axis(hidden, (lengths - 1)[:, None, None], axis=1)[:, 0]
             logits = self._constrain(self._head_fn(p, last.astype(compute_dtype)), cstate)
             tok0 = sample_tokens(logits, key, cfg)

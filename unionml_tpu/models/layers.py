@@ -50,6 +50,31 @@ def quantize_kv_rows(x: jax.Array):
     return rows.astype(jnp.int8), scale
 
 
+def _paged_scatter(pool: jax.Array, rows: jax.Array, blk: jax.Array, off: jax.Array) -> jax.Array:
+    """Write ``rows [B, L, H, last]`` into the heads-major ``pool [H, n_pages, page, last]`` at
+    each token's ``(blk, off) [B, L]``: ``pool[:, blk, off]`` has shape ``[H, B, L, last]``."""
+    return pool.at[:, blk, off].set(jnp.moveaxis(rows, 2, 0).astype(pool.dtype))
+
+
+def _paged_scatter_rows(pool: jax.Array, rows: jax.Array, blk: jax.Array, off: jax.Array) -> jax.Array:
+    """The kernel path's write (``L == 1``). The kernel reads the pools row-major; the
+    scatter of ``[H, last]`` slabs above makes XLA keep them heads-minor and copy
+    every pool, every layer, every step. As ``H * B`` rows of ``last`` it leaves them be."""
+    n_kv, n_pages, block_size = pool.shape[:3]
+    at = (jnp.arange(n_kv)[:, None] * n_pages + blk[:, 0]) * block_size + off[:, 0]  # [H, B]
+    flat = pool.reshape(-1, pool.shape[-1]).at[at.reshape(-1)].set(
+        jnp.moveaxis(rows[:, 0], 1, 0).reshape(-1, rows.shape[-1]).astype(pool.dtype)
+    )
+    return flat.reshape(pool.shape)
+
+
+def _paged_logical(pool: jax.Array, table: jax.Array) -> jax.Array:
+    """Every row's whole block table gathered back to the logical ``[B, MB * page, H, last]``."""
+    rows = pool[:, table]  # [H, B, MB, page, last]
+    rows = rows.reshape(rows.shape[0], rows.shape[1], -1, rows.shape[-1])
+    return jnp.transpose(rows, (1, 2, 0, 3))
+
+
 class RMSNorm(nn.Module):
     """Root-mean-square layer norm (pre-norm default for decoder stacks)."""
 
@@ -365,25 +390,9 @@ class Attention(nn.Module):
         blk = jnp.take_along_axis(table, positions // block_size, axis=1)  # [B, L]
         off = positions % block_size
 
-        def scatter(pool: jax.Array, rows: jax.Array) -> jax.Array:
-            # rows [B, L, H_kv, last] -> pool[:, blk, off] has shape [H_kv, B, L, last]
-            return pool.at[:, blk, off].set(jnp.moveaxis(rows, 2, 0).astype(pool.dtype))
-
-        def scatter_rows(pool: jax.Array, rows: jax.Array) -> jax.Array:
-            # the kernel path's write (L == 1). The kernel reads the pools row-major; the
-            # scatter of [H_kv, last] slabs above makes XLA keep them heads-minor and copy
-            # every pool, every layer, every step. As H_kv * B rows of ``last`` it leaves them be.
-            n_kv, n_pages = pool.shape[:2]
-            at = (jnp.arange(n_kv)[:, None] * n_pages + blk[:, 0]) * block_size + off[:, 0]  # [H_kv, B]
-            flat = pool.reshape(-1, pool.shape[-1]).at[at.reshape(-1)].set(
-                jnp.moveaxis(rows[:, 0], 1, 0).reshape(-1, rows.shape[-1]).astype(pool.dtype)
-            )
-            return flat.reshape(pool.shape)
-
-        def logical(pool: jax.Array) -> jax.Array:
-            rows = pool[:, table]  # [H_kv, B, MB, bs, last]
-            rows = rows.reshape(rows.shape[0], rows.shape[1], -1, rows.shape[-1])
-            return jnp.transpose(rows, (1, 2, 0, 3))  # [B, MB * bs, H_kv, last]
+        scatter = functools.partial(_paged_scatter, blk=blk, off=off)
+        scatter_rows = functools.partial(_paged_scatter_rows, blk=blk, off=off)
+        logical = functools.partial(_paged_logical, table=table)
 
         path = paged_read_path(self.impl, q, cache["k"], quantized="k_scale" in cache)
         if "k_scale" in cache:
@@ -420,6 +429,197 @@ class Attention(nn.Module):
             values = logical(cache["v"]).astype(q.dtype)
         visible = self._visible(jnp.arange(keys.shape[1]), positions)  # [B, 1, L, MB * bs]
         return multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla"), cache
+
+def _masked_softmax(scores: jax.Array, visible: jax.Array, dtype: Dtype) -> jax.Array:
+    """Softmax over the last axis in float32 under ``visible`` (True = attend), as
+    :func:`unionml_tpu.ops.attention.dot_product_attention` does it: masked scores
+    take the type's minimum and a row that sees nothing is zero."""
+    scores = jnp.where(visible, scores, jnp.finfo(scores.dtype).min)
+    weights = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(dtype)
+    return jnp.where(visible.any(axis=-1, keepdims=True), weights, 0)
+
+
+class _Kernel(nn.Module):
+    """A bare ``kernel`` parameter under its own name (``experts/wg/kernel``,
+    ``attn/kv_up/kernel``): the paths the partition rules and the int8 weight
+    quantizer match on, for a matrix its layer reads other than as one product
+    (stacked experts; :class:`LatentAttention`'s ``kv_up``, read in slices)."""
+
+    shape: Any
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self) -> jax.Array:
+        stacked = tuple(range(len(self.shape) - 2))  # an expert's fan-in is its own
+        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=stacked)
+        return self.param("kernel", init, tuple(self.shape), self.param_dtype)
+
+
+def latent_cache_width(kv_rank: int, rope_dim: int) -> int:
+    """The stored width of a latent row: ``kv_rank + rope_dim`` rounded up to whole
+    lanes (128), the tail zeros — the paged-attention kernel takes whole lanes only
+    (576 -> 640: Mosaic refuses a 576-wide block, tests/emulated/test_chip_compile.py)."""
+    return -(-(kv_rank + rope_dim) // 128) * 128
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA, the DeepSeek-V2/V3 block): queries and
+    key-values pass through low-rank bottlenecks with their own RMS norms, and
+    the cache holds neither keys nor values but one latent row a token,
+    ``[c_kv (kv_rank) | k_rope (rope_dim)]`` — after the norm, after the rotary
+    — shared by all heads. With ``a`` the normed stream::
+
+        c_q = RMSNorm(q_down a);  q_h = q_up_h c_q = [q_nope_h (nope) | q_rope_h (rope)];  q_rope <- rotary
+        [c | r] = kv_down a;      c_kv = RMSNorm(c);  k_rope = rotary(r), one head for all
+        expanded:  [k_nope_h | v_h] = kv_up_h c_kv;  s_h = (q_nope_h . k_nope_h + q_rope_h . k_rope) * scale
+                   o_h = softmax(s_h) v_h
+        absorbed:  qt_h = kv_up_h[:, :nope] q_nope_h  (kv_rank);  s_h = (qt_h . c_kv + q_rope_h . k_rope) * scale
+                   o_h = kv_up_h[:, nope:]^T (softmax(s_h) c_kv)
+        out = o_proj [o_1 .. o_H],   scale = (nope + rope) ** -0.5
+
+    The two reads give the same numbers: the expanded one up-projects every key
+    position (cheap per query-key pair, ``H * (nope + v)`` values a position to
+    build), the absorbed one attends on the latent itself (``H`` query heads of
+    ``kv_rank + rope`` on one shared "KV head"; nothing is built). The uncached
+    forward is expanded; every cached read is absorbed. One token (decode) over a
+    paged pool goes where :func:`~unionml_tpu.ops.paged_attention.paged_read_path`
+    says — on a TPU the paged-attention kernel with the latent pages as K and as
+    V, else the gather. Several tokens (a prefill chunk over the row cache, a
+    verify) attend on the row's latent under a mask: measured on a v5e, a
+    256-token chunk over an 8,960-position row costs 0.86 ms a layer absorbed
+    and 1.17 expanded (PERF.md section 6, "PR 30"), so the expanded cached read
+    was not kept.
+
+    The cache is one plane, ``{"k": [B, S, 1, width]}`` (paged: ``[1, n_pages,
+    page, width]`` + ``table``) whose ``width`` is the cache's own (the model's
+    ``cache_layout``; :func:`latent_cache_width`); channels past ``kv_rank +
+    rope_dim`` are zeros.
+    ``kv_up``'s two halves are slices taken inside the program; no second copy
+    of it is held. Counts into the ``counters`` collection:
+    ``latent_positions_read`` (one-token reads: the live rows' lengths, what the
+    read had to cover), ``latent_positions_attended`` (several-token reads: key
+    positions the read covered, masked or not) and ``latent_positions_needed``
+    (of those, the positions up to each live row's last query: what causality needs)."""
+
+    n_heads: int
+    q_rank: int
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    v_dim: int
+    rope_theta: float = 10000.0
+    norm_epsilon: float = 1e-6
+    impl: str = "auto"
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.float32
+
+    @nn.compact
+    def __call__(
+        self,
+        x: jax.Array,
+        positions: Optional[jax.Array] = None,
+        mask: Optional[jax.Array] = None,
+        cache: Optional[LayerCache] = None,
+        token_mask: Optional[jax.Array] = None,
+    ) -> Any:
+        features, heads = x.shape[-1], self.n_heads
+        batch, length = x.shape[0], x.shape[1]
+        dense = lambda feats, name: LoRADense(feats, dtype=self.dtype, param_dtype=self.param_dtype, name=name)  # noqa: E731
+        norm = lambda name: RMSNorm(epsilon=self.norm_epsilon, dtype=self.dtype, name=name)  # noqa: E731
+        if positions is None:
+            positions = jnp.arange(length)
+
+        with jax.named_scope("mla.q_path"):
+            q = dense(heads * (self.nope_dim + self.rope_dim), "q_up")(norm("q_norm")(dense(self.q_rank, "q_down")(x)))
+            q = q.reshape(batch, length, heads, self.nope_dim + self.rope_dim)
+            q_nope = q[..., : self.nope_dim]
+            q_rope = rotary_embedding(q[..., self.nope_dim :], positions, self.rope_theta)
+        with jax.named_scope("mla.kv_path"):
+            down = dense(self.kv_rank + self.rope_dim, "kv_down")(x)
+            c_kv = norm("kv_norm")(down[..., : self.kv_rank])
+            k_rope = rotary_embedding(down[..., None, self.kv_rank :], positions, self.rope_theta)  # [B, L, 1, rope]
+            latent = jnp.concatenate([c_kv[:, :, None], k_rope], axis=-1)  # [B, L, 1, kv_rank + rope]
+        kv_up = _Kernel(
+            (self.kv_rank, heads * (self.nope_dim + self.v_dim)), self.param_dtype, name="kv_up"
+        )().astype(self.dtype).reshape(self.kv_rank, heads, self.nope_dim + self.v_dim)
+        scale = (self.nope_dim + self.rope_dim) ** -0.5
+
+        def project(out: jax.Array) -> jax.Array:  # [B, L, H, v_dim]
+            return dense(features, "o_proj")(out.reshape(batch, length, heads * self.v_dim))
+
+        def expanded(rows: jax.Array, visible: jax.Array) -> jax.Array:
+            """Attend over latent ``rows [B, S, 1, >= kv_rank + rope]`` by up-projecting them."""
+            with jax.named_scope("mla.expand"):
+                kv = jnp.einsum("bsc,chd->bshd", rows[:, :, 0, : self.kv_rank].astype(self.dtype), kv_up)
+                keys_rope = rows[:, :, 0, self.kv_rank : self.kv_rank + self.rope_dim].astype(self.dtype)
+                scores = jnp.einsum("blhd,bshd->bhls", q_nope, kv[..., : self.nope_dim])
+                scores = (scores + jnp.einsum("blhd,bsd->bhls", q_rope, keys_rope)) * scale
+                return jnp.einsum("bhls,bshd->blhd", _masked_softmax(scores, visible, self.dtype), kv[..., self.nope_dim :])
+
+        def absorb() -> jax.Array:
+            """The query in the latent's own space, ``[B, L, H, kv_rank + rope]``."""
+            with jax.named_scope("mla.absorb"):
+                return jnp.concatenate([jnp.einsum("blhd,chd->blhc", q_nope, kv_up[..., : self.nope_dim]), q_rope], axis=-1)
+
+        def unabsorb(out: jax.Array) -> jax.Array:  # [B, L, H, kv_rank] -> [B, L, H, v_dim]
+            with jax.named_scope("mla.absorb"):
+                return jnp.einsum("blhc,chd->blhd", out.astype(self.dtype), kv_up[..., self.nope_dim :])
+
+        def absorbed(rows: jax.Array, visible: jax.Array) -> jax.Array:
+            """Attend on latent ``rows`` themselves; nothing is built a key position."""
+            q_abs = absorb()
+            with jax.named_scope("mla.absorb"):
+                keys = rows[:, :, 0, : self.kv_rank + self.rope_dim].astype(self.dtype)
+                weights = _masked_softmax(jnp.einsum("blhw,bsw->bhls", q_abs, keys) * scale, visible, self.dtype)
+                out = jnp.einsum("bhls,bsc->blhc", weights, keys[..., : self.kv_rank])
+            return unabsorb(out)
+
+        if cache is None:
+            self.sow("kvs", "k", latent)  # the post-norm, post-rotary latent, for a caller that assembles a cache
+            at = jnp.broadcast_to(positions, (batch, length)) if positions.ndim == 1 else positions
+            visible = at[:, None, :, None] >= at[:, None, None, :]  # causal
+            if mask is not None:
+                visible = jnp.logical_and(visible, mask)
+            return project(expanded(latent, visible))
+
+        if positions.ndim != 2:
+            raise ValueError("cached attention requires per-example positions [B, L]")
+        if mask is not None:
+            raise NotImplementedError("cached attention builds its own mask")
+        width = cache["k"].shape[-1]
+        stored = jnp.pad(latent, ((0, 0), (0, 0), (0, 0), (0, width - latent.shape[-1])))  # whole lanes: the tail is zeros
+        live = jnp.ones((batch, length), bool) if token_mask is None else token_mask
+        lengths = positions[:, 0] + 1  # a one-token read sees the token just written
+        if length == 1:
+            self.sow("counters", "latent_positions_read", jnp.sum(jnp.where(live[:, 0], lengths, 0), dtype=jnp.int32))
+        if "table" in cache:
+            from unionml_tpu.ops.paged_attention import LATENT_KERNEL, paged_latent_decode_attention, paged_read_path
+
+            table, block_size = cache["table"], cache["k"].shape[2]
+            blk = jnp.take_along_axis(table, positions // block_size, axis=1)
+            off = positions % block_size
+            if paged_read_path(self.impl, q, cache["k"], quantized=False, latent=True) == LATENT_KERNEL:
+                cache = {"k": _paged_scatter_rows(cache["k"], stored, blk, off), "table": table}
+                q_abs = jnp.pad(absorb()[:, 0], ((0, 0), (0, 0), (0, width - self.kv_rank - self.rope_dim)))
+                with jax.named_scope("mla.decode_read"):
+                    out = paged_latent_decode_attention(
+                        q_abs, cache["k"], lengths, table, scale=scale, value_width=self.kv_rank
+                    )
+                return project(unabsorb(out[:, None])), cache
+            cache = {"k": _paged_scatter(cache["k"], stored, blk, off), "table": table}
+            rows = _paged_logical(cache["k"], table)
+        else:
+            cache = {"k": _write_cache(cache["k"], stored, positions[:, 0])}
+            rows = cache["k"]
+        visible = jnp.arange(rows.shape[1])[None, None, None, :] <= positions[:, None, :, None]  # [B, 1, L, S]
+        if length == 1:
+            with jax.named_scope("mla.decode_read"):
+                return project(absorbed(rows, visible)), cache
+        self.sow("counters", "latent_positions_attended", jnp.sum(live.any(axis=1), dtype=jnp.int32) * rows.shape[1])
+        self.sow(
+            "counters", "latent_positions_needed", jnp.sum(jnp.max(jnp.where(live, positions + 1, 0), axis=1), dtype=jnp.int32)
+        )
+        return project(absorbed(rows, visible)), cache
 
 
 class MLP(nn.Module):

@@ -37,7 +37,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax.sharding import PartitionSpec as P
 
-from unionml_tpu.models.layers import MLP, Attention, IotaEmbed, RMSNorm
+from unionml_tpu.models.layers import MLP, Attention, IotaEmbed, RMSNorm, _Kernel
 from unionml_tpu.parallel.sharding import PartitionRules
 
 Dtype = Any
@@ -191,20 +191,6 @@ def grouped_matmul(rows: jax.Array, kernels: jax.Array, group_sizes: jax.Array) 
     # 1024-wide tiles: of those tried (512 .. 3072) none was more than 3 % faster, and these fit any width's VMEM
     out = gmm(padded, kernels, group_sizes, preferred_element_type=rows.dtype, tiling=(tile, 1024, 1024))
     return out[:m]
-
-
-class _Kernel(nn.Module):
-    """A bare ``kernel`` parameter under its own name (``experts/wg/kernel``): the
-    paths the partition rules and the int8 weight quantizer match on."""
-
-    shape: Tuple[int, ...]
-    param_dtype: Dtype = jnp.float32
-
-    @nn.compact
-    def __call__(self) -> jax.Array:
-        stacked = tuple(range(len(self.shape) - 2))  # an expert's fan-in is its own
-        init = nn.initializers.variance_scaling(1.0, "fan_in", "normal", in_axis=-2, out_axis=-1, batch_axis=stacked)
-        return self.param("kernel", init, self.shape, self.param_dtype)
 
 
 class _HeldExperts(nn.Module):

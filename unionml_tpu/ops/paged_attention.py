@@ -30,8 +30,11 @@ import jax.numpy as jnp
 
 __all__ = [
     "GATHER",
+    "LATENT_GATHER",
+    "LATENT_KERNEL",
     "PAGED_KERNEL",
     "paged_decode_attention",
+    "paged_latent_decode_attention",
     "paged_read_path",
     "paged_read_scope",
     "paged_window_decode_attention",
@@ -41,6 +44,9 @@ __all__ = [
 #: the two paths a paged read can take, as ``stats()["decode_attention_path"]`` names them
 PAGED_KERNEL = "paged_kernel"
 GATHER = "gather"
+#: the same two over a latent plane (one shared "KV head" whose key is the latent row and whose value is its head)
+LATENT_KERNEL = "latent_paged_kernel"
+LATENT_GATHER = "latent_gather"
 
 _scope = threading.local()
 
@@ -62,7 +68,7 @@ def paged_read_scope(*, sharded: bool) -> Iterator[List[str]]:
         _scope.current = previous
 
 
-def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: bool) -> str:
+def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: bool, latent: bool = False) -> str:
     """Which read serves ``q: [B, L, H, D]`` over ``k_pages: [H_kv, n_pages,
     page_size, D]``, decided from what the trace can observe and recorded in
     the enclosing :func:`paged_read_scope`.
@@ -75,6 +81,14 @@ def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: b
     it is known to run and to win: a TPU backend, bf16 pages with a lane-wide
     head (``D % 128 == 0``), and pools on one device (outside any scope a
     caller is taken to hold its pools on one device).
+
+    ``latent``: ``k_pages`` is a latent plane (``[1, n_pages, page_size, W]``,
+    :class:`unionml_tpu.models.layers.LatentAttention`) and ``q`` the heads'
+    queries before absorption; the same rules decide, on the plane's stored
+    width ``W`` (576 values stored unpadded gather; whole lanes, 640, take the
+    kernel: :func:`paged_latent_decode_attention`), and the path is named
+    :data:`LATENT_KERNEL` or :data:`LATENT_GATHER`. A latent plane is never
+    sharded (one head), but its queries' heads are: under a mesh it gathers.
     """
     sharded, paths = getattr(_scope, "current", None) or (False, None)
     kernel = q.shape[1] == 1 and not quantized and impl in ("flash", "auto")
@@ -85,7 +99,10 @@ def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: b
             and k_pages.shape[-1] % 128 == 0
             and not sharded
         )
-    path = PAGED_KERNEL if kernel else GATHER
+    if latent:
+        path = LATENT_KERNEL if kernel else LATENT_GATHER
+    else:
+        path = PAGED_KERNEL if kernel else GATHER
     if paths is not None:
         paths.append(path)
     return path
@@ -138,7 +155,6 @@ def paged_decode_attention(
     ``head_dim ** -0.5`` here — numerics then match
     :func:`unionml_tpu.ops.attention.dot_product_attention` and the gather path.
     """
-    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
     from jax.experimental.pallas.ops.tpu.paged_attention import quantization_utils
 
     if (k_scales is None) != (v_scales is None):
@@ -152,14 +168,21 @@ def paged_decode_attention(
         v_pages = quantization_utils.QuantizedTensor(
             weight=v_pages, scales=(v_scales * quantization_utils.MAX_INT8).astype(jnp.float32)
         )
+    # f32 in, so the softmax scale is not rounded into a bf16 query; the kernel
+    # returns its launch dtype (f32 here), the caller gets the query's own
+    out = _launch(q.astype(jnp.float32) * q.shape[-1] ** -0.5, k_pages, v_pages, lengths, page_indices, page_size, ppcb)
+    return out.astype(q.dtype)
+
+
+def _launch(q, k_pages, v_pages, lengths, page_indices, page_size: int, ppcb: int) -> jax.Array:
+    """The library's paged-attention kernel over a table of any width (``q`` pre-scaled: the kernel applies none)."""
+    from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention
+
     if page_indices.shape[1] % ppcb:
         # the kernel tiles the table exactly: widen it with page 0, which no length reaches
         page_indices = jnp.pad(page_indices, ((0, 0), (0, -page_indices.shape[1] % ppcb)))
-    # f32 in, so the softmax scale is not rounded into a bf16 query; the kernel
-    # returns its launch dtype (f32 here), the caller gets the query's own
-    scale = q.shape[-1] ** -0.5
-    out = paged_attention(
-        q.astype(jnp.float32) * scale,
+    return paged_attention(
+        q,
         k_pages,
         v_pages,
         # a length past the table's end would send the kernel's DMAs off the row's pages
@@ -167,7 +190,41 @@ def paged_decode_attention(
         page_indices,
         pages_per_compute_block=ppcb,
     )
-    return out.astype(q.dtype)
+
+
+def paged_latent_decode_attention(
+    q_abs: jax.Array,
+    pages: jax.Array,
+    lengths: jax.Array,
+    page_indices: jax.Array,
+    *,
+    scale: float,
+    value_width: int,
+    pages_per_compute_block: Optional[int] = None,
+) -> jax.Array:
+    """One decode step of ABSORBED latent attention over paged latent rows.
+
+    ``q_abs: [B, H, W]`` (each head's query in the latent's space, zeros past
+    the latent's real width), ``pages: [1, n_pages, page_size, W]`` (one plane:
+    the key of every head is the whole row, the value its first ``value_width``
+    channels), ``lengths``/``page_indices`` as :func:`paged_decode_attention`.
+    Returns ``[B, H, value_width]``.
+
+    The library's paged-attention kernel launched with the plane as K and as V:
+    one KV head, ``H`` query heads in its group, ``q`` pre-scaled by ``scale``
+    (the kernel applies none; the latent's softmax scale is the expanded head's,
+    not ``W ** -0.5``), the output's first ``value_width`` channels kept. Each
+    live page is DMA'd twice (once as K, once as V), at the stored width: 2 x W
+    values a position against the latent's ``value_width + rope``; a kernel that
+    reads a page once would halve it (PERF.md section 7). ``W`` must be whole
+    lanes (Mosaic refuses a 576-wide block)."""
+    page_size = pages.shape[2]
+    # 512 positions a compute block whatever the table's width: measured on a v5e at the long_sat cell's shape (48
+    # rows x 140 pages of 64 x 640, 24 live at 3,920 and 24 free): 4 pages 0.476 ms, 8 0.431, 16 0.475 (PERF.md
+    # section 6, "PR 30"); a free row streams one block whole, and a 640-wide block is five times a 128-wide one
+    ppcb = pages_per_compute_block or max(1, min(512 // page_size, _pages_per_block(page_indices.shape[1], page_size)))
+    out = _launch(q_abs.astype(jnp.float32) * scale, pages, pages, lengths, page_indices, page_size, ppcb)
+    return out[..., :value_width].astype(q_abs.dtype)
 
 
 def window_split(lengths: jax.Array, window: int, page_size: int):

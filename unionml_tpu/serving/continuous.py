@@ -98,6 +98,7 @@ from unionml_tpu.models.generate import (
     PrefixCache,
     chunk_aligned,
     gather_paged_rows,
+    cache_layout,
     init_cache,
     init_paged_cache,
     paste_prefix_rows,
@@ -111,6 +112,12 @@ _SENTINEL = object()
 #: benchmark cell runs and ``ops/paged_attention.py`` ``_pages_per_block`` was
 #: measured at
 KV_BLOCK_SIZE = 64
+
+
+def _plane(layer: Any) -> Any:
+    """One of a cache layer's planes (not its table): the shape oracle of the
+    programs that move rows and pages, whatever the model's layout names."""
+    return next(buf for name, buf in layer.items() if name != "table")
 
 
 def _tev(session: "_Session", name: str, **attrs: Any) -> None:
@@ -684,14 +691,14 @@ class ContinuousBatcher:
         #: int8-aware byte gauges on /metrics reflect what HBM actually
         #: holds, not a naive values-only halving
         mcfg = generator.module.config
-        head_dim = mcfg.dim // mcfg.n_heads
-        if cfg.kv_cache_dtype == "int8":
-            kv_itemsize, scale_bytes = 1, 8  # k_scale + v_scale, f32 each
-        else:
-            kv_itemsize, scale_bytes = jnp.dtype(mcfg.dtype).itemsize, 0
+        #: the planes a layer keeps a position in, by name: heads, width, bytes a value
+        #: (the model's own layout: keys and values, or a latent layer's one row)
+        self._kv_layout = {
+            name: (heads, width, jnp.dtype(dtype).itemsize)
+            for name, (heads, width, dtype) in cache_layout(mcfg, cfg.kv_cache_dtype).items()
+        }
         self._block_bytes = int(
-            mcfg.n_layers * mcfg.n_kv_heads * block_size
-            * (2 * head_dim * kv_itemsize + scale_bytes)
+            mcfg.n_layers * block_size * sum(heads * width * size for heads, width, size in self._kv_layout.values())
         )
         self._kv_dtype_label = cfg.kv_cache_dtype or str(jnp.dtype(mcfg.dtype))
         self._free_blocks: "List[int]" = list(range(self.pool_blocks))
@@ -903,11 +910,11 @@ class ContinuousBatcher:
         request already wrote — whose content the row duplicates exactly, so
         re-writing them per admission would be wasted bandwidth (and, for
         tree-owned pages, a data race against their other readers)."""
-        block_size = cache[0]["k"].shape[2]  # pools are heads-major [H_kv, NB, bs, last]
-        scratch = cache[0]["k"].shape[1] - 1  # scratch is the last pool block
+        _, n_pool, block_size, _ = _plane(cache[0]).shape  # pools are heads-major [H_kv, NB, bs, last]
+        scratch = n_pool - 1  # scratch is the last pool block
         new_layers = []
         for layer, row in zip(cache, row_cache):
-            pos = jnp.arange(row["k"].shape[1])  # the row is [1, cache_len, H, last]
+            pos = jnp.arange(_plane(row).shape[1])  # the row is [1, cache_len, H, last]
             blk, off = blocks_row[pos // block_size], pos % block_size
             blk = jnp.where(pos < skip * block_size, scratch, blk)
             new_layer = {"table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
@@ -957,8 +964,8 @@ class ContinuousBatcher:
         pages' content, and tree-shared pages must never be re-written under
         their other readers — the same contract as the row scatter's
         ``skip``."""
-        n_blocks = pages[0]["k"].shape[1]
-        scratch = cache[0]["k"].shape[1] - 1  # scratch is the last pool block
+        n_blocks = _plane(pages[0]).shape[1]
+        scratch = _plane(cache[0]).shape[1] - 1  # scratch is the last pool block
         ids = jnp.where(jnp.arange(n_blocks) < skip, scratch, blocks_row[:n_blocks])
         new_layers = []
         for layer, page in zip(cache, pages):
@@ -1886,6 +1893,11 @@ class ContinuousBatcher:
                 "block_bytes": self._block_bytes,
                 "used_bytes": used * self._block_bytes,
                 "kv_dtype": self._kv_dtype_label,
+            }
+            # what a layer keeps a position in (the model's layout) and what a block of it weighs
+            snapshot["kv_layout"] = {
+                "planes": {name: {"heads": h, "width": w, "value_bytes": b} for name, (h, w, b) in self._kv_layout.items()},
+                "block_bytes": self._block_bytes,
             }
             if self.prefix is not None:
                 # the static prefix's partial tail block is NOT among the
