@@ -232,27 +232,26 @@ _paste_prefix_rows = jax.jit(paste_prefix_rows, donate_argnums=(0,))
 
 def gather_paged_rows(pool_cache: Any, blocks_row: jax.Array, width: int) -> Tuple[Any, ...]:
     """Materialize a dense ``[1, width, H_kv, last]`` cache row from a PAGED
-    pool (:func:`init_paged_cache`): position ``pos`` reads block
-    ``blocks_row[pos // block_size]`` at offset ``pos % block_size`` — the
-    exact inverse of the admission scatter, so a row gathered from cached
-    blocks is bit-identical to the row that was scattered in. The serving
+    pool (:func:`init_paged_cache`): the blocks ``blocks_row`` names are read
+    as whole pages (``pool[:, blocks_row]``, the pool's own layout, so no pool
+    is re-laid for the read), run together and cut to ``width`` — the exact
+    inverse, page-wise, of the admission's page write, so a row gathered from
+    cached blocks is bit-identical to the row that was written in. The serving
     engine's radix prefix cache uses this to seed an admission's prefill row
     from arbitrary cached block runs (positions past the cached region gather
     scratch/garbage, which the suffix prefill overwrites before anything can
     attend to it). ``width`` is static (one compile per engine: callers pass
-    their fixed ``cache_len``); the per-layer ``table`` entries ride along
-    unused."""
-    block_size = pool_cache[0]["k"].shape[2]  # pools are heads-major [H, NB, bs, last]
-    pos = jnp.arange(width)
-    blk, off = blocks_row[pos // block_size], pos % block_size
+    their fixed ``cache_len``) and at most ``len(blocks_row) * block_size``;
+    the per-layer ``table`` entries ride along unused."""
     rows = []
     for layer in pool_cache:
         row = {}
         for name in layer:
             if name == "table":
                 continue
-            # [H, width, last] -> [1, width, H, last], the dense-row layout
-            row[name] = jnp.swapaxes(layer[name][:, blk, off], 0, 1)[None]
+            pages = layer[name][:, blocks_row]  # pools are heads-major [H, NB, bs, last]: [H, n, bs, last]
+            run = pages.reshape(pages.shape[0], -1, pages.shape[-1])[:, :width]
+            row[name] = jnp.swapaxes(run, 0, 1)[None]  # [1, width, H, last], the dense-row layout
         rows.append(row)
     return tuple(rows)
 

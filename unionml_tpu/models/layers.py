@@ -52,7 +52,10 @@ def quantize_kv_rows(x: jax.Array):
 
 def _paged_scatter(pool: jax.Array, rows: jax.Array, blk: jax.Array, off: jax.Array) -> jax.Array:
     """Write ``rows [B, L, H, last]`` into the heads-major ``pool [H, n_pages, page, last]`` at
-    each token's ``(blk, off) [B, L]``: ``pool[:, blk, off]`` has shape ``[H, B, L, last]``."""
+    each token's ``(blk, off) [B, L]``: ``pool[:, blk, off]`` has shape ``[H, B, L, last]``.
+    Who still calls this: the paged write inside a model step where the kernel's row-major write
+    does not apply (int8 pages, ``L > 1`` tokens, the gathered read). No admission does: the engine
+    pastes a row as whole pages (``ContinuousBatcher._write_pages``), which leaves the pools as they lie."""
     return pool.at[:, blk, off].set(jnp.moveaxis(rows, 2, 0).astype(pool.dtype))
 
 

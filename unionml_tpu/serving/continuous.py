@@ -16,8 +16,8 @@ Design (classic continuous batching, expressed in fixed XLA shapes):
   XLA compiles exactly one decode program and one admission program;
 - **join at prefill**: an arriving prompt prefills through the Generator's own
   jitted prefill at batch 1 (same numerics, same bucket set) into a fresh
-  ``[1, cache_len]`` row, which a jitted scatter pastes into the blocks
-  allocated to a free slot between decode chunks;
+  ``[1, cache_len]`` row, which one jitted program lays as pages and writes
+  whole into the blocks allocated to a free slot between decode chunks;
 - **stall-free admission**: with ``admit_chunk`` set the admission prefill is
   sliced into fixed-size chunks through the Generator's chunked-prefill program
   and the engine alternates chunks with decode dispatches under a
@@ -181,7 +181,7 @@ class _Session:
     #: single ``is not None`` test, the strictly-zero-cost-off contract)
     trace: Any = None
     #: leading block-table entries that are SHARED (tree- or prefix-owned,
-    #: read-only to this stream): the admission scatter diverts their writes to
+    #: read-only to this stream): the admission's page write diverts them to
     #: scratch. Without the radix cache this is the static shared-prefix count
     #: — identical numbers to the historical behavior.
     shared_blocks: int = 0
@@ -210,7 +210,7 @@ class _Session:
     handoff: "Optional[Dict[str, Any]]" = None
     #: an IMPORT session's inbound payload (a sibling replica's export): the
     #: admission skips prefill entirely — the row is placed onto this engine's
-    #: submesh and scattered into freshly allocated blocks
+    #: submesh and written, whole pages, into freshly allocated blocks
     pending_import: "Optional[Dict[str, Any]]" = None
     #: multi-tenant QoS (serving/tenancy.py): the submitting request's tenant
     #: id (None = anonymous) and priority tier — the deficit-round-robin
@@ -278,8 +278,8 @@ class _Admission:
     cached: int = 0
     gather_row: Optional[np.ndarray] = None
     # handoff import: the payload's KV pages in pool layout, placed on this
-    # engine's submesh — finalize scatters them whole-block into the
-    # allocation instead of the row's per-position paste
+    # engine's submesh — finalize hands them to the paste's own page write,
+    # with no row to lay as pages first
     import_pages: Optional[tuple] = None
     # completion products consumed by _finalize_admission
     tok0: Any = None
@@ -676,7 +676,7 @@ class ContinuousBatcher:
         self.block_size = block_size
         # the pool composes with TP: the heads-major pools shard over the model
         # axis (Generator._place_paged_cache), tables replicate, and
-        # admission's row scatter touches only unsharded pool dims
+        # admission's page write indexes only an unsharded pool dim (the blocks)
         self.max_blocks = -(-self.cache_len // block_size)
         self.pool_blocks = pool_blocks if pool_blocks is not None else slots * self.max_blocks
         if self.pool_blocks < self.max_blocks:
@@ -763,17 +763,18 @@ class ContinuousBatcher:
         # block-native handoff (docs/serving.md "Disaggregated and elastic
         # serving"): the export slices the prefilled row into block-sized
         # pages (payload bytes scale with the PROMPT, not cache_len — the
-        # cross-host transfer contract) and the import scatters whole pages
-        # into the pool. One compile per distinct page count, each a trivial
-        # reshape/scatter; bounded by max_blocks.
+        # cross-host transfer contract) and the import writes them whole into
+        # the pool, by the page write the local paste ends in. One compile per
+        # distinct page count, each a trivial reshape/scatter; bounded by
+        # max_blocks.
         self._export_pages_fn = jax.jit(self._export_pages_impl, static_argnums=(1, 2))
         self._paged_page_admit_fn = jax.jit(self._paged_page_admit_impl, donate_argnums=(0,))
         # a constrained generator's DFA state enters the carry's tail at admission
         self._slot_set_fn = jax.jit(lambda arr, slot, value: arr.at[slot].set(value), donate_argnums=(0,))
         self._build_admission_programs()
         if self._aot is not None:
-            # the admission scatter helpers preload too — on a cold TPU the
-            # scatter over a big pool is its own multi-second compile
+            # the admission's page writes preload too — on a cold TPU the
+            # write into a big pool is its own multi-second compile
             ectx = self.gen._aot_context()
             self._paged_admit_fn = AOTFunction(
                 self._paged_admit_fn, "paged_admit", self._aot, ectx
@@ -895,51 +896,16 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------ device fns
 
     @staticmethod
-    def _paged_admit_impl(cache, row_cache, tok, lengths, done, slot, row_tok, row_len, blocks_row,
-                          skip=0):
-        """Admission: point slot ``slot``'s table row at ``blocks_row`` in
-        every layer, scatter the prefilled ``[1, cache_len]`` row into those
-        blocks and activate the slot's carry entries. One compile total:
-        ``slot`` is a traced scalar. ``blocks_row`` ([max_blocks] int32) is
-        scratch-padded past the request's allocation, so the row's unused tail
-        lands in the
-        scratch block, never in another request's pages. ``skip`` (traced, so
-        per-request cached-run lengths don't multiply compiles) diverts the
-        first ``skip`` blocks' writes to scratch: those table entries are
-        SHARED pages — the static prefix's, or radix-cached runs another
-        request already wrote — whose content the row duplicates exactly, so
-        re-writing them per admission would be wasted bandwidth (and, for
-        tree-owned pages, a data race against their other readers)."""
-        _, n_pool, block_size, _ = _plane(cache[0]).shape  # pools are heads-major [H_kv, NB, bs, last]
-        scratch = n_pool - 1  # scratch is the last pool block
-        new_layers = []
-        for layer, row in zip(cache, row_cache):
-            pos = jnp.arange(_plane(row).shape[1])  # the row is [1, cache_len, H, last]
-            blk, off = blocks_row[pos // block_size], pos % block_size
-            blk = jnp.where(pos < skip * block_size, scratch, blk)
-            new_layer = {"table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
-            for name in row:
-                new_layer[name] = layer[name].at[:, blk, off].set(
-                    jnp.swapaxes(row[name][0], 0, 1).astype(layer[name].dtype)
-                )
-            new_layers.append(new_layer)
-        tok = jax.lax.dynamic_update_slice(tok, row_tok.astype(tok.dtype), (slot,))
-        lengths = jax.lax.dynamic_update_slice(lengths, row_len.astype(lengths.dtype), (slot,))
-        done = jax.lax.dynamic_update_slice(done, jnp.zeros((1,), bool), (slot,))
-        return tuple(new_layers), tok, lengths, done
-
-    @staticmethod
     def _export_pages_impl(row_cache, n_blocks, block_size):
-        """Slice a prefilled ``[1, cache_len, H, last]`` row into its first
+        """Lay a prefilled ``[1, cache_len, H, last]`` row as its first
         ``n_blocks`` block-sized pages in POOL layout
-        (``[H, n_blocks, block_size, last]``) — the handoff payload.
-        ``n_blocks``/``block_size`` are static (one small compile per distinct
-        page count); the page contents are byte-identical to what the
-        admission scatter would have written into those blocks, which is what
-        makes an imported stream bit-identical to a locally prefilled one.
-        ``cache_len`` need not be a block multiple: where the last page reaches
-        past the row's end it is zero-padded (the importer's ``lengths`` says
-        how many positions are live; the decode read masks the rest)."""
+        (``[H, n_blocks, block_size, last]``): the handoff payload, and the
+        first half of the local paste. ``n_blocks``/``block_size`` are static
+        (the handoff: one small compile per distinct page count; the paste:
+        ``max_blocks``, inside its one program). ``cache_len`` need not be a
+        block multiple: where the last page reaches past the row's end it is
+        zero-padded (``lengths`` says how many positions are live; the decode
+        read masks the rest)."""
         width = n_blocks * block_size
         pages = []
         for layer in row_cache:
@@ -953,30 +919,63 @@ class ContinuousBatcher:
         return tuple(pages)
 
     @staticmethod
-    def _paged_page_admit_impl(cache, pages, tok, lengths, done, slot, row_tok, row_len,
+    def _write_pages(cache, pages, ids):
+        """The one write into a pool: page ``i`` of every plane
+        (``[H, n, block_size, last]``, the pool's own layout) becomes block
+        ``ids[i]``, whole. A plane keeps its layout through it (a write of
+        ``[H, last]`` slabs a position made XLA re-lay every pool heads-minor
+        and back); ids that repeat (the scratch block's) take any one writer."""
+        return tuple(
+            {**layer, **{name: layer[name].at[:, ids].set(page[name].astype(layer[name].dtype)) for name in page}}
+            for layer, page in zip(cache, pages)
+        )
+
+    @classmethod
+    def _paged_page_admit_impl(cls, cache, pages, tok, lengths, done, slot, row_tok, row_len,
                                blocks_row, skip=0):
-        """Handoff import: point slot ``slot``'s table at ``blocks_row``
-        and write the payload's pages WHOLE-BLOCK into the first
-        ``n_blocks`` allocated blocks — no ``cache_len``-wide row is
-        ever materialized on the importing engine. ``skip`` (traced) diverts
-        the first ``skip`` pages to the scratch block: those table entries are
-        SHARED (the static prefix's blocks), already holding exactly the
-        pages' content, and tree-shared pages must never be re-written under
-        their other readers — the same contract as the row scatter's
-        ``skip``."""
+        """Point slot ``slot``'s table row at ``blocks_row`` in every layer,
+        write ``pages`` (pool layout, ``n_blocks <= max_blocks`` of them) WHOLE
+        into the first ``n_blocks`` blocks of the row and activate the slot's
+        carry entries: the handoff import as it stands (no ``cache_len``-wide
+        row is ever materialized on the importing engine), and the second half
+        of the local paste. ``blocks_row`` ([max_blocks] int32) is
+        scratch-padded past the request's allocation, so pages past it land in
+        the scratch block, never in another request's. ``skip`` (traced, so
+        per-request cached-run lengths don't multiply compiles) diverts the
+        first ``skip`` pages to scratch: those table entries are SHARED pages
+        — the static prefix's, or radix-cached runs another request already
+        wrote — which already hold exactly the pages' content, so re-writing
+        them per admission would be wasted bandwidth (and, for tree-owned
+        pages, a data race against their other readers)."""
         n_blocks = _plane(pages[0]).shape[1]
         scratch = _plane(cache[0]).shape[1] - 1  # scratch is the last pool block
         ids = jnp.where(jnp.arange(n_blocks) < skip, scratch, blocks_row[:n_blocks])
-        new_layers = []
-        for layer, page in zip(cache, pages):
-            new_layer = {"table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
-            for name in page:
-                new_layer[name] = layer[name].at[:, ids].set(page[name].astype(layer[name].dtype))
-            new_layers.append(new_layer)
+        new_layers = tuple(
+            {**layer, "table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
+            for layer in cls._write_pages(cache, pages, ids)
+        )
         tok = jax.lax.dynamic_update_slice(tok, row_tok.astype(tok.dtype), (slot,))
         lengths = jax.lax.dynamic_update_slice(lengths, row_len.astype(lengths.dtype), (slot,))
         done = jax.lax.dynamic_update_slice(done, jnp.zeros((1,), bool), (slot,))
-        return tuple(new_layers), tok, lengths, done
+        return new_layers, tok, lengths, done
+
+    @classmethod
+    def _paged_admit_impl(cls, cache, row_cache, tok, lengths, done, slot, row_tok, row_len, blocks_row,
+                          skip=0):
+        """Admission's paste: the prefilled ``[1, cache_len]`` row, laid as
+        ``max_blocks`` pages (:meth:`_export_pages_impl`; the last one
+        zero-padded where ``cache_len`` is no block multiple), goes through the
+        handoff import's own page write (:meth:`_paged_page_admit_impl`: the
+        table row, ``skip`` and the carry entries as described there). One
+        compile total: ``slot``, ``skip`` and ``blocks_row`` are traced. The
+        row's unused tail lands in the scratch block, which ``blocks_row`` is
+        padded with; of the last allocated page the positions past the row's
+        length hold what the row held there (zeros past ``cache_len``), and
+        ``lengths`` masks them. The device trace names this program by this
+        function (``perf/layer_metrics/admit_paste_ms.py``)."""
+        block_size = _plane(cache[0]).shape[2]  # pools are heads-major [H_kv, NB, bs, last]
+        pages = cls._export_pages_impl(row_cache, blocks_row.shape[0], block_size)
+        return cls._paged_page_admit_impl(cache, pages, tok, lengths, done, slot, row_tok, row_len, blocks_row, skip)
 
     @classmethod
     def _paged_spec_admit_impl(cls, t_cache, d_cache, out_buf, t_row, d_row, tok, lengths, done,
@@ -984,7 +983,7 @@ class ContinuousBatcher:
         """Speculative admission: the SAME block ids serve both models —
         their pools are sized in identical block counts (shapes differ), and a
         slot's logical positions are identical in both caches, so one
-        allocation drives two scatters."""
+        allocation drives two page writes."""
         t_cache, tok, lengths, done = cls._paged_admit_impl(
             t_cache, t_row, tok, lengths, done, slot, row_tok, row_len, blocks_row, skip
         )
@@ -1099,24 +1098,14 @@ class ContinuousBatcher:
         return self._issue(self._setup_fn, np.uint32(seed), np.int32(total), self._setup_prefixes)
 
     def _seed_shared_prefix(self, cache: Any, prefix_layers: Any) -> Any:
-        """Write the prefix's FULL blocks into a pool once; every admission's
-        table then points at these ids and nothing ever writes them again
-        (decode writes start at ``lengths >= p0``)."""
+        """Write the prefix's FULL blocks into a pool once, as whole pages;
+        every admission's table then points at these ids and nothing ever
+        writes them again (decode writes start at ``lengths >= p0``)."""
         ids = jnp.asarray(self._shared_prefix_blocks, jnp.int32)
-        width = len(self._shared_prefix_blocks) * self.block_size
+        n_blocks, block_size = len(self._shared_prefix_blocks), self.block_size
 
-        def seed(cache, prefix_layers, ids):
-            pos = jnp.arange(width)
-            blk, off = ids[pos // self.block_size], pos % self.block_size
-            new_layers = []
-            for layer, pre in zip(cache, prefix_layers):
-                new_layer = dict(layer)
-                for name in pre:  # pools heads-major; prefix rows [1, p0, H, last]
-                    new_layer[name] = layer[name].at[:, blk, off].set(
-                        jnp.swapaxes(pre[name][0, :width], 0, 1).astype(layer[name].dtype)
-                    )
-                new_layers.append(new_layer)
-            return tuple(new_layers)
+        def seed(cache, prefix_layers, ids):  # prefix rows [1, p0, H, last], p0 >= n_blocks * block_size
+            return self._write_pages(cache, self._export_pages_impl(prefix_layers, n_blocks, block_size), ids)
 
         return jax.jit(seed, donate_argnums=(0,))(cache, prefix_layers, ids)
 
@@ -1901,8 +1890,8 @@ class ContinuousBatcher:
             }
             if self.prefix is not None:
                 # the static prefix's partial tail block is NOT among the
-                # seeded shared pages — each admission re-scatters those
-                # tokens into a private block (the radix cache, when on,
+                # seeded shared pages — each admission writes those
+                # tokens again, into a private block (the radix cache, when on,
                 # caches the tail like any other run); surface the count
                 # so a misaligned prefix/block_size choice is visible
                 snapshot["kv_blocks"]["shared_prefix_tail_tokens"] = (
@@ -2639,7 +2628,7 @@ class ContinuousBatcher:
             # check is a pure backstop)
             return False
         # the dense row materializes FROM the cached pool blocks — the exact
-        # inverse of the admission scatter, one fused gather dispatch; stale
+        # inverse of the admission's page write, one fused gather dispatch; stale
         # positions past the cached run are overwritten by the suffix prefill
         # before anything can attend to them
         # (the same program hands out the length, key and flags a cold set-up does:
@@ -2659,7 +2648,7 @@ class ContinuousBatcher:
             session.cached_tokens += start - p0
             if start % self.block_size:
                 # the partially shared tail block: its matched prefix was
-                # gathered into the row and will scatter back into THIS
+                # gathered into the row and will be written back into THIS
                 # request's private block — copy-on-write via the row
                 self.prefix_cache_cow += 1
         _tev(session, "prefill.cache_hit", tokens=start - p0, cached=start)
@@ -2828,8 +2817,8 @@ class ContinuousBatcher:
             if self._spec is None:
                 cache, tok, lengths, done, key, *cst = self._carry
                 if adm.import_pages is not None:
-                    # handoff import: whole pages scatter straight into the
-                    # allocated blocks — no per-position re-scatter ever runs
+                    # handoff import: the pages are in pool layout already, so
+                    # the page write runs alone, with no row to lay as pages
                     cache, tok, lengths, done = self._issue(
                         self._paged_page_admit_fn, cache, adm.import_pages, tok, lengths, done, *row_args, *table_args
                     )
