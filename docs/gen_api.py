@@ -47,6 +47,8 @@ MODULES = [
     "unionml_tpu.models.mlp",
     "unionml_tpu.models.moe",
     "unionml_tpu.models.glm4_moe_lite",
+    "unionml_tpu.models.bailing_hybrid",
+    "unionml_tpu.ops.delta_rule",
     "unionml_tpu.ops.attention",
     "unionml_tpu.ops.ring_attention",
     "unionml_tpu.ops.quant",

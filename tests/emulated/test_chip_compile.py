@@ -392,3 +392,77 @@ def test_chip_smoke_fails_and_claims_nothing_without_a_tpu(tmp_path, where):
     done = subprocess.run([sys.executable, str(script)], cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
     assert done.returncode != 0, done.stdout[-2000:]
     assert '"ok": true' not in done.stdout
+
+
+def _ling(**changes):
+    """bailing_hybrid at Ling-3.0-flash's widths, cut to a dense KDA layer, an expert KDA layer and the expert MLA
+    layer, routing group 0 (64 of 512 experts) and an eighth of the vocabulary held."""
+    from unionml_tpu.models import BailingHybridConfig, BailingHybridTransformer
+
+    return BailingHybridTransformer(BailingHybridConfig(**{**dict(
+        vocab_size=19648, n_layers=3, layer_types=("kda", "kda", "mla"), n_dense_layers=1, experts_held=(0, 64),
+        attention_impl="flash", param_dtype=jnp.bfloat16,
+    ), **changes}))
+
+
+def test_hybrid_decode_steps_compile_for_v5e_over_slot_state_and_latent_pages(chip):
+    """The bailing_hybrid decode program at published widths over the think_sat cell's pool — 168 slots; a KDA
+    layer's state ``[168, 32, 128, 128]`` float32 and tails a slot, no table; the MLA layer's one latent plane of
+    9,409 pages with its table — compiles for the described chip with the latent read through the kernel, keeps
+    no second copy of a layer's state (its temporaries stay under one layer's state) and counts its ten counters."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_paged_cache
+
+    slots, pool, page, pages = 168, 9409, 64, 57
+    module = _ling()
+    config, on_chip = module.config, functools.partial(_on_chip, chip)
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
+    assert set(cache[0]) == {"S", "conv"} and cache[0]["S"].shape == (slots, 32, 128, 128) and cache[0]["S"].dtype == jnp.float32
+    assert cache[0]["conv"].shape == (slots, 3, 3, 4096) and set(cache[2]) == {"k", "table"} and cache[2]["k"].shape == (1, pool, page, 640)
+    tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
+    counts = jax.ShapeDtypeStruct((10,), jnp.int32, sharding=chip)
+    gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
+    compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
+    assert gen.decode_attention_path == "latent_paged_kernel" and gen.counter_names[-3:] == module.counters[-3:]
+    assert compiled.memory_analysis().temp_size_in_bytes < slots * 32 * 128 * 128 * 4
+
+
+def test_hybrid_admission_programs_compile_for_v5e(chip):
+    """The think_sat cell's admission at published widths (three layers): the set-up builds a row cache whose KDA
+    layers are a zero state and zero tails and whose MLA layer is a latent row of ``cache_len``; the chunk program
+    takes 256 tokens through the delta rule's chunk form (the triangular solve and the sub-block products among
+    them) inside half a GB of temporaries; the paste writes the latent pages and row ``slot`` of the state planes
+    in one program, without re-laying the latent pool."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_paged_cache
+    from unionml_tpu.serving import ContinuousBatcher
+
+    module, chunk, page, max_prompt, max_new = _ling(), 256, 64, 2048, 1536
+    on_chip = functools.partial(_on_chip, chip)
+
+    def scalar(dtype, shape=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cfg = GenerationConfig(max_new_tokens=max_new, temperature=0.0, prompt_buckets=tuple(range(chunk, max_prompt + 1, chunk)))
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(gen, slots=168, pool_blocks=9408, decode_chunk=8, block_size=page, admit_chunk=chunk, prefix_cache=False)
+    try:
+        assert batcher.cache_len == 3592 and batcher.max_blocks == 57
+        lengths, key, row_valid, (last,), (row,) = on_chip(lambda: batcher._setup_fn(jnp.uint32(0), jnp.int32(0), ()))
+        assert row[0]["S"].shape == (1, 32, 128, 128) and row[2]["k"].shape == (1, batcher.cache_len, 1, 640)
+        step = gen._prefill_chunk.lower(params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), lengths, row, row_valid, last).compile()
+        assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+        pool = on_chip(lambda: init_paged_cache(
+            module.config, batcher.slots, batcher.pool_blocks + 1, page, batcher.max_blocks, fill_block=batcher.pool_blocks
+        ))
+        carry = (scalar(jnp.int32, (batcher.slots,)), scalar(jnp.int32, (batcher.slots,)), scalar(jnp.bool_, (batcher.slots,)))
+        paste = batcher._paged_admit_fn.lower(
+            pool, row, *carry, scalar(jnp.int32), scalar(jnp.int32, (1,)), scalar(jnp.int32, (1,)),
+            scalar(jnp.int32, (batcher.max_blocks,)), scalar(jnp.int32),
+        ).compile()
+        assert not re.search(rf"= bf16\[1,{batcher.pool_blocks + 1},{page},640\]\S* copy\(", paste.as_text())
+        assert paste.memory_analysis().temp_size_in_bytes < (batcher.pool_blocks + 1) * page * 640 * 2
+    finally:
+        batcher.close()

@@ -443,3 +443,20 @@ def test_app_request_deadline_propagates_to_batcher_shed(sklearn_model):
         assert 99.0 not in seen_x1
 
     asyncio.run(scenario())
+
+
+@pytest.mark.parametrize(
+    "slots, max_waiting, cap",
+    [(64, 128, 256), (192, 256, 448), (None, None, 256)],
+    ids=["engine-under-the-default", "engine-over-the-default", "no-engine"],
+)
+def test_the_fronts_default_cap_never_refuses_what_the_engine_would_queue(sklearn_model, slots, max_waiting, cap):
+    """A stream holds its handler for its whole life, so the front's default cap is the engine's own
+    ``slots + max_waiting`` where that is over ``SERVE_MAX_INFLIGHT``; an explicit ``configure_overload`` still wins."""
+    import types
+
+    if slots is not None:
+        sklearn_model.generation_batcher = types.SimpleNamespace(slots=slots, max_waiting=max_waiting)
+    app = serving_app(sklearn_model)
+    assert app.server.max_inflight == cap
+    assert app.configure_overload(max_inflight=8).server.max_inflight == 8

@@ -39,7 +39,9 @@ MODEL_PATH_ENV_VAR = "UNIONML_MODEL_PATH"
 # knob here is overridable per-app (ServingApp.configure_overload) and from the
 # CLI (`serve --max-inflight/--deadline-ms/--max-deadline-ms/--drain-timeout`).
 
-#: concurrent requests executing handlers before the HTTP layer sheds with 429.
+#: concurrent requests executing handlers before the HTTP layer sheds with 429;
+#: an app built around a generation engine that holds and queues more takes the
+#: engine's slots + max_waiting instead (serving/app.py default_max_inflight).
 SERVE_MAX_INFLIGHT = 256
 
 #: micro-batcher admission queue bound (requests waiting to join a dispatch);

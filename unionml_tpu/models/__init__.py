@@ -1,6 +1,11 @@
 """Model library: flagship flax models for the benchmark configs (BASELINE.json)."""
 
 from unionml_tpu.models.afmoe import AfmoeConfig, AfmoeTransformer, afmoe_partition_rules  # noqa: F401
+from unionml_tpu.models.bailing_hybrid import (  # noqa: F401
+    BailingHybridConfig,
+    BailingHybridTransformer,
+    bailing_hybrid_partition_rules,
+)
 from unionml_tpu.models.bert import BertConfig, BertEncoder, bert_partition_rules, classification_loss  # noqa: F401
 from unionml_tpu.models.generate import (  # noqa: F401
     DraftSpec,

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Sequence, Tuple
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -38,8 +38,10 @@ import jax
 import jax.numpy as jnp
 
 from unionml_tpu._logging import logger
+from unionml_tpu.models.layers import SlotPlane
 
 __all__ = [
+    "cache_layouts",
     "DraftSpec",
     "GenerationConfig",
     "Generator",
@@ -134,23 +136,36 @@ def _head_dim(config: Any) -> int:
     return int(getattr(config, "head_dim", None) or config.dim // config.n_heads)
 
 
+def _stated_layout(stated: Any, dtype: Any, kv_dtype: Optional[str]) -> Dict[str, Any]:
+    """One layer's planes as a configuration states them, the paged ones given the compute dtype."""
+    if kv_dtype == "int8":
+        raise ValueError(
+            f"kv_cache_dtype='int8' over a stated cache layout ({dict(stated)}): int8 pages are "
+            "per-head keys and values with a scale a (position, head); a latent plane or a "
+            "recurrent state a slot has none"
+        )
+    return {
+        name: plane if isinstance(plane, SlotPlane) else (int(plane[0]), int(plane[1]), dtype)
+        for name, plane in stated.items()
+    }
+
+
 def cache_layout(config: Any, kv_dtype: Optional[str] = None) -> Dict[str, Tuple[int, int, Any]]:
     """A layer's cache planes by name, each ``(heads, width, dtype)``: what the
     configuration states (``config.cache_layout``: ``{name: (heads, width)}`` in
     the compute dtype — a latent layer's ``{"k": (1, 640)}``, no ``"v"``), else
     keys and values at ``n_kv_heads`` heads of the configuration's own head
     width. ``kv_dtype="int8"`` stores those two int8 beside per-(position, head)
-    float32 scale planes; a stated layout has no such form."""
+    float32 scale planes; a stated layout has no such form. This is the layout
+    of a configuration that means one for every layer; one that states a layout
+    a layer is read through :func:`cache_layouts`."""
     if kv_dtype not in (None, "int8"):
         raise ValueError(f"unsupported kv_cache_dtype {kv_dtype!r}; expected None or 'int8'")
     stated = getattr(config, "cache_layout", None)
     if stated:
-        if kv_dtype == "int8":
-            raise ValueError(
-                f"kv_cache_dtype='int8' over a stated cache layout ({dict(stated)}): int8 pages are "
-                "per-head keys and values with a scale a (position, head); a latent plane has none"
-            )
-        return {name: (int(heads), int(width), config.dtype) for name, (heads, width) in stated.items()}
+        if not isinstance(stated, dict):
+            raise ValueError("this configuration states a cache layout a layer: read it through cache_layouts")
+        return _stated_layout(stated, config.dtype, kv_dtype)
     heads, width = config.n_kv_heads, _head_dim(config)
     if kv_dtype == "int8":
         return {
@@ -160,18 +175,55 @@ def cache_layout(config: Any, kv_dtype: Optional[str] = None) -> Dict[str, Tuple
     return {"k": (heads, width, config.dtype), "v": (heads, width, config.dtype)}
 
 
+def cache_layouts(config: Any, kv_dtype: Optional[str] = None) -> Tuple[Dict[str, Any], ...]:
+    """Every layer's planes, ``config.n_layers`` layouts. A plane is of one of two
+    kinds: paged by position, ``(heads, width, dtype)`` as :func:`cache_layout`
+    gives it, or one row a slot, a :class:`~unionml_tpu.models.layers.SlotPlane`
+    (``shape``, its own ``dtype``: a recurrent state, a convolution's tail). A
+    configuration that states one layout (or none) means it for every layer; one
+    whose layers differ states a sequence of them, one a layer."""
+    stated = getattr(config, "cache_layout", None)
+    if stated and not isinstance(stated, dict):
+        if len(stated) != config.n_layers:
+            raise ValueError(f"cache_layout states {len(stated)} layers, the model has {config.n_layers}")
+        return tuple(_stated_layout(layout, config.dtype, kv_dtype) for layout in stated)
+    return (cache_layout(config, kv_dtype),) * config.n_layers
+
+
+def has_slot_planes(config: Any) -> bool:
+    """Whether any layer keeps state with no position axis (a row a slot)."""
+    return any(isinstance(plane, SlotPlane) for layout in cache_layouts(config) for plane in layout.values())
+
+
+def refuse_draft_over_slot_state(config: Any) -> None:
+    """Speculative decoding over a model with slot planes: the one refusal of ``Generator``, ``SpeculativeGenerator``
+    and, through them, the engine."""
+    if has_slot_planes(config):
+        raise ValueError(
+            "speculative decoding (config.draft) over a model that keeps a recurrent state a slot: "
+            "a rejected draft would have to roll the state back, and no snapshot of it is kept"
+        )
+
+
+def _zeros(plane: Any, rows: int, paged_shape: Callable[[int, int], Tuple[int, ...]]) -> jax.Array:
+    if isinstance(plane, SlotPlane):
+        return jnp.zeros((rows, *plane.shape), plane.dtype)
+    heads, width, dtype = plane
+    return jnp.zeros(paged_shape(heads, width), dtype)
+
+
 def init_cache(config: Any, batch: int, cache_len: int, kv_dtype: Optional[str] = None) -> Tuple[Any, ...]:
     """Zeroed per-layer cache buffers ``[batch, cache_len, heads, width]`` for a
     decoder with ``config.n_layers`` layers, one per plane of
-    :func:`cache_layout`: by default ``config.n_kv_heads`` KV heads and the
+    :func:`cache_layouts`: by default ``config.n_kv_heads`` KV heads and the
     configuration's own head width (``head_dim``, else ``dim // n_heads``), stored in the
     compute dtype (bf16 on TPU — halves cache HBM vs f32). ``kv_dtype="int8"``
     adds per-(position, head) scale planes and stores values int8 (see
-    :class:`~unionml_tpu.models.layers.Attention`'s cached branch)."""
-    layout = cache_layout(config, kv_dtype)
+    :class:`~unionml_tpu.models.layers.Attention`'s cached branch). A slot plane
+    is ``[batch, *shape]`` in its own dtype, whatever ``cache_len``."""
     return tuple(
-        {name: jnp.zeros((batch, cache_len, heads, width), dtype) for name, (heads, width, dtype) in layout.items()}
-        for _ in range(config.n_layers)
+        {name: _zeros(plane, batch, lambda heads, width: (batch, cache_len, heads, width)) for name, plane in layout.items()}
+        for layout in cache_layouts(config, kv_dtype)
     )
 
 
@@ -190,8 +242,10 @@ def init_paged_cache(
     initialized to ``fill_block``. Pools are HEADS-MAJOR
     (``[H_kv, n_blocks, block_size, D]``) — the layout
     ``jax.experimental.pallas.ops.tpu.paged_attention`` consumes directly, so
-    the kernel path needs no transpose. The planes are :func:`cache_layout`'s:
-    a latent layer has one, ``{"k": [1, n_blocks, block_size, W], "table"}``.
+    the kernel path needs no transpose. The planes are :func:`cache_layouts`'s:
+    a latent layer has one, ``{"k": [1, n_blocks, block_size, W], "table"}``; a
+    layer whose planes are all slot planes holds ``[slots, *shape]`` each and NO
+    table (it owns no block: that is how the engine's programs tell it).
     ``fill_block`` is REQUIRED and must be a reserved scratch block (allocate
     ``n_blocks = real + 1`` and pass ``fill_block = real``, as
     ``ContinuousBatcher._init_carry`` does): free and finished slots keep
@@ -201,17 +255,19 @@ def init_paged_cache(
     layer (same values; a few hundred bytes). See
     :meth:`unionml_tpu.models.layers.Attention._paged_cached_attention` for the
     read/write contract; HBM scales with the pool, not slots x worst-case."""
-    layout = cache_layout(config, kv_dtype)
     # one table PER layer (same values): the cache is donated through admission
     # and decode, and donating an array aliased across layers is an XLA error
     # ("donate the same buffer twice"); the duplication is a few hundred bytes
-    return tuple(
-        {
-            **{name: jnp.zeros((heads, n_blocks, block_size, width), dtype) for name, (heads, width, dtype) in layout.items()},
-            "table": jnp.full((slots, max_blocks), fill_block, jnp.int32),
-        }
-        for _ in range(config.n_layers)
-    )
+    layers = []
+    for layout in cache_layouts(config, kv_dtype):
+        layer = {name: _zeros(plane, slots, lambda heads, width: (heads, n_blocks, block_size, width)) for name, plane in layout.items()}
+        kinds = {isinstance(plane, SlotPlane) for plane in layout.values()}
+        if kinds == {True, False}:
+            raise ValueError(f"a layer's planes are all paged or all a row a slot, not both: {sorted(layout)}")
+        if kinds == {False}:
+            layer["table"] = jnp.full((slots, max_blocks), fill_block, jnp.int32)
+        layers.append(layer)
+    return tuple(layers)
 
 
 def paste_prefix_rows(cache: Any, prefix_layers: Any) -> Any:
@@ -245,6 +301,8 @@ def gather_paged_rows(pool_cache: Any, blocks_row: jax.Array, width: int) -> Tup
     the per-layer ``table`` entries ride along unused."""
     rows = []
     for layer in pool_cache:
+        if "table" not in layer:
+            raise ValueError("a layer that keeps a row a slot has no pages to gather: its state at a position is not held")
         row = {}
         for name in layer:
             if name == "table":
@@ -373,6 +431,8 @@ class Generator:
             raise ValueError(
                 f"unsupported kv_cache_dtype {config.kv_cache_dtype!r}; expected None or 'int8'"
             )
+        if config.draft is not None:
+            refuse_draft_over_slot_state(module.config)
         self.module = module
         self.config = config
         self.mesh = mesh
@@ -698,6 +758,8 @@ class Generator:
 
         cfg = self.config
         mesh = self.mesh
+        if has_slot_planes(self.module.config):
+            raise ValueError("sp_prefill over a model that keeps a recurrent state a slot: the state runs through the sequence it would split")
         sp_module = type(self.module)(_dc.replace(self.module.config, attention_impl=cfg.sp_prefill))
         n_layers = self.module.config.n_layers
         #: what each layer's attention sows for the cache: its layout's planes (keys and values; a latent layer's one)
@@ -769,22 +831,41 @@ class Generator:
         logger.info(f"prompt length {max_prompt} exceeds configured buckets; padding to {bucket}")
         return bucket
 
+    def _plane_shardings(self, cache: Any, paged: Callable[[Any, Optional[str]], Any], rows: Optional[str]) -> Any:
+        """A cache's placements, leaf for leaf: a plane paged by position where ``paged(leaf,
+        model)`` says, a slot plane ``[rows, *shape]`` with its heads' axis over ``model`` and its
+        rows over ``rows`` (a mesh axis or None), a table whole."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        model = "model" if "model" in self.mesh.axis_names else None
+        layouts = cache_layouts(self.module.config, self.config.kv_cache_dtype)
+
+        def spec(a: Any, plane: Any) -> NamedSharding:
+            if not isinstance(plane, SlotPlane):
+                return NamedSharding(self.mesh, P() if plane is None else paged(a, model))
+            axes = [None] * len(plane.shape)
+            if model is not None and plane.model_axis is not None and plane.shape[plane.model_axis] % self.mesh.shape["model"] == 0:
+                axes[plane.model_axis] = model
+            return NamedSharding(self.mesh, P(rows, *axes))
+
+        return tuple({name: spec(a, layout.get(name)) for name, a in layer.items()} for layer, layout in zip(cache, layouts))
+
     def _cache_shardings(self, cache: Any) -> Any:
         """Where a contiguous ``[B, L, H, last]`` cache lives on the mesh, leaf for
         leaf (shapes are enough: a program's ``out_shardings`` are built from
         these); ``None`` without a mesh."""
         if self.mesh is None:
             return None
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-        def spec(a: Any) -> NamedSharding:
-            data = "data" if "data" in self.mesh.axis_names else None
-            model = "model" if "model" in self.mesh.axis_names else None
+        data = "data" if "data" in self.mesh.axis_names else None
+
+        def paged(a: Any, model: Optional[str]) -> Any:
             if model is not None and a.shape[2] % self.mesh.shape["model"] != 0:
                 model = None  # KV heads not divisible by the model axis: replicate heads
-            return NamedSharding(self.mesh, P(data, None, model, None))
+            return P(data, None, model, None)
 
-        return jax.tree_util.tree_map(spec, cache)
+        return self._plane_shardings(cache, paged, data)
 
     def _place_cache(self, cache: Any) -> Any:
         if self.mesh is None:
@@ -797,18 +878,18 @@ class Generator:
         dim over the model axis — the same axis the dense ``[B, L, H, D]``
         cache shards in :meth:`_place_cache` — and the ``[slots, max_blocks]``
         block tables replicate (every shard needs the full table to gather its
-        own heads' blocks)."""
+        own heads' blocks). A slot plane ``[slots, *shape]`` shards the axis its
+        layout names as its heads'."""
         if self.mesh is None:
             return cache
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import PartitionSpec as P
 
-        def spec(a: jax.Array) -> NamedSharding:
-            model = "model" if "model" in self.mesh.axis_names else None
-            if a.ndim != 4 or (model is not None and a.shape[0] % self.mesh.shape["model"] != 0):
-                model = None  # tables, or KV heads indivisible by the axis: replicate
-            return NamedSharding(self.mesh, P(model))
+        def paged(a: Any, model: Optional[str]) -> Any:
+            if model is not None and a.shape[0] % self.mesh.shape["model"] != 0:
+                model = None  # KV heads indivisible by the axis: replicate
+            return P(model)
 
-        return jax.tree_util.tree_map(lambda a: jax.device_put(a, spec(a)), cache)
+        return jax.tree_util.tree_map(jax.device_put, cache, self._plane_shardings(cache, paged, None))
 
     # ------------------------------------------------------------------ generate
 
@@ -820,6 +901,11 @@ class Generator:
         p0 = len(prefix_tokens)
         if p0 == 0:
             raise ValueError("prefix_tokens must be non-empty")
+        if has_slot_planes(self.module.config):
+            raise ValueError(
+                "cache_prefix over a model that keeps a row a slot (a recurrent state): a prefix's rows are "
+                "cut by position, and such a state has no position axis to cut"
+            )
         _, _, _, carry = self._start([list(prefix_tokens)], 0)
         cache = carry[0]
         return PrefixCache(

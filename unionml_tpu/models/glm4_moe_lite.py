@@ -157,6 +157,7 @@ class Glm4MoeLiteTransformer(nn.Module):
 
     counters = MOE_COUNTERS + LATENT_COUNTERS
     counter_views = {"moe": MOE_COUNTERS, "latent": LATENT_COUNTERS}  # the serving engine's stats()["moe"], ["latent"]
+    block = Glm4MoeLiteBlock  # a decoder of the same shell with another block names it here (models/bailing_hybrid.py)
 
     @nn.compact
     def __call__(
@@ -173,7 +174,7 @@ class Glm4MoeLiteTransformer(nn.Module):
             positions = jnp.arange(tokens.shape[1])
         new_cache = []
         for i in range(cfg.n_layers):
-            block = Glm4MoeLiteBlock(cfg, i, name=f"layer_{i}")
+            block = self.block(cfg, i, name=f"layer_{i}")
             if cache is not None:
                 x, layer_cache = block(x, positions, None, cache[i], token_mask)
                 new_cache.append(layer_cache)

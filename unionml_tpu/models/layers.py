@@ -14,7 +14,7 @@ model library backing the BASELINE.json configs. Conventions:
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import functools
 
@@ -29,6 +29,18 @@ Dtype = Any
 
 #: One layer's KV cache: ``{"k": [B, S_max, H_kv, D], "v": [B, S_max, H_kv, D]}``.
 LayerCache = Dict[str, jax.Array]
+
+
+class SlotPlane(NamedTuple):
+    """A plane of a layer's state that has no position axis: one row a sequence, ``[batch, *shape]``
+    in a row cache and ``[slots, *shape]`` in an engine's pool (a recurrent state, a convolution's
+    tail), in its own ``dtype``. A configuration's ``cache_layout`` states it beside the planes paged by
+    position (``(heads, width)``). ``model_axis``: the axis of ``shape`` that follows the heads, which a
+    mesh's ``model`` axis shards (``None``: replicate)."""
+
+    shape: Tuple[int, ...]
+    dtype: Any
+    model_axis: Optional[int] = None
 
 
 def _write_cache(buffer: jax.Array, new: jax.Array, starts: jax.Array) -> jax.Array:
@@ -502,10 +514,15 @@ class LatentAttention(nn.Module):
     ``latent_positions_read`` (one-token reads: the live rows' lengths, what the
     read had to cover), ``latent_positions_attended`` (several-token reads: key
     positions the read covered, masked or not) and ``latent_positions_needed``
-    (of those, the positions up to each live row's last query: what causality needs)."""
+    (of those, the positions up to each live row's last query: what causality needs).
+
+    ``q_rank=None``: no query bottleneck, one full-rank ``q_proj`` and no query norm.
+    ``gated``: each head's output is multiplied by ``sigmoid(gate_proj(a))_h``, one
+    scalar a head, before ``o_proj``. Both default to the block described above and
+    add no parameter when off."""
 
     n_heads: int
-    q_rank: int
+    q_rank: Optional[int]
     kv_rank: int
     nope_dim: int
     rope_dim: int
@@ -515,6 +532,7 @@ class LatentAttention(nn.Module):
     impl: str = "auto"
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
+    gated: bool = False
 
     @nn.compact
     def __call__(
@@ -533,7 +551,10 @@ class LatentAttention(nn.Module):
             positions = jnp.arange(length)
 
         with jax.named_scope("mla.q_path"):
-            q = dense(heads * (self.nope_dim + self.rope_dim), "q_up")(norm("q_norm")(dense(self.q_rank, "q_down")(x)))
+            if self.q_rank is None:
+                q = dense(heads * (self.nope_dim + self.rope_dim), "q_proj")(x)
+            else:
+                q = dense(heads * (self.nope_dim + self.rope_dim), "q_up")(norm("q_norm")(dense(self.q_rank, "q_down")(x)))
             q = q.reshape(batch, length, heads, self.nope_dim + self.rope_dim)
             q_nope = q[..., : self.nope_dim]
             q_rope = rotary_embedding(q[..., self.nope_dim :], positions, self.rope_theta)
@@ -548,6 +569,10 @@ class LatentAttention(nn.Module):
         scale = (self.nope_dim + self.rope_dim) ** -0.5
 
         def project(out: jax.Array) -> jax.Array:  # [B, L, H, v_dim]
+            if self.gated:
+                with jax.named_scope("mla.gate"):
+                    gate = jax.nn.sigmoid(dense(heads, "gate_proj")(x).astype(jnp.float32))
+                    out = out * gate[..., None].astype(out.dtype)
             return dense(features, "o_proj")(out.reshape(batch, length, heads * self.v_dim))
 
         def expanded(rows: jax.Array, visible: jax.Array) -> jax.Array:
@@ -623,6 +648,123 @@ class LatentAttention(nn.Module):
             "counters", "latent_positions_needed", jnp.sum(jnp.max(jnp.where(live, positions + 1, 0), axis=1), dtype=jnp.int32)
         )
         return project(absorbed(rows, visible)), cache
+
+
+class KimiDeltaAttention(nn.Module):
+    """Kimi Delta Attention (KDA, arXiv:2510.26692): linear attention whose state is one ``d_k x d_v``
+    matrix a head, rewritten by the gated delta rule with a decay a channel
+    (:mod:`unionml_tpu.ops.delta_rule`), behind short causal depthwise convolutions. Nothing it
+    keeps grows with the sequence. With ``a`` the normed stream and per head unless said::
+
+        q = l2norm(silu(conv(q_proj a))) * d_k ** -0.5;  k = l2norm(silu(conv(k_proj a)));  v = silu(conv(v_proj a))
+        g = decay_bound * sigmoid(exp(A_log) * (f_proj a + dt_bias))     # log-decay a channel, in (decay_bound, 0)
+        beta = sigmoid(b_proj a)                                         # one scalar a head
+        S' = Diag(exp(g)) S;  S = S' + beta k (v - S'^T k)^T;  o = S^T q
+        out = o_proj [ RMSNorm_{d_v}(o) * sigmoid(g_proj a) ]            # one learned norm scale of d_v for all heads
+
+    ``conv`` sums the last ``conv_size`` positions under its own taps a channel (no bias);
+    ``f_proj`` and ``g_proj`` are full-rank. The decay gate, ``beta`` and the state are float32.
+
+    Three reads of one state. Uncached: the whole sequence from a zero state, in chunks
+    (:func:`~unionml_tpu.ops.delta_rule.delta_rule_chunked`). Cached with several tokens (a prefill
+    chunk): the same, from the state and the convolutions' tails the cache hands in, which come out
+    advanced; positions ``token_mask`` masks leave both untouched (they must be a row's tail: a
+    right-padded prompt, a row that is not live). Cached with one token (decode):
+    :func:`~unionml_tpu.ops.delta_rule.delta_rule_step`; a masked row's state and tails are held.
+
+    The cache is two planes with no position axis (:class:`SlotPlane`): ``{"S": [B, H, d_k, d_v]``
+    in ``state_dtype``, ``"conv": [B, conv_size - 1, 3, H * d_k]}``, the last pre-convolution rows of
+    ``q_proj a``, ``k_proj a``, ``v_proj a``; ``B`` is a row cache's batch or an engine's slots, the
+    same to this module. Counts into the ``counters`` collection: ``state_rows_updated`` (one-token
+    reads: live rows), ``state_positions_run`` (several-token reads: positions the chunk form ran
+    for rows with a live token, padded or not) and ``state_positions_needed`` (of those, live)."""
+
+    n_heads: int
+    head_dim: int  # d_k = d_v
+    conv_size: int = 4
+    decay_bound: float = -5.0
+    norm_epsilon: float = 1e-6
+    state_dtype: Dtype = jnp.float32
+    dtype: Dtype = jnp.bfloat16
+    param_dtype: Dtype = jnp.float32
+
+    @staticmethod
+    def cache_planes(n_heads: int, head_dim: int, conv_size: int, state_dtype: Dtype, dtype: Dtype) -> Dict[str, SlotPlane]:
+        """This layer's state as a configuration's ``cache_layout`` states it."""
+        return {
+            "S": SlotPlane((n_heads, head_dim, head_dim), state_dtype, 0),
+            "conv": SlotPlane((conv_size - 1, 3, n_heads * head_dim), dtype, 2),
+        }
+
+    @nn.compact
+    def __call__(
+        self,
+        x: jax.Array,
+        positions: Optional[jax.Array] = None,
+        mask: Optional[jax.Array] = None,
+        cache: Optional[LayerCache] = None,
+        token_mask: Optional[jax.Array] = None,
+    ) -> Any:
+        from unionml_tpu.ops.delta_rule import MIN_LOG_DECAY, delta_rule_chunked, delta_rule_step
+
+        if mask is not None:
+            raise NotImplementedError("a delta-rule layer is causal by construction and takes no mask")
+        if not MIN_LOG_DECAY <= self.decay_bound <= 0.0:
+            raise ValueError(
+                f"decay_bound {self.decay_bound} outside [{MIN_LOG_DECAY}, 0]: below it the chunk form's factorised "
+                "decay products overflow float32 (ops/delta_rule.py), above it the state would grow"
+            )
+        features, heads, d = x.shape[-1], self.n_heads, self.head_dim
+        batch, length, width, taps = x.shape[0], x.shape[1], self.n_heads * self.head_dim, self.conv_size
+        dense = lambda feats, name: LoRADense(feats, dtype=self.dtype, param_dtype=self.param_dtype, name=name)  # noqa: E731
+        in_f32 = lambda feats, name: jnp.dot(  # noqa: E731  a float32 result of the stream's own (compute dtype) operands
+            x, _Kernel((features, feats), self.param_dtype, name=name)().astype(self.dtype), preferred_element_type=jnp.float32
+        )
+        live = jnp.ones((batch, length), bool) if token_mask is None else token_mask
+
+        with jax.named_scope("kda.proj"):
+            pre = jnp.stack([dense(width, name)(x) for name in ("q_proj", "k_proj", "v_proj")], axis=2)  # [B, L, 3, H * d]
+        with jax.named_scope("kda.conv"):
+            kernel = self.param("conv_taps", nn.initializers.normal(taps ** -0.5), (taps, 3, width), self.param_dtype)
+            tail = jnp.zeros((batch, taps - 1, 3, width), self.dtype) if cache is None else cache["conv"].astype(self.dtype)
+            window = jnp.concatenate([tail, pre], axis=1)  # [B, taps - 1 + L, 3, H * d]
+            mixed = sum(window[:, j : j + length] * kernel[j].astype(self.dtype) for j in range(taps))
+            mixed = jax.nn.silu(mixed.astype(jnp.float32)).reshape(batch, length, 3, heads, d)
+            unit = lambda t: t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)  # noqa: E731
+            q, k, v = unit(mixed[:, :, 0]) * d ** -0.5, unit(mixed[:, :, 1]), mixed[:, :, 2]
+        with jax.named_scope("kda.gate"):
+            a_log = self.param("A_log", nn.initializers.zeros, (heads,), jnp.float32)
+            dt_bias = self.param("dt_bias", nn.initializers.constant(-4.0), (width,), jnp.float32)
+            rate = (jnp.exp(a_log)[:, None] * (in_f32(width, "f_proj") + dt_bias).reshape(batch, length, heads, d))
+            g = self.decay_bound * jax.nn.sigmoid(rate)
+            beta = jax.nn.sigmoid(in_f32(heads, "b_proj"))
+
+        if cache is not None and length == 1:
+            with jax.named_scope("kda.step"):
+                out, state = delta_rule_step(
+                    cache["S"], q[:, 0], k[:, 0], v[:, 0],
+                    jnp.where(live[:, 0, None, None], g[:, 0], 0.0), jnp.where(live[:, 0, None], beta[:, 0], 0.0),
+                )
+                out = out[:, None]
+                tail = jnp.where(live[:, 0, None, None, None], window[:, 1:], tail)
+            self.sow("counters", "state_rows_updated", jnp.sum(live, dtype=jnp.int32))
+        else:
+            start = jnp.zeros((batch, heads, d, d), jnp.float32) if cache is None else cache["S"]
+            with jax.named_scope("kda.chunk"):
+                out, state = delta_rule_chunked(start, q, k, v, g, beta, live)
+                if cache is not None:  # the last taps - 1 rows before the first masked position
+                    rows = jnp.sum(live, axis=1)[:, None] + jnp.arange(taps - 1)[None]  # [B, taps - 1]
+                    tail = jnp.take_along_axis(window, rows[:, :, None, None], axis=1)
+            self.sow("counters", "state_positions_run", jnp.sum(live.any(axis=1), dtype=jnp.int32) * length)
+            self.sow("counters", "state_positions_needed", jnp.sum(live, dtype=jnp.int32))
+
+        with jax.named_scope("kda.out"):
+            gate = jax.nn.sigmoid(dense(width, "g_proj")(x).astype(jnp.float32)).reshape(batch, length, heads, d)
+            normed = RMSNorm(epsilon=self.norm_epsilon, dtype=jnp.float32, name="o_norm")(out)
+            y = dense(features, "o_proj")((normed * gate).astype(self.dtype).reshape(batch, length, width))
+        if cache is None:
+            return y
+        return y, {"S": state.astype(cache["S"].dtype), "conv": tail.astype(cache["conv"].dtype)}
 
 
 class MLP(nn.Module):

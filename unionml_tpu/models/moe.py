@@ -152,13 +152,26 @@ MOE_COUNTERS = ("routed_pairs", "local_pairs", "experts_hit", "max_expert_load")
 
 
 def route_top_k(
-    scores: jax.Array, bias: jax.Array, k: int, *, normalize: bool = True, scale: float = 1.0
+    scores: jax.Array, bias: jax.Array, k: int, *, normalize: bool = True, scale: float = 1.0,
+    n_group: int = 1, topk_group: int = 1,
 ) -> Tuple[jax.Array, jax.Array]:
     """Choose ``k`` experts a token by ``scores + bias`` and weigh them by the
     scores alone (the bias steers the choice, never the mixture): ``(experts [N,
     k] int32, weights [N, k] f32)``. ``normalize`` divides by the chosen scores'
-    sum — over all ``k``, wherever their experts live — before ``scale``."""
-    _, chosen = jax.lax.top_k(scores + bias, k)
+    sum — over all ``k``, wherever their experts live — before ``scale``.
+
+    ``n_group > 1`` limits the choice to groups (DeepSeek-V3's ``noaux_tc``): the
+    experts lie in ``n_group`` equal runs, a run scores the sum of its two largest
+    ``scores + bias``, the ``topk_group`` best runs stay and every expert of the
+    others is out of the choice. One group is the rule above, bit for bit."""
+    biased = scores + bias
+    if n_group > 1:
+        with jax.named_scope("moe.groups"):
+            runs = biased.reshape(biased.shape[0], n_group, -1)
+            _, best = jax.lax.top_k(jnp.sum(jax.lax.top_k(runs, 2)[0], axis=-1), topk_group)  # [N, topk_group]
+            stays = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)  # [N, n_group]
+            biased = jnp.where(stays[:, :, None], runs, -jnp.inf).reshape(biased.shape)
+    _, chosen = jax.lax.top_k(biased, k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalize:
         weights = weights / (jnp.sum(weights, axis=-1, keepdims=True) + 1e-20)
@@ -235,6 +248,8 @@ class ExpertShare(nn.Module):
     route_scale: float = 1.0
     dtype: Dtype = jnp.bfloat16
     param_dtype: Dtype = jnp.float32
+    n_group: int = 1  # group-limited routing (route_top_k); one group: none
+    topk_group: int = 1
 
     @nn.compact
     def __call__(self, x: jax.Array, token_mask: Optional[jax.Array] = None) -> jax.Array:
@@ -253,7 +268,10 @@ class ExpertShare(nn.Module):
             logits = jnp.dot(
                 tokens.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
             )
-            chosen, weights = route_top_k(jax.nn.sigmoid(logits), bias, self.k, normalize=self.route_norm, scale=self.route_scale)
+            chosen, weights = route_top_k(
+                jax.nn.sigmoid(logits), bias, self.k, normalize=self.route_norm, scale=self.route_scale,
+                n_group=self.n_group, topk_group=self.topk_group,
+            )
             held = (chosen >= first) & (chosen < first + count) & live[:, None]  # [N, k]
             # pairs on absent experts (and masked rows) sort behind every held expert
             group = jnp.where(held, chosen - first, count).reshape(n * self.k)

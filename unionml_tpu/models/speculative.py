@@ -53,7 +53,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from unionml_tpu.models.generate import GenerationConfig, Generator, PrefixCache
+from unionml_tpu.models.generate import GenerationConfig, Generator, PrefixCache, refuse_draft_over_slot_state
 
 __all__ = ["SpeculativeGenerator"]
 
@@ -114,6 +114,7 @@ class SpeculativeGenerator:
         cannot drift."""
         if gamma < 1:
             raise ValueError("gamma must be >= 1")
+        refuse_draft_over_slot_state(target.module.config)
         self.config = config
         self.gamma = int(gamma)
         self.rounds = 0

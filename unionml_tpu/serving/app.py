@@ -65,6 +65,21 @@ _BANNER = """
 """
 
 
+def default_max_inflight(engine: Any) -> int:
+    """The HTTP front's default in-flight cap: ``SERVE_MAX_INFLIGHT``, or what
+    the generation engine behind it holds and queues (``slots + max_waiting``)
+    where that is more. A stream occupies a handler from admission to its last
+    token, so a cap under the engine's own bounds sheds requests the engine was
+    sized to queue (192 slots + 256 waiting behind a cap of 256: the 257th
+    caller got a 429 with 192 of the queue's 256 places free) and leaves the
+    engine's ``max_waiting`` shed unreachable. An engine without the two numbers
+    (a replica set, none yet) keeps the plain default."""
+    slots, waiting = getattr(engine, "slots", None), getattr(engine, "max_waiting", None)
+    if isinstance(slots, int) and isinstance(waiting, int):
+        return max(SERVE_MAX_INFLIGHT, slots + waiting)
+    return SERVE_MAX_INFLIGHT
+
+
 class ServingApp:
     """HTTP serving app bound to a :class:`unionml_tpu.model.Model`."""
 
@@ -85,8 +100,9 @@ class ServingApp:
         # production overload posture turns on: bounded in-flight admission
         # (429 + Retry-After past the cap) and a default per-request deadline
         # (503 shed for work the client has given up on). Tunable via
-        # configure_overload() / the serve CLI flags.
-        self.server.max_inflight = SERVE_MAX_INFLIGHT
+        # configure_overload() / the serve CLI flags. The cap follows the
+        # engine the model already carries (default_max_inflight).
+        self.server.max_inflight = default_max_inflight(getattr(model, "generation_batcher", None))
         self.server.default_deadline_ms = SERVE_DEFAULT_DEADLINE_MS
         self.server.on_drained = self._on_drained
         self.metrics = ServingMetrics()

@@ -93,12 +93,14 @@ from unionml_tpu.serving.tenancy import (
     current_tenant,
     priority_name,
 )
+from unionml_tpu.models.layers import SlotPlane
 from unionml_tpu.models.generate import (
     Generator,
     PrefixCache,
     chunk_aligned,
     gather_paged_rows,
-    cache_layout,
+    cache_layouts,
+    has_slot_planes,
     init_cache,
     init_paged_cache,
     paste_prefix_rows,
@@ -118,6 +120,18 @@ def _plane(layer: Any) -> Any:
     """One of a cache layer's planes (not its table): the shape oracle of the
     programs that move rows and pages, whatever the model's layout names."""
     return next(buf for name, buf in layer.items() if name != "table")
+
+
+def _slot_layers(pool_cache: Any) -> "Tuple[bool, ...]":
+    """Which of a pool's layers keep a row a slot (a recurrent state, a convolution's
+    tail: ``[slots, *shape]`` planes) and not pages: those with no block table
+    (:func:`~unionml_tpu.models.generate.init_paged_cache`)."""
+    return tuple("table" not in layer for layer in pool_cache)
+
+
+def _first_paged(cache: Any, slot_layers: "Tuple[bool, ...]") -> Any:
+    """The first layer of ``cache`` (a pool, a row cache, a run of pages) that is paged by position."""
+    return next(layer for layer, is_slot in zip(cache, slot_layers) if not is_slot)
 
 
 def _tev(session: "_Session", name: str, **attrs: Any) -> None:
@@ -554,6 +568,10 @@ class ContinuousBatcher:
         if max_admissions is None:
             max_admissions = serve_max_admissions()
         self.max_admissions = max(int(max_admissions), 1) if max_admissions else 1
+        #: whether a layer of the model keeps state with no position axis, one row a slot (a
+        #: recurrent state): what no position of a pool can give back, so the paths that resume
+        #: in the middle of a sequence are refused below, each by name (``config.draft`` already by ``Generator``)
+        slot_state = has_slot_planes(generator.module.config)
         #: speculative mode: with ``config.draft`` set, resident rows advance by
         #: draft-and-verify ROUNDS instead of single decode steps — the engine
         #: drives the SpeculativeGenerator's batch round loop (per-row floors
@@ -631,6 +649,12 @@ class ContinuousBatcher:
         #: admit_chunk: constructor kwarg, then the serve CLI's
         #: UNIONML_TPU_PREFIX_CACHE export; off is the default.
         enable_radix = serve_prefix_cache() if prefix_cache is None else bool(prefix_cache)
+        if enable_radix and slot_state:
+            raise ValueError(
+                "prefix_cache over a model that keeps a recurrent state a slot: a hit at position p needs "
+                "every such layer's state at p, and the pool holds only each slot's newest"
+                + ("" if prefix_cache else " (UNIONML_TPU_PREFIX_CACHE switched it on: unset it for this model)")
+            )
         if enable_radix:
             if cfg.draft is not None:
                 raise ValueError(
@@ -693,13 +717,24 @@ class ContinuousBatcher:
         mcfg = generator.module.config
         #: the planes a layer keeps a position in, by name: heads, width, bytes a value
         #: (the model's own layout: keys and values, or a latent layer's one row)
-        self._kv_layout = {
-            name: (heads, width, jnp.dtype(dtype).itemsize)
-            for name, (heads, width, dtype) in cache_layout(mcfg, cfg.kv_cache_dtype).items()
-        }
-        self._block_bytes = int(
-            mcfg.n_layers * block_size * sum(heads * width * size for heads, width, size in self._kv_layout.values())
-        )
+        #: a layer states either those or planes with no position axis, one row a slot
+        #: (models/layers.py SlotPlane: a recurrent state): by name, shape and bytes a value
+        self._kv_layout, self._slot_layout = {}, {}
+        self._block_bytes = self._slot_bytes = 0
+        layouts = cache_layouts(mcfg, cfg.kv_cache_dtype)
+        for layout in layouts:
+            for name, plane in layout.items():
+                if isinstance(plane, SlotPlane):
+                    self._slot_layout[name] = (tuple(plane.shape), jnp.dtype(plane.dtype).itemsize)
+                    self._slot_bytes += int(np.prod(plane.shape)) * jnp.dtype(plane.dtype).itemsize
+                else:
+                    heads, width, dtype = plane
+                    self._kv_layout[name] = (heads, width, jnp.dtype(dtype).itemsize)
+                    self._block_bytes += block_size * heads * width * jnp.dtype(dtype).itemsize
+        if self._slot_bytes and not self._block_bytes:
+            raise ValueError("the engine's pool is pages: a model none of whose layers keeps a position has none")
+        #: which layers keep a row a slot, for the one program that cannot ask a pool (the handoff's export); () with none
+        self._slot_layers = tuple(isinstance(next(iter(layout.values())), SlotPlane) for layout in layouts) if slot_state else ()
         self._kv_dtype_label = cfg.kv_cache_dtype or str(jnp.dtype(mcfg.dtype))
         self._free_blocks: "List[int]" = list(range(self.pool_blocks))
         self._slot_blocks: Dict[int, "List[int]"] = {}
@@ -767,7 +802,7 @@ class ContinuousBatcher:
         # the pool, by the page write the local paste ends in. One compile per
         # distinct page count, each a trivial reshape/scatter; bounded by
         # max_blocks.
-        self._export_pages_fn = jax.jit(self._export_pages_impl, static_argnums=(1, 2))
+        self._export_pages_fn = jax.jit(self._export_pages_impl, static_argnums=(1, 2, 3))
         self._paged_page_admit_fn = jax.jit(self._paged_page_admit_impl, donate_argnums=(0,))
         # a constrained generator's DFA state enters the carry's tail at admission
         self._slot_set_fn = jax.jit(lambda arr, slot, value: arr.at[slot].set(value), donate_argnums=(0,))
@@ -896,7 +931,7 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------ device fns
 
     @staticmethod
-    def _export_pages_impl(row_cache, n_blocks, block_size):
+    def _export_pages_impl(row_cache, n_blocks, block_size, slot_layers=()):
         """Lay a prefilled ``[1, cache_len, H, last]`` row as its first
         ``n_blocks`` block-sized pages in POOL layout
         (``[H, n_blocks, block_size, last]``): the handoff payload, and the
@@ -905,10 +940,15 @@ class ContinuousBatcher:
         ``max_blocks``, inside its one program). ``cache_len`` need not be a
         block multiple: where the last page reaches past the row's end it is
         zero-padded (``lengths`` says how many positions are live; the decode
-        read masks the rest)."""
+        read masks the rest). A layer ``slot_layers`` (static, a flag a layer;
+        none by default) marks keeps a row a slot and no pages: its planes
+        ``[1, *shape]`` ride beside the pages as they are."""
         width = n_blocks * block_size
         pages = []
-        for layer in row_cache:
+        for index, layer in enumerate(row_cache):
+            if index < len(slot_layers) and slot_layers[index]:
+                pages.append(dict(layer))
+                continue
             page = {}
             for name, buf in layer.items():
                 sliced = buf[0, :width]
@@ -924,9 +964,24 @@ class ContinuousBatcher:
         (``[H, n, block_size, last]``, the pool's own layout) becomes block
         ``ids[i]``, whole. A plane keeps its layout through it (a write of
         ``[H, last]`` slabs a position made XLA re-lay every pool heads-minor
-        and back); ids that repeat (the scratch block's) take any one writer."""
+        and back); ids that repeat (the scratch block's) take any one writer.
+        A layer with no table holds no page and passes through."""
         return tuple(
             {**layer, **{name: layer[name].at[:, ids].set(page[name].astype(layer[name].dtype)) for name in page}}
+            if "table" in layer else layer
+            for layer, page in zip(cache, pages)
+        )
+
+    @staticmethod
+    def _write_slot_rows(cache, pages, slot):
+        """The write of the layers that keep a row a slot: each of their planes' one row
+        (``[1, *shape]``, riding in ``pages``) becomes row ``slot`` of the pool's, whole —
+        whatever the slot's last tenant left there is gone."""
+        return tuple(
+            layer if "table" in layer else {
+                name: jax.lax.dynamic_update_slice(buf, page[name].astype(buf.dtype), (slot,) + (0,) * (buf.ndim - 1))
+                for name, buf in layer.items()
+            }
             for layer, page in zip(cache, pages)
         )
 
@@ -947,12 +1002,14 @@ class ContinuousBatcher:
         wrote — which already hold exactly the pages' content, so re-writing
         them per admission would be wasted bandwidth (and, for tree-owned
         pages, a data race against their other readers)."""
-        n_blocks = _plane(pages[0]).shape[1]
-        scratch = _plane(cache[0]).shape[1] - 1  # scratch is the last pool block
+        slot_layers = _slot_layers(cache)
+        n_blocks = _plane(_first_paged(pages, slot_layers)).shape[1]
+        scratch = _plane(_first_paged(cache, slot_layers)).shape[1] - 1  # scratch is the last pool block
         ids = jnp.where(jnp.arange(n_blocks) < skip, scratch, blocks_row[:n_blocks])
         new_layers = tuple(
             {**layer, "table": jax.lax.dynamic_update_slice(layer["table"], blocks_row[None], (slot, 0))}
-            for layer in cls._write_pages(cache, pages, ids)
+            if "table" in layer else layer
+            for layer in cls._write_slot_rows(cls._write_pages(cache, pages, ids), pages, slot)
         )
         tok = jax.lax.dynamic_update_slice(tok, row_tok.astype(tok.dtype), (slot,))
         lengths = jax.lax.dynamic_update_slice(lengths, row_len.astype(lengths.dtype), (slot,))
@@ -973,8 +1030,9 @@ class ContinuousBatcher:
         length hold what the row held there (zeros past ``cache_len``), and
         ``lengths`` masks them. The device trace names this program by this
         function (``perf/layer_metrics/admit_paste_ms.py``)."""
-        block_size = _plane(cache[0]).shape[2]  # pools are heads-major [H_kv, NB, bs, last]
-        pages = cls._export_pages_impl(row_cache, blocks_row.shape[0], block_size)
+        slot_layers = _slot_layers(cache)
+        block_size = _plane(_first_paged(cache, slot_layers)).shape[2]  # pools are heads-major [H_kv, NB, bs, last]
+        pages = cls._export_pages_impl(row_cache, blocks_row.shape[0], block_size, slot_layers)
         return cls._paged_page_admit_impl(cache, pages, tok, lengths, done, slot, row_tok, row_len, blocks_row, skip)
 
     @classmethod
@@ -1888,6 +1946,13 @@ class ContinuousBatcher:
                 "planes": {name: {"heads": h, "width": w, "value_bytes": b} for name, (h, w, b) in self._kv_layout.items()},
                 "block_bytes": self._block_bytes,
             }
+            if self._slot_bytes:
+                # the layers that keep a row a slot instead (a recurrent state): what a slot of
+                # them weighs whatever its length, beside what a block of the others does
+                snapshot["kv_layout"]["slot_planes"] = {
+                    name: {"shape": list(shape), "value_bytes": b} for name, (shape, b) in self._slot_layout.items()
+                }
+                snapshot["kv_layout"]["slot_bytes"] = self._slot_bytes
             if self.prefix is not None:
                 # the static prefix's partial tail block is NOT among the
                 # seeded shared pages — each admission writes those
@@ -1929,6 +1994,11 @@ class ContinuousBatcher:
                     snapshot[key] = {**counted(names), "decode": counted(names, "decode")}
                 grouped = {n for names in self.gen.counter_views.values() for n in names}
                 snapshot.update(counted([n for n in self.gen.counter_names if n not in grouped]))
+            if self._slot_bytes:
+                # beside what the model counted of its recurrent state (its "state" view, where it has one)
+                snapshot.setdefault("state", {}).update(
+                    slot_bytes=self._slot_bytes, state_bytes_live=len(self._sessions) * self._slot_bytes
+                )
             if self.role is not None:
                 snapshot["role"] = self.role
             if self.role is not None or self.handoffs_exported or self.handoffs_imported:
@@ -2721,7 +2791,7 @@ class ContinuousBatcher:
             # the payload scales with the prompt, not with cache_len,
             # in-process or across hosts
             n_blocks = -(-row_len_host // self.block_size)
-            pages = self._issue(self._export_pages_fn, adm.row_cache, n_blocks, self.block_size)
+            pages = self._issue(self._export_pages_fn, adm.row_cache, n_blocks, self.block_size, self._slot_layers)
         adm.row_cache = adm.last = None  # the row never leaves the engine
         with self._lock:
             if adm in self._admissions:
@@ -2957,7 +3027,7 @@ class ContinuousBatcher:
         # speculative mode keeps BOTH caches' tables (carry slots 0 and 1)
         caches = (0,) if self._spec is None else (0, 1)
         at = 2 if self._spec is None else 3  # lengths, then done
-        tables = tuple(tuple(layer["table"] for layer in state[c]) for c in caches)
+        tables = tuple(tuple(layer["table"] for layer in state[c] if "table" in layer) for c in caches)
         # the pools are never passed: their buffers stay where they are. The
         # program is handed copies: a backend may read a numpy argument in
         # place, after this thread has gone on editing it
@@ -2965,7 +3035,8 @@ class ContinuousBatcher:
             tables, state[at], state[at + 1], self._table_host.copy(), self._edited_host, self._released_host
         )
         for c, synced in zip(caches, tables):
-            state[c] = tuple({**layer, "table": t} for layer, t in zip(state[c], synced))
+            fresh = iter(synced)  # a layer that keeps a row a slot has no table
+            state[c] = tuple({**layer, "table": next(fresh)} if "table" in layer else layer for layer in state[c])
         self._carry = tuple(state)
         self._edited_host = np.zeros_like(self._edited_host)
         self._released_host = np.zeros_like(self._released_host)
