@@ -174,7 +174,7 @@ def test_afmoe_decode_steps_compiles_for_v5e(chip):
     """The afmoe decode program at published widths (a dense sliding layer, a sliding and a full expert layer,
     32 held of 256 experts) over the cell's paged cache, through the kernel reads (forced, as above): Mosaic
     takes the windowed read, the plain read and — the trace's backend being the CPU, the routed product is
-    ``ragged_dot`` here — XLA's own grouped kernel; the program counts its five counters into the carry."""
+    ``ragged_dot`` here — XLA's own grouped kernel; the program counts its counters into the carry."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
 
@@ -185,10 +185,10 @@ def test_afmoe_decode_steps_compiles_for_v5e(chip):
     cache = on_chip(lambda: init_paged_cache(config, slots, pool, page, pages, fill_block=pool - 1))
     assert cache[0]["k"].shape == (8, pool, page, 128)  # the published head width, not dim // n_heads = 64
     tok, lengths, done = (jax.ShapeDtypeStruct((slots,), dtype, sharding=chip) for dtype in (jnp.int32, jnp.int32, jnp.bool_))
-    counts = jax.ShapeDtypeStruct((5,), jnp.int32, sharding=chip)
     gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
+    counts = jax.ShapeDtypeStruct((len(gen.counter_names),), jnp.int32, sharding=chip)
     compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
-    assert gen.decode_attention_path == "paged_kernel" and gen.counter_names[-1] == "decode_window_pages_skipped"
+    assert gen.decode_attention_path == "paged_kernel" and "decode_window_pages_skipped" in gen.counter_names
     text = compiled.as_text()
     assert text.count("paged_window_attention") >= 2  # the two sliding layers' reads
     assert not re.search(rf"= bf16\[8,{pool},{page},128\]\S* copy\(", text)  # no pool re-laid for a kernel
@@ -232,11 +232,18 @@ def test_latent_decode_steps_compile_for_v5e_over_one_plane(chip):
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
 
 
+def _walks_the_row_in_blocks(text, layers, chunk, cache_len):
+    """The compiled chunk program reads its row cache in key blocks: one ``while`` a layer, and no float32 array
+    (scores, weights) whose key extent is the whole row."""
+    return len(re.findall(r" while\(", text)) >= layers and not re.search(rf"f32\[[\d,]*{chunk},{cache_len}\]", text)
+
+
 def test_latent_prefill_chunk_compiles_for_v5e(chip):
     """A 256-token chunk over an 8,960-position latent row cache (the cell's admission), by the absorbed read: the row
     is one plane ``[1, 8960, 1, 640]`` a layer (11 MB; XLA re-lays it once a layer on the way out, 0.03 ms at the
-    chip's peak: PERF.md section 7), and the program's temporaries stay under 0.5 GB (30 MB in this compile: the
-    scores ``[20, 256, 8960]`` never stand whole; the expanded read's up-projected row left 273 MB)."""
+    chip's peak: PERF.md section 7), walked in key blocks by one ``while`` a layer (no ``[20, 256, 8960]`` array
+    of scores exists, masked or not), and the program's temporaries stay under 0.5 GB (17 MB in this compile; the
+    expanded read's up-projected row left 273 MB)."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_cache
 
@@ -253,6 +260,7 @@ def test_latent_prefill_chunk_compiles_for_v5e(chip):
     args = (params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), scalar(jnp.int32, (1,)), row, scalar(jnp.bool_, (1,)), scalar(jnp.float32, (1, 2048)))
     compiled = gen._prefill_chunk.lower(*args).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    assert _walks_the_row_in_blocks(compiled.as_text(), module.config.n_layers, chunk, cache_len)
 
 
 #: the serving cells' engines (perf/configs/*.json "engine" + perf/workloads/*.json): module, widest prompt bucket,
@@ -324,6 +332,12 @@ def test_admission_programs_compile_for_v5e_without_a_copied_row(chip, cell):
         # the merge's select is a [1, dim] row: the cache row is still written in place (donated), never copied
         # (but for the one-head latent row, which XLA re-lays once a layer on the way out: the latent chunk's test above)
         assert heads == 1 or not row_copy.search(step.as_text())
+        # the chunk's read walks a long row in key blocks: no array of scores over the whole row ([48, 256, 5384],
+        # [20, 256, 8968]); chat_sat's and docs' rows are short enough to be read whole, with no loop
+        from unionml_tpu.ops.attention import ONE_TRIP_KEYS
+
+        walked = _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, chunk, batcher.cache_len)
+        assert walked == (batcher.cache_len > ONE_TRIP_KEYS) == (cell in ("chat_wide_sat", "long_sat"))
     finally:
         batcher.close()
 

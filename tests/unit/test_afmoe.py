@@ -205,19 +205,35 @@ def routing_counts(weights, cfg, sequence, n_prompt, chunk):
 
 
 @pytest.mark.parametrize("n_prompt", [21, 40])
-def test_engine_counters_equal_the_reference_routing(weights, n_prompt):
+def test_engine_counters_equal_the_reference_routing(weights, n_prompt, monkeypatch):
     """``stats()["moe"]``: pairs chosen, pairs on held experts, held experts hit and the largest load, over the
-    prefill chunks and the decode steps of one request (free slots ride along masked: they count nothing)."""
+    prefill chunks and the decode steps of one request (free slots ride along masked: they count nothing).
+    ``kv_positions_attended`` / ``kv_positions_needed``: a chunk over positions ``start .. end - 1`` walks a layer's
+    row in key blocks from the block of its first visible slot (``first = max(start - window + 1, 0)`` on a sliding
+    layer, 0 on a full one) to the block of ``end - 1``: ``min(ceil(end / block) * block, cache_len) -
+    first // block * block`` positions covered, ``end - first`` needed; the one-token reads count neither."""
+    from unionml_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "KEY_BLOCK", 12)
+    monkeypatch.setattr(attention, "ONE_TRIP_KEYS", 12)  # or rows this short would be read whole
     cfg, chunk, new = config(), 16, 9  # 8 decode steps: two whole dispatches, so no step runs past the budget
     gen = Generator(module_for(cfg), weights, GenerationConfig(max_new_tokens=new, temperature=0.0, prompt_buckets=(16, 32, 48)))
     engine = ContinuousBatcher(gen, slots=4, decode_chunk=4, block_size=4, admit_chunk=chunk, pool_blocks=64)
     try:
         p = prompt(n_prompt, seed=11)
         out = [int(t) for piece in engine.submit(p) for t in piece]
-        stats = engine.stats()
+        stats, cache_len = engine.stats(), engine.cache_len
     finally:
         engine.close()
     total, decode = routing_counts(weights, cfg, p + out[:-1], n_prompt, chunk)
     assert stats["moe"] == {**total, "decode": decode}
     assert stats["decode_window_pages_skipped"] == 0  # the gather read masks the window: it skips nothing
     assert decode["routed_pairs"] == 2 * 4 * (new - 1)
+    attended = needed = 0
+    for start in range(0, n_prompt, chunk):
+        end = min(start + chunk, n_prompt)
+        for kind in cfg["layer_types"]:
+            first = max(start - WINDOW + 1, 0) if kind == SLIDING else 0
+            attended += min(-(-end // 12) * 12, cache_len) - first // 12 * 12
+            needed += end - first
+    assert cache_len > 48 and (stats["kv_positions_attended"], stats["kv_positions_needed"]) == (attended, needed)

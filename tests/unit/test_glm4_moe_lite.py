@@ -185,10 +185,16 @@ def test_the_shares_of_a_block_add_up_to_the_uncut_reference_layer(held):
 
 
 @pytest.mark.parametrize("n_prompt", [21, 40])
-def test_engine_counters_count_the_latent_reads(weights, n_prompt):
-    """``stats()["latent"]``: over one request, the decode reads covered each step's visible length a layer, the
-    chunks' reads the whole row cache a layer, of which causality needed each chunk's last position + 1; the
-    routing's counters ride beside them under ``stats()["moe"]``, and ``"decode"`` sums the decode dispatches."""
+def test_engine_counters_count_the_latent_reads(weights, n_prompt, monkeypatch):
+    """``stats()["latent"]``: over one request, the decode reads covered each step's visible length a layer; a
+    chunk's read walked the row cache in key blocks up to its last position, so it covered
+    ``min(ceil(end / block) * block, cache_len)`` positions a layer (``block`` is ``KEY_BLOCK``, 12 here; ``end`` the
+    chunk's last position + 1), of which causality needed ``end``; the routing's counters ride beside them under
+    ``stats()["moe"]``, and ``"decode"`` sums the decode dispatches."""
+    from unionml_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "KEY_BLOCK", 12)  # the test's rows are 57 and 73 positions: five and seven blocks
+    monkeypatch.setattr(attention, "ONE_TRIP_KEYS", 12)  # or rows this short would be read whole
     cfg, chunk, new, layers = config(), 16, 9, 4  # 8 decode steps: two whole dispatches
     gen = Generator(module_for(cfg), weights, GenerationConfig(max_new_tokens=new, temperature=0.0, prompt_buckets=(16, 32, 48)))
     assert gen.counter_names == MOE_COUNTERS + LATENT_COUNTERS
@@ -199,9 +205,11 @@ def test_engine_counters_count_the_latent_reads(weights, n_prompt):
     finally:
         engine.close()
     ends = [min(s + chunk, n_prompt) for s in range(0, n_prompt, chunk)]
+    walked = [min(-(-end // 12) * 12, cache_len) for end in ends]
+    assert cache_len > 48 and walked[0] == 24 and walked[-1] < cache_len  # no chunk walks the whole row
     read = layers * sum(n_prompt + 1 + step for step in range(new - 1))  # the token just written is visible
     assert stats["latent"] == {
-        "latent_positions_read": read, "latent_positions_attended": layers * len(ends) * cache_len,
+        "latent_positions_read": read, "latent_positions_attended": layers * sum(walked),
         "latent_positions_needed": layers * sum(ends),
         "decode": {"latent_positions_read": read, "latent_positions_attended": 0, "latent_positions_needed": 0},
     }

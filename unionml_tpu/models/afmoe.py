@@ -166,8 +166,8 @@ class AfmoeTransformer(nn.Module):
 
     config: AfmoeConfig
 
-    counters = MOE_COUNTERS + ("decode_window_pages_skipped",)
-    counter_views = {"moe": MOE_COUNTERS}  # the serving engine's stats()["moe"]; the window's count under its own name
+    counters = MOE_COUNTERS + ("decode_window_pages_skipped", "kv_positions_attended", "kv_positions_needed")
+    counter_views = {"moe": MOE_COUNTERS}  # the serving engine's stats()["moe"]; the attention's counts under their own names
 
     @nn.compact
     def __call__(

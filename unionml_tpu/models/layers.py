@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from flax import linen as nn
 from jax import lax
 
-from unionml_tpu.ops.attention import multihead_attention
+from unionml_tpu.ops.attention import blocked_cached_attention, cache_visible, multihead_attention, walks_in_blocks
 
 Dtype = Any
 
@@ -292,11 +292,13 @@ class Attention(nn.Module):
         if cache is not None:
             # Incremental decoding: the new rows' K/V land in the cache at each
             # example's next free slots (= the absolute positions), and attention
-            # runs over the full static-shape buffer with an explicit visibility
+            # runs over the static-shape buffer under an explicit visibility
             # mask — key slot j is visible to the query at absolute position p
             # iff j <= p, which is causal over everything written so far and
-            # hides slots not yet (re)written. Static shapes throughout: the
-            # decode step compiles exactly once per (batch, cache_len).
+            # hides slots not yet (re)written. One token reads the whole buffer
+            # under it; several walk it in key blocks up to the last live query
+            # (_cached_read). Static shapes throughout: the decode step compiles
+            # exactly once per (batch, cache_len), the chunk once whatever its offset.
             if positions is None or positions.ndim != 2:
                 raise ValueError("cached attention requires per-example positions [B, L]")
             if mask is not None:
@@ -330,18 +332,12 @@ class Attention(nn.Module):
                     "k_scale": _write_cache(cache["k_scale"], k_scale, starts),
                     "v_scale": _write_cache(cache["v_scale"], v_scale, starts),
                 }
-                keys = (cache["k"].astype(jnp.float32) * cache["k_scale"]).astype(q.dtype)
-                values = (cache["v"].astype(jnp.float32) * cache["v_scale"]).astype(q.dtype)
             else:
                 cache = {
                     "k": _write_cache(cache["k"], k, starts),
                     "v": _write_cache(cache["v"], v, starts),
                 }
-                keys = cache["k"].astype(q.dtype)
-                values = cache["v"].astype(q.dtype)
-            visible = self._visible(jnp.arange(cache["k"].shape[1]), positions)  # [B,1,L,S_max]
-            out = multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla")
-            return project(out), cache
+            return project(self._cached_read(q, cache, positions, token_mask)), cache
 
         # uncached forward: expose post-RoPE K/V for cache assembly (materialized
         # only when the caller passes mutable=["kvs"], e.g. the sequence-parallel
@@ -367,15 +363,54 @@ class Attention(nn.Module):
 
         return project(out)
 
-    def _visible(self, slots: jax.Array, positions: jax.Array) -> jax.Array:
-        """``[B, 1, L, S]``: cache slot ``j`` is visible to the query at absolute
-        position ``p`` iff ``j <= p`` — causal over everything written so far,
-        hiding slots not yet (re)written — and, under a window, ``j > p - window``."""
-        at = positions[:, None, :, None]
-        visible = slots[None, None, None, :] <= at
-        if self.window is not None:
-            visible = visible & (slots[None, None, None, :] > at - self.window)
-        return visible
+    def _cached_read(self, q, rows, positions, token_mask):
+        """Attend ``q [B, L, H, D]`` over a row cache's logical planes (``rows["k"]``,
+        ``rows["v"]``: ``[B, S, H_kv, D]``, with their ``_scale`` planes when int8) under
+        :func:`~unionml_tpu.ops.attention.cache_visible` and the layer's window. One
+        token, and any read of a short row, attends the whole row under the mask;
+        several tokens over a long one (a prefill chunk, a monolithic prefill, a
+        verify: :func:`~unionml_tpu.ops.attention.walks_in_blocks`) walk it in key
+        blocks from the window's first visible slot to the last live query's
+        (:func:`~unionml_tpu.ops.attention.blocked_cached_attention`), dequantising an
+        int8 row a block at a time. Several tokens count ``kv_positions_attended`` (key
+        positions the read covered, a row with a live token) and ``kv_positions_needed``
+        (of those, what the visibility rule lets such a row's live queries see)."""
+
+        def dequant(plane: jax.Array, scale: Optional[jax.Array]) -> jax.Array:
+            return plane.astype(q.dtype) if scale is None else (plane.astype(jnp.float32) * scale).astype(q.dtype)
+
+        batch, length, heads, head_dim = q.shape
+        size, n_kv = rows["k"].shape[1:3]
+        live = jnp.ones((batch, length), bool) if token_mask is None else token_mask
+        if walks_in_blocks(length, size):
+            # query heads grouped by the KV head they read (h = kv * group + g, as ``jnp.repeat`` lays them)
+            grouped = jnp.transpose(q.reshape(batch, length, n_kv, heads // n_kv, head_dim), (0, 2, 3, 1, 4))
+
+            def score(k, v, k_scale=None, v_scale=None):
+                scores = jnp.einsum("bkgld,bskd->bkgls", grouped, dequant(k, k_scale), preferred_element_type=jnp.float32)
+                return scores.reshape(batch, heads, length, -1) * head_dim**-0.5
+
+            def value(weights, k, v, k_scale=None, v_scale=None):
+                weights = weights.reshape(batch, n_kv, heads // n_kv, length, -1)
+                out = jnp.einsum("bkgls,bskd->bkgld", weights, dequant(v, v_scale), preferred_element_type=jnp.float32)
+                return out.reshape(batch, heads, length, head_dim)
+
+            out, covered = blocked_cached_attention(
+                score, value, [rows[name] for name in ("k", "v", "k_scale", "v_scale") if name in rows], positions, live,
+                heads=heads, width=head_dim, window=self.window, dtype=q.dtype,
+            )
+        else:
+            keys, values = dequant(rows["k"], rows.get("k_scale")), dequant(rows["v"], rows.get("v_scale"))
+            visible = cache_visible(jnp.arange(size), positions, self.window)  # [B, 1, L, S]
+            out, covered = multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla"), size
+        if length > 1:
+            needed = jnp.max(jnp.where(live, positions + 1, 0), axis=1)  # [B]: a row's last live query's, 0 with none
+            if self.window is not None:  # a row's first live query sees no further back than its window
+                first = jnp.min(jnp.where(live, positions, jnp.iinfo(jnp.int32).max), axis=1)
+                needed = jnp.where(live.any(axis=1), needed - jnp.maximum(first - self.window + 1, 0), 0)
+            self.sow("counters", "kv_positions_attended", jnp.sum(live.any(axis=1), dtype=jnp.int32) * covered)
+            self.sow("counters", "kv_positions_needed", jnp.sum(needed, dtype=jnp.int32))
+        return out
 
     def _paged_cached_attention(self, q, k, v, positions, cache, token_mask=None):
         """The paged write+read: scatter new rows through the block table, then
@@ -386,9 +421,11 @@ class Attention(nn.Module):
         named pages stream block by block, up to the row's length, at KV-head
         width; no gathered copy); a CPU or GPU backend, ``L > 1``, int8 pages
         and pools sharded over a mesh take the portable gather (``pool[:,
-        table]`` back to the logical layout under the same ``slot <= position``
-        visibility mask as the contiguous branch — numerically identical to
-        it). ``impl="xla"`` forces the gather, ``impl="flash"`` the kernel.
+        table]`` back to the logical layout, then :meth:`_cached_read`, the
+        contiguous branch's own read under the same ``slot <= position``
+        visibility: one token over the whole gathered row, several in key
+        blocks — numerically identical to that branch by construction).
+        ``impl="xla"`` forces the gather, ``impl="flash"`` the kernel.
         Pools are heads-major ``[H_kv, n_pages, page_size, last]``. Scatter
         indices collide only on the scratch block (finished rows), where the
         winning value is irrelevant — real slots own disjoint blocks."""
@@ -420,8 +457,6 @@ class Attention(nn.Module):
                 "v_scale": scatter(cache["v_scale"], v_scale),
                 "table": table,
             }
-            keys = (logical(cache["k"]).astype(jnp.float32) * logical(cache["k_scale"])).astype(q.dtype)
-            values = (logical(cache["v"]).astype(jnp.float32) * logical(cache["v_scale"])).astype(q.dtype)
         else:
             if path == PAGED_KERNEL:
                 cache = {"k": scatter_rows(cache["k"], k), "v": scatter_rows(cache["v"], v), "table": table}
@@ -440,10 +475,8 @@ class Attention(nn.Module):
                     self.sow("counters", "decode_window_pages_skipped", jnp.sum(skipped, dtype=jnp.int32))
                 return out[:, None], cache
             cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v), "table": table}
-            keys = logical(cache["k"]).astype(q.dtype)
-            values = logical(cache["v"]).astype(q.dtype)
-        visible = self._visible(jnp.arange(keys.shape[1]), positions)  # [B, 1, L, MB * bs]
-        return multihead_attention(q, keys, values, causal=False, mask=visible, impl="xla"), cache
+        rows = {name: logical(pool) for name, pool in cache.items() if name != "table"}  # [B, MB * bs, H_kv, last]
+        return self._cached_read(q, rows, positions, token_mask), cache
 
 def _masked_softmax(scores: jax.Array, visible: jax.Array, dtype: Dtype) -> jax.Array:
     """Softmax over the last axis in float32 under ``visible`` (True = attend), as
@@ -499,11 +532,15 @@ class LatentAttention(nn.Module):
     forward is expanded; every cached read is absorbed. One token (decode) over a
     paged pool goes where :func:`~unionml_tpu.ops.paged_attention.paged_read_path`
     says — on a TPU the paged-attention kernel with the latent pages as K and as
-    V, else the gather. Several tokens (a prefill chunk over the row cache, a
-    verify) attend on the row's latent under a mask: measured on a v5e, a
-    256-token chunk over an 8,960-position row costs 0.86 ms a layer absorbed
-    and 1.17 expanded (PERF.md section 6, "PR 30"), so the expanded cached read
-    was not kept.
+    V, else the gather, whole under a mask. Several tokens (a prefill chunk over
+    the row cache, a verify, contiguous or gathered) over a long row
+    (:func:`~unionml_tpu.ops.attention.walks_in_blocks`) walk its latent in key
+    blocks up to the last live query's position
+    (:func:`~unionml_tpu.ops.attention.blocked_cached_attention`), over a short one
+    they attend it whole under the mask. Measured on a v5e, a 256-token chunk over
+    an 8,968-position row costs a layer 0.85 ms whole (1.17 expanded, PERF.md
+    section 6, "PR 30": the expanded cached read was not kept) and, walked, 0.15 ms
+    at offset 0, 0.32 at 2,304, 0.53 at 4,480 and 0.78 at 7,936 (same section, "PR 34").
 
     The cache is one plane, ``{"k": [B, S, 1, width]}`` (paged: ``[1, n_pages,
     page, width]`` + ``table``) whose ``width`` is the cache's own (the model's
@@ -513,8 +550,9 @@ class LatentAttention(nn.Module):
     of it is held. Counts into the ``counters`` collection:
     ``latent_positions_read`` (one-token reads: the live rows' lengths, what the
     read had to cover), ``latent_positions_attended`` (several-token reads: key
-    positions the read covered, masked or not) and ``latent_positions_needed``
-    (of those, the positions up to each live row's last query: what causality needs).
+    positions the read covered a row with a live token: the blocks walked, clipped to
+    the row, or a short row whole) and ``latent_positions_needed`` (of those, the
+    positions up to each live row's last query: what causality needs).
 
     ``q_rank=None``: no query bottleneck, one full-rank ``q_proj`` and no query norm.
     ``gated``: each head's output is multiplied by ``sigmoid(gate_proj(a))_h``, one
@@ -594,7 +632,7 @@ class LatentAttention(nn.Module):
                 return jnp.einsum("blhc,chd->blhd", out.astype(self.dtype), kv_up[..., self.nope_dim :])
 
         def absorbed(rows: jax.Array, visible: jax.Array) -> jax.Array:
-            """Attend on latent ``rows`` themselves; nothing is built a key position."""
+            """Attend on latent ``rows`` themselves, whole under ``visible``; nothing is built a key position."""
             q_abs = absorb()
             with jax.named_scope("mla.absorb"):
                 keys = rows[:, :, 0, : self.kv_rank + self.rope_dim].astype(self.dtype)
@@ -639,15 +677,32 @@ class LatentAttention(nn.Module):
         else:
             cache = {"k": _write_cache(cache["k"], stored, positions[:, 0])}
             rows = cache["k"]
-        visible = jnp.arange(rows.shape[1])[None, None, None, :] <= positions[:, None, :, None]  # [B, 1, L, S]
-        if length == 1:
-            with jax.named_scope("mla.decode_read"):
-                return project(absorbed(rows, visible)), cache
-        self.sow("counters", "latent_positions_attended", jnp.sum(live.any(axis=1), dtype=jnp.int32) * rows.shape[1])
-        self.sow(
-            "counters", "latent_positions_needed", jnp.sum(jnp.max(jnp.where(live, positions + 1, 0), axis=1), dtype=jnp.int32)
-        )
-        return project(absorbed(rows, visible)), cache
+        if walks_in_blocks(length, rows.shape[1]):
+            q_abs = jnp.transpose(absorb(), (0, 2, 1, 3))  # [B, H, L, kv_rank + rope]
+
+            def score(block: jax.Array) -> jax.Array:
+                keys = block[:, :, 0, : self.kv_rank + self.rope_dim].astype(self.dtype)
+                return jnp.einsum("bhlw,bsw->bhls", q_abs, keys, preferred_element_type=jnp.float32) * scale
+
+            def value(weights: jax.Array, block: jax.Array) -> jax.Array:
+                values = block[:, :, 0, : self.kv_rank].astype(self.dtype)
+                return jnp.einsum("bhls,bsc->bhlc", weights, values, preferred_element_type=jnp.float32)
+
+            with jax.named_scope("mla.absorb"):
+                out, covered = blocked_cached_attention(
+                    score, value, [rows], positions, live, heads=heads, width=self.kv_rank, dtype=self.dtype
+                )
+            out = unabsorb(out)
+        else:
+            visible = cache_visible(jnp.arange(rows.shape[1]), positions)  # [B, 1, L, S]
+            with jax.named_scope("mla.decode_read" if length == 1 else "mla.absorb"):
+                out, covered = absorbed(rows, visible), rows.shape[1]
+        if length > 1:
+            self.sow("counters", "latent_positions_attended", jnp.sum(live.any(axis=1), dtype=jnp.int32) * covered)
+            self.sow(
+                "counters", "latent_positions_needed", jnp.sum(jnp.max(jnp.where(live, positions + 1, 0), axis=1), dtype=jnp.int32)
+            )
+        return project(out), cache
 
 
 class KimiDeltaAttention(nn.Module):
