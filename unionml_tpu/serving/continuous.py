@@ -805,7 +805,17 @@ class ContinuousBatcher:
         self._export_pages_fn = jax.jit(self._export_pages_impl, static_argnums=(1, 2, 3))
         self._paged_page_admit_fn = jax.jit(self._paged_page_admit_impl, donate_argnums=(0,))
         # a constrained generator's DFA state enters the carry's tail at admission
-        self._slot_set_fn = jax.jit(lambda arr, slot, value: arr.at[slot].set(value), donate_argnums=(0,))
+        def slot_set(arr, slot, value):
+            return arr.at[slot].set(value)
+
+        self._slot_set_fn = jax.jit(slot_set, donate_argnums=(0,))
+
+        # a speculative dispatch's per-row floor: every unfinished row gains >= decode_chunk tokens (capped by its
+        # budget); free slots are done and ignored
+        def spec_floor(produced, budget, chunk):
+            return jnp.minimum(produced + chunk, budget)
+
+        self._spec_floor_fn = jax.jit(spec_floor)
         self._build_admission_programs()
         if self._aot is not None:
             # the admission's page writes preload too — on a cold TPU the
@@ -1143,17 +1153,19 @@ class ContinuousBatcher:
             pre.layers for pre in (self.prefix, self._draft_prefix)[: len(models)] if pre is not None
         )
 
-    def _issue(self, fn: Any, *args: Any) -> Any:
-        """Hand the runtime one program or transfer of the admit phase (engine
-        thread): counted on the iteration's record as ``admit_dispatches``."""
-        log = self.engine_log  # engine thread only, like the pass's other counters
-        log.admit_dispatches += 1
-        return fn(*args)
+    def _issue(self, name: str, fn: Any, *args: Any, **kwargs: Any) -> Any:
+        """Hand the runtime one program or transfer (engine thread): the only
+        place the engine thread calls a jitted program. ``name`` is the
+        program's XLA module name, as a device trace prints it; the engine log
+        tallies the iteration's dispatches by it (``dispatched``; those inside
+        ``admit`` are ``admit_dispatches``) and asks what ``fn`` returns whether
+        the device still has work (``starved_s``)."""
+        return self.engine_log.dispatch(name, fn, *args, **kwargs)
 
     def _admission_setup(self, seed: int, total: int) -> tuple:
         """A cold admission's starting state, one dispatch:
         ``(lengths, key, row_valid, lasts, rows)``, the last two per model."""
-        return self._issue(self._setup_fn, np.uint32(seed), np.int32(total), self._setup_prefixes)
+        return self._issue("admit_setup", self._setup_fn, np.uint32(seed), np.int32(total), self._setup_prefixes)
 
     def _seed_shared_prefix(self, cache: Any, prefix_layers: Any) -> Any:
         """Write the prefix's FULL blocks into a pool once, as whole pages;
@@ -1308,20 +1320,20 @@ class ContinuousBatcher:
             if mode == "chunks":
                 for c in range(0, tokens.shape[1], chunk):
                     last, row_cache, _ = self._issue(
-                        gen._prefill_chunk, gen.params, tokens[:, c : c + chunk], np.int32(p0 + c),
+                        "prefill_chunk", gen._prefill_chunk, gen.params, tokens[:, c : c + chunk], np.int32(p0 + c),
                         lengths, row_cache, row_valid, last,
                     )
-                tok0 = self._issue(gen._first_token, gen.params, last, key, *cstate)
+                tok0 = self._issue("first_token", gen._first_token, gen.params, last, key, *cstate)
             elif mode == "sp":
                 if gen._sp_prefill_fn is None:
                     gen._sp_prefill_fn = gen._build_sp_prefill()
                 last = None
                 tok0, row_cache, _ = self._issue(
-                    gen._sp_prefill_fn, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
+                    "sp_prefill", gen._sp_prefill_fn, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
                 )
             else:
                 tok0, row_cache, last = self._issue(
-                    gen._prefill, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
+                    "prefill", gen._prefill, gen.params, tokens, lengths, row_cache, key, row_valid, *cstate
                 )
             filled.append((tok0, row_cache, last))
         tok0, row_cache, last = filled[0]
@@ -1417,9 +1429,9 @@ class ContinuousBatcher:
                 )[:, 0]
 
             self._lp0_fn = jax.jit(impl)
-        lp0 = self._issue(self._lp0_fn, gen.params, adm.last, adm.tok0, *adm.cstate)
-        with self.engine_log.phase("fetch"):
-            return float(np.asarray(lp0)[0])
+        lp0 = self._issue("impl", self._lp0_fn, gen.params, adm.last, adm.tok0, *adm.cstate)
+        (lp0,) = self.engine_log.fetch("first_logprob", lp0)
+        return float(lp0[0])
 
     def submit(
         self, prompt: Sequence[int], *, max_new_tokens: Optional[int] = None,
@@ -2652,7 +2664,7 @@ class ContinuousBatcher:
         # (device_put copies between disjoint device sets — and accepts the
         # numpy arrays a cross-host wire delivers)
         place = jax.device_put if self.gen.mesh is None else self.gen._place_paged_cache
-        adm.import_pages = self._issue(place, tuple(dict(layer) for layer in payload["pages"]))
+        adm.import_pages = self._issue("device_put", place, tuple(dict(layer) for layer in payload["pages"]))
         # host values: they ride the paste's own dispatch
         adm.tok0 = np.asarray([int(payload["first"])], np.int32)
         adm.row_len = np.asarray([int(payload["lengths"])], np.int32)
@@ -2704,7 +2716,8 @@ class ContinuousBatcher:
         # (the same program hands out the length, key and flags a cold set-up does:
         # the first sampled token is bit-identical to a cold admission's)
         adm.lengths, adm.key, adm.row_valid, (adm.last,), (adm.row_cache,) = self._issue(
-            self._cached_setup_fn, self._carry[0], adm.gather_row, np.uint32(adm.seed), np.int32(total)
+            "admit_setup_cached", self._cached_setup_fn,
+            self._carry[0], adm.gather_row, np.uint32(adm.seed), np.int32(total),
         )
         tokens = np.full((1, width), cfg.pad_id, np.int32)
         tokens[0, : len(suffix)] = np.asarray(suffix, np.int32)
@@ -2742,14 +2755,14 @@ class ContinuousBatcher:
         # host values: the chunk's columns and its offset travel with the dispatch
         sl, start = adm.tokens[:, c : c + adm.chunk], np.int32(adm.start + c)
         adm.last, adm.row_cache, counts = self._issue(
-            gen._prefill_chunk, gen.params, sl, start, adm.lengths, adm.row_cache, adm.row_valid, adm.last
+            "prefill_chunk", gen._prefill_chunk, gen.params, sl, start, adm.lengths, adm.row_cache, adm.row_valid, adm.last
         )
         if gen.counter_names:
             adm.counts.append(counts)  # read with the admission's first token: no fetch of their own
         if self._spec is not None:
             draft = self._spec._draft
             adm.d_last, adm.d_row_cache, _ = self._issue(
-                draft._prefill_chunk, draft.params, sl, start, adm.lengths,
+                "prefill_chunk", draft._prefill_chunk, draft.params, sl, start, adm.lengths,
                 adm.d_row_cache, adm.row_valid, adm.d_last,
             )
         adm.pos = c + adm.chunk
@@ -2761,7 +2774,7 @@ class ContinuousBatcher:
             pos=adm.pos, width=adm.width, chunk=adm.chunk,
         )
         if adm.pos >= adm.width:
-            adm.tok0 = self._issue(gen._first_token, gen.params, adm.last, adm.key, *adm.cstate)
+            adm.tok0 = self._issue("first_token", gen._first_token, gen.params, adm.last, adm.key, *adm.cstate)
             adm.row_len = adm.lengths
             adm.done = True
         return adm.chunk
@@ -2776,14 +2789,11 @@ class ContinuousBatcher:
         there is nothing left to decode anywhere."""
         cfg = self.gen.config
         session, slot = adm.session, adm.slot
-        with self.engine_log.phase("fetch"):
-            first = np.asarray(adm.tok0)
+        # the row's length is the handoff payload's; computed long before the token, it rides the token's wait
+        first, row_len = self.engine_log.fetch("export", adm.tok0, adm.row_len)
         hit_eos = cfg.eos_id is not None and int(first[0]) == cfg.eos_id
         done_now = hit_eos or session.produced + 1 >= session.max_new
-        row_len_host = 0
-        if not done_now:  # the handoff payload's length
-            with self.engine_log.phase("fetch"):
-                row_len_host = int(np.asarray(adm.row_len)[0])
+        row_len_host = 0 if done_now else int(row_len[0])
         pages = None
         if not done_now:
             # ship only the ceil(lengths / block_size) pages the prompt
@@ -2791,7 +2801,9 @@ class ContinuousBatcher:
             # the payload scales with the prompt, not with cache_len,
             # in-process or across hosts
             n_blocks = -(-row_len_host // self.block_size)
-            pages = self._issue(self._export_pages_fn, adm.row_cache, n_blocks, self.block_size, self._slot_layers)
+            pages = self._issue(
+                "_export_pages_impl", self._export_pages_fn, adm.row_cache, n_blocks, self.block_size, self._slot_layers
+            )
         adm.row_cache = adm.last = None  # the row never leaves the engine
         with self._lock:
             if adm in self._admissions:
@@ -2866,12 +2878,13 @@ class ContinuousBatcher:
             lp0 = self._first_logprob(adm)
         try:
             if self._carry is None:
-                self._carry = self._init_carry()
-            with self.engine_log.phase("fetch"):
-                first = np.asarray(adm.tok0)
-                for counts in jax.device_get(adm.counts):  # long since computed: the first token came after them
-                    self.engine_log.count("prefill", self.gen.counter_names, counts)
-                adm.counts = []
+                # the engine's one build of its carry: the pools, the flags, the key (a few programs; once a life)
+                self._carry = self._issue("init_carry", self._init_carry)
+            # the chunks' counts were computed long before the first token came: they ride its wait
+            first, *chunk_counts = self.engine_log.fetch("first_token", adm.tok0, *adm.counts)
+            for counts in chunk_counts:
+                self.engine_log.count("prefill", self.gen.counter_names, counts)
+            adm.counts = []
             hit_eos = cfg.eos_id is not None and int(first[0]) == cfg.eos_id
             # produced carries across preemptions; this residency adds one token.
             # An imported handoff's first token was emitted (and its eos/budget
@@ -2890,17 +2903,20 @@ class ContinuousBatcher:
                     # handoff import: the pages are in pool layout already, so
                     # the page write runs alone, with no row to lay as pages
                     cache, tok, lengths, done = self._issue(
-                        self._paged_page_admit_fn, cache, adm.import_pages, tok, lengths, done, *row_args, *table_args
+                        "_paged_page_admit_impl", self._paged_page_admit_fn,
+                        cache, adm.import_pages, tok, lengths, done, *row_args, *table_args,
                     )
                 else:
                     cache, tok, lengths, done = self._issue(
-                        self._paged_admit_fn, cache, adm.row_cache, tok, lengths, done, *row_args, *table_args
+                        "_paged_admit_impl", self._paged_admit_fn,
+                        cache, adm.row_cache, tok, lengths, done, *row_args, *table_args,
                     )
                 self._carry = (cache, tok, lengths, done, key, *cst)
             else:
                 t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst = self._carry
                 t_cache, d_cache, out_buf, tok, lengths, done, produced = self._issue(
-                    self._paged_spec_admit_fn, t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache,
+                    "_paged_spec_admit_impl", self._paged_spec_admit_fn,
+                    t_cache, d_cache, out_buf, adm.row_cache, adm.d_row_cache,
                     tok, lengths, done, produced, *row_args, np.asarray([start_done]), np.int32(cfg.pad_id), *table_args,
                 )
                 self._carry = (t_cache, d_cache, tok, lengths, done, produced, out_buf, rounds, acc, key, *cst)
@@ -2918,7 +2934,7 @@ class ContinuousBatcher:
                 state = list(self._carry)
                 at = -2 if self._spec is None and self.gen.counter_names else -1
                 state[at] = self._issue(
-                    self._slot_set_fn, state[at], np.int32(slot),
+                    "slot_set", self._slot_set_fn, state[at], np.int32(slot),
                     np.int32(self.gen._cs.trans[adm.dfa_state, int(first[0])]),
                 )
                 self._carry = tuple(state)
@@ -3031,8 +3047,9 @@ class ContinuousBatcher:
         # the pools are never passed: their buffers stay where they are. The
         # program is handed copies: a backend may read a numpy argument in
         # place, after this thread has gone on editing it
-        tables, state[at], state[at + 1] = self._sync_fn(
-            tables, state[at], state[at + 1], self._table_host.copy(), self._edited_host, self._released_host
+        tables, state[at], state[at + 1] = self._issue(
+            "_sync_impl", self._sync_fn,
+            tables, state[at], state[at + 1], self._table_host.copy(), self._edited_host, self._released_host,
         )
         for c, synced in zip(caches, tables):
             fresh = iter(synced)  # a layer that keeps a row a slot has no table
@@ -3290,15 +3307,17 @@ class ContinuousBatcher:
             return self._spec_chunk()
         cfg = self.gen.config
         with log.phase("dispatch"):
-            toks, lps, carry = self.gen._decode(self.gen.params, *self._carry, steps=self.decode_chunk)
+            toks, lps, carry = self._issue(
+                "decode_steps", self.gen._decode, self.gen.params, *self._carry, steps=self.decode_chunk
+            )
         self._carry = carry
         log.decode_attention_path = self.gen.decode_attention_path
-        with log.phase("fetch"):
-            toks_np = np.asarray(toks)  # [S, chunk]; also fences the dispatch
-            lps_np = np.asarray(lps)  # [S, chunk] f32: each sampled token's logprob
-            done_np = np.asarray(carry[3])
-            if self.gen.counter_names:
-                log.count("decode", self.gen.counter_names, np.asarray(carry[-1]))
+        # [S, chunk] tokens (the wait fences the dispatch), each one's f32 logprob, the done flags, a counting model's counts
+        toks_np, lps_np, done_np, *counted = log.fetch(
+            "decode", toks, lps, carry[3], *((carry[-1],) if self.gen.counter_names else ())
+        )
+        if counted:
+            log.count("decode", self.gen.counter_names, counted[0])
         registry = self._registry()
         with log.phase("emit"), self._lock:
             self.decode_dispatches += 1
@@ -3360,20 +3379,18 @@ class ContinuousBatcher:
                     # session's out_buf restarted at its re-admission, so its
                     # device budget is the tokens remaining at that point
                     budget_np[slot] = session.max_new - session.resident_base
-            budget = jnp.asarray(budget_np)
-            # per-row floor: every unfinished row gains >= decode_chunk tokens this
-            # dispatch (capped by its budget); free slots are done and ignored
-            floor = jnp.minimum(self._carry[5] + self.decode_chunk, budget)
-            state = spec._round_fn(
-                spec._target.params, spec._draft.params, self._carry, floor, budget
+            # host values: the budgets travel with the two dispatches
+            floor = self._issue(
+                "spec_floor", self._spec_floor_fn, self._carry[5], budget_np, np.int32(self.decode_chunk)
+            )
+            state = self._issue(
+                "spec_loop", spec._round_fn, spec._target.params, spec._draft.params, self._carry, floor, budget_np
             )
         self._carry = state
         log.decode_attention_path = self.gen.decode_attention_path
-        with log.phase("fetch"):
-            out_np = np.asarray(state[6])  # also fences the dispatch
-            prod_np = np.asarray(state[5])
-            done_np = np.asarray(state[4])
-            rounds_total, accepted_total = int(state[7]), int(state[8])
+        # the wait for the tokens fences the dispatch
+        out_np, prod_np, done_np, rounds, accepted = log.fetch("spec", state[6], state[5], state[4], state[7], state[8])
+        rounds_total, accepted_total = int(rounds), int(accepted)
         registry = self._registry()
         with log.phase("emit"), self._lock:
             # fold the ride-along counters into the engine's acceptance
