@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from unionml_tpu.ops.delta_rule import delta_rule_step_kernel
 from unionml_tpu.ops.flash_attention import flash_attention
 from unionml_tpu.ops.int8_matmul import int8_matmul
 from unionml_tpu.ops.paged_attention import paged_decode_attention, paged_window_decode_attention
@@ -93,6 +94,12 @@ def _grouped_matmul(rows):
     return product, [((rows, 3072), jnp.bfloat16), ((32, 3072, 3072), jnp.bfloat16), ((32,), jnp.int32)]
 
 
+def _state_step():
+    """A KDA layer's one-token update at the think_sat cell's shape: 192 rows x 32 heads of 128 x 128 float32 state."""
+    vector = ((192, 32, 128), jnp.float32)
+    return delta_rule_step_kernel, [((192, 32, 128, 128), jnp.float32), vector, vector, vector, vector, ((192, 32), jnp.float32)]
+
+
 CASES = {
     "flash_fwd": _flash("fwd"),
     "flash_bwd": _flash("bwd"),
@@ -103,6 +110,7 @@ CASES = {
     "paged_window_decode": _paged_window(),
     "grouped_matmul_decode_512": _grouped_matmul(512),
     "grouped_matmul_chunk_1024": _grouped_matmul(1024),
+    "delta_rule_step_192x32": _state_step(),
 }
 
 
@@ -419,14 +427,17 @@ def _ling(**changes):
     ), **changes}))
 
 
-def test_hybrid_decode_steps_compile_for_v5e_over_slot_state_and_latent_pages(chip):
+def test_hybrid_decode_steps_compile_for_v5e_over_slot_state_and_latent_pages(chip, monkeypatch):
     """The bailing_hybrid decode program at published widths over the think_sat cell's pool — 168 slots; a KDA
     layer's state ``[168, 32, 128, 128]`` float32 and tails a slot, no table; the MLA layer's one latent plane of
-    9,409 pages with its table — compiles for the described chip with the latent read through the kernel, keeps
-    no second copy of a layer's state (its temporaries stay under one layer's state) and counts its ten counters."""
+    9,409 pages with its table — compiles for the described chip with the latent read through the kernel and the
+    state's update through its kernel (the trace is told it runs on a TPU, as on the chip: the routed product is
+    then the pallas grouped matmul too), keeps no second copy of a layer's state around the kernel's alias (its
+    temporaries stay under one layer's state) and counts its ten counters."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
 
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     slots, pool, page, pages = 168, 9409, 64, 57
     module = _ling()
     config, on_chip = module.config, functools.partial(_on_chip, chip)
@@ -439,6 +450,7 @@ def test_hybrid_decode_steps_compile_for_v5e_over_slot_state_and_latent_pages(ch
     gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
     compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
     assert gen.decode_attention_path == "latent_paged_kernel" and gen.counter_names[-3:] == module.counters[-3:]
+    assert gen.decode_state_path == "state_kernel" and compiled.as_text().count("delta_rule_step") >= 2
     assert compiled.memory_analysis().temp_size_in_bytes < slots * 32 * 128 * 128 * 4
 
 
@@ -480,3 +492,31 @@ def test_hybrid_admission_programs_compile_for_v5e(chip):
         assert paste.memory_analysis().temp_size_in_bytes < (batcher.pool_blocks + 1) * page * 640 * 2
     finally:
         batcher.close()
+
+
+@pytest.mark.skipif(len(jax.devices()) < 2, reason="needs 2 emulated devices")
+def test_under_a_mesh_the_decode_program_keeps_the_xla_state_update(monkeypatch):
+    """On virtual CPU devices told they are a TPU: one device's decode program takes the state kernel at whole
+    128 x 128 tiles; the same model over a ``model=2`` mesh, whose state planes shard their heads, keeps
+    ``delta_rule_step`` (a custom call cannot be partitioned over the mesh) and says so."""
+    from unionml_tpu.models import BailingHybridConfig, BailingHybridTransformer, GenerationConfig, Generator
+    from unionml_tpu.models import bailing_hybrid_partition_rules
+    from unionml_tpu.models.generate import init_paged_cache
+    from unionml_tpu.parallel import MeshSpec
+
+    cfg = BailingHybridConfig.tiny(n_heads=2, kda_head_dim=128, dtype=jnp.float32)
+    module = BailingHybridTransformer(cfg)
+    params = module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+    gen_cfg = GenerationConfig(max_new_tokens=4, temperature=0.0, prompt_buckets=(16,))
+    slots = 2
+    carry = (jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), jnp.int32), jnp.zeros((slots,), bool), jax.random.PRNGKey(0))
+    paths = {}
+    for placed, kwargs in (("one", {}), ("mesh", dict(mesh=MeshSpec(model=2).build(jax.devices()[:2]), partition_rules=bailing_hybrid_partition_rules()))):
+        gen = Generator(module, params, gen_cfg, **kwargs)
+        pool = gen._place_paged_cache(init_paged_cache(cfg, slots, 5, 4, 6, fill_block=4))
+        counts = jnp.zeros((len(gen.counter_names),), jnp.int32)
+        with monkeypatch.context() as tpu:
+            tpu.setattr(jax, "default_backend", lambda: "tpu")
+            gen._decode.trace(gen.params, pool, *carry, counts, steps=2)  # traced only: nothing lowers on the CPU
+        paths[placed] = gen.decode_state_path
+    assert paths == {"one": "state_kernel", "mesh": "state_xla"}

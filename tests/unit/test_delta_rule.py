@@ -1,6 +1,9 @@
 """The gated delta rule's chunked form against its one-step form looped (``unionml_tpu/ops/delta_rule.py``), on the
 CPU in float32: the two are one function, so they differ by summation order and the triangular solve alone, 2e-5 on
-outputs of unit scale (1e-4 where every decay sits at the bound and the sub-block factors reach e^80)."""
+outputs of unit scale (1e-4 where every decay sits at the bound and the sub-block factors reach e^80). The step's
+Pallas kernel, in interpret mode, against the step: summation order alone, 1e-5 after 32 chained steps."""
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -8,7 +11,7 @@ import numpy as np
 import pytest
 
 from unionml_tpu.models.moe import route_top_k
-from unionml_tpu.ops.delta_rule import delta_rule_chunked, delta_rule_step
+from unionml_tpu.ops.delta_rule import delta_rule_chunked, delta_rule_step, delta_rule_step_kernel
 
 B, H, DK, DV = 2, 3, 16, 8
 
@@ -57,12 +60,70 @@ def test_chunked_equals_the_step_looped(length, bound, zero_state, masked):
     assert np.isfinite(np.asarray(got)).all()
 
 
-def test_a_masked_row_keeps_its_state_bit_for_bit():
+#: the one-token step's two forms: XLA's, and the kernel in interpret mode over blocks of one head (H = 3)
+STEPS = {"step": delta_rule_step, "step_kernel": functools.partial(delta_rule_step_kernel, block_heads=1, interpret=True)}
+
+
+@pytest.mark.parametrize("form", ["step", "step_kernel", "chunked"])
+def test_a_masked_row_keeps_its_state_bit_for_bit(form):
+    """Row 0 masked (``beta = 0``, ``g = 0``), row 1 live: row 0's state comes back bit for bit, row 1's moves."""
     state, q, k, v, g, beta, _ = inputs(1, 9)
-    _, kept = delta_rule_step(state, q[:, 0], k[:, 0], v[:, 0], jnp.zeros_like(g[:, 0]), jnp.zeros_like(beta[:, 0]))
-    assert (np.asarray(kept) == np.asarray(state)).all()
-    _, kept = delta_rule_chunked(state, q, k, v, g, beta, jnp.zeros((B, 1), bool))
-    assert (np.asarray(kept) == np.asarray(state)).all()
+    live = jnp.arange(B) > 0
+    if form == "chunked":
+        _, kept = delta_rule_chunked(state, q, k, v, g, beta, jnp.broadcast_to(live[:, None], (B, 1)))
+    else:
+        masked_g, masked_beta = jnp.where(live[:, None, None], g[:, 0], 0.0), jnp.where(live[:, None], beta[:, 0], 0.0)
+        _, kept = STEPS[form](state, q[:, 0], k[:, 0], v[:, 0], masked_g, masked_beta)
+    assert (np.asarray(kept[0]) == np.asarray(state[0])).all()
+    assert not np.allclose(np.asarray(kept[1]), np.asarray(state[1]))
+
+
+KB, KH, KD = 3, 4, 128  # the kernel's tests: whole 128 x 128 tiles, blocks of 2 heads, so the grid is 3 x 2
+
+
+def kernel_inputs(seed, bound):
+    """A non-zero state and one token a step for 32 steps, as the layer makes them (unit keys, queries scaled by
+    ``d_k ** -0.5``); every log-decay at the bound -5 where ``bound``."""
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda x: x / jnp.linalg.norm(x, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (32, KB, KH, KD))) * KD ** -0.5
+    k = unit(jax.random.normal(ks[1], (32, KB, KH, KD)))
+    v = jax.random.normal(ks[2], (32, KB, KH, KD))
+    g = jnp.full((32, KB, KH, KD), -5.0) if bound else -5.0 * jax.nn.sigmoid(3.0 * jax.random.normal(ks[3], (32, KB, KH, KD)))
+    beta = jax.nn.sigmoid(jax.random.normal(ks[4], (32, KB, KH)))
+    return jax.random.normal(ks[5], (KB, KH, KD, KD)), (q, k, v, g, beta)
+
+
+@pytest.mark.parametrize("bound", [False, True], ids=["decays_drawn", "decays_at_bound"])
+def test_the_kernel_equals_the_step_over_32_chained_steps(bound):
+    state, tokens = kernel_inputs(36, bound)
+    kernel = functools.partial(delta_rule_step_kernel, block_heads=2, interpret=True)
+
+    def chained(step):
+        def body(s, xs):
+            out, s = step(s, *xs)
+            return s, out
+
+        return jax.jit(lambda s: jax.lax.scan(body, s, tokens))(state)
+
+    want_state, want = chained(delta_rule_step)
+    got_state, got = chained(kernel)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got_state), np.asarray(want_state), atol=1e-5)
+
+
+def test_the_kernel_updates_the_state_in_place():
+    """The state's input is its output's buffer: the kernel is called with the alias, and under ``jit`` with the
+    state donated the returned state is the donated buffer, not a copy beside it."""
+    state, (q, k, v, g, beta) = kernel_inputs(37, False)
+    step = functools.partial(delta_rule_step_kernel, block_heads=2, interpret=True)
+    args = (state, q[0], k[0], v[0], g[0], beta[0])
+    (call,) = [e for e in jax.make_jaxpr(step)(*args).jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert call.params["input_output_aliases"] == ((0, 1),)
+    state = jnp.array(state)
+    buffer = state.unsafe_buffer_pointer()
+    _, updated = jax.jit(step, donate_argnums=0)(state, *args[1:])
+    assert state.is_deleted() and updated.unsafe_buffer_pointer() == buffer
 
 
 def test_the_layer_refuses_a_decay_bound_the_sub_blocks_cannot_carry():

@@ -448,6 +448,11 @@ class Generator:
         #: before the first trace and for a contiguous cache
         self.decode_attention_path: Optional[str] = None
         self._paged_read_traced: Optional[str] = None  # the newest traced apply's paged read
+        #: how the decode program updates a recurrent state a slot, recorded when it is traced:
+        #: ``"state_kernel"`` or ``"state_xla"`` (ops/delta_rule.py); ``None`` before the first
+        #: trace and for a model with no slot state
+        self.decode_state_path: Optional[str] = None
+        self._state_step_traced: Optional[str] = None  # the newest traced apply's state update
         #: what the module counts per call (its ``counters`` attribute: names it sows into
         #: the ``counters`` collection; ``max_*`` merge by maximum, the rest add up). With
         #: any, ``prefill_chunk`` returns the chunk's counts as its fourth output and the
@@ -509,6 +514,7 @@ class Generator:
 
         self._constrain = constrain  # shared by sp_prefill and beam search
 
+        from unionml_tpu.ops.delta_rule import STATE_KERNEL, STATE_XLA
         from unionml_tpu.ops.paged_attention import paged_read_scope
 
         # a paged pool shards its KV heads over the mesh (_place_paged_cache);
@@ -546,8 +552,11 @@ class Generator:
                     mutable=["counters"] if names else False,
                 )
             (hidden, cache), sown = out if names else (out, {})
-            # which way a paged cache was read (None: a contiguous one), for whoever traces a decode program
-            self._paged_read_traced = "+".join(sorted(set(paths))) or None
+            # which way a paged cache was read (None: a contiguous one) and a slot state updated (None: none is
+            # kept), for whoever traces a decode program
+            updates = set(paths) & {STATE_KERNEL, STATE_XLA}
+            self._paged_read_traced = "+".join(sorted(set(paths) - updates)) or None
+            self._state_step_traced = "+".join(sorted(updates)) or None
             return hidden, cache, collect_counts(sown.get("counters", {}))
 
         def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
@@ -641,6 +650,7 @@ class Generator:
                 body, (cache, tok, lengths, done, key, *cstate), None, length=steps
             )
             self.decode_attention_path = self._paged_read_traced
+            self.decode_state_path = self._state_step_traced
             # the advanced carry (incl. cache) is returned so the donated input
             # buffers have outputs to alias with — one cache in HBM throughout
             return toks.T, lps.T, carry

@@ -724,8 +724,9 @@ class KimiDeltaAttention(nn.Module):
     (:func:`~unionml_tpu.ops.delta_rule.delta_rule_chunked`). Cached with several tokens (a prefill
     chunk): the same, from the state and the convolutions' tails the cache hands in, which come out
     advanced; positions ``token_mask`` masks leave both untouched (they must be a row's tail: a
-    right-padded prompt, a row that is not live). Cached with one token (decode):
-    :func:`~unionml_tpu.ops.delta_rule.delta_rule_step`; a masked row's state and tails are held.
+    right-padded prompt, a row that is not live). Cached with one token (decode): the one-token update,
+    as one Pallas kernel where :func:`~unionml_tpu.ops.delta_rule.state_step_path` says (a TPU, the state on
+    one device), else :func:`~unionml_tpu.ops.delta_rule.delta_rule_step`; a masked row's state and tails are held.
 
     The cache is two planes with no position axis (:class:`SlotPlane`): ``{"S": [B, H, d_k, d_v]``
     in ``state_dtype``, ``"conv": [B, conv_size - 1, 3, H * d_k]}``, the last pre-convolution rows of
@@ -760,7 +761,14 @@ class KimiDeltaAttention(nn.Module):
         cache: Optional[LayerCache] = None,
         token_mask: Optional[jax.Array] = None,
     ) -> Any:
-        from unionml_tpu.ops.delta_rule import MIN_LOG_DECAY, delta_rule_chunked, delta_rule_step
+        from unionml_tpu.ops.delta_rule import (
+            MIN_LOG_DECAY,
+            STATE_KERNEL,
+            delta_rule_chunked,
+            delta_rule_step,
+            delta_rule_step_kernel,
+            state_step_path,
+        )
 
         if mask is not None:
             raise NotImplementedError("a delta-rule layer is causal by construction and takes no mask")
@@ -796,7 +804,8 @@ class KimiDeltaAttention(nn.Module):
 
         if cache is not None and length == 1:
             with jax.named_scope("kda.step"):
-                out, state = delta_rule_step(
+                step = delta_rule_step_kernel if state_step_path(cache["S"]) == STATE_KERNEL else delta_rule_step
+                out, state = step(
                     cache["S"], q[:, 0], k[:, 0], v[:, 0],
                     jnp.where(live[:, 0, None, None], g[:, 0], 0.0), jnp.where(live[:, 0, None], beta[:, 0], 0.0),
                 )

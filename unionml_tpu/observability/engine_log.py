@@ -250,6 +250,9 @@ class EngineLog:
         #: how the engine's decode program reads its paged cache (``"paged_kernel"`` / ``"gather"``), set by the
         #: engine after a decode dispatch; a fact about the program, not a counter: :meth:`clear` leaves it
         self.decode_attention_path: Optional[str] = None
+        #: how it updates a recurrent state a slot (``"state_kernel"`` / ``"state_xla"``; None for a model with
+        #: none), set beside it
+        self.decode_state_path: Optional[str] = None
         #: what the served model counted over the engine's dispatches (routed-expert pairs, window pages not
         #: read, ...: the names its ``counters`` attribute declares), cumulative, by kind of dispatch
         #: (``"decode"`` / ``"prefill"``); ``max_*`` hold the largest seen. Empty for a model that counts
@@ -548,6 +551,7 @@ class EngineLog:
         return {
             "capacity": self.capacity,
             "decode_attention_path": self.decode_attention_path,
+            "decode_state_path": self.decode_state_path,
             **({"model_counters": {k: dict(v) for k, v in self.model_counters.items()}} if self.model_counters else {}),
             **self.totals(),
             "iterations_log": iterations,

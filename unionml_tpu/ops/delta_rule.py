@@ -10,7 +10,9 @@ float32, and a token with query ``q``, key ``k`` (both ``[d_k]``), value ``v``
     S  = S' + beta k (v - S'^T k)^T   # move what S' holds under k towards v
     o  = S^T q
 
-:func:`delta_rule_step` is that, one token a row (decode).
+:func:`delta_rule_step` is that, one token a row (decode), written for XLA;
+:func:`delta_rule_step_kernel` is the same step as one Pallas kernel for a TPU,
+and :func:`state_step_path` says which of the two a trace takes.
 :func:`delta_rule_chunked` gives the same numbers for ``L`` tokens without
 ``L`` sequential rank-one updates: inside a chunk of ``C`` positions, with
 ``G_r = sum_{i<=r} g_i`` and ``D(r, i) = exp(G_r - G_i)`` (``i <= r``, so never
@@ -48,11 +50,18 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _HIGHEST = jax.lax.Precision.HIGHEST
 CHUNK, SUB = 64, 16
 #: the lowest log-decay a position may have: ``exp(SUB * -g)`` must stay a float32
 MIN_LOG_DECAY = -88.0 / SUB
+#: the two updates a one-token step can take, as ``stats()["decode_state_path"]`` names them
+STATE_KERNEL = "state_kernel"
+STATE_XLA = "state_xla"
+#: heads a grid step of :func:`delta_rule_step_kernel` carries (PERF.md section 6, PR 36)
+BLOCK_HEADS = 16
 
 
 def delta_rule_step(
@@ -74,6 +83,89 @@ def delta_rule_step(
     write, strength = v - held, beta[..., None] * k
     out = seen + jnp.sum(strength * q, axis=-1, keepdims=True) * write
     return out, decay[..., None] * state + strength[..., None] * write[..., None, :]
+
+
+def state_step_path(state: jax.Array) -> str:
+    """Which update serves a one-token step over ``state [B, H, d_k, d_v]``, decided from what the trace can
+    observe and recorded in the enclosing :func:`~unionml_tpu.ops.paged_attention.paged_read_scope`: the kernel
+    on a TPU, over a float32 state whose tile is whole lanes and sublanes (``d_k``, ``d_v`` multiples of 128)
+    and whose heads split into blocks, held on one device (a custom call cannot be partitioned over a mesh);
+    :func:`delta_rule_step` everywhere else."""
+    from unionml_tpu.ops.paged_attention import read_scope
+
+    sharded, paths = read_scope()
+    heads, d_k, d_v = state.shape[-3:]
+    kernel = (
+        jax.default_backend() == "tpu"
+        and not sharded
+        and state.dtype == jnp.float32
+        and d_k % 128 == 0
+        and d_v % 128 == 0
+        and heads % min(BLOCK_HEADS, heads) == 0
+    )
+    path = STATE_KERNEL if kernel else STATE_XLA
+    if paths is not None:
+        paths.append(path)
+    return path
+
+
+def _step_block(s_ref, q_ref, k_ref, v_ref, g_ref, beta_ref, out_ref, s_out_ref):
+    """One row's block of heads: each head's tile is read from VMEM once, for both sums and the update. The
+    key-channel vectors multiply the tile down its sublanes, so they are laid out as columns (one transpose a
+    block); the sums over ``d_k`` are sums over sublanes and come out as rows, as ``v`` is."""
+    heads = s_ref.shape[1]
+    q, k, v, g, beta = q_ref[0], k_ref[0], v_ref[0], g_ref[0], beta_ref[0]  # [heads, d]; beta [heads, 1]
+    decay = jnp.exp(g)
+    strength = beta * k
+    gain = jnp.sum(strength * q, axis=-1, keepdims=True)  # strength . q: [heads, 1]
+    cols = jnp.concatenate([decay * k, decay * q, decay, strength], axis=0).T  # [d_k, 4 heads]
+    col = lambda i, h: cols[:, i * heads + h : i * heads + h + 1]  # noqa: E731
+    outs = []
+    for h in range(heads):
+        s = s_ref[0, h]
+        held = jnp.sum(s * col(0, h), axis=0, keepdims=True)  # S'^T k: [1, d_v]
+        seen = jnp.sum(s * col(1, h), axis=0, keepdims=True)  # S'^T q
+        write = v[h : h + 1] - held
+        outs.append(seen + gain[h : h + 1] * write)
+        s_out_ref[0, h] = col(2, h) * s + col(3, h) * write
+    out_ref[0] = jnp.concatenate(outs, axis=0)
+
+
+def delta_rule_step_kernel(
+    state: jax.Array,
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    g: jax.Array,
+    beta: jax.Array,
+    *,
+    block_heads: int = BLOCK_HEADS,
+    interpret: bool = False,
+) -> Tuple[jax.Array, jax.Array]:
+    """:func:`delta_rule_step` as one Pallas kernel (``delta_rule_step`` in a device trace): the same operands,
+    the same ``(o, state)``, float32 throughout. The grid walks rows x blocks of ``block_heads`` heads; each
+    head's ``d_k x d_v`` tile is streamed from HBM once and written back once, in place (the state's input is
+    its output's buffer), where XLA's lowering of the step reads it twice. ``state`` must be float32; the heads
+    must split into blocks (:func:`state_step_path` says where the kernel serves)."""
+    batch, heads, d_k, d_v = state.shape
+    block = min(block_heads, heads)
+    if state.dtype != jnp.float32 or heads % block:
+        raise ValueError(f"the state step kernel takes a float32 state whose {heads} heads split into blocks of {block}")
+    q, k, v, g, beta = (x.astype(jnp.float32) for x in (q, k, v, g, beta))
+    vector = lambda d: pl.BlockSpec((1, block, d), lambda b, h: (b, h, 0))  # noqa: E731
+    tile = pl.BlockSpec((1, block, d_k, d_v), lambda b, h: (b, h, 0, 0))
+    out, state = pl.pallas_call(
+        _step_block,
+        grid=(batch, heads // block),
+        in_specs=[tile, vector(d_k), vector(d_k), vector(d_v), vector(d_k), vector(1)],
+        out_specs=[vector(d_v), tile],
+        out_shape=[jax.ShapeDtypeStruct((batch, heads, d_v), jnp.float32), jax.ShapeDtypeStruct(state.shape, jnp.float32)],
+        input_output_aliases={0: 1},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel")),
+        name="delta_rule_step",
+        interpret=interpret,
+    )(state, q, k, v, g, beta[..., None])
+    return out, state
 
 
 def delta_rule_chunked(

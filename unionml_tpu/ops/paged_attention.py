@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
-from typing import Iterator, List, Optional
+from typing import Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +38,7 @@ __all__ = [
     "paged_read_path",
     "paged_read_scope",
     "paged_window_decode_attention",
+    "read_scope",
     "window_split",
 ]
 
@@ -58,7 +59,8 @@ def paged_read_scope(*, sharded: bool) -> Iterator[List[str]]:
     ``module.apply``). It tells the layer the one thing it cannot see from its
     operands — whether the pools are ``sharded`` over a mesh of several devices
     (a tracer carries no placement) — and yields the list to which each paged
-    read traced inside appends the path it took."""
+    read traced inside appends the path it took, as does each recurrent state's
+    one-token update (:func:`unionml_tpu.ops.delta_rule.state_step_path`)."""
     previous = getattr(_scope, "current", None)
     paths: List[str] = []
     _scope.current = (sharded, paths)
@@ -66,6 +68,12 @@ def paged_read_scope(*, sharded: bool) -> Iterator[List[str]]:
         yield paths
     finally:
         _scope.current = previous
+
+
+def read_scope() -> Tuple[bool, Optional[List[str]]]:
+    """What the enclosing :func:`paged_read_scope` says, ``(sharded, paths)``; outside any scope ``(False,
+    None)``."""
+    return getattr(_scope, "current", None) or (False, None)
 
 
 def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: bool, latent: bool = False) -> str:
@@ -90,7 +98,7 @@ def paged_read_path(impl: str, q: jax.Array, k_pages: jax.Array, *, quantized: b
     :data:`LATENT_KERNEL` or :data:`LATENT_GATHER`. A latent plane is never
     sharded (one head), but its queries' heads are: under a mesh it gathers.
     """
-    sharded, paths = getattr(_scope, "current", None) or (False, None)
+    sharded, paths = read_scope()
     kernel = q.shape[1] == 1 and not quantized and impl in ("flash", "auto")
     if kernel and impl == "auto":
         kernel = (

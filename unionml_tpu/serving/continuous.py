@@ -1916,6 +1916,9 @@ class ContinuousBatcher:
                 # how the decode program reads the cache, recorded when it
                 # was traced: "paged_kernel" or "gather" (None: not traced yet)
                 "decode_attention_path": self.gen.decode_attention_path,
+                # how it updates a recurrent state a slot: "state_kernel" or
+                # "state_xla" (None: not traced yet, or no slot state)
+                "decode_state_path": self.gen.decode_state_path,
                 "rows_per_dispatch": round(
                     self.decoded_rows / self.decode_dispatches, 3
                 ) if self.decode_dispatches else None,
@@ -3312,6 +3315,7 @@ class ContinuousBatcher:
             )
         self._carry = carry
         log.decode_attention_path = self.gen.decode_attention_path
+        log.decode_state_path = self.gen.decode_state_path
         # [S, chunk] tokens (the wait fences the dispatch), each one's f32 logprob, the done flags, a counting model's counts
         toks_np, lps_np, done_np, *counted = log.fetch(
             "decode", toks, lps, carry[3], *((carry[-1],) if self.gen.counter_names else ())
