@@ -48,6 +48,7 @@ MODULES = [
     "unionml_tpu.models.moe",
     "unionml_tpu.models.glm4_moe_lite",
     "unionml_tpu.models.bailing_hybrid",
+    "unionml_tpu.models.smallthinker",
     "unionml_tpu.ops.delta_rule",
     "unionml_tpu.ops.attention",
     "unionml_tpu.ops.ring_attention",

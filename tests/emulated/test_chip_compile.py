@@ -520,3 +520,59 @@ def test_under_a_mesh_the_decode_program_keeps_the_xla_state_update(monkeypatch)
             gen._decode.trace(gen.params, pool, *carry, counts, steps=2)  # traced only: nothing lowers on the CPU
         paths[placed] = gen.decode_state_path
     assert paths == {"one": "state_kernel", "mesh": "state_xla"}
+
+
+def _smallthinker():
+    """SmallThinker at SmallThinker-21BA3B's widths, cut to a global and a windowed layer (the published period's
+    first two), every one of the 64 experts held, the whole 151,936-token vocabulary."""
+    from unionml_tpu.models import SmallThinkerConfig, SmallThinkerTransformer
+
+    return SmallThinkerTransformer(SmallThinkerConfig(
+        n_layers=2, sliding_window_layout=(0, 1), rope_layout=(0, 1), param_dtype=jnp.bfloat16,
+    ))
+
+
+def test_smallthinker_programs_compile_for_v5e_with_seven_query_heads_a_kv_head(chip, monkeypatch):
+    """The mixed_long_sat cell's programs at published widths (28 query heads on 4 key/value heads of 128: a group of
+    seven, which the library kernel takes by its ``groups % 8`` layout), as on the chip (the trace told it runs on a
+    TPU: the routed product is the pallas grouped matmul): the decode program over 96 slots of 257 pages in a pool
+    of 4,609 reads the global layer through the paged kernel and the windowed one through the windowed kernel, keeps
+    no re-laid pool and counts the window's two counters; the chunk program walks the 16,392-position row in key
+    blocks within half a GB of temporaries."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.models.generate import init_paged_cache
+    from unionml_tpu.models.layers import WINDOW_COUNTERS
+    from unionml_tpu.serving import ContinuousBatcher
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    slots, pool, page, chunk = 96, 4609, 64, 256
+    module = _smallthinker()
+    on_chip = functools.partial(_on_chip, chip)
+
+    def scalar(dtype, shape=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cfg = GenerationConfig(max_new_tokens=1024, temperature=0.0, prompt_buckets=tuple(range(chunk, 15360 + 1, chunk)))
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(gen, slots=slots, pool_blocks=pool - 1, decode_chunk=8, block_size=page, admit_chunk=chunk, prefill_budget=4096)
+    try:
+        assert batcher.cache_len == 16392 and batcher.max_blocks == 257
+        cache = on_chip(lambda: init_paged_cache(module.config, slots, pool, page, batcher.max_blocks, fill_block=pool - 1))
+        assert cache[0]["k"].shape == (4, pool, page, 128)
+        carry = (scalar(jnp.int32, (slots,)), scalar(jnp.int32, (slots,)), scalar(jnp.bool_, (slots,)))
+        counts = scalar(jnp.int32, (len(gen.counter_names),))
+        compiled = gen._decode.lower(params, cache, *carry, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
+        assert gen.decode_attention_path == "paged_kernel" and set(WINDOW_COUNTERS) <= set(gen.counter_names)
+        text = compiled.as_text()
+        assert "paged_window_attention" in text and text.count("tpu_custom_call") >= 2 + 3 * 2  # two reads, three gmm a layer
+        assert not re.search(rf"= bf16\[4,{pool},{page},128\]\S* copy\(", text)
+        memory = compiled.memory_analysis()
+        assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 16e9
+
+        lengths, key, row_valid, (last,), (row,) = on_chip(lambda: batcher._setup_fn(jnp.uint32(0), jnp.int32(0), ()))
+        step = gen._prefill_chunk.lower(params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), lengths, row, row_valid, last).compile()
+        assert step.memory_analysis().temp_size_in_bytes < 0.5e9
+        assert _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, chunk, batcher.cache_len)
+    finally:
+        batcher.close()

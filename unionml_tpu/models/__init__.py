@@ -20,6 +20,7 @@ from unionml_tpu.models.glm4_moe_lite import (  # noqa: F401
     Glm4MoeLiteTransformer,
     glm4_moe_lite_partition_rules,
 )
+from unionml_tpu.models.smallthinker import SmallThinkerConfig, SmallThinkerTransformer  # noqa: F401
 from unionml_tpu.models.speculative import SpeculativeGenerator  # noqa: F401
 from unionml_tpu.models.structured import (  # noqa: F401
     ConstraintSet,

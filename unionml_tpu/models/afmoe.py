@@ -73,7 +73,7 @@ class AfmoeConfig:
 
     def __post_init__(self) -> None:
         if self.score_func != "sigmoid":
-            raise ValueError(f"score_func {self.score_func!r}: the routed layer scores by sigmoid alone")
+            raise ValueError(f"score_func {self.score_func!r}: afmoe scores its experts by sigmoid")
         kinds = self.layer_types
         if kinds is None:
             kinds = tuple(FULL if (i + 1) % self.global_every == 0 else SLIDING for i in range(self.n_layers))

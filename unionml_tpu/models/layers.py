@@ -30,6 +30,9 @@ Dtype = Any
 #: One layer's KV cache: ``{"k": [B, S_max, H_kv, D], "v": [B, S_max, H_kv, D]}``.
 LayerCache = Dict[str, jax.Array]
 
+#: what a sliding layer's kernel read counts a decode step (``counters`` collection; summed over the live rows)
+WINDOW_COUNTERS = ("window_positions_live", "window_positions_read")
+
 
 class SlotPlane(NamedTuple):
     """A plane of a layer's state that has no position axis: one row a sequence, ``[batch, *shape]``
@@ -226,8 +229,10 @@ class Attention(nn.Module):
     position ``i`` iff ``i - window < j <= i`` (on top of causality). Every read
     masks it; the kernel read of a paged cache starts at the window's first page
     instead (:func:`unionml_tpu.ops.paged_attention.paged_window_decode_attention`),
-    and counts the pages it never touched for rows live in ``token_mask`` under
-    ``counters/decode_window_pages_skipped``. ``qk_norm``: an RMS norm with a learned
+    and counts, over the rows live in ``token_mask``, the pages it never touched
+    (``counters/decode_window_pages_skipped``), the rows' lengths
+    (``window_positions_live``) and the positions of the pages it touched
+    (``window_positions_read``: :data:`WINDOW_COUNTERS`). ``qk_norm``: an RMS norm with a learned
     scale over each query and key head's channels, before the rotary embedding.
     ``gated``: the heads' output is multiplied by ``sigmoid(gate_proj(x))``
     before ``o_proj``. All three default off, and add no parameter when off.
@@ -470,9 +475,12 @@ class Attention(nn.Module):
                             q[:, 0], cache["k"], cache["v"], lengths, table, window=self.window
                         )
                     skipped = window_split(lengths, self.window, block_size)[0]
+                    touched = (-(-lengths // block_size) - skipped) * block_size  # the edge page and the whole ones after it
                     if token_mask is not None:
-                        skipped = jnp.where(token_mask[:, 0], skipped, 0)
+                        skipped, touched, lengths = (jnp.where(token_mask[:, 0], a, 0) for a in (skipped, touched, lengths))
                     self.sow("counters", "decode_window_pages_skipped", jnp.sum(skipped, dtype=jnp.int32))
+                    self.sow("counters", "window_positions_live", jnp.sum(lengths, dtype=jnp.int32))
+                    self.sow("counters", "window_positions_read", jnp.sum(touched, dtype=jnp.int32))
                 return out[:, None], cache
             cache = {"k": scatter(cache["k"], k), "v": scatter(cache["v"], v), "table": table}
         rows = {name: logical(pool) for name, pool in cache.items() if name != "table"}  # [B, MB * bs, H_kv, last]

@@ -225,16 +225,26 @@ class _HeldExperts(nn.Module):
 class ExpertShare(nn.Module):
     """One chip's share of a routed-experts feed-forward layer, dropless.
 
-    The router (``router/kernel [D, n_experts]``, float32 compute, sigmoid scores, and the
-    selection-only ``router_bias``) scores all ``n_experts``; ``experts_held =
-    (first, count)`` says which of them live here, as stacked SwiGLU weights
-    under ``experts/{wg,wi,wo}/kernel`` with a leading ``[count]`` dim. Every token
-    chooses ``k`` experts; the pairs whose expert is held are sorted by expert,
-    pass through one grouped product per projection (no capacity, no ``[N, E,
-    C]`` tensor, nothing dropped however skewed the routing), are un-sorted and
-    summed under their routing weights. Pairs on experts held elsewhere add
-    nothing here, and no code stands in for them. Rows masked out by
+    The router (``router/kernel [D, n_experts]``, float32 compute) scores all
+    ``n_experts``; ``experts_held = (first, count)`` says which of them live here,
+    as stacked gated weights under ``experts/{wg,wi,wo}/kernel`` with a leading
+    ``[count]`` dim. Every token chooses ``k`` experts; the pairs whose expert is
+    held are sorted by expert, pass through one grouped product per projection (no
+    capacity, no ``[N, E, C]`` tensor, nothing dropped however skewed the routing),
+    are un-sorted and summed under their routing weights. Pairs on experts held
+    elsewhere add nothing here, and no code stands in for them. Rows masked out by
     ``token_mask`` (padding, finished slots) route nowhere and count nowhere.
+
+    Three options, whose defaults are the sigmoid-routed SwiGLU layer:
+    ``score`` — ``"sigmoid"`` (each expert's own sigmoid, chosen by ``scores +
+    router_bias``, a selection-only bias) or ``"softmax"`` (a softmax over the
+    ``n_experts`` logits and no bias; with ``route_norm`` the chosen ``k`` are
+    renormalised, which is a softmax over the chosen logits); ``activation`` —
+    ``"silu"`` (SwiGLU) or ``"relu"`` (ReGLU) on the gate projection; and the
+    call's ``router_input`` — where given (``[B, L, D]``, as ``x``), the routing
+    (logits, choice, sort, group sizes) is decided from it while the experts
+    transform ``x``, as a model whose router reads the block's input before
+    attention needs.
 
     Counts :data:`MOE_COUNTERS` into the ``counters`` collection when the
     caller makes it mutable.
@@ -250,26 +260,37 @@ class ExpertShare(nn.Module):
     param_dtype: Dtype = jnp.float32
     n_group: int = 1  # group-limited routing (route_top_k); one group: none
     topk_group: int = 1
+    score: str = "sigmoid"
+    activation: str = "silu"
 
     @nn.compact
-    def __call__(self, x: jax.Array, token_mask: Optional[jax.Array] = None) -> jax.Array:
+    def __call__(
+        self, x: jax.Array, token_mask: Optional[jax.Array] = None, router_input: Optional[jax.Array] = None
+    ) -> jax.Array:
         batch, length, dim = x.shape
         first, count = self.experts_held
         if not (0 <= first and count >= 1 and first + count <= self.n_experts):
             raise ValueError(f"experts_held {self.experts_held} lies outside the router's {self.n_experts} experts")
+        if self.score not in ("sigmoid", "softmax") or self.activation not in ("silu", "relu"):
+            raise ValueError(f"score {self.score!r} / activation {self.activation!r}: expected sigmoid|softmax, silu|relu")
         n = batch * length
         tokens = x.reshape(n, dim)
+        scored = tokens if router_input is None else router_input.reshape(n, dim)
         live = jnp.ones((n,), bool) if token_mask is None else token_mask.reshape(n)
 
         with jax.named_scope("afmoe.router"):
             router = _Kernel((dim, self.n_experts), self.param_dtype, name="router")()
-            bias = self.param("router_bias", nn.initializers.zeros, (self.n_experts,), jnp.float32)
             # float32 at full precision: the choice is discrete, a rounded score flips it
             logits = jnp.dot(
-                tokens.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
+                scored.astype(jnp.float32), router.astype(jnp.float32), precision=jax.lax.Precision.HIGHEST
             )
+            if self.score == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                bias = self.param("router_bias", nn.initializers.zeros, (self.n_experts,), jnp.float32)
+            else:
+                scores, bias = jax.nn.softmax(logits, axis=-1), jnp.zeros((self.n_experts,), jnp.float32)
             chosen, weights = route_top_k(
-                jax.nn.sigmoid(logits), bias, self.k, normalize=self.route_norm, scale=self.route_scale,
+                scores, bias, self.k, normalize=self.route_norm, scale=self.route_scale,
                 n_group=self.n_group, topk_group=self.topk_group,
             )
             held = (chosen >= first) & (chosen < first + count) & live[:, None]  # [N, k]
@@ -282,7 +303,8 @@ class ExpertShare(nn.Module):
         wg, wi, wo = _HeldExperts(count, dim, self.hidden_dim, self.param_dtype, name="experts")()
         with jax.named_scope("afmoe.experts"):
             rows = tokens.astype(self.dtype)[order // self.k]  # [N * k, D], held pairs first, by expert
-            gate = jax.nn.silu(grouped_matmul(rows, wg.astype(self.dtype), sizes))
+            act = jax.nn.silu if self.activation == "silu" else jax.nn.relu
+            gate = act(grouped_matmul(rows, wg.astype(self.dtype), sizes))
             up = grouped_matmul(rows, wi.astype(self.dtype), sizes)
             out = grouped_matmul(gate * up, wo.astype(self.dtype), sizes)
             weight = jnp.where(held, weights, 0.0).reshape(n * self.k)[order]
