@@ -576,3 +576,47 @@ def test_smallthinker_programs_compile_for_v5e_with_seven_query_heads_a_kv_head(
         assert _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, chunk, batcher.cache_len)
     finally:
         batcher.close()
+
+
+#: the widest call of a cell's admission (admit_chunk x 2^k up to the iteration's budget) and the engine it belongs
+#: to: module, widest prompt bucket, answer budget, budget, engine options, the row's positions
+WIDEST_CALLS = {
+    "mixed_long_sat": (_smallthinker, 15360, 1024, 4096, {"slots": 96, "pool_blocks": 4608}, 16392),
+    "long_sat": (_glm, 8192, 768, 2048, {"slots": 48, "pool_blocks": 3584}, 8968),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(WIDEST_CALLS))
+def test_widest_prefill_call_compiles_for_v5e(chip, monkeypatch, cell):
+    """The widest call an iteration's budget makes (4,096 columns in SmallThinker's cell, 2,048 in GLM's) compiles
+    for the described chip: it is the engine's widest width, its read still walks the long row in key blocks, and
+    its temporaries stay within the sixth of the chip that the cell's weights and pool leave free (PERF.md
+    section 5 reads the bytes)."""
+    from unionml_tpu.models import GenerationConfig, Generator
+    from unionml_tpu.serving import ContinuousBatcher
+
+    if cell == "mixed_long_sat":
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the routed product is the pallas grouped matmul
+    make, max_prompt, max_new, budget, options, cache_len = WIDEST_CALLS[cell]
+    module, chunk, page = make(), 256, 64
+    on_chip = functools.partial(_on_chip, chip)
+
+    def scalar(dtype, shape=()):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    params = on_chip(lambda: module.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    cfg = GenerationConfig(max_new_tokens=max_new, temperature=0.0, prompt_buckets=tuple(range(chunk, max_prompt + 1, chunk)))
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(
+        gen, decode_chunk=8, block_size=page, admit_chunk=chunk, prefill_budget=budget, prefix_cache=True, **options
+    )
+    try:
+        assert batcher.cache_len == cache_len and batcher._prefill_widths[-1] == budget
+        lengths, key, row_valid, (last,), (row,) = on_chip(lambda: batcher._setup_fn(jnp.uint32(0), jnp.int32(0), ()))
+        step = gen._prefill_chunk.lower(params, scalar(jnp.int32, (1, budget)), scalar(jnp.int32), lengths, row, row_valid, last).compile()
+        temp = step.memory_analysis().temp_size_in_bytes
+        print(f"{cell}: a {budget}-column call's temporaries {temp / 1e9:.3f} GB")
+        assert temp < 16e9 / 6
+        assert _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, budget, cache_len)
+    finally:
+        batcher.close()

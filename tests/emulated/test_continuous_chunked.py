@@ -152,3 +152,32 @@ def test_chunked_paged_preempt_resume_and_shared_prefix(tiny):
         assert batcher.stats()["kv_blocks"]["preemptions"] > 0
     finally:
         batcher.close()
+
+
+def test_tp2_wide_admission_calls_match_sequential_and_warm_every_width(tiny):
+    """tp=2 leg of the power-of-two calls: a budget of four chunks runs an iteration's columns in calls 4, 8 and 16
+    wide over the model-sharded engine, the streams (admitted together, and a radix hit that starts mid-prompt)
+    equal the single-device sequential run, and after ``warmup()`` that traffic traces no prefill program."""
+    module, params = tiny
+    cfg = _cfg(prompt_buckets=(16, 32))
+    prompts = [list(range(1, 30)), [3, 1, 4, 1, 5], [9, 2, 6, 5, 3, 5, 8, 9, 7, 1, 6, 2]]
+    hit = list(range(1, 21)) + [5, 5]
+    expected = _expected(module, params, prompts + [hit], cfg)
+    mesh = MeshSpec(data=1, model=2).build(devices=jax.devices()[:2])
+    gen = Generator(module, params, cfg, mesh=mesh, partition_rules=llama_partition_rules())
+    batcher = ContinuousBatcher(
+        gen, slots=3, decode_chunk=4, block_size=4, admit_chunk=4, prefill_budget=16, max_admissions=2, prefix_cache=True,
+    )
+    try:
+        assert batcher._prefill_widths == (4, 8, 16)
+        batcher.warmup()
+        traces = gen.prefill_traces
+        streams = [batcher.submit(p) for p in prompts]
+        served = _drain_concurrently(streams)
+        served.append(_drain(batcher.submit(hit)))
+        assert served == expected
+        stats = batcher.stats()
+        assert max(stats["prefill_widths"]) > 4 and set(stats["prefill_widths"]) <= {4, 8, 16}
+        assert stats["prefix_cache"]["hits"] >= 1 and gen.prefill_traces == traces
+    finally:
+        batcher.close()

@@ -722,7 +722,9 @@ def test_chunked_admission_interleaves_decode_with_prefill(tiny_gen):
 
 def test_prefill_budget_groups_chunks_per_iteration(tiny_gen):
     """prefill_budget tokens of prefill run per engine iteration: with a
-    budget of two chunks, chunks land in pairs between decode dispatches."""
+    budget of two chunks, each iteration's two chunks run as one call two
+    chunks wide (one stream of the weights), and decode advances between
+    the calls."""
     module, params = tiny_gen
     cfg = GenerationConfig(max_new_tokens=48, temperature=0.0, prompt_buckets=(4, 32))
     gen = Generator(module, params, cfg)
@@ -730,25 +732,23 @@ def test_prefill_budget_groups_chunks_per_iteration(tiny_gen):
     try:
         occupant = batcher.submit([5, 5, 5])
         next(occupant)
-        dispatches_at_chunk = []
+        dispatches_at_chunk, widths = [], []
         orig = gen._prefill_chunk
 
         def spy(*args, **kwargs):
             dispatches_at_chunk.append(batcher.decode_dispatches)
+            widths.append(args[1].shape[1])
             return orig(*args, **kwargs)
 
         gen._prefill_chunk = spy
         try:
-            _drain(batcher.submit([9] * 20, max_new_tokens=2))  # bucket 32 -> 8 chunks
+            _drain(batcher.submit([9] * 20, max_new_tokens=2))  # bucket 32 -> 8 chunks, 4 calls of two
         finally:
             gen._prefill_chunk = orig
         _drain(occupant)
-        assert len(dispatches_at_chunk) == 8
-        # chunks arrive in pairs: both members of a pair see the same decode
-        # count, and decode advances between pairs
-        pairs = list(zip(dispatches_at_chunk[0::2], dispatches_at_chunk[1::2]))
-        assert all(a == b for a, b in pairs), dispatches_at_chunk
-        assert all(n[0] > p[0] for p, n in zip(pairs, pairs[1:])), dispatches_at_chunk
+        assert widths == [8, 8, 8, 8]
+        assert all(b > a for a, b in zip(dispatches_at_chunk, dispatches_at_chunk[1:])), dispatches_at_chunk
+        assert batcher.stats()["prefill_widths"] == {4: 1, 8: 4}  # the occupant's one chunk, then the four calls
     finally:
         batcher.close()
 
@@ -1602,5 +1602,180 @@ def test_no_unjitted_jax_op_in_the_admit_phase(tiny_gen, monkeypatch, mode):
         assert stats["loop"]["admit_dispatches"] > 0 and stats["prefill"]["chunks"] >= 10
         if mode != "speculative":
             assert stats["prefix_cache"]["hits"] >= 3
+    finally:
+        batcher.close()
+
+
+# ------------------------------------------------------------------ an iteration's budget in power-of-two calls
+
+
+def _widths_by_iteration(batcher, gen):
+    """Spy on the chunk program and on the bottom of the engine loop: the widths and offsets of the calls of each
+    iteration, and whether an admission was still owed columns when the iteration ended."""
+    from collections import defaultdict
+
+    log = batcher.engine_log
+    calls, owed = defaultdict(list), {}
+    orig, real_end = gen._prefill_chunk, log.end
+
+    def spy(params, tokens, start, *rest):
+        calls[log.index].append((tokens.shape[1], int(start)))
+        return orig(params, tokens, start, *rest)
+
+    def end():
+        owed[log.index] = any(not a.done and not a.session.finished for a in batcher._admissions)
+        real_end()
+
+    gen._prefill_chunk, log.end = spy, end
+    return calls, owed
+
+
+def test_admission_calls_take_power_of_two_widths_within_the_budget(tiny_gen):
+    """An iteration's budget runs in as few calls as admit_chunk x 2^k widths allow: every call's width is one of
+    the engine's widths, reaches no column past its admission's aligned row, and an iteration's calls add up to
+    the whole budget unless no admission is left owing columns (what one-chunk calls would have spent); the streams
+    are the sequential run's, and the width counter, the chunk counters and the iteration records agree."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(8, 16, 32))
+    prompts = [list(range(1, 31)), [5, 5, 5], list(range(40, 61)), [7] * 12, list(range(3, 17)), [9, 8]]
+    expected = _sequential_expected(module, params, cfg, prompts)
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(gen, slots=3, decode_chunk=2, admit_chunk=4, prefill_budget=12, max_admissions=2)
+    try:
+        assert batcher._prefill_widths == (4, 8)  # 12 columns an iteration: widths up to 8, never 12
+        calls, owed = _widths_by_iteration(batcher, gen)
+        with batcher._lock:  # re-entrant: the engine meets them together
+            streams = [batcher.submit(p) for p in prompts]
+        assert [_drain(s) for s in streams] == expected
+        batcher.close()
+        assert calls
+        for index, made in calls.items():
+            assert all(width in (4, 8) and start + width <= batcher.cache_len for width, start in made)
+            spent = sum(width for width, _ in made)
+            assert spent <= 12 and (spent == 12 or not owed[index]), (index, made)
+        stats = batcher.stats()
+        widths = stats["prefill_widths"]
+        assert set(widths) == {4, 8} and all(isinstance(n, int) and n > 0 for n in widths.values())
+        assert sum(widths.values()) == stats["prefill"]["chunks"] == sum(len(m) for m in calls.values())
+        assert sum(w * n for w, n in widths.items()) == stats["prefill"]["chunk_tokens"]
+        records = batcher.engine_log.iteration_records()
+        assert sum(r.prefill_calls for r in records) == stats["prefill"]["chunks"]
+        assert sum(r.prefill_tokens for r in records) == stats["prefill"]["chunk_tokens"]
+    finally:
+        batcher.close()
+
+
+def _tiny_module(name):
+    from unionml_tpu.models import (
+        BailingHybridConfig, BailingHybridTransformer, Glm4MoeLiteConfig, Glm4MoeLiteTransformer, SmallThinkerConfig,
+        SmallThinkerTransformer,
+    )
+
+    f32 = dict(dtype=jnp.float32, param_dtype=jnp.float32)
+    module = {
+        "smallthinker": lambda: SmallThinkerTransformer(SmallThinkerConfig.tiny(**f32)),  # a window of 8
+        "glm": lambda: Glm4MoeLiteTransformer(Glm4MoeLiteConfig.tiny(**f32)),
+        "bailing": lambda: BailingHybridTransformer(BailingHybridConfig.tiny(**f32)),
+    }[name]()
+    return module, module.init(jax.random.PRNGKey(3), jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+@pytest.mark.parametrize("model", ["llama", "llama_speculative", "smallthinker", "glm", "bailing"])
+def test_wide_calls_serve_what_one_chunk_calls_and_monolithic_admission_serve(tiny_gen, model):
+    """The same prompts, admitted together and then as radix hits that start mid-prompt, through three engines: a
+    budget of four chunks (calls 4, 8 and 16 wide), a budget of one chunk (every call one chunk) and monolithic
+    admission. Every stream, its first token included, is token for token the same in all three, and its
+    log-probabilities agree: for a windowed model whose rows outgrow the window, a latent model, a model with a
+    recurrent state (no radix cache), and a draft's row chunked in lockstep."""
+    spec = model == "llama_speculative"
+    if model.startswith("llama"):
+        module, params = tiny_gen
+    else:
+        module, params = _tiny_module(model)
+    cfg = GenerationConfig(max_new_tokens=6, temperature=0.0, prompt_buckets=(16, 32, 48))
+    if spec:
+        cfg = _speculative(cfg)
+    radix = model not in ("bailing", "llama_speculative")
+    first = [list(range(1, 38)), [5, 5, 5, 2, 9], list(range(40, 61)), [7] * 12]
+    hits = [list(range(1, 31)) + [90, 91, 92], list(range(40, 51)) + [3, 3]]  # start past a block of an earlier prompt
+
+    def serve(**options):
+        batcher = ContinuousBatcher(
+            Generator(module, params, cfg), slots=2, decode_chunk=4, block_size=4, pool_blocks=64, max_admissions=2,
+            prefix_cache=radix, **options,
+        )
+        try:
+            with batcher._lock:
+                streams = [batcher.submit(p, logprobs=not spec) for p in first]
+            out = [(_drain(s), list(s.logprobs or [])) for s in streams]
+            for p in hits:
+                stream = batcher.submit(p, logprobs=not spec)
+                out.append((_drain(stream), list(stream.logprobs or [])))
+            return out, batcher.stats()
+        finally:
+            batcher.close()
+
+    wide, wide_stats = serve(admit_chunk=4, prefill_budget=16)
+    narrow, narrow_stats = serve(admit_chunk=4, prefill_budget=4)
+    whole, _ = serve(admit_chunk=0)
+    assert max(wide_stats["prefill_widths"]) > 4 and set(narrow_stats["prefill_widths"]) == {4}
+    if radix:
+        assert wide_stats["prefix_cache"]["hits"] >= 2
+    for (tokens, lps), (n_tokens, n_lps), (m_tokens, m_lps) in zip(wide, narrow, whole):
+        assert len(tokens) == 6 and tokens == n_tokens == m_tokens
+        np.testing.assert_allclose(lps, n_lps, atol=1e-5)
+        np.testing.assert_allclose(lps, m_lps, atol=1e-5)
+
+
+def test_warmup_resolves_every_width_the_traffic_takes(tiny_gen):
+    """Buckets that are not the widths themselves (12 and 32 columns for widths 4, 8 and 16): the warm-up caps each
+    probe's passes at its width, so traffic that takes every width, radix hits included, traces no prefill
+    program and compiles nothing."""
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=4, temperature=0.0, prompt_buckets=(12, 32))
+    gen = Generator(module, params, cfg)
+    batcher = ContinuousBatcher(gen, slots=2, decode_chunk=2, block_size=4, admit_chunk=4, prefill_budget=16, prefix_cache=True)
+    compiled = []
+
+    def on_duration(event, seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiled.append(seconds)
+
+    try:
+        assert batcher._prefill_widths == (4, 8, 16)
+        batcher.warmup()
+        traces = (gen.prefill_traces, gen.decode_traces)
+        assert batcher.stats()["prefill_widths"] == {}  # the probes are not traffic
+        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        prompts = [list(range(2, 32)), [5, 6, 7, 8, 9], list(range(2, 22)) + [4, 4], [3] * 7]
+        with batcher._lock:
+            streams = [batcher.submit(p) for p in prompts]
+        assert all(len(_drain(s)) == 4 for s in streams)
+        stats = batcher.stats()
+        assert set(stats["prefill_widths"]) == {4, 8, 16} and stats["prefix_cache"]["hits"] >= 1
+        assert (gen.prefill_traces, gen.decode_traces) == traces and compiled == []
+    finally:
+        batcher.close()
+        from jax._src import monitoring
+
+        monitoring.unregister_event_duration_listener(on_duration)
+
+
+def test_prefill_widths_reach_the_debug_view_and_the_exposition_without_none(tiny_gen):
+    """The width counter is served by ``GET /debug/engine`` (the engine log's snapshot) and by the Prometheus
+    exposition of ``stats()``: ints keyed by columns, one series a width, no None anywhere."""
+    from unionml_tpu.observability.prometheus import render
+
+    module, params = tiny_gen
+    cfg = GenerationConfig(max_new_tokens=4, temperature=0.0, prompt_buckets=(8, 16))
+    batcher = ContinuousBatcher(Generator(module, params, cfg), slots=2, decode_chunk=2, admit_chunk=4, prefill_budget=8)
+    try:
+        _drain(batcher.submit(list(range(1, 15))))
+        widths = batcher.stats()["prefill_widths"]
+        assert widths == {8: 2} and batcher.engine_log.snapshot(4)["prefill_widths"] == widths
+        text = render({"generation": batcher.stats()})
+        assert "unionml_tpu_generation_prefill_widths_8 2" in text.splitlines()
+        assert "None" not in text and "nan" not in text.lower()
+        assert all(r.prefill_calls == (r.prefill_tokens // 8) for r in batcher.engine_log.iteration_records())
     finally:
         batcher.close()

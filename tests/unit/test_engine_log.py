@@ -153,6 +153,9 @@ def test_phases_partition_the_iteration_and_idle_lies_outside(monkeypatch):
     with log.phase("emit"):
         clock.now = 119.0
     log.rows, log.prefill_tokens, log.admitted, log.finished, log.blocks_grown, log.table_syncs = 3, 16, 1, 2, 4, 2
+    log.prefill_call(8)
+    log.prefill_call(8)
+    log.prefill_call()  # a monolithic prefill: a call, no width
     with log.phase("admit"):
         for _ in range(7):
             log.dispatch("step", int)  # admit_dispatches is what was dispatched inside admit, nothing bumped by hand
@@ -169,7 +172,9 @@ def test_phases_partition_the_iteration_and_idle_lies_outside(monkeypatch):
     assert dict(record.dispatched) == {"step": 7, "_sync_impl": 1}
     # appended, with defaults: readers that build a record by position keep their ten
     assert record._fields[8:10] == ("table_syncs", "admit_dispatches")
-    assert record._fields[10:] == ("dispatched", "wait_s", "wait_copy_s", "starved_s") and len(record._field_defaults) == 4
+    assert record._fields[10:] == ("dispatched", "wait_s", "wait_copy_s", "starved_s", "prefill_calls")
+    assert len(record._field_defaults) == 5
+    assert record.prefill_calls == 3 and log.prefill_widths() == {8: 2} and log.prefill_calls == 0
     totals = log.totals()
     assert totals["iterations"] == 1 and totals["idle_s"] == 11.0
     assert totals["phase_s"]["fetch"] == 4.0 and totals["table_syncs"] == 2 and totals["admit_dispatches"] == 7

@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -447,12 +448,13 @@ class Generator:
         #: ``"paged_kernel"`` or ``"gather"`` (ops/paged_attention.py); ``None``
         #: before the first trace and for a contiguous cache
         self.decode_attention_path: Optional[str] = None
-        self._paged_read_traced: Optional[str] = None  # the newest traced apply's paged read
         #: how the decode program updates a recurrent state a slot, recorded when it is traced:
         #: ``"state_kernel"`` or ``"state_xla"`` (ops/delta_rule.py); ``None`` before the first
         #: trace and for a model with no slot state
         self.decode_state_path: Optional[str] = None
-        self._state_step_traced: Optional[str] = None  # the newest traced apply's state update
+        #: the newest traced apply's paged read (``read``) and state update (``state``), kept
+        #: apart by the thread that traced it: a warm-up traces programs on several at once
+        self._traced = threading.local()
         #: what the module counts per call (its ``counters`` attribute: names it sows into
         #: the ``counters`` collection; ``max_*`` merge by maximum, the rest add up). With
         #: any, ``prefill_chunk`` returns the chunk's counts as its fourth output and the
@@ -555,8 +557,8 @@ class Generator:
             # which way a paged cache was read (None: a contiguous one) and a slot state updated (None: none is
             # kept), for whoever traces a decode program
             updates = set(paths) & {STATE_KERNEL, STATE_XLA}
-            self._paged_read_traced = "+".join(sorted(set(paths) - updates)) or None
-            self._state_step_traced = "+".join(sorted(updates)) or None
+            self._traced.read = "+".join(sorted(set(paths) - updates)) or None
+            self._traced.state = "+".join(sorted(updates)) or None
             return hidden, cache, collect_counts(sown.get("counters", {}))
 
         def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
@@ -649,8 +651,8 @@ class Generator:
             carry, (toks, lps) = jax.lax.scan(
                 body, (cache, tok, lengths, done, key, *cstate), None, length=steps
             )
-            self.decode_attention_path = self._paged_read_traced
-            self.decode_state_path = self._state_step_traced
+            self.decode_attention_path = self._traced.read
+            self.decode_state_path = self._traced.state
             # the advanced carry (incl. cache) is returned so the donated input
             # buffers have outputs to alias with — one cache in HBM throughout
             return toks.T, lps.T, carry

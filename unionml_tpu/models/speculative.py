@@ -237,7 +237,7 @@ class SpeculativeGenerator:
             verify_mask = jnp.broadcast_to((~done)[:, None], inputs.shape)
             logits, t_cache = target_apply(tp, inputs, positions, t_cache, verify_mask)
             # the verify pass is this engine's decode read of the target's cache
-            target.decode_attention_path = target._paged_read_traced
+            target.decode_attention_path = target._traced.read
             if cs is not None:
                 # target logits at position i masked by the state its row
                 # reached after drafts[:i] — p becomes the constrained policy

@@ -103,6 +103,9 @@ class IterationRecord(NamedTuple):
     wait_s: Tuple[float, ...] = _NO_WAITS  #: seconds waited for a device result, by :data:`WAITS`; their sum is ``phase_s[fetch]``
     wait_copy_s: Tuple[float, ...] = _NO_WAITS  #: of ``wait_s``, the copy to the host after the result was there
     starved_s: Tuple[float, ...] = _NO_PHASES  #: seconds the device had nothing of the engine's to run, by :data:`PHASES`
+    #: prefill programs handed the runtime (chunks of any width, and monolithic prefills): each streams the weights
+    #: once, so ``prefill_tokens / prefill_calls`` is the columns a stream of them carried
+    prefill_calls: int = 0
 
     def render(self) -> Dict[str, Any]:
         out = self._asdict()
@@ -247,6 +250,7 @@ class EngineLog:
         self.finished = 0
         self.blocks_grown = 0
         self.table_syncs = 0
+        self.prefill_calls = 0
         #: how the engine's decode program reads its paged cache (``"paged_kernel"`` / ``"gather"``), set by the
         #: engine after a decode dispatch; a fact about the program, not a counter: :meth:`clear` leaves it
         self.decode_attention_path: Optional[str] = None
@@ -273,6 +277,15 @@ class EngineLog:
         self._total_compiles = 0
         self._total_compile_s = 0.0
         self._slow_count = 0
+        self._prefill_widths: Dict[int, int] = {}
+
+    def prefill_call(self, chunk: Optional[int] = None) -> None:
+        """One prefill program was handed the runtime (engine thread): a call on the pass's record, and a chunk of
+        ``chunk`` columns on the cumulative count of chunk calls by width too (a monolithic prefill passes none)."""
+        self.prefill_calls += 1
+        if chunk is not None:
+            with self._lock:
+                self._prefill_widths[chunk] = self._prefill_widths.get(chunk, 0) + 1
 
     def count(self, kind: str, names: Sequence[str], values: Sequence[int]) -> None:
         """Add one dispatch's counts (engine thread)."""
@@ -428,6 +441,7 @@ class EngineLog:
         tail = (
             self._t0, phase_s, self.rows, self.prefill_tokens, self.admitted, self.finished, self.blocks_grown,
             table_syncs, admit_dispatches, MappingProxyType(dispatched), wait_s, wait_copy_s, starved_s,
+            self.prefill_calls,
         )
         compiles = self._compiles.count - self._compiles0[0]
         compile_s = self._compiles.seconds - self._compiles0[1]
@@ -445,6 +459,7 @@ class EngineLog:
         epoch = self._pass_epoch
         self._t0 = None
         self.rows = self.prefill_tokens = self.admitted = self.finished = self.blocks_grown = self.table_syncs = 0
+        self.prefill_calls = 0
         kept: Optional[SlowIteration] = None
         with self._lock:
             if epoch != self._epoch:
@@ -526,6 +541,11 @@ class EngineLog:
                 "slow_iterations": self._slow_count,
             }
 
+    def prefill_widths(self) -> Dict[int, int]:
+        """Chunked prefill calls by their columns, cumulative, narrowest first (``stats()["prefill_widths"]``)."""
+        with self._lock:
+            return dict(sorted(self._prefill_widths.items()))
+
     def iteration_records(self) -> List[IterationRecord]:
         """The retained iterations, oldest first."""
         with self._lock:
@@ -554,6 +574,7 @@ class EngineLog:
             "decode_state_path": self.decode_state_path,
             **({"model_counters": {k: dict(v) for k, v in self.model_counters.items()}} if self.model_counters else {}),
             **self.totals(),
+            "prefill_widths": self.prefill_widths(),
             "iterations_log": iterations,
             "requests_log": requests,
             "slow_iterations_log": slow,

@@ -644,6 +644,16 @@ class ContinuousBatcher:
                 chunk_aligned(b, self.admit_chunk) for b in (cfg.prompt_buckets or (widest,))
             )
             self.cache_len = max(self.cache_len, p0 + aligned)
+        #: the column counts a chunked admission's prefill calls take: ``admit_chunk`` times a
+        #: power of two, up to the most columns one iteration's budget runs and no wider than the
+        #: widest aligned bucket (a radix hit's suffix is no wider): one compile of the chunk
+        #: program each, log2(budget / admit_chunk) + 1 at most
+        self._prefill_widths: "tuple[int, ...]" = ()
+        if self.admit_chunk:
+            top = min(-(-self.prefill_budget // self.admit_chunk), aligned // self.admit_chunk)
+            self._prefill_widths = tuple(self.admit_chunk << k for k in range(top.bit_length()))
+        #: warm-up only: the most columns an admission runs in one pass (None: the budget decides)
+        self._warm_cap: Optional[int] = None
         #: radix prefix cache (automatic cross-request KV reuse over the
         #: pool's blocks, serving/prefix_cache.py). Resolution mirrors
         #: admit_chunk: constructor kwarg, then the serve CLI's
@@ -1713,31 +1723,68 @@ class ContinuousBatcher:
         """Resolve the admission/prefill/decode programs before traffic
         arrives, so the first real request never pays a cold XLA compile (tens
         of seconds on TPU — the same rationale as CompiledPredictor's startup
-        warmup). A bucket-FILLING request runs through each prompt bucket
-        (budget 1: admission only — each bucket is its own prefill shape), then
-        a short request exercises one decode/round chunk (the decode program is
-        bucket-independent). With an AOT store armed (``aot=`` /
-        ``UNIONML_TPU_AOT_PRELOAD``) every program resolves
+        warmup). Bucket-FILLING requests (budget 1: admission only) resolve
+        the prefill shapes the engine's mode can dispatch: with chunked
+        admission every bucket shares the chunk program, whose shapes are the
+        widths of ``_prefill_widths``, so one admission reaches each width,
+        its passes capped at that width: the narrowest first, the wider ones
+        last, once their programs, built on threads of their own meanwhile
+        (:meth:`_build_prefill_widths`), are there. Otherwise each bucket is
+        its own prefill shape and runs once. A radix cache's hit has its
+        set-up resolved, and a short request exercises one decode/round
+        chunk (the decode program is bucket-independent). With an AOT store
+        armed (``aot=`` / ``UNIONML_TPU_AOT_PRELOAD``) every program resolves
         **load-before-compile**: a populated store makes this whole pass
         deserialize-bound (milliseconds per program) and an empty one compiles
         once and serializes the result for the next cold process. Counters are
         reset afterwards so ``/metrics`` reflects real traffic only (the AOT
         load/compile telemetry deliberately survives the reset — preload work
         IS the warmup story ``stats()["aot"]`` exists to tell)."""
-        cfg = self.gen.config
-        for bucket in sorted(cfg.prompt_buckets):
-            # length == bucket: _bucket() maps shorter prompts to the smallest
-            # fitting bucket, which would leave the larger shapes cold
-            prompt = [cfg.pad_id + 1] * bucket
-            for _ in self.submit(prompt, max_new_tokens=1):
-                pass
-        if self._radix is not None and cfg.prompt_buckets:
+        cfg, gen = self.gen.config, self.gen
+        buckets = sorted(cfg.prompt_buckets)
+        # (prompt length, the most columns a pass of its admission runs); a length equal to its
+        # bucket: _bucket() maps shorter prompts to the smallest fitting bucket
+        probes = [(bucket, None) for bucket in buckets]
+        builder: Optional[threading.Thread] = None
+        sp = cfg.sp_prefill and gen.mesh is not None and self._sp_seq > 1 and self.prefix is None
+        if self.admit_chunk and not sp and buckets:
+            chunk = self.admit_chunk
+            probes = [
+                (min(b for b in buckets if chunk_aligned(b, chunk) >= width), width)
+                for width in self._prefill_widths
+            ]
+            if len(self._prefill_widths) > 1 and all(hasattr(g._prefill_chunk, "lower") for g in self._models):
+                # the wider widths' programs build meanwhile, and their probes wait for them (an
+                # AOT store's wrapper has no lower(): it builds at its first call, in a probe)
+                builder = threading.Thread(
+                    target=self._build_prefill_widths, args=(self._prefill_widths[1:],), name="warmup-prefill", daemon=True
+                )
+                builder.start()
+        vocab = int(getattr(gen.module.config, "vocab_size", 2))
+        # a token of its own per width probe: one probe's prompt would otherwise hit the radix
+        # cache on another's blocks and prefill only its uncached suffix
+        tokens = [
+            1 + (cfg.pad_id + i) % max(vocab - 1, 1) if cap else cfg.pad_id + 1 for i, (_, cap) in enumerate(probes)
+        ]
+        first = len(probes) if builder is None else 1
+
+        def run_probes(which: slice) -> None:
+            try:
+                for (length, cap), tok in zip(probes[which], tokens[which]):
+                    self._warm_cap = cap  # read at the admission's step: the one admission in flight is this one
+                    for _ in self.submit([tok] * length, max_new_tokens=1):
+                        pass
+            finally:
+                self._warm_cap = None
+
+        run_probes(slice(0, first))
+        if self._radix is not None and probes:
             with self._lock:
                 hit = self.prefix_cache_hits > 0
             if not hit:
                 # a radix hit's set-up is a program of its own (the row gathered
-                # from the pool): the widest probe again finds its own blocks cached
-                for _ in self.submit([cfg.pad_id + 1] * max(cfg.prompt_buckets), max_new_tokens=1):
+                # from the pool): the widest probe so far again finds its own blocks cached
+                for _ in self.submit([tokens[first - 1]] * probes[first - 1][0], max_new_tokens=1):
                     pass
         if cfg.max_new_tokens >= 2:
             # an eos-emitting model can finish a junk prompt at admission
@@ -1746,7 +1793,6 @@ class ContinuousBatcher:
             # freshly initialized carry, whose jit signature differs subtly
             # from the steady-state (decode-output) carry and compiles
             # separately; the second covers what real traffic sees.
-            vocab = int(getattr(self.gen.module.config, "vocab_size", 2))
             for salt in range(6):
                 if self.decode_dispatches >= 2:
                     break
@@ -1759,6 +1805,9 @@ class ContinuousBatcher:
                     "at admission for every probe prompt); the first streams "
                     "may pay a compile"
                 )
+        if builder is not None:
+            builder.join()
+        run_probes(slice(first, None))
         with self._lock:
             self.decode_dispatches = 0
             self.decoded_rows = 0
@@ -1791,6 +1840,42 @@ class ContinuousBatcher:
                 self._spec.accepted_tokens = 0
         with self._health_lock:
             self._health_cache = None  # next health() sees post-reset telemetry
+
+    def _build_prefill_widths(self, widths: Sequence[int]) -> None:
+        """Warm-up, on a thread of its own: build the chunk program at each of
+        ``widths`` (each model's, under speculation) from the arguments a cold
+        admission's first call takes. This thread traces and lowers them one
+        after another (the part that holds the interpreter lock), and each
+        lowered program compiles, or is read back from the compile cache, on
+        a thread of its own (the part that leaves it): the builds overlap each
+        other and the warm-up's admissions, whose own reads and compiles leave
+        the lock too. Returns once every width is built."""
+        lengths, _, row_valid, lasts, rows = self._setup_fn(np.uint32(0), np.int32(1), self._setup_prefixes)
+
+        def compile_one(lowered: Any, width: int) -> None:
+            try:
+                lowered.compile()
+            except Exception:  # the width's probe builds it on the engine thread instead
+                logger.warning("warmup could not build the %d-column prefill program ahead", width, exc_info=True)
+
+        compiles = []
+        for g, last, row in zip(self._models, lasts, rows):
+            for width in widths:
+                try:
+                    lowered = g._prefill_chunk.lower(
+                        g.params, jax.ShapeDtypeStruct((1, width), np.int32), jax.ShapeDtypeStruct((), np.int32),
+                        lengths, row, row_valid, last,
+                    )
+                except Exception:
+                    logger.warning("warmup could not lower the %d-column prefill program ahead", width, exc_info=True)
+                    continue
+                compile_thread = threading.Thread(
+                    target=compile_one, args=(lowered, width), name=f"warmup-prefill-{width}", daemon=True
+                )
+                compile_thread.start()
+                compiles.append(compile_thread)
+        for compile_thread in compiles:
+            compile_thread.join()
 
     def configure_slo(self, config: "SLOConfig") -> None:
         """Swap this engine's SLO targets at runtime (retuning a live fleet,
@@ -1939,6 +2024,9 @@ class ContinuousBatcher:
                     "monolithic_admissions": self.prefill_monolithic,
                     "backlog_tokens": backlog,
                 },
+                # the chunk calls by their columns (each one stream of the weights): how often
+                # an admission took more than one chunk in a call
+                "prefill_widths": self.engine_log.prefill_widths(),
             }
             # "used" includes the permanently resident shared-prefix pages
             used = self.pool_blocks - len(self._free_blocks)
@@ -2189,14 +2277,15 @@ class ContinuousBatcher:
         it; the engine thread is the sole device-state owner, so the unlocked
         sections touch the carry safely.
 
-        With ``admit_chunk`` set, each in-flight admission advances ONE chunk
-        per pass and this method returns once ``prefill_budget`` prefill
-        tokens have run — the caller's decode dispatch interleaves with long
-        prefills, bounding resident streams' time-between-tokens at ~one
-        chunk instead of one whole prompt. Monolithic admissions (chunking
-        disabled, the sequence-parallel path, or an exact-width resume whose
-        aligned width would overflow the cache) complete in a single step,
-        exactly as before."""
+        With ``admit_chunk`` set, in-flight admissions are served first come
+        first served: each takes as many of its columns as the budget left
+        allows (:meth:`_admission_step`), and this method returns once
+        ``prefill_budget`` prefill tokens have run — the caller's decode
+        dispatch interleaves with long prefills, bounding resident streams'
+        time-between-tokens at ~one budget instead of one whole prompt.
+        Monolithic admissions (chunking disabled, the sequence-parallel path,
+        or an exact-width resume whose aligned width would overflow the
+        cache) complete in a single step, exactly as before."""
         budget = self.prefill_budget
         spent = 0
         log = self.engine_log
@@ -2209,7 +2298,7 @@ class ContinuousBatcher:
                     if not self._admission_alive(adm):
                         continue
                     try:
-                        cost = self._admission_step(adm)
+                        cost = self._admission_step(adm, None if budget is None else budget - spent)
                     except ValueError as exc:
                         # a bad prompt (e.g. longer than the cache can hold) fails
                         # its own stream; the engine and other residents keep going
@@ -2620,6 +2709,7 @@ class ContinuousBatcher:
                 allow_sp=not session.want_logprobs,
             )
             adm.done = True
+            self.engine_log.prefill_call()
             with self._lock:
                 self.prefill_monolithic += 1
             _tev(session, "engine.prefill", tokens=p0 + bucket, mode="monolithic")
@@ -2740,47 +2830,72 @@ class ContinuousBatcher:
         _tev(session, "prefill.cache_hit", tokens=start - p0, cached=start)
         return True
 
-    def _admission_step(self, adm: _Admission) -> int:
-        """Advance one admission's prefill by one unit (engine thread; device
-        work runs unlocked). Monolithic admissions complete inside
-        :meth:`_admission_begin`; chunked admissions run exactly one
-        ``admit_chunk``-wide slice through the Generator's chunked-prefill
-        program — one compile total, the chunk shape is bucket-independent —
-        and sample the first token via ``_first_token`` once the last chunk
-        lands. Returns the prefill tokens spent (the per-iteration budget's
+    def _prefill_pieces(self, columns: int) -> "List[int]":
+        """The widths of the calls that run ``columns`` columns (a multiple of
+        ``admit_chunk``): one per power of two in ``columns / admit_chunk``,
+        largest first, none wider than the widest of ``_prefill_widths``."""
+        chunk, widest = self.admit_chunk, self._prefill_widths[-1]
+        pieces = []
+        while columns > 0:
+            pieces.append(min(widest, chunk << ((columns // chunk).bit_length() - 1)))
+            columns -= pieces[-1]
+        return pieces
+
+    def _admission_step(self, adm: _Admission, allowance: Optional[int] = None) -> int:
+        """Advance one admission's prefill (engine thread; device work runs
+        unlocked). Monolithic admissions complete inside
+        :meth:`_admission_begin`. A chunked admission takes its remaining
+        columns, or as many as ``allowance`` (the iteration's budget left)
+        allows, rounded up to whole chunks, in as few calls of the
+        Generator's chunked-prefill program as :meth:`_prefill_pieces` gives:
+        each call streams the weights once, and its width is one of
+        ``_prefill_widths``, each a compile of its own. Without
+        ``admit_chunk`` (a radix hit's suffix on a monolithic engine) it runs
+        one chunk. The first token is sampled via ``_first_token`` once the
+        last column lands. Returns the prefill tokens spent (the budget's
         unit)."""
         gen = self.gen
         if adm.tokens is None:
             cost = self._admission_begin(adm)
             if adm.done:
                 return cost
-        c = adm.pos
-        # host values: the chunk's columns and its offset travel with the dispatch
-        sl, start = adm.tokens[:, c : c + adm.chunk], np.int32(adm.start + c)
-        adm.last, adm.row_cache, counts = self._issue(
-            "prefill_chunk", gen._prefill_chunk, gen.params, sl, start, adm.lengths, adm.row_cache, adm.row_valid, adm.last
-        )
-        if gen.counter_names:
-            adm.counts.append(counts)  # read with the admission's first token: no fetch of their own
-        if self._spec is not None:
-            draft = self._spec._draft
-            adm.d_last, adm.d_row_cache, _ = self._issue(
-                "prefill_chunk", draft._prefill_chunk, draft.params, sl, start, adm.lengths,
-                adm.d_row_cache, adm.row_valid, adm.d_last,
+        pieces = [adm.chunk]
+        if self.admit_chunk:
+            columns = adm.width - adm.pos
+            if allowance is not None:
+                columns = min(columns, -(-allowance // adm.chunk) * adm.chunk)
+            if self._warm_cap is not None:
+                columns = min(columns, self._warm_cap)
+            pieces = self._prefill_pieces(columns)
+        for width in pieces:
+            c = adm.pos
+            # host values: the call's columns and its offset travel with the dispatch
+            sl, start = adm.tokens[:, c : c + width], np.int32(adm.start + c)
+            adm.last, adm.row_cache, counts = self._issue(
+                "prefill_chunk", gen._prefill_chunk, gen.params, sl, start, adm.lengths, adm.row_cache, adm.row_valid, adm.last
             )
-        adm.pos = c + adm.chunk
-        with self._lock:
-            self.prefill_chunks += 1
-            self.prefill_chunk_tokens += adm.chunk
-        _tev(
-            adm.session, "engine.prefill_chunk",
-            pos=adm.pos, width=adm.width, chunk=adm.chunk,
-        )
+            if gen.counter_names:
+                adm.counts.append(counts)  # read with the admission's first token: no fetch of their own
+            if self._spec is not None:
+                draft = self._spec._draft
+                adm.d_last, adm.d_row_cache, _ = self._issue(
+                    "prefill_chunk", draft._prefill_chunk, draft.params, sl, start, adm.lengths,
+                    adm.d_row_cache, adm.row_valid, adm.d_last,
+                )
+            adm.pos = c + width
+            self.engine_log.prefill_call(width)
+            with self._lock:
+                self.prefill_chunks += 1
+                self.prefill_chunk_tokens += width
+            _tev(
+                adm.session, "engine.prefill_chunk",
+                pos=adm.pos, width=adm.width, chunk=width,
+            )
         if adm.pos >= adm.width:
             adm.tok0 = self._issue("first_token", gen._first_token, gen.params, adm.last, adm.key, *adm.cstate)
             adm.row_len = adm.lengths
             adm.done = True
-        return adm.chunk
+        return sum(pieces)
 
     def _export_admission(self, adm: _Admission) -> None:
         """Complete an EXPORT admission (the prefill-role path): emit the
