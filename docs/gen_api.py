@@ -50,6 +50,7 @@ MODULES = [
     "unionml_tpu.models.bailing_hybrid",
     "unionml_tpu.models.smallthinker",
     "unionml_tpu.ops.delta_rule",
+    "unionml_tpu.ops.chunk_attention",
     "unionml_tpu.ops.attention",
     "unionml_tpu.ops.ring_attention",
     "unionml_tpu.ops.quant",

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from unionml_tpu.ops.chunk_attention import chunk_attention
 from unionml_tpu.ops.delta_rule import delta_rule_step_kernel
 from unionml_tpu.ops.flash_attention import flash_attention
 from unionml_tpu.ops.int8_matmul import int8_matmul
@@ -100,6 +101,20 @@ def _state_step():
     return delta_rule_step_kernel, [((192, 32, 128, 128), jnp.float32), vector, vector, vector, vector, ((192, 32), jnp.float32)]
 
 
+def _chunk_read(group, n_kv, length, row, widths, window=None):
+    """A prefill chunk's read of a long row through the Pallas kernel: ``length`` queries of ``n_kv x group`` heads
+    over a row of ``row`` positions (a latent row, one KV head, is read as keys alone: ``widths`` stored and valued)."""
+    stored, valued = widths
+    keys = ((1, row, n_kv, stored), jnp.bfloat16)
+    args = [((1, n_kv, group, length, stored), jnp.bfloat16), keys, ((1, length), jnp.int32), ((1, length), jnp.bool_)]
+
+    def read(q, keys, *rest):
+        values, (positions, live) = (rest[0], rest[1:]) if n_kv > 1 else (None, rest)
+        return chunk_attention(q, keys, values, positions, live, scale=0.08, window=window, value_width=None if n_kv > 1 else valued)
+
+    return read, args[:2] + ([((1, row, n_kv, valued), jnp.bfloat16)] if n_kv > 1 else []) + args[2:]
+
+
 CASES = {
     "flash_fwd": _flash("fwd"),
     "flash_bwd": _flash("bwd"),
@@ -111,6 +126,10 @@ CASES = {
     "grouped_matmul_decode_512": _grouped_matmul(512),
     "grouped_matmul_chunk_1024": _grouped_matmul(1024),
     "delta_rule_step_192x32": _state_step(),
+    "chunk_read_gqa7_4096_global": _chunk_read(7, 4, 4096, 16392, (128, 128)),
+    "chunk_read_gqa7_4096_window": _chunk_read(7, 4, 4096, 16392, (128, 128), window=4096),
+    "chunk_read_gqa6_256_window": _chunk_read(6, 8, 256, 5384, (128, 128), window=4096),
+    "chunk_read_latent_2048": _chunk_read(20, 1, 2048, 8968, (640, 512)),
 }
 
 
@@ -244,6 +263,13 @@ def _walks_the_row_in_blocks(text, layers, chunk, cache_len):
     """The compiled chunk program reads its row cache in key blocks: one ``while`` a layer, and no float32 array
     (scores, weights) whose key extent is the whole row."""
     return len(re.findall(r" while\(", text)) >= layers and not re.search(rf"f32\[[\d,]*{chunk},{cache_len}\]", text)
+
+
+def _reads_the_row_through_the_kernel(text, layers, chunk):
+    """The compiled chunk program reads its row cache through the Pallas chunk kernel, one custom call a layer, and
+    walks no block in a ``while`` (no float32 ``[..., chunk, 1]`` statistics carried between blocks)."""
+    calls = re.findall(r"%chunk_attention(?:\.\d+)? = \S+ custom-call\(", text)
+    return len(calls) == layers and not re.search(rf" while\(.*f32\[[\d,]*{chunk},1\]", text)
 
 
 def test_latent_prefill_chunk_compiles_for_v5e(chip):
@@ -537,8 +563,8 @@ def test_smallthinker_programs_compile_for_v5e_with_seven_query_heads_a_kv_head(
     seven, which the library kernel takes by its ``groups % 8`` layout), as on the chip (the trace told it runs on a
     TPU: the routed product is the pallas grouped matmul): the decode program over 96 slots of 257 pages in a pool
     of 4,609 reads the global layer through the paged kernel and the windowed one through the windowed kernel, keeps
-    no re-laid pool and counts the window's two counters; the chunk program walks the 16,392-position row in key
-    blocks within half a GB of temporaries."""
+    no re-laid pool and counts the window's two counters; the chunk program reads the 16,392-position row through
+    the Pallas chunk kernel, one call a layer, within half a GB of temporaries."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
     from unionml_tpu.models.layers import WINDOW_COUNTERS
@@ -573,7 +599,8 @@ def test_smallthinker_programs_compile_for_v5e_with_seven_query_heads_a_kv_head(
         lengths, key, row_valid, (last,), (row,) = on_chip(lambda: batcher._setup_fn(jnp.uint32(0), jnp.int32(0), ()))
         step = gen._prefill_chunk.lower(params, scalar(jnp.int32, (1, chunk)), scalar(jnp.int32), lengths, row, row_valid, last).compile()
         assert step.memory_analysis().temp_size_in_bytes < 0.5e9
-        assert _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, chunk, batcher.cache_len)
+        assert gen.chunk_attention_path == "chunk_kernel"
+        assert _reads_the_row_through_the_kernel(step.as_text(), module.config.n_layers, chunk)
     finally:
         batcher.close()
 
@@ -589,14 +616,14 @@ WIDEST_CALLS = {
 @pytest.mark.parametrize("cell", sorted(WIDEST_CALLS))
 def test_widest_prefill_call_compiles_for_v5e(chip, monkeypatch, cell):
     """The widest call an iteration's budget makes (4,096 columns in SmallThinker's cell, 2,048 in GLM's) compiles
-    for the described chip: it is the engine's widest width, its read still walks the long row in key blocks, and
-    its temporaries stay within the sixth of the chip that the cell's weights and pool leave free (PERF.md
+    for the described chip as the chip runs it (the trace told it runs on a TPU: the routed product is the pallas
+    grouped matmul, the long row's read the Pallas chunk kernel, one call a layer): it is the engine's widest width,
+    and its temporaries stay within the sixth of the chip that the cell's weights and pool leave free (PERF.md
     section 5 reads the bytes)."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.serving import ContinuousBatcher
 
-    if cell == "mixed_long_sat":
-        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the routed product is the pallas grouped matmul
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     make, max_prompt, max_new, budget, options, cache_len = WIDEST_CALLS[cell]
     module, chunk, page = make(), 256, 64
     on_chip = functools.partial(_on_chip, chip)
@@ -617,6 +644,7 @@ def test_widest_prefill_call_compiles_for_v5e(chip, monkeypatch, cell):
         temp = step.memory_analysis().temp_size_in_bytes
         print(f"{cell}: a {budget}-column call's temporaries {temp / 1e9:.3f} GB")
         assert temp < 16e9 / 6
-        assert _walks_the_row_in_blocks(step.as_text(), module.config.n_layers, budget, cache_len)
+        assert gen.chunk_attention_path == "chunk_kernel"
+        assert _reads_the_row_through_the_kernel(step.as_text(), module.config.n_layers, budget)
     finally:
         batcher.close()

@@ -452,7 +452,11 @@ class Generator:
         #: ``"state_kernel"`` or ``"state_xla"`` (ops/delta_rule.py); ``None`` before the first
         #: trace and for a model with no slot state
         self.decode_state_path: Optional[str] = None
-        #: the newest traced apply's paged read (``read``) and state update (``state``), kept
+        #: how a prefill (a chunk, a whole prompt) reads a row cache too long to read whole in one trip, recorded
+        #: when such a program is traced: ``"chunk_kernel"`` or ``"chunk_xla"`` (ops/chunk_attention.py); ``None``
+        #: before, and for a model whose rows are all read whole
+        self.chunk_attention_path: Optional[str] = None
+        #: the newest traced apply's paged read (``read``), state update (``state``) and long-row read (``chunk``), kept
         #: apart by the thread that traced it: a warm-up traces programs on several at once
         self._traced = threading.local()
         #: what the module counts per call (its ``counters`` attribute: names it sows into
@@ -516,6 +520,7 @@ class Generator:
 
         self._constrain = constrain  # shared by sp_prefill and beam search
 
+        from unionml_tpu.ops.chunk_attention import CHUNK_KERNEL, CHUNK_XLA
         from unionml_tpu.ops.delta_rule import STATE_KERNEL, STATE_XLA
         from unionml_tpu.ops.paged_attention import paged_read_scope
 
@@ -554,11 +559,12 @@ class Generator:
                     mutable=["counters"] if names else False,
                 )
             (hidden, cache), sown = out if names else (out, {})
-            # which way a paged cache was read (None: a contiguous one) and a slot state updated (None: none is
-            # kept), for whoever traces a decode program
-            updates = set(paths) & {STATE_KERNEL, STATE_XLA}
-            self._traced.read = "+".join(sorted(set(paths) - updates)) or None
+            # which way a paged cache was read (None: a contiguous one), a slot state updated (None: none is
+            # kept) and a long row walked (None: no read walked one), for whoever traces a program
+            updates, chunks = set(paths) & {STATE_KERNEL, STATE_XLA}, set(paths) & {CHUNK_KERNEL, CHUNK_XLA}
+            self._traced.read = "+".join(sorted(set(paths) - updates - chunks)) or None
             self._traced.state = "+".join(sorted(updates)) or None
+            self._traced.chunk = "+".join(sorted(chunks)) or None
             return hidden, cache, collect_counts(sown.get("counters", {}))
 
         def apply(p: Any, tokens: jax.Array, positions: jax.Array, cache: Any, token_mask: Any):
@@ -577,6 +583,7 @@ class Generator:
             # routed-expert capacity — mask it out of the token stream
             token_mask = (jnp.arange(prompt_len)[None] < lengths[:, None]) & row_valid[:, None]
             hidden, cache = apply(p, tokens, positions, cache, token_mask)
+            self.chunk_attention_path = self._traced.chunk or self.chunk_attention_path
             last = jnp.take_along_axis(hidden, (lengths - 1)[:, None, None], axis=1)[:, 0]
             tok0 = sample_tokens(constrain(head(p, last), cstate), key, config)
             return tok0, cache, last.astype(jnp.float32)
@@ -594,6 +601,7 @@ class Generator:
             positions = start + jnp.broadcast_to(jnp.arange(chunk)[None], (batch, chunk))
             token_mask = (positions < lengths[:, None]) & row_valid[:, None]
             hidden, cache, counts = apply_counted(p, tokens, positions, cache, token_mask)
+            self.chunk_attention_path = self._traced.chunk or self.chunk_attention_path
             sel = positions == (lengths - 1)[:, None]  # at most one true column per row
             chunk_last = jnp.einsum("blc,bl->bc", hidden.astype(jnp.float32), sel.astype(jnp.float32))
             return jnp.where(sel.any(axis=1)[:, None], chunk_last, last), cache, counts

@@ -376,8 +376,9 @@ class Attention(nn.Module):
         several tokens over a long one (a prefill chunk, a monolithic prefill, a
         verify: :func:`~unionml_tpu.ops.attention.walks_in_blocks`) walk it in key
         blocks from the window's first visible slot to the last live query's
-        (:func:`~unionml_tpu.ops.attention.blocked_cached_attention`), dequantising an
-        int8 row a block at a time. Several tokens count ``kv_positions_attended`` (key
+        (:func:`~unionml_tpu.ops.attention.blocked_cached_attention`: on a TPU one Pallas
+        kernel over a bf16 row, else an XLA ``while`` that dequantises an int8 row a block
+        at a time). Several tokens count ``kv_positions_attended`` (key
         positions the read covered, a row with a live token) and ``kv_positions_needed``
         (of those, what the visibility rule lets such a row's live queries see)."""
 
@@ -390,19 +391,9 @@ class Attention(nn.Module):
         if walks_in_blocks(length, size):
             # query heads grouped by the KV head they read (h = kv * group + g, as ``jnp.repeat`` lays them)
             grouped = jnp.transpose(q.reshape(batch, length, n_kv, heads // n_kv, head_dim), (0, 2, 3, 1, 4))
-
-            def score(k, v, k_scale=None, v_scale=None):
-                scores = jnp.einsum("bkgld,bskd->bkgls", grouped, dequant(k, k_scale), preferred_element_type=jnp.float32)
-                return scores.reshape(batch, heads, length, -1) * head_dim**-0.5
-
-            def value(weights, k, v, k_scale=None, v_scale=None):
-                weights = weights.reshape(batch, n_kv, heads // n_kv, length, -1)
-                out = jnp.einsum("bkgls,bskd->bkgld", weights, dequant(v, v_scale), preferred_element_type=jnp.float32)
-                return out.reshape(batch, heads, length, head_dim)
-
+            scales = (rows["k_scale"], rows["v_scale"]) if "k_scale" in rows else None
             out, covered = blocked_cached_attention(
-                score, value, [rows[name] for name in ("k", "v", "k_scale", "v_scale") if name in rows], positions, live,
-                heads=heads, width=head_dim, window=self.window, dtype=q.dtype,
+                grouped, rows["k"], rows["v"], positions, live, scale=head_dim**-0.5, window=self.window, scales=scales
             )
         else:
             keys, values = dequant(rows["k"], rows.get("k_scale")), dequant(rows["v"], rows.get("v_scale"))
@@ -549,6 +540,9 @@ class LatentAttention(nn.Module):
     an 8,968-position row costs a layer 0.85 ms whole (1.17 expanded, PERF.md
     section 6, "PR 30": the expanded cached read was not kept) and, walked, 0.15 ms
     at offset 0, 0.32 at 2,304, 0.53 at 4,480 and 0.78 at 7,936 (same section, "PR 34").
+    On a TPU the walk is the Pallas chunk kernel (:mod:`unionml_tpu.ops.chunk_attention`):
+    one KV head of ``n_heads`` query heads, the stored row scored whole against the query
+    padded to its width.
 
     The cache is one plane, ``{"k": [B, S, 1, width]}`` (paged: ``[1, n_pages,
     page, width]`` + ``table``) whose ``width`` is the cache's own (the model's
@@ -686,19 +680,13 @@ class LatentAttention(nn.Module):
             cache = {"k": _write_cache(cache["k"], stored, positions[:, 0])}
             rows = cache["k"]
         if walks_in_blocks(length, rows.shape[1]):
-            q_abs = jnp.transpose(absorb(), (0, 2, 1, 3))  # [B, H, L, kv_rank + rope]
-
-            def score(block: jax.Array) -> jax.Array:
-                keys = block[:, :, 0, : self.kv_rank + self.rope_dim].astype(self.dtype)
-                return jnp.einsum("bhlw,bsw->bhls", q_abs, keys, preferred_element_type=jnp.float32) * scale
-
-            def value(weights: jax.Array, block: jax.Array) -> jax.Array:
-                values = block[:, :, 0, : self.kv_rank].astype(self.dtype)
-                return jnp.einsum("bhls,bsc->bhlc", weights, values, preferred_element_type=jnp.float32)
-
+            # one shared "KV head": the stored row scored whole against the query padded to its width (the tail
+            # is zeros on both sides), valued on its first kv_rank channels
+            q_abs = jnp.pad(absorb(), ((0, 0), (0, 0), (0, 0), (0, width - self.kv_rank - self.rope_dim)))
             with jax.named_scope("mla.absorb"):
                 out, covered = blocked_cached_attention(
-                    score, value, [rows], positions, live, heads=heads, width=self.kv_rank, dtype=self.dtype
+                    jnp.transpose(q_abs, (0, 2, 1, 3))[:, None], rows, None, positions, live, scale=scale,
+                    value_width=self.kv_rank,
                 )
             out = unabsorb(out)
         else:

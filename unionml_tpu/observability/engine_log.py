@@ -257,6 +257,9 @@ class EngineLog:
         #: how it updates a recurrent state a slot (``"state_kernel"`` / ``"state_xla"``; None for a model with
         #: none), set beside it
         self.decode_state_path: Optional[str] = None
+        #: how its prefill reads a row too long to read whole (``"chunk_kernel"`` / ``"chunk_xla"``; None where every
+        #: row is read whole), set beside them
+        self.chunk_attention_path: Optional[str] = None
         #: what the served model counted over the engine's dispatches (routed-expert pairs, window pages not
         #: read, ...: the names its ``counters`` attribute declares), cumulative, by kind of dispatch
         #: (``"decode"`` / ``"prefill"``); ``max_*`` hold the largest seen. Empty for a model that counts
@@ -572,6 +575,7 @@ class EngineLog:
             "capacity": self.capacity,
             "decode_attention_path": self.decode_attention_path,
             "decode_state_path": self.decode_state_path,
+            "chunk_attention_path": self.chunk_attention_path,
             **({"model_counters": {k: dict(v) for k, v in self.model_counters.items()}} if self.model_counters else {}),
             **self.totals(),
             "prefill_widths": self.prefill_widths(),

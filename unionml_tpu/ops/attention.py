@@ -12,7 +12,7 @@ sequence dim sits next to batch so sequence-parallel sharding specs stay rank-st
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +21,8 @@ from jax import lax
 #: key positions one block of :func:`blocked_cached_attention` covers (chosen on a v5e at
 #: ``glm47-flash.long_sat``'s shape: 256, 512 and 1,024 read 476.4, 475.9 and 467.3 tokens/s
 #: there; PERF.md section 6, "PR 34")
+#: in the XLA walk, and the unit of the span it reports; the Pallas kernel reads blocks of its own
+#: (:func:`unionml_tpu.ops.chunk_attention._key_block`)
 KEY_BLOCK = 512
 #: a cached read of several tokens walks a row cache longer than this in key blocks and reads a
 #: shorter one whole under its mask (:func:`walks_in_blocks`): inside a whole chunk program the
@@ -91,72 +93,92 @@ def walks_in_blocks(length: int, size: int) -> bool:
 
 
 def blocked_cached_attention(
-    score: Callable[..., jax.Array],
-    value: Callable[..., jax.Array],
-    planes: Sequence[jax.Array],
+    q: jax.Array,
+    keys: jax.Array,
+    values: Optional[jax.Array],
     positions: jax.Array,
     live: jax.Array,
     *,
-    heads: int,
-    width: int,
+    scale: float,
     window: Optional[int] = None,
-    dtype=jnp.bfloat16,
+    value_width: Optional[int] = None,
+    scales: Optional[Tuple[jax.Array, jax.Array]] = None,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Several queries a row over a row cache, reading the keys they can see: the
-    cache's ``planes`` (each ``[B, S, ...]``, position on axis 1) are walked in blocks
-    of :data:`KEY_BLOCK` positions from the block that holds the first visible key to
-    the one that holds the last live query, with the running softmax statistics (a
-    float32 maximum, sum and accumulator) kept between blocks. Both bounds are traced
-    values (``positions [B, L]`` absolute, ``live [B, L]`` the real tokens; one pair of
-    bounds serves the batch), so the walk is ONE ``while`` with a dynamic trip count
-    inside the caller's program, whatever the chunk's offset; nothing live, no trip.
+    """Several queries a row over a row cache, reading the keys they can see: grouped
+    queries ``q [B, H_kv, G, L, D_k]`` (query head ``kv * G + g`` reads KV head ``kv``)
+    over the key plane ``keys [B, S, H_kv, D_k]`` and the value plane ``values [B, S,
+    H_kv, D_v]``, or, with ``values`` None, the keys' first ``value_width`` channels (a
+    latent row's). ``scales``: an int8 row's ``(k_scale, v_scale)`` planes ``[B, S, H_kv,
+    1]``. Scores are ``q . k * scale`` in float32; weights meet the values in ``q.dtype``.
 
-    The products are the caller's, over one block's slice of every plane:
-    ``score(*blocks) -> [B, heads, L, block]`` float32 (scaled), ``value(weights,
-    *blocks) -> [B, heads, L, width]`` float32 with ``weights [B, heads, L, block]`` in
-    ``dtype`` — an int8 row is dequantised there, a block at a time. Visibility is
-    :func:`cache_visible` on the block's absolute slots; the last block of a row that is
-    no multiple of the block is clamped to end on the row's last slot and masks the
-    slots the block before it already counted (a row shorter than a block is one block).
-    A query that sees no key yields zero, as :func:`dot_product_attention` does.
+    The row is walked in blocks of :data:`KEY_BLOCK` positions from the block that holds
+    the first visible key to the one that holds the last live query, with the running
+    softmax statistics (a float32 maximum, sum and accumulator) kept between blocks.
+    Both bounds are traced values (``positions [B, L]`` absolute, ``live [B, L]`` the
+    real tokens), so the walk is one program whatever the chunk's offset; nothing live,
+    nothing read. Where :func:`~unionml_tpu.ops.chunk_attention.chunk_read_path` says (a
+    TPU, a bf16 row on one device, lane-wide planes) the walk is the Pallas kernel
+    :func:`~unionml_tpu.ops.chunk_attention.chunk_attention`, which keeps scores, weights
+    and statistics in VMEM and bounds each query tile by its own queries; everywhere else
+    it is ONE ``while`` with a dynamic trip count inside the caller's program, one pair of
+    bounds for the batch, dequantising an int8 row a block at a time. Visibility is
+    :func:`cache_visible` on the block's absolute slots; in the ``while`` the last block of
+    a row that is no multiple of the block is clamped to end on the row's last slot and
+    masks the slots the block before it already counted (a row shorter than a block is
+    one block). A query that sees no key yields zero, as :func:`dot_product_attention` does.
 
-    Returns ``([B, L, heads, width]`` in ``dtype``, the key positions the walk covered a row``)``.
+    Returns ``([B, L, H_kv * G, D_v]`` in ``q.dtype``, the key positions the walk spans a
+    row, from its first block to its last``)``.
     """
-    size = planes[0].shape[1]
+    from unionml_tpu.ops.chunk_attention import CHUNK_KERNEL, chunk_attention, chunk_read_path
+
+    batch, n_kv, group, length, _ = q.shape
+    size = keys.shape[1]
     block = min(KEY_BLOCK, size)
-    batch, length = positions.shape
     last = jnp.max(jnp.where(live, positions, -1))
     hi = jnp.minimum((last + block) // block, -(-size // block))  # blocks up to the last live query; 0 with none
     lo = 0
     if window is not None:
         first = jnp.min(jnp.where(live, positions, last))
         lo = jnp.minimum(jnp.maximum(first - window + 1, 0) // block, hi)
+    covered = jnp.maximum(jnp.minimum(hi * block, size) - lo * block, 0).astype(jnp.int32)
+    if chunk_read_path(keys, values) == CHUNK_KERNEL:
+        out = chunk_attention(q, keys, values, positions, live, scale=scale, window=window, value_width=value_width)
+        return out, covered
+
+    k_scale, v_scale = scales if scales is not None else (None, None)
     low = jnp.finfo(jnp.float32).min
+
+    def plane_block(plane: jax.Array, plane_scale: Optional[jax.Array], at: jax.Array) -> jax.Array:
+        rows = lax.dynamic_slice_in_dim(plane, at, block, axis=1)
+        if plane_scale is None:
+            return rows.astype(q.dtype)
+        return (rows.astype(jnp.float32) * lax.dynamic_slice_in_dim(plane_scale, at, block, axis=1)).astype(q.dtype)
 
     def step(i, carry):
         top, total, acc = carry
         start = i * block
         at = jnp.minimum(start, size - block)
-        blocks = [lax.dynamic_slice_in_dim(plane, at, block, axis=1) for plane in planes]
+        k = plane_block(keys, k_scale, at)
+        v = k[..., :value_width] if values is None else plane_block(values, v_scale, at)
         slots = at + jnp.arange(block)
-        visible = cache_visible(slots, positions, window) & (slots >= start)
-        scores = jnp.where(visible, score(*blocks), low)
+        visible = (cache_visible(slots, positions, window) & (slots >= start))[:, :, None]  # [B, 1, 1, L, block]
+        scores = jnp.einsum("bkgld,bskd->bkgls", q, k, preferred_element_type=jnp.float32) * scale
+        scores = jnp.where(visible, scores, low)
         new_top = jnp.maximum(top, scores.max(axis=-1, keepdims=True))
         weights = jnp.where(visible, jnp.exp(scores - new_top), 0.0)
         decay = jnp.exp(top - new_top)
         total = decay * total + weights.sum(axis=-1, keepdims=True)
-        acc = decay * acc + value(weights.astype(dtype), *blocks)
+        acc = decay * acc + jnp.einsum("bkgls,bskd->bkgld", weights.astype(q.dtype), v, preferred_element_type=jnp.float32)
         return new_top, total, acc
 
-    init = (
-        jnp.full((batch, heads, length, 1), low),
-        jnp.zeros((batch, heads, length, 1), jnp.float32),
-        jnp.zeros((batch, heads, length, width), jnp.float32),
-    )
+    width = value_width if values is None else values.shape[-1]
+    stats = (batch, n_kv, group, length, 1)
+    init = (jnp.full(stats, low), jnp.zeros(stats, jnp.float32), jnp.zeros((*stats[:-1], width), jnp.float32))
     _, total, acc = lax.fori_loop(lo, hi, step, init)
     out = acc / jnp.where(total > 0, total, 1.0)  # a query that saw no key kept a zero accumulator
-    covered = jnp.maximum(jnp.minimum(hi * block, size) - lo * block, 0)
-    return jnp.transpose(out, (0, 2, 1, 3)).astype(dtype), covered.astype(jnp.int32)
+    out = jnp.moveaxis(out, 3, 1).reshape(batch, length, n_kv * group, width)
+    return out.astype(q.dtype), covered
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "impl"))

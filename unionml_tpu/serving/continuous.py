@@ -2004,6 +2004,9 @@ class ContinuousBatcher:
                 # how it updates a recurrent state a slot: "state_kernel" or
                 # "state_xla" (None: not traced yet, or no slot state)
                 "decode_state_path": self.gen.decode_state_path,
+                # how a prefill chunk reads a row too long to read whole:
+                # "chunk_kernel" or "chunk_xla" (None: not traced yet, or every row is read whole)
+                "chunk_attention_path": self.gen.chunk_attention_path,
                 "rows_per_dispatch": round(
                     self.decoded_rows / self.decode_dispatches, 3
                 ) if self.decode_dispatches else None,
@@ -3431,6 +3434,7 @@ class ContinuousBatcher:
         self._carry = carry
         log.decode_attention_path = self.gen.decode_attention_path
         log.decode_state_path = self.gen.decode_state_path
+        log.chunk_attention_path = self.gen.chunk_attention_path
         # [S, chunk] tokens (the wait fences the dispatch), each one's f32 logprob, the done flags, a counting model's counts
         toks_np, lps_np, done_np, *counted = log.fetch(
             "decode", toks, lps, carry[3], *((carry[-1],) if self.gen.counter_names else ())
