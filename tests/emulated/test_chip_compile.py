@@ -22,7 +22,9 @@ from unionml_tpu.ops.chunk_attention import chunk_attention
 from unionml_tpu.ops.delta_rule import delta_rule_step_kernel
 from unionml_tpu.ops.flash_attention import flash_attention
 from unionml_tpu.ops.int8_matmul import int8_matmul
-from unionml_tpu.ops.paged_attention import paged_decode_attention, paged_window_decode_attention
+from unionml_tpu.ops.paged_attention import (
+    paged_decode_attention, paged_latent_decode_attention, paged_window_decode_attention,
+)
 
 REPO = Path(__file__).resolve().parents[2]
 
@@ -115,6 +117,15 @@ def _chunk_read(group, n_kv, length, row, widths, window=None):
     return read, args[:2] + ([((1, row, n_kv, valued), jnp.bfloat16)] if n_kv > 1 else []) + args[2:]
 
 
+def _latent_read(slots, heads, pages, pool):
+    """The one-token latent read at a cell's shape: ``slots`` rows of ``heads`` absorbed queries over a table of
+    ``pages`` latent pages of 64 x 640 in a pool of ``pool``."""
+    def latent(q, plane, lengths, table):
+        return paged_latent_decode_attention(q, plane, lengths, table, scale=0.0625, value_width=512)
+
+    return latent, [((slots, heads, 640), jnp.bfloat16), ((1, pool, 64, 640), jnp.bfloat16), ((slots,), jnp.int32), ((slots, pages), jnp.int32)]
+
+
 CASES = {
     "flash_fwd": _flash("fwd"),
     "flash_bwd": _flash("bwd"),
@@ -130,6 +141,8 @@ CASES = {
     "chunk_read_gqa7_4096_window": _chunk_read(7, 4, 4096, 16392, (128, 128), window=4096),
     "chunk_read_gqa6_256_window": _chunk_read(6, 8, 256, 5384, (128, 128), window=4096),
     "chunk_read_latent_2048": _chunk_read(20, 1, 2048, 8968, (640, 512)),
+    "latent_read_glm_48x141": _latent_read(48, 20, 141, 3585),
+    "latent_read_ling_192x56": _latent_read(192, 32, 56, 9409),
 }
 
 
@@ -190,6 +203,9 @@ def test_decode_steps_compiles_for_v5e_without_a_gathered_copy(chip, shape):
     text = compiled.as_text()
     assert gen.decode_attention_path == "paged_kernel"
     assert text.count("tpu_custom_call") == layers
+    # a grouped-query read keeps the library's kernel: the latent read is the latent plane's alone
+    assert len(re.findall(r"%paged_attention\S* = ", text)) == layers
+    assert not re.search(r"%latent_once_attention\S* = ", text)
     # left at two layers: the hoisted q/k/v projection transposes (96 MB) and a step's activations
     gathered = slots * pages * page * 32 * 128 * 2
     assert compiled.memory_analysis().temp_size_in_bytes < gathered
@@ -234,8 +250,8 @@ def _glm():
 def test_latent_decode_steps_compile_for_v5e_over_one_plane(chip):
     """The glm4_moe_lite decode program at published widths (a dense and two expert layers, 8 held of 64 experts) over
     the long_sat cell's paged latent cache — 48 slots, 140 pages a row, one plane of 640 (576 in whole lanes) a
-    layer, no ``"v"`` — through the kernel read (forced, as above): Mosaic takes the library kernel with the latent
-    pages as K and as V, one launch a layer; no pool is re-laid for it and no gathered copy of the rows' tables
+    layer, no ``"v"`` — through the kernel read (forced, as above): Mosaic takes the latent once read, one launch a
+    layer, and the library's kernel is nowhere; no pool is re-laid for it and no gathered copy of the rows' tables
     (``[48, 8960, 640]`` bf16) is left; the program counts its seven counters into the carry."""
     from unionml_tpu.models import GenerationConfig, Generator
     from unionml_tpu.models.generate import init_paged_cache
@@ -250,9 +266,10 @@ def test_latent_decode_steps_compile_for_v5e_over_one_plane(chip):
     counts = jax.ShapeDtypeStruct((7,), jnp.int32, sharding=chip)
     gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
     compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
-    assert gen.decode_attention_path == "latent_paged_kernel" and gen.counter_names[-3] == "latent_positions_read"
+    assert gen.decode_attention_path == "latent_once_kernel" and gen.counter_names[-3] == "latent_positions_read"
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 3  # one read a layer (the routed product is XLA's own here)
+    assert len(re.findall(r"%latent_once_attention\S* = ", text)) == 3 and not re.search(r"%paged_attention\S* = ", text)
     assert not re.search(rf"= bf16\[1,{pool},{page},640\]\S* copy\(", text)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < slots * pages * page * 640 * 2
@@ -475,8 +492,10 @@ def test_hybrid_decode_steps_compile_for_v5e_over_slot_state_and_latent_pages(ch
     counts = jax.ShapeDtypeStruct((10,), jnp.int32, sharding=chip)
     gen = Generator(module, params, GenerationConfig(max_new_tokens=64, temperature=0.0))
     compiled = gen._decode.lower(params, cache, tok, lengths, done, on_chip(lambda: jax.random.PRNGKey(0)), counts, steps=8).compile()
-    assert gen.decode_attention_path == "latent_paged_kernel" and gen.counter_names[-3:] == module.counters[-3:]
+    assert gen.decode_attention_path == "latent_once_kernel" and gen.counter_names[-3:] == module.counters[-3:]
     assert gen.decode_state_path == "state_kernel" and compiled.as_text().count("delta_rule_step") >= 2
+    text = compiled.as_text()
+    assert len(re.findall(r"%latent_once_attention\S* = ", text)) == 1 and not re.search(r"%paged_attention\S* = ", text)
     assert compiled.memory_analysis().temp_size_in_bytes < slots * 32 * 128 * 128 * 4
 
 

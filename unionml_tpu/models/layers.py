@@ -530,8 +530,10 @@ class LatentAttention(nn.Module):
     ``kv_rank + rope`` on one shared "KV head"; nothing is built). The uncached
     forward is expanded; every cached read is absorbed. One token (decode) over a
     paged pool goes where :func:`~unionml_tpu.ops.paged_attention.paged_read_path`
-    says — on a TPU the paged-attention kernel with the latent pages as K and as
-    V, else the gather, whole under a mask. Several tokens (a prefill chunk over
+    says — on a TPU the repo's latent read, which DMAs each live latent page once
+    and reads no page for a row with no live token
+    (:func:`~unionml_tpu.ops.paged_attention.paged_latent_decode_attention`), else
+    the gather, whole under a mask. Several tokens (a prefill chunk over
     the row cache, a verify, contiguous or gathered) over a long row
     (:func:`~unionml_tpu.ops.attention.walks_in_blocks`) walk its latent in key
     blocks up to the last live query's position
@@ -670,8 +672,9 @@ class LatentAttention(nn.Module):
                 cache = {"k": _paged_scatter_rows(cache["k"], stored, blk, off), "table": table}
                 q_abs = jnp.pad(absorb()[:, 0], ((0, 0), (0, 0), (0, width - self.kv_rank - self.rope_dim)))
                 with jax.named_scope("mla.decode_read"):
+                    # a row with no live token (a released slot) reads nothing
                     out = paged_latent_decode_attention(
-                        q_abs, cache["k"], lengths, table, scale=scale, value_width=self.kv_rank
+                        q_abs, cache["k"], jnp.where(live[:, 0], lengths, 0), table, scale=scale, value_width=self.kv_rank
                     )
                 return project(unabsorb(out[:, None])), cache
             cache = {"k": _paged_scatter(cache["k"], stored, blk, off), "table": table}

@@ -17,16 +17,24 @@ dispatch is zero-copy. The kernel is TPU-only (no interpret mode).
 :func:`paged_read_path` is the policy: on a TPU the kernel is the decode path
 wherever it can serve (measured on a v5e at Mistral-7B's widths, PERF.md
 section 6 "PR 25"); everything else keeps the gather.
+
+A latent plane (one "KV head" whose rows are both key and value) is read by
+this module's own Pallas kernel, :func:`paged_latent_decode_attention`: each
+live page DMA'd once, a row with no live token read not at all. The library's
+kernel would take the plane twice, as K and as V.
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import threading
 from typing import Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "GATHER",
@@ -46,10 +54,11 @@ __all__ = [
 PAGED_KERNEL = "paged_kernel"
 GATHER = "gather"
 #: the same two over a latent plane (one shared "KV head" whose key is the latent row and whose value is its head)
-LATENT_KERNEL = "latent_paged_kernel"
+LATENT_KERNEL = "latent_once_kernel"
 LATENT_GATHER = "latent_gather"
 
 _scope = threading.local()
+_LANES = 128
 
 
 @contextlib.contextmanager
@@ -127,7 +136,15 @@ def _pages_per_block(pages_per_sequence: int, page_size: int) -> int:
     fastest at 8 pages a block (4 and 5: +16 %, 16: +7 %), rows of ~2,700 in a
     table of 56 at 16 (8: +10 %, 14: +4 %, 28: no better). So: 512 positions a
     block where a row can hold at most 2,048, else 1,024. The table is widened
-    to a multiple (:func:`paged_decode_attention`), so any width is served."""
+    to a multiple (:func:`paged_decode_attention`), so any width is served.
+
+    The latent read (:func:`paged_latent_decode_attention`) takes the same rule,
+    measured on a v5e, kernel alone, at 1 latent head of 640 and pages of 64
+    (PERF.md section 6, "PR 43"): long_sat's 48 rows of a 141-page table, 16
+    live at ~4,000 positions, read in 0.164 / 0.139 / 0.136 ms at 8 / 16 / 32
+    pages a block; think_sat's 192 rows of a 56-page table at ~800 in 0.475-0.483
+    / 0.486-0.494 / 0.610. It fetches no page past a row's length, so a block is
+    a trade between steps and the positions computed past it."""
     positions = 512 if pages_per_sequence * page_size <= 2048 else 1024
     return max(1, min(positions // page_size, pages_per_sequence))
 
@@ -200,6 +217,151 @@ def _launch(q, k_pages, v_pages, lengths, page_indices, page_size: int, ppcb: in
     )
 
 
+#: bf16 terms the float32 softmax weights are split into before they meet the bf16 values: three hold a float32
+#: exactly (8 significant bits each), so the product is the float32 one
+WEIGHT_TERMS = 3
+
+
+def _bf16_terms(x: jax.Array, terms: int) -> List[jax.Array]:
+    """``x`` (float32) as ``terms`` bfloat16 arrays whose sum is ``x`` to ``8 x terms`` significant bits."""
+    out, rest = [], x
+    for _ in range(terms):
+        out.append(rest.astype(jnp.bfloat16))
+        rest = rest - out[-1].astype(jnp.float32)
+    return out
+
+
+def _latent_once_kernel(
+    lengths_ref, table_ref, q_hbm, pages_hbm, o_hbm,
+    q_buf, page_buf, o_buf, zeros, top_ref, total_ref, acc_ref, q_sem, page_sem, o_sem, z_sem,
+    *, scale, pages_per_block,
+):
+    """The whole batch's read in one grid step: the live rows in order, each row's pages in blocks of
+    ``pages_per_block`` through an online softmax, the next block's pages (the next live row's first block and its
+    query, at a row's last block) in flight while a block computes."""
+    batch, heads, width = q_hbm.shape
+    pages_per_row = table_ref.shape[0] // batch
+    page = page_buf.shape[2]
+    block = pages_per_block * page
+    value_width = o_buf.shape[-1]
+
+    def next_live(b):
+        """The first row after ``b`` with a live token; ``batch`` if there is none."""
+        return jax.lax.while_loop(
+            lambda r: (r < batch) & (lengths_ref[jnp.minimum(r, batch - 1)] == 0), lambda r: r + 1, b + 1
+        )
+
+    def page_copy(b, j, slot, i):
+        """Page ``i`` of row ``b``'s block ``j`` into ``slot``."""
+        index = table_ref[b * pages_per_row + j * pages_per_block + i]
+        return pltpu.make_async_copy(pages_hbm.at[0, index], page_buf.at[slot, i], page_sem.at[slot])
+
+    def each_page(b, j, slot, action):
+        """``action`` on the copy of each page of the block that holds a position below the row's length."""
+        held = jnp.minimum(pages_per_block, pl.cdiv(lengths_ref[b] - j * block, page))
+
+        def one(i, carry):
+            action(page_copy(b, j, slot, i))
+            return carry
+
+        jax.lax.fori_loop(0, held, one, 0)
+
+    def fetch(b, j, slot):
+        each_page(b, j, slot, lambda copy: copy.start())
+
+    def q_copy(b, slot):
+        return pltpu.make_async_copy(q_hbm.at[b], q_buf.at[slot], q_sem.at[slot])
+
+    def out_copy(b):
+        return pltpu.make_async_copy(o_buf, o_hbm.at[b], o_sem.at[0])
+
+    def zero_copy(b):
+        return pltpu.make_async_copy(zeros, o_hbm.at[b], z_sem.at[0])
+
+    def free_rows(action):
+        def each(b, carry):
+            pl.when(lengths_ref[b] == 0)(lambda: action(zero_copy(b)))
+            return carry
+
+        jax.lax.fori_loop(0, batch, each, 0)
+
+    # a block's pages past the row's end are not fetched: their slots keep an earlier block's pages, or these zeros
+    page_buf[...] = jnp.zeros(page_buf.shape, page_buf.dtype)
+    zeros[...] = jnp.zeros(zeros.shape, zeros.dtype)
+    free_rows(lambda copy: copy.start())
+    first = next_live(-1)
+
+    @pl.when(first < batch)
+    def _prefetch_first():
+        q_copy(first, 0).start()
+        fetch(first, 0, 0)
+
+    def row(carry):
+        b, walked, rows = carry
+        length = lengths_ref[b]
+        q_slot = rows % 2
+        q_copy(b, q_slot).wait()
+        q = q_buf[q_slot]  # [H, W]
+        q_terms = [q] if q.dtype == page_buf.dtype else _bf16_terms(q.astype(jnp.float32), WEIGHT_TERMS)
+        after = next_live(b)
+        blocks = pl.cdiv(length, block)
+        top_ref[...] = jnp.full(top_ref.shape, -jnp.inf, jnp.float32)
+        total_ref[...] = jnp.zeros(total_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+        def step(j, walked):
+            slot = walked % 2
+
+            @pl.when(j + 1 < blocks)
+            def _prefetch_next_block():
+                fetch(b, j + 1, 1 - slot)
+
+            @pl.when((j + 1 == blocks) & (after < batch))
+            def _prefetch_next_row():
+                q_copy(after, 1 - q_slot).start()
+                fetch(after, 0, 1 - slot)
+
+            each_page(b, j, slot, lambda copy: copy.wait())
+            keys = page_buf[slot].reshape(block, width)
+            scores = sum(
+                jax.lax.dot_general(t, keys, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+                for t in q_terms
+            ) * scale
+            seen = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1) < length
+            scores = jnp.where(seen, scores, -jnp.inf)
+            top = top_ref[:, :1]
+            new_top = jnp.maximum(top, scores.max(axis=-1, keepdims=True))  # finite: a block holds a live position
+            weights = jnp.exp(scores - new_top)
+            decay = jnp.exp(top - new_top)
+            values = keys[:, :value_width]
+            total_ref[...] = jnp.broadcast_to(decay * total_ref[:, :1] + weights.sum(axis=-1, keepdims=True), total_ref.shape)
+            acc_ref[...] = decay * acc_ref[...] + sum(
+                jax.lax.dot_general(t, values, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                for t in _bf16_terms(weights, WEIGHT_TERMS)
+            )
+            top_ref[...] = jnp.broadcast_to(new_top, top_ref.shape)
+            return walked + 1
+
+        walked = jax.lax.fori_loop(0, blocks, step, walked)
+
+        @pl.when(rows > 0)
+        def _previous_row_written():
+            out_copy(0).wait()
+
+        o_buf[...] = (acc_ref[...] / total_ref[:, :1]).astype(o_buf.dtype)
+        out_copy(b).start()
+        return after, walked, rows + 1
+
+    _, _, rows = jax.lax.while_loop(lambda carry: carry[0] < batch, row, (first, 0, 0))
+
+    @pl.when(rows > 0)
+    def _last_row_written():
+        out_copy(0).wait()
+
+    free_rows(lambda copy: copy.wait())
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "value_width", "pages_per_compute_block", "interpret"))
 def paged_latent_decode_attention(
     q_abs: jax.Array,
     pages: jax.Array,
@@ -209,30 +371,78 @@ def paged_latent_decode_attention(
     scale: float,
     value_width: int,
     pages_per_compute_block: Optional[int] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """One decode step of ABSORBED latent attention over paged latent rows.
 
     ``q_abs: [B, H, W]`` (each head's query in the latent's space, zeros past
     the latent's real width), ``pages: [1, n_pages, page_size, W]`` (one plane:
     the key of every head is the whole row, the value its first ``value_width``
-    channels), ``lengths``/``page_indices`` as :func:`paged_decode_attention`.
-    Returns ``[B, H, value_width]``.
+    channels), ``lengths: [B] int32`` (visible positions a row, the token just
+    written included; 0 for a row with no live token), ``page_indices: [B,
+    pages_per_sequence] int32``. Returns ``[B, H, value_width]`` in
+    ``q_abs.dtype``.
 
-    The library's paged-attention kernel launched with the plane as K and as V:
-    one KV head, ``H`` query heads in its group, ``q`` pre-scaled by ``scale``
-    (the kernel applies none; the latent's softmax scale is the expanded head's,
-    not ``W ** -0.5``), the output's first ``value_width`` channels kept. Each
-    live page is DMA'd twice (once as K, once as V), at the stored width: 2 x W
-    values a position against the latent's ``value_width + rope``; a kernel that
-    reads a page once would halve it (PERF.md section 7). ``W`` must be whole
-    lanes (Mosaic refuses a 576-wide block)."""
+    One Pallas call for the whole batch, one grid step: each live page is
+    DMA'd once, at the stored width, into VMEM, where it is both the key (scored
+    over all ``W`` channels against the query; the tail past the latent is
+    zeros on both sides) and the value (the same tile's first ``value_width``
+    channels). A row walks its pages in blocks of ``pages_per_compute_block``
+    pages (:func:`_pages_per_block`) through an online softmax; its last
+    block fetches only the pages up to its length, and the next live row's
+    first block and query are in flight while a row's last block computes. A
+    row of length 0 is fetched and computed not at all and yields zeros. The
+    numbers are the float32 read's: bf16 operands meet on the MXU with float32
+    accumulation, the scores ``q . k`` (a bf16 query is exact; a float32 one is
+    split into :data:`WEIGHT_TERMS` bf16 terms) times ``scale`` in float32, the
+    float32 weights split into :data:`WEIGHT_TERMS` bf16 terms against the
+    values. ``W`` must be whole lanes (Mosaic refuses a 576-wide block).
+    ``interpret``: run the kernel in Pallas' TPU interpret mode (the tests on
+    the CPU). Jitted, as the library's kernel is: a model's layers trace and
+    lower the kernel once, not once a layer (24 lowerings cost GLM's decode
+    program ~28 s of set-up on a v5e host)."""
+    batch, heads, width = q_abs.shape
     page_size = pages.shape[2]
-    # 512 positions a compute block whatever the table's width: measured on a v5e at the long_sat cell's shape (48
-    # rows x 140 pages of 64 x 640, 24 live at 3,920 and 24 free): 4 pages 0.476 ms, 8 0.431, 16 0.475 (PERF.md
-    # section 6, "PR 30"); a free row streams one block whole, and a 640-wide block is five times a 128-wide one
-    ppcb = pages_per_compute_block or max(1, min(512 // page_size, _pages_per_block(page_indices.shape[1], page_size)))
-    out = _launch(q_abs.astype(jnp.float32) * scale, pages, pages, lengths, page_indices, page_size, ppcb)
-    return out[..., :value_width].astype(q_abs.dtype)
+    pages_per_row = page_indices.shape[1]
+    ppcb = pages_per_compute_block or _pages_per_block(pages_per_row, page_size)
+    # a row's heads are whole sublane tiles (20 -> 32 bf16): Mosaic slices and copies a row's [H, W] no other way
+    tile = 32 // jnp.dtype(q_abs.dtype).itemsize
+    head_rows = -(-heads // tile) * tile
+    kernel = functools.partial(_latent_once_kernel, scale=scale, pages_per_block=ppcb)
+    anywhere = pl.BlockSpec(memory_space=pl.ANY)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # lengths, the flattened table
+            grid=(1,),
+            in_specs=[anywhere, anywhere],
+            out_specs=anywhere,
+            scratch_shapes=[
+                pltpu.VMEM((2, head_rows, width), q_abs.dtype),
+                pltpu.VMEM((2, ppcb, page_size, width), pages.dtype),
+                pltpu.VMEM((head_rows, value_width), q_abs.dtype),
+                pltpu.VMEM((head_rows, value_width), q_abs.dtype),
+                pltpu.VMEM((head_rows, _LANES), jnp.float32),
+                pltpu.VMEM((head_rows, _LANES), jnp.float32),
+                pltpu.VMEM((head_rows, value_width), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((1,)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((batch, head_rows, value_width), q_abs.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
+        name="latent_once_attention",
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(
+        # a length past the table's end would send the DMAs off the row's pages
+        jnp.minimum(lengths.astype(jnp.int32), pages_per_row * page_size),
+        page_indices.astype(jnp.int32).reshape(-1),
+        jnp.pad(q_abs, ((0, 0), (0, head_rows - heads), (0, 0))),
+        pages,
+    )
+    return out[:, :heads]
 
 
 def window_split(lengths: jax.Array, window: int, page_size: int):
@@ -261,10 +471,6 @@ def _paged_attention_stats(q, k_pages, v_pages, lengths, page_indices, *, pages_
     ``q.k`` logits and ``l: [B, H, 1]`` the sum of ``exp(logit - m)`` — what a
     caller needs to merge the read with keys the kernel was not shown. A row of
     length 0 reads nothing: ``o = 0``, ``l = 0``, ``m = -inf``."""
-    import functools
-
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
     from jax.experimental.pallas.ops.tpu.paged_attention import paged_attention_kernel as lib
 
     batch, n_heads, head_dim = q.shape
